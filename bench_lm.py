@@ -434,8 +434,8 @@ def main(argv: list[str] | None = None) -> int:
     if not args.real:
         import jax
 
-        from distributedtensorflowexample_tpu.compat import (
-            cpu_collective_flags, set_num_cpu_devices)
+        from distributedtensorflowexample_tpu.runtime import (
+            cpu_collective_flags)
         if "collective_call_terminate" not in os.environ.get("XLA_FLAGS",
                                                              ""):
             os.environ["XLA_FLAGS"] = (
@@ -449,7 +449,7 @@ def main(argv: list[str] | None = None) -> int:
                 break
         else:
             try:
-                set_num_cpu_devices(args.devices)
+                jax.config.update("jax_num_cpu_devices", args.devices)
             except RuntimeError:
                 pass
     else:

@@ -150,7 +150,10 @@ def _supervised_headline(args, snapshot: str, workdir: str) -> dict:
             "--drive", str(args.requests),
             "--clients", str(args.clients_sweep[-1]),
             "--drive_max_new", str(args.max_new),
-            "--seed", str(args.seed), "--stats", stats_path]
+            "--seed", str(args.seed), "--stats", stats_path,
+            # The worker initializes the demo snapshot itself: this
+            # parent has not touched jax yet (one process per chip).
+            "--init_if_missing"]
     if args.real:
         argv.append("--real")
     res = Supervisor(heartbeat_timeout_s=180.0).run(
@@ -225,25 +228,14 @@ def main(argv: list[str] | None = None) -> int:
                 flags + " --xla_force_host_platform_device_count="
                 f"{args.host_devices}").strip()
 
-    import jax
-    if not args.real:
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            pass
-
     from distributedtensorflowexample_tpu.obs import ledger as obs_ledger
     from distributedtensorflowexample_tpu.obs import (
         recorder as obs_recorder)
     from distributedtensorflowexample_tpu.obs import serve as obs_serve
     from distributedtensorflowexample_tpu.obs.anomaly import (
         spread_fraction)
-    from distributedtensorflowexample_tpu.serving.engine import (
-        DecodeEngine)
     from distributedtensorflowexample_tpu.serving.loadgen import (
         load_requests_default)
-    from distributedtensorflowexample_tpu.serving.promote import (
-        init_lm_snapshot, promote)
 
     obs_recorder.maybe_install()
     obs_ledger.maybe_begin("bench_serving", config=vars(args))
@@ -255,21 +247,17 @@ def main(argv: list[str] | None = None) -> int:
     # which turns the headline into a heartbeat-fed hang.
     requests = args.requests = (
         args.requests or max(128, load_requests_default() * 8))
-    platform = jax.default_backend()
     size = args.size
     lines: list = []
     errors: dict = {}
-
-    from distributedtensorflowexample_tpu.resilience.snapshot import (
-        SnapshotStore)
-    if SnapshotStore(snapshot).latest_valid() is None:
-        init_lm_snapshot(snapshot, size, seed=args.seed)
-
-    shared = {"platform": platform, "size": size, "slots": args.slots,
+    shared = {"size": size, "slots": args.slots,
               "max_len": args.max_len, "max_new": args.max_new,
               "requests": requests}
 
     # 1. supervised end-to-end headline -----------------------------------
+    # FIRST, while this parent has not initialized a jax backend: a chip
+    # belongs to one process at a time, and the serve_lm child needs it.
+    # The parent touches jax only after the child has exited (below).
     if not args.skip_supervised:
         try:
             sup_runs = [
@@ -282,7 +270,8 @@ def main(argv: list[str] | None = None) -> int:
             if st and st.get("tokens_per_sec"):
                 _emit(f"serve_{size}_supervised_tokens_per_sec",
                       st["tokens_per_sec"], "tokens/sec",
-                      {**shared, "supervised": True,
+                      {**shared, "platform": st.get("platform"),
+                       "supervised": True,
                        "clients": args.clients_sweep[-1],
                        "repeats": rates,
                        "spread_frac": round(spread_fraction(rates), 4),
@@ -308,6 +297,21 @@ def main(argv: list[str] | None = None) -> int:
             traceback.print_exc()
 
     # 2 + 3. in-process sweeps (one engine, one compile set) --------------
+    import jax
+    if not args.real:
+        try:
+            jax.config.update("jax_platforms", "cpu")
+        except RuntimeError:
+            pass
+    from distributedtensorflowexample_tpu.resilience.snapshot import (
+        SnapshotStore)
+    from distributedtensorflowexample_tpu.serving.engine import (
+        DecodeEngine)
+    from distributedtensorflowexample_tpu.serving.promote import (
+        init_lm_snapshot, promote)
+    if SnapshotStore(snapshot).latest_valid() is None:
+        init_lm_snapshot(snapshot, size, seed=args.seed)
+    platform = shared["platform"] = jax.default_backend()
     pm = engine = None
     try:
         pm = promote(snapshot, size)

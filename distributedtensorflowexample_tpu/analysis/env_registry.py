@@ -69,6 +69,10 @@ ENV_REGISTRY: dict[str, str] = {
         "stub instead of gang rollback — the actuator writes an "
         "advisory file a future trainer LR hook consumes "
         "(resilience/remediate.py)."),
+    "JAX_COMPILATION_CACHE_DIR": (
+        "Where jax keeps its persistent compile cache.  Set: jax reads "
+        "it itself and the code sets no directory; unset: the cache is "
+        "<checkout>/.jax_cache (runtime.enable_compilation_cache)."),
     "OBS_ANOMALY_SKIP": (
         "Steps ignored at window start before the anomaly baseline "
         "arms (obs/anomaly.py; default 1 — the compile step)."),
@@ -198,7 +202,4 @@ ENV_REGISTRY: dict[str, str] = {
         "Reference-compatible cluster topology JSON; parsed for "
         "process count/index compatibility, topology itself is "
         "jax.distributed's job (cluster.py)."),
-    "XLA_FLAGS": (
-        "XLA backend flags; compat.py appends version-gated CPU "
-        "collective rendezvous flags in-process (read + write)."),
 }

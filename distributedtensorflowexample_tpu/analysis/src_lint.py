@@ -690,8 +690,6 @@ WIRING_NAMES = frozenset({
 #: is that file's JOB, not a missed port).  Anything else that needs an
 #: escape goes through the waiver budget and therefore ratchets.
 WIRING_ALLOWLIST = {
-    "distributedtensorflowexample_tpu/compat.py":
-        "defines the shard_map version shim the ban protects",
     "distributedtensorflowexample_tpu/ops/pallas/sgd.py":
         "fused-optimizer kernel launch idiom — per-device pallas "
         "dispatch under shard_map, not trainer wiring",

@@ -107,9 +107,7 @@ def maybe_initialize_distributed(info: ClusterInfo) -> None:
     Idempotent: a second trainer run in the same process (tests, notebooks,
     back-to-back ``main()`` calls) must reuse the live runtime — a repeat
     ``initialize`` raises once the XLA backend exists."""
-    from distributedtensorflowexample_tpu.compat import (
-        distributed_is_initialized)
-    if info.is_distributed and not distributed_is_initialized():
+    if info.is_distributed and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=info.coordinator_address,
             num_processes=info.num_processes,

@@ -7,10 +7,21 @@ float32 min tile is 8 sublanes x 128 lanes).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 LANES = 128      # last-dim tile width, all dtypes
 SUBLANES = 8     # float32 second-to-last-dim tile
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means: the Pallas interpreter on the ``cpu``
+    platform (so CPU tests run the identical kernel code), the compiled
+    Mosaic kernel everywhere else.  A chip run is never interpreted
+    unless the caller says so in as many words."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
 
 
 def pick_block(rows: int, max_block: int) -> int:

@@ -261,8 +261,7 @@ def _build_shard_map_step(num_workers: int, period: int,
                     correct / total)
 
         wspec = P(DATA_AXIS)
-        from distributedtensorflowexample_tpu.compat import shard_map
-        body = shard_map(
+        body = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(), wspec, wspec, wspec, wspec, wspec, wspec),
             out_specs=(wspec, wspec, wspec, P(), P()), check_vma=False)

@@ -238,7 +238,6 @@ def build_bucketed_step_fn(label_smoothing: float, ce_impl: str, mesh,
     shard_map twin of ``sync._build_step_fn`` (see module docstring for
     the two modes and the parity contract).  The caller jits it with the
     same donation the plain body gets."""
-    from distributedtensorflowexample_tpu.compat import shard_map
     from distributedtensorflowexample_tpu.parallel.sync import make_loss_rows
     from jax.sharding import PartitionSpec as P
 
@@ -361,7 +360,7 @@ def build_bucketed_step_fn(label_smoothing: float, ce_impl: str, mesh,
             # LM shard; == global_b for [b] image labels).
             return new_params, new_opt, loss, correct / (lab.size * D)
 
-        body_m = shard_map(
+        body_m = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), pspec, ospec, wspec, wspec),
             out_specs=(pspec, ospec, P(), P()), check_vma=False)

@@ -237,7 +237,6 @@ def build_zero3_step_fn(label_smoothing: float, ce_impl: str, mesh,
     ``opt_state`` the matching ``init_bucketed_opt_state`` rows); the
     caller jits it with the same donation the other bodies get.  See
     the module docstring for the schedule and the parity contract."""
-    from distributedtensorflowexample_tpu.compat import shard_map
     from distributedtensorflowexample_tpu.parallel.sync import make_loss_rows
     from jax.sharding import PartitionSpec as P
 
@@ -343,7 +342,7 @@ def build_zero3_step_fn(label_smoothing: float, ce_impl: str, mesh,
             return (tuple(new_rows), tuple(new_opt), loss,
                     correct / (lab.size * D))
 
-        body_m = shard_map(
+        body_m = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), pspec, ospec, wspec, wspec),
             out_specs=(pspec, ospec, P(), P()), check_vma=False)

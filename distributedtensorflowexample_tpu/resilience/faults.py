@@ -1,6 +1,6 @@
 """Deterministic, seed-addressable fault injection for the train loop.
 
-Every fault the rounds-3-5 outage (OUTAGE_r05.md) and the round-2/3
+Every fault the rounds-3-5 outage and the round-2/3
 postmortems actually produced, reproducible on CPU at will:
 
 ==================  =====================================================
@@ -374,7 +374,7 @@ class FaultInjectionHook(Hook):
             if s.kind == "slow_rank":
                 self._slow_s += s.arg
             elif s.kind == "wedge":
-                # Blocks without raising — exactly what a dead tunnel
+                # Blocks without raising — exactly what a dead backend
                 # does to a jit call.  The heartbeat goes stale; only an
                 # external watchdog (resilience.supervisor) can act.
                 time.sleep(s.arg)

@@ -7,6 +7,11 @@ and TensorFlow (arXiv:1605.08695) both treat as a CLUSTER-level event:
 detect, tear down the gang, restart from a mutually consistent
 checkpoint.  This module is that layer.
 
+A gang on ONE host is a CPU drill: a chip belongs to one process at a
+time (one process drives all the devices of a host), so N jax ranks
+side by side can only share the CPU platform.  Across hosts each rank
+is the one process of its host.
+
 State machine (one "gang attempt" = one co-scheduled launch of all
 surviving ranks)::
 
@@ -1204,7 +1209,7 @@ class FleetSupervisor:
                     if outcome == "wedged":
                         # The backend is provably gone under EVERY rank
                         # of this gang; relaunching N processes against
-                        # a dead tunnel resolves nothing (supervisor
+                        # a dead backend resolves nothing (supervisor
                         # rc=3 contract).
                         attrs["status"] = "wedged"
                         return GangResult("wedged", attempt + 1, restarts,

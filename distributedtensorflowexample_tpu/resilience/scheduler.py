@@ -893,7 +893,7 @@ class Scheduler:
             st.width = 0
             st.why_last = ("a rank reported the backend provably "
                            "wedged (rc 3) — requeueing would burn the "
-                           "window against a dead tunnel")
+                           "window against a dead backend")
             self._applied(seq, "quarantine", job.job, rcs=rcs,
                           why=st.why_last)
             _log(f"{job.job}: QUARANTINED (rc 3)")

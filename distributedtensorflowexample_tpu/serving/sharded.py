@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributedtensorflowexample_tpu.compat import shard_map
 from distributedtensorflowexample_tpu.models.transformer_lm import (
     TransformerLM)
 from distributedtensorflowexample_tpu.parallel.bucketing import (
@@ -182,13 +181,13 @@ class ShardedDecodeEngine:
         P_ = jax.sharding.PartitionSpec
         pspec = jax.tree.map(lambda _: P_(DATA_AXIS), self.rows)
         cspec = P_(None, DATA_AXIS)
-        self._decode_fn = shard_map(
+        self._decode_fn = jax.shard_map(
             _decode_body, mesh=self.mesh,
             in_specs=(pspec, cspec, cspec, P_(DATA_AXIS), P_(DATA_AXIS)),
             out_specs=(P_(DATA_AXIS), cspec, cspec), check_vma=False)
         self._decode_jit = jax.jit(self._decode_fn,
                                    donate_argnums=(1, 2))
-        self._prefill_jit = jax.jit(shard_map(
+        self._prefill_jit = jax.jit(jax.shard_map(
             _prefill_body, mesh=self.mesh,
             in_specs=(pspec, cspec, cspec, P_(), P_(), P_()),
             out_specs=(P_(), cspec, cspec), check_vma=False),

@@ -35,7 +35,10 @@ from distributedtensorflowexample_tpu.engine import Engine, RunSpec
 from distributedtensorflowexample_tpu.models import LM_SIZES
 
 
-def main(argv=None) -> dict:
+def build_spec(argv=None) -> RunSpec:
+    """The declaration ``main`` runs, resolved from ``argv`` — also what
+    a caller hands ``Engine(...).build()`` to get this trainer's state
+    and step without the hook stack."""
     sp = argparse.ArgumentParser(add_help=False)
     sp.add_argument("--size", default="lm_tiny", choices=sorted(LM_SIZES))
     ns, rest = sp.parse_known_args(argv)
@@ -51,11 +54,21 @@ def main(argv=None) -> dict:
         # unchanged math.  Both are parity-safe knobs; --shard_update
         # stays opt-in because it changes the checkpoint's
         # optimizer-state layout (a resume contract, not just a
-        # schedule).  Explicit flags still win — these are argparse
-        # defaults.
-        overrides.update(remat="block", bucket_grads="auto")
+        # schedule).  The learning rate is the chip's verdict (PR 21,
+        # one v5e, 256 steps each): the ladder's 0.1 with momentum 0.9
+        # and no warmup is too hot at this depth and width — loss 5.67,
+        # 5.46, 4.82 at steps 16-48, then 7.85 and worse (the CPU
+        # oscillates the same way) — while 0.03 fell to the corpus's
+        # ~1.3-nat floor by step 128.  Explicit flags still win — these
+        # are argparse defaults.
+        overrides.update(remat="block", bucket_grads="auto",
+                         learning_rate=0.03)
     cfg = parse_flags(rest, description=__doc__, **overrides)
-    return Engine(RunSpec(model=ns.size, dataset="lm", config=cfg)).run()
+    return RunSpec(model=ns.size, dataset="lm", config=cfg)
+
+
+def main(argv=None) -> dict:
+    return Engine(build_spec(argv)).run()
 
 
 if __name__ == "__main__":

@@ -335,14 +335,20 @@ def test_pallas_fused_gather_dequant_parity(spec, shape):
     _bitwise_equal(out, ref)
 
 
-def test_pallas_gather_path_matches_affine_gather():
+@pytest.mark.parametrize("mesh_size", [0, 4])
+def test_pallas_gather_path_matches_affine_gather(mesh_size):
     """dequant_impl='pallas' through make_device_gather == the unfused
-    affine gather, bitwise, labels included."""
+    affine gather, bitwise, labels included — on one device and on a
+    mesh, where the kernel runs per device under shard_map (XLA:TPU
+    refuses to partition a Mosaic call; the CPU interpreter never
+    minded, so only the wrapped form is what the chip runs)."""
+    from distributedtensorflowexample_tpu.parallel import make_mesh
+    mesh = make_mesh(mesh_size) if mesh_size else None
     x, y = _data()
     outs = {}
     for impl in ("affine", "pallas"):
-        ds = DeviceDataset(x, y, 32, seed=4, dequant_impl=impl)
-        g = make_device_gather(32, ds.steps_per_epoch,
+        ds = DeviceDataset(x, y, 32, mesh=mesh, seed=4, dequant_impl=impl)
+        g = make_device_gather(32, ds.steps_per_epoch, mesh=mesh,
                                num_slots=ds.num_slots, dequant_impl=impl)
         outs[impl] = jax.jit(g)(jnp.asarray(1, jnp.int32),
                                 jax.random.PRNGKey(2), ds.peek())

@@ -83,7 +83,7 @@ def _capture_tasks(start_ts: float,
     # covers both regions; `python -m tools.graftlint --fix` re-stamps
     # after a deliberate re-sync).  tests/test_resilience.py::
     # test_supervise_capture_queue_shape pins this queue's shape.
-    # KEEP-IN-SYNC(capture-phases) digest=1921cee5f541
+    # KEEP-IN-SYNC(capture-phases) digest=705886ff9619
     env = os.environ
     py = sys.executable
     log = env.get("LOG", "/tmp/bench_capture.log")
