@@ -85,19 +85,24 @@ class DecoderBlock(nn.Module):
         q = q.reshape(B, T, self.n_heads, Dh)
         k = k.reshape(B, T, self.n_heads, Dh)
         v = v.reshape(B, T, self.n_heads, Dh)
-        scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
-            Dh ** 0.5, self.dtype)
-        # Causal mask: position t attends to s <= t.  Built from iota at
-        # trace time — no resident [T, T] constant in HBM.
-        causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
-        scores = jnp.where(causal[None, None], scores,
-                           jnp.asarray(-1e9, scores.dtype))
-        # Softmax in f32: bf16 exp/normalize is where logit noise turns
-        # into loss noise; the [B,H,T,T] f32 probs are exactly the
-        # activation bytes remat="block" exists to not keep resident.
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        probs = probs.astype(self.dtype)
-        att = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, T, -1)
+        # "attn" names the score / softmax / weighted-sum operations in
+        # the device trace (flax scopes each Dense and LayerNorm by its
+        # module name already); metadata only.
+        with jax.named_scope("attn"):
+            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
+                Dh ** 0.5, self.dtype)
+            # Causal mask: position t attends to s <= t.  Built from iota
+            # at trace time — no resident [T, T] constant in HBM.
+            causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
+            scores = jnp.where(causal[None, None], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+            # Softmax in f32: bf16 exp/normalize is where logit noise
+            # turns into loss noise; the [B,H,T,T] f32 probs are exactly
+            # the activation bytes remat="block" exists to not keep
+            # resident.
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            probs = probs.astype(self.dtype)
+            att = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, T, -1)
         att = nn.Dense(self.d_model, dtype=self.dtype, name="attn_out")(att)
         att = nn.Dropout(self.dropout_rate,
                          deterministic=not train)(att)
@@ -161,7 +166,8 @@ class TransformerLM(nn.Module):
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         # Weight-tied head: logits = x @ E^T (flax attend), f32 at the
         # boundary like every other model's logits.
-        logits = embed.attend(x).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = embed.attend(x).astype(jnp.float32)
         return logits + jnp.where(oov, jnp.float32(jnp.nan),
                                   jnp.float32(0.0))
 

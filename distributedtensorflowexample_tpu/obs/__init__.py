@@ -15,7 +15,10 @@ Four cooperating pieces, each usable alone:
   guarded below 2 us (tests/test_obs.py).
 - :mod:`.trace` — nestable span API (``with span("dispatch"): ...``)
   emitting JSONL trace events with step/attempt/phase context picked up
-  from the supervisor's env (``SUPERVISE_ATTEMPT``, ``OBS_PHASE``).
+  from the supervisor's env (``SUPERVISE_ATTEMPT``, ``OBS_PHASE``), and
+  under it the always-on hot-path tape: ``hot_span`` for per-boundary
+  use (a ring record plus a profiler annotation, about a microsecond),
+  ``tape()`` to read it.
 - :mod:`.recorder` — bounded in-memory flight recorder (ring of recent
   spans, metric deltas, and the loss-tape tail) that dumps atomically
   to ``flight_<pid>.json`` on SIGTERM / NaN-guard trip / supervisor
@@ -59,4 +62,4 @@ from distributedtensorflowexample_tpu.obs.metrics import (  # noqa: F401
 from distributedtensorflowexample_tpu.obs.recorder import (  # noqa: F401
     FlightRecorder, dump_global, flight_path, install, maybe_install)
 from distributedtensorflowexample_tpu.obs.trace import (  # noqa: F401
-    add_sink, event, remove_sink, span)
+    add_sink, event, hot_span, remove_sink, span, tape, tape_dropped)

@@ -25,18 +25,36 @@ One event per closed span::
 
 Sinks: every event goes to each registered sink (the flight recorder
 registers itself on install) and, when ``OBS_TRACE_FILE`` names a path,
-is appended there as one JSON line.  Span close is NOT a hot path —
-spans wrap phases, snapshot writes, and log-boundary windows, never the
-per-step dispatch — so the per-event env lookups and the append-open
-are deliberate simplicity, not an oversight.  Sink exceptions are
-swallowed: telemetry must never kill the run it observes.
+is appended there as one JSON line.  ``span()``/``event()`` close is
+NOT a hot path — they wrap phases, snapshot writes, log-boundary
+windows and a request's whole life, never the per-step dispatch — so
+the per-event env lookups and the append-open are deliberate
+simplicity, not an oversight.  Sink exceptions are swallowed: telemetry
+must never kill the run it observes.
+
+The hot path is :func:`hot_span`, for what happens at every boundary of
+a serving loop (admit, dispatch, read-back, retire).  It does two
+things only.  (1) It appends ``(name, t0, t1, parent, rid)`` —
+``_metrics._now()`` stamps, the enclosing span's name, the request id
+where the span belongs to one request — to :func:`tape`, a bounded
+in-memory ring that every ``span()`` and ``event()`` lands on too;
+:func:`tape_dropped` counts what fell off its end.  (2) It holds a
+``jax.profiler.TraceAnnotation("dtf:" + name)`` open, so that a
+profiler session sees the same span on the device trace's clock.  jax
+is looked up through ``sys.modules`` — importing obs still never
+imports it, and a process that has not imported jax has no device to
+annotate.  Always on: there is no switch.  The guard is in
+tests/test_obs.py (under 5 us a span; it measures about 1).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 
 from distributedtensorflowexample_tpu.obs import metrics as _metrics
@@ -45,6 +63,14 @@ _tls = threading.local()
 _sinks: list = []
 _SPAN_SECONDS = _metrics.histogram(
     "span_seconds", "wall seconds per closed trace span")
+
+#: Entries the in-memory tape keeps (about 7 MB when full; a serving
+#: loop at a dozen boundaries a second writes ~150 a second).
+TAPE_LEN = 65536
+_tape: collections.deque = collections.deque(maxlen=TAPE_LEN)
+_seq = itertools.count()    # each entry's number: next() is one C call,
+#                             so threads never hand out one number twice
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
 
 
 def add_sink(sink) -> None:
@@ -63,6 +89,80 @@ def _stack() -> list:
     if stack is None:
         stack = _tls.stack = []
     return stack
+
+
+def record(name: str, t0: float, t1: float, parent: str | None = None,
+           rid: str | None = None) -> None:
+    """Append one closed span to the tape: the primitive every span of
+    this module ends in."""
+    _tape.append((name, t0, t1, parent, rid, next(_seq)))
+
+
+def tape() -> list:
+    """The newest :data:`TAPE_LEN` closed spans, oldest first, as
+    ``(name, t0, t1, parent, rid)`` — monotonic seconds (``_now``), in
+    order of closing."""
+    return [e[:5] for e in list(_tape)]
+
+
+def tape_dropped() -> int:
+    """How many entries the ring has lost off its end: the entries ever
+    numbered less those it holds (exact once no ``record`` is in
+    flight on another thread)."""
+    kept = list(_tape)
+    return max(e[5] for e in kept) + 1 - len(kept) if kept else 0
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is already imported, else
+    None (and then there is no profiler session to annotate)."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class hot_span:
+    """``with hot_span("engine.decode.dispatch"): ...`` — the
+    per-boundary span: the tape record and the profiler annotation,
+    nothing else (no context, no histogram, no sinks, no file)."""
+
+    __slots__ = ("name", "rid", "_stack", "_t0", "_ann", "_keep")
+
+    def __init__(self, name: str, rid: str | None = None):
+        self.name = name
+        self.rid = rid
+        self._keep = True
+
+    def cancel(self) -> None:
+        """Leave this span off the tape: for a boundary that turned out
+        to hold no work (a serving loop polls an empty queue a hundred
+        times a second, and the ring is for its busy periods)."""
+        self._keep = False
+
+    def __enter__(self):
+        cls = _annotation or _trace_annotation()
+        self._ann = None
+        if cls is not None:
+            self._ann = cls("dtf:" + self.name)
+            self._ann.__enter__()
+        self._stack = _stack()
+        self._stack.append(self.name)
+        self._t0 = _metrics._now()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = _metrics._now()
+        stack = self._stack
+        stack.pop()
+        if self._keep:
+            record(self.name, self._t0, t1, stack[-1] if stack else None,
+                   self.rid)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 def _context() -> dict:
@@ -98,6 +198,8 @@ def event(name: str, dur_s: float, t0_s: float | None = None,
     now = _metrics._now()
     if t0_s is None:
         t0_s = now - dur_s
+    record(name, t0_s, t0_s + dur_s, stack[-1] if stack else None,
+           attrs.get("rid"))
     rec = {"name": name,
            "t0_s": round(t0_s, 6),
            # The same open instant on the wall clock: wall-now minus the

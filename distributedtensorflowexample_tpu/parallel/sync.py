@@ -451,7 +451,8 @@ def _build_step_fn(label_smoothing: float = 0.0, ce_impl: str = "xla",
                 logits = state.apply_fn(variables, batch["image"], train=True,
                                         rngs={"dropout": step_rng})
                 new_stats = state.batch_stats
-            loss = compute_loss(logits, batch["label"], state.step)
+            with jax.named_scope("loss"):
+                loss = compute_loss(logits, batch["label"], state.step)
             return loss, (logits, new_stats)
 
         (loss, (logits, new_stats)), grads = jax.value_and_grad(

@@ -310,7 +310,8 @@ def build_zero3_step_fn(label_smoothing: float, ce_impl: str, mesh,
                 logits = state.apply_fn(
                     {"params": params}, img, train=True,
                     rngs={"dropout": jax.random.fold_in(step_rng, d)})
-                rows = loss_rows(logits, lab)
+                with jax.named_scope("loss"):
+                    rows = loss_rows(logits, lab)
                 if not partial_agg:
                     return jnp.sum(rows) / global_b, logits
                 # SyncReplicasOptimizer partial aggregation in GLOBAL

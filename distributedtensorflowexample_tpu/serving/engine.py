@@ -48,7 +48,20 @@ import numpy as np
 
 from distributedtensorflowexample_tpu.models.transformer_lm import (
     TransformerLM)
+from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+from distributedtensorflowexample_tpu.obs.trace import hot_span
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
+
+_PREFILL_POSITIONS = obs_metrics.counter(
+    "serve_prefill_positions_total", "token positions run through "
+    "bucketed prefill, by kind (prompt = real tokens, pad = the rest of "
+    "each [B, bucket] block)")
+_POS_PROMPT = _PREFILL_POSITIONS.labels(kind="prompt")
+_POS_PAD = _PREFILL_POSITIONS.labels(kind="pad")
+_PREFILL_PROGRAMS = obs_metrics.gauge(
+    "serve_prefill_programs", "distinct (bucket, B) prefill shapes — one "
+    "compiled program each — of the engine that last met a cold one (one "
+    "series a process: beside a draft engine, whichever wrote last)")
 
 #: The decode step's compiled-HLO contract (graftlint HLO front,
 #: analysis/hlo_lint.py `serving_suite`): the KV-cache donation actually
@@ -135,14 +148,15 @@ class ServingBlock(nn.Module):
         q = q.reshape(B, P, self.n_heads, Dh)
         k = k.reshape(B, P, self.n_heads, Dh)
         v = v.reshape(B, P, self.n_heads, Dh)
-        scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
-            Dh ** 0.5, self.dtype)
-        causal = (jnp.arange(P)[:, None] >= jnp.arange(P)[None, :])
-        scores = jnp.where(causal[None, None], scores,
-                           jnp.asarray(-1e9, scores.dtype))
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        probs = probs.astype(self.dtype)
-        att = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, P, -1)
+        with jax.named_scope("attn"):
+            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
+                Dh ** 0.5, self.dtype)
+            causal = (jnp.arange(P)[:, None] >= jnp.arange(P)[None, :])
+            scores = jnp.where(causal[None, None], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            probs = probs.astype(self.dtype)
+            att = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, P, -1)
         x = x + self.attn_out(att)
         return self._mlp(x), k, v
 
@@ -174,16 +188,19 @@ class ServingBlock(nn.Module):
         v = v.reshape(S, K, self.n_heads, Dh)
         rows = pos[:, None] + jnp.arange(K, dtype=pos.dtype)[None]  # [S, K]
         sl = jnp.arange(S)[:, None]
-        ck = ck.at[sl, rows].set(k)
-        cv = cv.at[sl, rows].set(v)
-        scores = jnp.einsum("skhd,sthd->shkt", q, ck) / jnp.asarray(
-            Dh ** 0.5, self.dtype)
-        live = (jnp.arange(T)[None, None, :] <= rows[:, :, None])  # [S,K,T]
-        scores = jnp.where(live[:, None], scores,
-                           jnp.asarray(-1e9, scores.dtype))
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        probs = probs.astype(self.dtype)
-        att = jnp.einsum("shkt,sthd->skhd", probs, cv).reshape(S, K, -1)
+        with jax.named_scope("cache_update"):
+            ck = ck.at[sl, rows].set(k)
+            cv = cv.at[sl, rows].set(v)
+        with jax.named_scope("attn"):
+            scores = jnp.einsum("skhd,sthd->shkt", q, ck) / jnp.asarray(
+                Dh ** 0.5, self.dtype)
+            live = (jnp.arange(T)[None, None, :]
+                    <= rows[:, :, None])                        # [S,K,T]
+            scores = jnp.where(live[:, None], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            probs = probs.astype(self.dtype)
+            att = jnp.einsum("shkt,sthd->skhd", probs, cv).reshape(S, K, -1)
         x = x + self.attn_out(att)
         return self._mlp(x), ck, cv
 
@@ -230,7 +247,8 @@ class ServingLM(nn.Module):
             ks.append(k)
             vs.append(v)
         x = self.ln_f(x)
-        logits = self.embed.attend(x).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = self.embed.attend(x).astype(jnp.float32)
         return logits, jnp.stack(ks), jnp.stack(vs)
 
     def verify(self, toks, positions, ck, cv):
@@ -248,7 +266,8 @@ class ServingLM(nn.Module):
         ck = jnp.stack(new_k)
         cv = jnp.stack(new_v)
         x = self.ln_f(x)
-        logits = self.embed.attend(x).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = self.embed.attend(x).astype(jnp.float32)
         return logits, ck, cv
 
     def decode(self, tok, positions, ck, cv):
@@ -450,26 +469,34 @@ class DecodeEngine:
         cold = False
         for bucket, group in sorted(groups.items()):
             B = len(group)
-            if (bucket, B) not in self._warm_buckets:
-                cold = True
-            self._warm_buckets.add((bucket, B))
-            padded = np.zeros((B, bucket), np.int32)
-            slots_ix = np.zeros((B,), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            for i, (slot, prompt) in enumerate(group):
-                padded[i, :len(prompt)] = prompt
-                slots_ix[i] = slot
-                lengths[i] = len(prompt)
-            toks, last, self._ck, self._cv = _prefill_bucketed(
-                self.smodel, self.params, self._ck, self._cv,
-                jnp.asarray(padded), slots_ix, lengths)
-            toks = np.asarray(toks)
-            last = np.asarray(last)
+            with hot_span("engine.prefill.pack"):
+                padded = np.zeros((B, bucket), np.int32)
+                slots_ix = np.zeros((B,), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                for i, (slot, prompt) in enumerate(group):
+                    padded[i, :len(prompt)] = prompt
+                    slots_ix[i] = slot
+                    lengths[i] = len(prompt)
+                toks_in = jnp.asarray(padded)
+            with hot_span("engine.prefill.dispatch"):
+                toks, last, self._ck, self._cv = _prefill_bucketed(
+                    self.smodel, self.params, self._ck, self._cv,
+                    toks_in, slots_ix, lengths)
+            with hot_span("engine.prefill.readback"):
+                toks = np.asarray(toks)
+                last = np.asarray(last)
             for i, (slot, prompt) in enumerate(group):
                 self.positions[slot] = len(prompt)
                 self.last_tokens[slot] = int(toks[i])
                 out[slot] = (int(toks[i]), last[i])
             self.prefills += B
+            real = int(lengths.sum())
+            _POS_PROMPT.inc(real)
+            _POS_PAD.inc(B * bucket - real)
+            if (bucket, B) not in self._warm_buckets:
+                cold = True
+                self._warm_buckets.add((bucket, B))
+                _PREFILL_PROGRAMS.set(len(self._warm_buckets))
         self.last_prefill_was_cold = cold
         return out
 
@@ -481,10 +508,12 @@ class DecodeEngine:
         slots' frontiers (``busy=None`` advances all): an idle slot's
         parked frontier must not drift toward the cache/positional-
         table edge one row per step of everyone else's work."""
-        toks, self._ck, self._cv = _decode_step(
-            self.smodel, self.params, self._ck, self._cv,
-            self.last_tokens, self.positions)
-        out = np.asarray(toks)
+        with hot_span("engine.decode.dispatch"):
+            toks, self._ck, self._cv = _decode_step(
+                self.smodel, self.params, self._ck, self._cv,
+                self.last_tokens, self.positions)
+        with hot_span("engine.decode.readback"):
+            out = np.asarray(toks)
         advance = (np.ones(self.slots, bool) if busy is None
                    else np.zeros(self.slots, bool))
         if busy is not None:
@@ -502,10 +531,12 @@ class DecodeEngine:
         slot's next token: it must ``set_slot(slot, token,
         positions[slot])`` before the next step (greedy's fused-argmax
         program, and its HLO contract, are untouched by this seam)."""
-        logits, self._ck, self._cv = _decode_logits_step(
-            self.smodel, self.params, self._ck, self._cv,
-            self.last_tokens, self.positions)
-        out = np.asarray(logits)
+        with hot_span("engine.decode.dispatch"):
+            logits, self._ck, self._cv = _decode_logits_step(
+                self.smodel, self.params, self._ck, self._cv,
+                self.last_tokens, self.positions)
+        with hot_span("engine.decode.readback"):
+            out = np.asarray(logits)
         advance = (np.ones(self.slots, bool) if busy is None
                    else np.zeros(self.slots, bool))
         if busy is not None:
@@ -521,12 +552,14 @@ class DecodeEngine:
         are garbage to discard).  Returns (greedy [S, K] int32,
         logits [S, K, V] f32).  Advances NOTHING — the caller owns
         accept/rollback bookkeeping via :meth:`set_slot`."""
-        g, logits, self._ck, self._cv = _verify_window(
-            self.smodel, self.params, self._ck, self._cv,
-            jnp.asarray(np.asarray(toks, np.int32)),
-            jnp.asarray(np.asarray(positions, np.int32)))
+        with hot_span("engine.decode.dispatch"):
+            g, logits, self._ck, self._cv = _verify_window(
+                self.smodel, self.params, self._ck, self._cv,
+                jnp.asarray(np.asarray(toks, np.int32)),
+                jnp.asarray(np.asarray(positions, np.int32)))
         self.decode_steps += 1
-        return np.asarray(g), np.asarray(logits)
+        with hot_span("engine.decode.readback"):
+            return np.asarray(g), np.asarray(logits)
 
     def extend(self, slot: int, tokens, start: int) -> tuple:
         """Append already-known ``tokens`` to ``slot``'s cache at rows

@@ -28,7 +28,22 @@ against the next placement), and returns — the worker then exits 143
 with every ACCEPTED-and-admitted request answered.  Telemetry flows
 through the shared obs registry: queue depth, slot occupancy,
 tokens/sec counters, a latency histogram, and p50/p99 gauges refreshed
-from the exact host-side tape.
+from the newest completions of the exact host-side tape.
+
+Spans (obs/trace.py): every ``step()`` is one ``serve.step`` on the
+hot-path tape, holding ``serve.admit`` (queue pops, gates, prefix-cache
+probes), the engine's ``engine.prefill.*`` and ``engine.decode.*``
+spans, and ``serve.retire`` (token append, retirement, gauges); what is
+left — the per-request bookkeeping after a prefill, the step-time EWMA,
+``on_step`` — is the step's self time.  An idle poll (nothing queued,
+nothing live) leaves no span: the ring is for the busy periods.  Per
+request three events
+partition its life and carry its ``rid``: ``serve_queue`` (``submit_t``
+→ ``prefill_t``), ``serve_prefill`` (``prefill_t`` → ``first_token_t``)
+and ``serve_decode`` (``first_token_t`` → ``done_t``).  There is no
+per-token stamp: tokens are emitted only at a boundary's end, so the
+``serve.step`` spans' end times between a request's ``first_token_t``
+and ``done_t`` are its tokens' times.
 """
 
 from __future__ import annotations
@@ -79,6 +94,10 @@ def serve_slo_ms_default() -> float:
         return 0.0
 
 
+#: Completions the p50/p99 gauges are read over (the newest).
+GAUGE_WINDOW = 1024
+
+
 def recent_p99_ms(completed: list, window: int = 32) -> float | None:
     """p99 (ms) over the newest ``window`` completed requests — the
     remediation layer's breach/recovery signal.  Whole-tape percentiles
@@ -109,7 +128,9 @@ class Request:
     prompt: np.ndarray
     max_new: int
     submit_t: float
-    admit_t: float | None = None
+    prefill_t: float | None = None      # stamped BEFORE the admitting
+    #                                     prefill (or prefix-cache probe)
+    admit_t: float | None = None        # stamped with first_token_t
     first_token_t: float | None = None
     done_t: float | None = None
     outcome: str = ""           # ok | slo_rejected | drained | refused
@@ -274,59 +295,72 @@ class ContinuousBatcher:
     def _free_slots(self) -> list:
         return [i for i, s in enumerate(self._slots) if s.req is None]
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> int:
         """Fill open slots from the queue head; SLO-reject requests
         that can no longer finish in time (they would only burn slot
         capacity to miss).  Admissions passing the gates are collected
         and prefilled as ONE batch per padding bucket
-        (``engine.prefill_many`` — the burst-amortization rung)."""
-        free = self._free_slots()
-        batch: list = []
-        while free and len(self.queue):
-            req = self.queue.pop()
-            if req is None:
-                break
-            try:
-                # Geometry check BEFORE the slot is spent: a request
-                # that can never finish inside the cache is refused by
-                # name — one impossible request must cost itself, never
-                # the serving loop (the batcher thread has no other
-                # handler above it).
-                self.engine.bucket_for(len(req.prompt), req.max_new)
-            except ValueError as e:
-                req.error = str(e)
-                req.finish("refused", time.monotonic())
-                _REQUESTS.labels(outcome="refused").inc()
-                self.rejected.append(req)
-                continue
-            if self.slo_ms > 0 and self._predicted_latency_s(
-                    req, now) * 1000.0 > self.slo_ms:
-                req.finish("slo_rejected", time.monotonic())
-                _REQUESTS.labels(outcome="slo_rejected").inc()
-                self.rejected.append(req)
-                continue
-            batch.append((free.pop(0), req))
+        (``engine.prefill_many`` — the burst-amortization rung).
+        Returns how many requests left the queue."""
+        with obs_trace.hot_span("serve.admit") as sp:
+            free = self._free_slots()
+            batch: list = []
+            popped = 0
+            while free and len(self.queue):
+                req = self.queue.pop()
+                if req is None:
+                    break
+                popped += 1
+                try:
+                    # Geometry check BEFORE the slot is spent: a request
+                    # that can never finish inside the cache is refused
+                    # by name — one impossible request must cost itself,
+                    # never the serving loop (the batcher thread has no
+                    # other handler above it).
+                    self.engine.bucket_for(len(req.prompt), req.max_new)
+                except ValueError as e:
+                    req.error = str(e)
+                    req.finish("refused", time.monotonic())
+                    _REQUESTS.labels(outcome="refused").inc()
+                    self.rejected.append(req)
+                    continue
+                if self.slo_ms > 0 and self._predicted_latency_s(
+                        req, now) * 1000.0 > self.slo_ms:
+                    req.finish("slo_rejected", time.monotonic())
+                    _REQUESTS.labels(outcome="slo_rejected").inc()
+                    self.rejected.append(req)
+                    continue
+                batch.append((free.pop(0), req))
+            # Prefix-cache probes belong to admission too (a hit skips
+            # the forward entirely); every request of the batch leaves
+            # the queue here, whichever way its rows arrive.
+            served: dict = {}             # slot -> (first, logits, outcome)
+            todo: list = []
+            t_pre = time.monotonic()
+            for slot, req in batch:
+                req.prefill_t = t_pre
+                hit = None if self.prefix_cache is None \
+                    else self.prefix_cache.admit(slot, req.prompt)
+                if hit is not None:
+                    served[slot] = hit
+                else:
+                    todo.append((slot, req))
+            if not popped and len(free) == self.engine.slots:
+                sp.cancel()     # an idle poll: nothing queued, nothing live
         if batch:
-            self._prefill_batch(batch)
+            self._prefill_batch(batch, served, todo)
         _SLOTS_BUSY.set(self.engine.slots - len(self._free_slots()))
+        return popped
 
-    def _prefill_batch(self, batch: list) -> None:
-        """Admit ``batch`` = [(slot, req), ...]: prefix-cache probes
-        first (a hit skips the forward entirely), the remaining misses
-        in one bucketed ``prefill_many`` call, then per-request
+    def _prefill_batch(self, batch: list, served: dict,
+                       todo: list) -> None:
+        """Admit ``batch`` = [(slot, req), ...], of which ``served``
+        already holds the prefix-cache hits: the misses (``todo``) in
+        one bucketed ``prefill_many`` call, then per-request
         bookkeeping (first token — sampled when a sampler is armed —
-        tracing spans, draft-engine prefill for speculation)."""
-        served: dict = {}                 # slot -> (first, logits, outcome)
-        todo: list = []
-        for slot, req in batch:
-            hit = None if self.prefix_cache is None \
-                else self.prefix_cache.admit(slot, req.prompt)
-            if hit is not None:
-                served[slot] = hit
-            else:
-                todo.append((slot, req))
-        t0 = time.monotonic()
+        tracing events, draft-engine prefill for speculation)."""
         if todo:
+            t0 = time.monotonic()
             out = self.engine.prefill_many(
                 [(slot, req.prompt, req.max_new) for slot, req in todo])
             dt = time.monotonic() - t0
@@ -351,7 +385,6 @@ class ContinuousBatcher:
                 _PREFILLS.labels(
                     bucket=self.engine.bucket_for(len(req.prompt),
                                                   req.max_new)).inc()
-        prefill_dt = time.monotonic() - t0
         for slot, req in batch:
             first, last, outcome = served[slot]
             if self.sampler is not None:
@@ -362,11 +395,16 @@ class ContinuousBatcher:
                 self.engine.set_slot(slot, first,
                                      int(self.engine.positions[slot]))
             req.admit_t = req.first_token_t = time.monotonic()
-            obs_trace.event("serve_queue", req.admit_t - req.submit_t,
+            # The request's own spans, end to start: queued until its
+            # batch left the queue, then prefilling until its first
+            # token (the whole batch's forward, and the bookkeeping of
+            # the requests before it).
+            obs_trace.event("serve_queue", req.prefill_t - req.submit_t,
                             t0_s=req.submit_t, rid=req.rid, slot=slot)
-            obs_trace.event("serve_prefill", prefill_dt, t0_s=t0,
-                            rid=req.rid, slot=slot, outcome=outcome,
-                            batch=len(todo))
+            obs_trace.event("serve_prefill",
+                            req.first_token_t - req.prefill_t,
+                            t0_s=req.prefill_t, rid=req.rid, slot=slot,
+                            outcome=outcome, batch=len(todo))
             req.tokens.append(int(first))
             self._slots[slot].req = req
             self.admitted_total += 1
@@ -401,7 +439,8 @@ class ContinuousBatcher:
         if self.spec is not None:
             self.spec.park(slot)
         if len(self.completed) % 32 == 0 or len(self.completed) < 8:
-            tape = sorted(r.latency_s for r in self.completed)
+            tape = sorted(r.latency_s
+                          for r in self.completed[-GAUGE_WINDOW:])
             _P50.set(round(percentile(tape, 0.50) * 1000.0, 3))
             _P99.set(round(percentile(tape, 0.99) * 1000.0, 3))
         return True
@@ -439,53 +478,48 @@ class ContinuousBatcher:
                 s: self._slots[s].req.max_new - len(self._slots[s].req.tokens)
                 for s in busy}
             emitted = self.spec.round(busy, remaining)
-            self._note_step_time(time.monotonic() - t0)
-            _STEPS.inc()
-            now = time.monotonic()
-            for slot in busy:
-                toks = emitted[slot]
-                if self.eos_id is not None and self.eos_id in toks:
-                    # Plain greedy stops AT eos; a round must not hand
-                    # the request tokens greedy would never have
-                    # produced (the oracle contract).
-                    toks = toks[:toks.index(self.eos_id) + 1]
-                self._slots[slot].req.tokens.extend(toks)
-                self._maybe_retire(slot, now)
         elif self.sampler is not None:
             logits = self.engine.decode_logits(busy=busy)
-            self._note_step_time(time.monotonic() - t0)
-            _STEPS.inc()
-            now = time.monotonic()
-            for slot in busy:
-                req = self._slots[slot].req
-                tok = self.sampler.sample(req.rid, len(req.tokens),
-                                          logits[slot])
-                self.engine.set_slot(slot, tok,
-                                     int(self.engine.positions[slot]))
-                req.tokens.append(tok)
-                self._maybe_retire(slot, now)
         else:
             toks = self.engine.decode(busy=busy)
-            self._note_step_time(time.monotonic() - t0)
-            _STEPS.inc()
+        self._note_step_time(time.monotonic() - t0)
+        _STEPS.inc()
+        with obs_trace.hot_span("serve.retire"):
             now = time.monotonic()
             for slot in busy:
                 req = self._slots[slot].req
-                req.tokens.append(int(toks[slot]))
+                if self.spec is not None:
+                    new = emitted[slot]
+                    if self.eos_id is not None and self.eos_id in new:
+                        # Plain greedy stops AT eos; a round must not
+                        # hand the request tokens greedy would never
+                        # have produced (the oracle contract).
+                        new = new[:new.index(self.eos_id) + 1]
+                    req.tokens.extend(new)
+                elif self.sampler is not None:
+                    tok = self.sampler.sample(req.rid, len(req.tokens),
+                                              logits[slot])
+                    self.engine.set_slot(slot, tok,
+                                         int(self.engine.positions[slot]))
+                    req.tokens.append(tok)
+                else:
+                    req.tokens.append(int(toks[slot]))
                 self._maybe_retire(slot, now)
-        _SLOTS_BUSY.set(self.engine.slots - len(self._free_slots()))
+            _SLOTS_BUSY.set(self.engine.slots - len(self._free_slots()))
         return len(busy)
 
     def step(self) -> int:
         """One boundary: admit into open slots, one decode boundary
         over the batch, retire finished requests.  Returns the number
         of live slots decoded (0 = idle boundary)."""
-        self._admit(time.monotonic())
-        n = self._decode_once()
-        if n == 0:
-            return 0
-        if self.on_step is not None:
-            self.on_step(self)
+        with obs_trace.hot_span("serve.step") as sp:
+            popped = self._admit(time.monotonic())
+            n = self._decode_once()
+            if n and self.on_step is not None:
+                self.on_step(self)
+            if not (n or popped):
+                sp.cancel()     # an idle poll (run() makes one every
+                #                 20 ms): kept off the tape
         return n
 
     def run(self, should_stop=lambda: False,
@@ -522,7 +556,8 @@ class ContinuousBatcher:
         # greedy's tokens either way), a sampled batch keeps its RNG
         # lanes.
         while self._busy():
-            self._decode_once()
+            with obs_trace.hot_span("serve.step"):
+                self._decode_once()
         _SLOTS_BUSY.set(0)
         obs_trace.event("serve_drain", time.monotonic() - t0, t0_s=t0,
                         in_flight=in_flight, tail=len(tail))

@@ -156,6 +156,33 @@ def test_single_device_step_and_resident_eval_token_denominator():
     assert got == pytest.approx(want, abs=1e-9)
 
 
+@pytest.mark.parametrize("scope", ["attn", "head", "loss"])
+def test_lm_step_carries_the_named_scopes(scope):
+    """The names a device trace is split by (forward / backward,
+    attention's share, the head and the loss) are on the step's
+    operations:
+    metadata only, read here from the lowered text with debug info."""
+    from distributedtensorflowexample_tpu.parallel.sync import (
+        make_train_step)
+    model = build_model("lm_tiny")
+    state = TrainState.create(model, _tx(), jnp.zeros((2, 16), jnp.int32))
+    batch = {"image": jnp.zeros((2, 16), jnp.int32),
+             "label": jnp.zeros((2, 16), jnp.int32)}
+    text = jax.jit(make_train_step()).lower(state, batch).as_text(
+        debug_info=True)
+    # e.g. loc("jvp(TransformerLM)/block0/attn/div") and, for a scope
+    # opened under the gradient, loc("jvp(loss)/div")
+    import re
+    named = re.compile(r'loc\("[^"]*\b%s\)*/' % scope)
+    paths = [line for line in text.splitlines() if named.search(line)]
+    assert paths, scope
+    if scope == "attn":
+        # forward under the model's block, backward under its transpose
+        assert any("jvp(" in p and "block0/attn/" in p for p in paths)
+        assert any("transpose(" in p and "block0/attn/" in p
+                   for p in paths)
+
+
 # ---- knob parity at lm_tiny (the satellite gates) -----------------------
 
 def _run_pair(mesh, step_a, state_a, step_b, state_b, seq=SEQ, calls=2,

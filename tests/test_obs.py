@@ -22,6 +22,7 @@ import signal
 import subprocess
 import sys
 import textwrap
+import threading
 import time
 
 import jax
@@ -181,6 +182,155 @@ def test_span_attrs_writable_and_exception_safe(sink):
             raise RuntimeError("boom")
     assert sink[-1]["name"] == "doomed" and sink[-1]["rc"] == 7
     assert obs_trace._stack() == []         # stack unwound
+
+
+# --- the hot-path tape -----------------------------------------------------
+
+@pytest.fixture()
+def tape(monkeypatch):
+    """An empty tape of its own for one test (the module's ring is
+    process-wide and other tests write to it)."""
+    import collections
+    import itertools
+    ring = collections.deque(maxlen=8)
+    monkeypatch.setattr(obs_trace, "_tape", ring)
+    monkeypatch.setattr(obs_trace, "_seq", itertools.count())
+    return ring
+
+
+def _ticking_clock(monkeypatch, step=1.0):
+    """Pin the monotonic seam: every read is ``step`` later."""
+    now = [0.0]
+
+    def tick():
+        now[0] += step
+        return now[0]
+
+    monkeypatch.setattr(obs_metrics, "_now", tick)
+
+
+def test_tape_keeps_name_stamps_parent_and_rid(tape, monkeypatch):
+    _ticking_clock(monkeypatch)
+    with obs_trace.hot_span("outer"):
+        with obs_trace.hot_span("inner", rid="r7"):
+            pass
+    assert obs_trace.tape() == [("inner", 2.0, 3.0, "outer", "r7"),
+                                ("outer", 1.0, 4.0, None, None)]
+    assert obs_trace.tape_dropped() == 0
+    assert obs_trace._stack() == []
+
+
+def test_tape_nested_spans_give_self_time(tape, monkeypatch):
+    _ticking_clock(monkeypatch)
+    with obs_trace.hot_span("step"):            # 1 .. 8
+        with obs_trace.hot_span("a"):           # 2 .. 3
+            pass
+        with obs_trace.hot_span("b"):           # 4 .. 7
+            with obs_trace.hot_span("leaf"):    # 5 .. 6
+                pass
+    spans = {e[0]: e for e in obs_trace.tape()}
+
+    def self_time(name):
+        _, t0, t1, _, _ = spans[name]
+        return (t1 - t0) - sum(c[2] - c[1] for c in spans.values()
+                               if c[3] == name)
+
+    assert [spans[n][3] for n in ("a", "b", "leaf")] == [
+        "step", "step", "b"]
+    assert self_time("step") == 7.0 - 1.0 - 3.0     # less a and b only
+    assert self_time("b") == 2.0 and self_time("leaf") == 1.0
+
+
+def test_event_and_span_land_on_the_tape_too(tape, sink):
+    with obs_trace.span("phase", step=3):
+        obs_trace.event("serve_queue", 0.25, t0_s=10.0, rid="r1", slot=2)
+        with obs_trace.hot_span("hot"):
+            pass
+    queue, hot, phase = obs_trace.tape()
+    assert queue == ("serve_queue", 10.0, 10.25, "phase", "r1")
+    assert hot[0] == "hot" and hot[3] == "phase" and hot[4] is None
+    assert phase[0] == "phase" and phase[3] is None
+    assert phase[1] <= hot[1] <= hot[2] <= phase[2]
+    # ... and the phase recorder's sinks still get what they got (the
+    # hot span goes to the tape only).
+    assert [e["name"] for e in sink[-2:]] == ["serve_queue", "phase"]
+    assert sink[-2]["rid"] == "r1" and sink[-1]["step"] == 3
+
+
+def test_tape_ring_wraps_and_counts_what_it_dropped(tape):
+    for i in range(11):
+        with obs_trace.hot_span(f"s{i}"):
+            pass
+    assert [e[0] for e in obs_trace.tape()] == [f"s{i}"
+                                                for i in range(3, 11)]
+    assert obs_trace.tape_dropped() == 3
+    assert obs_trace.TAPE_LEN >= 60000      # the real ring: ~64 k
+
+
+def test_tape_dropped_counts_across_threads(tape):
+    """The count comes from the entries' own numbers, handed out by one
+    C call each: four threads writing at once lose none of it."""
+    def write():
+        for _ in range(500):
+            with obs_trace.hot_span("t"):
+                pass
+
+    threads = [threading.Thread(target=write) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(obs_trace.tape()) == 8
+    assert obs_trace.tape_dropped() == 4 * 500 - 8
+
+
+def test_a_cancelled_hot_span_leaves_no_entry(tape):
+    with obs_trace.hot_span("busy"):
+        with obs_trace.hot_span("idle") as sp:
+            sp.cancel()
+    assert [e[0] for e in obs_trace.tape()] == ["busy"]
+    assert obs_trace.tape_dropped() == 0 and obs_trace._stack() == []
+
+
+def test_hot_span_exception_safe_and_annotates_when_jax_is_loaded(tape):
+    assert "jax" in sys.modules             # this file imports it
+    with pytest.raises(RuntimeError):
+        with obs_trace.hot_span("doomed"):
+            raise RuntimeError("boom")
+    assert obs_trace.tape()[-1][0] == "doomed"
+    assert obs_trace._stack() == []
+    assert obs_trace._trace_annotation() is jax.profiler.TraceAnnotation
+
+
+def test_hot_span_microbench_guard():
+    """Tentpole promise: a per-boundary span (the tape record plus the
+    profiler annotation, jax loaded, no profiler session) is about a
+    microsecond — the budget is 2 us, the guard 5 us mean over 20 k
+    (the suite runs six workers wide)."""
+    n, best = 20000, float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with obs_trace.hot_span("bench"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 5e-6, f"hot span {best * 1e9:.0f}ns >= 5us"
+
+
+def test_importing_obs_pulls_no_jax():
+    """obs/ is stdlib-only by contract (its docstring): the hot span
+    finds jax through ``sys.modules`` and never imports it."""
+    code = textwrap.dedent("""
+        import sys
+        import distributedtensorflowexample_tpu.obs as obs
+        with obs.hot_span("x"):
+            pass
+        assert obs.tape()[-1][0] == "x"
+        heavy = [m for m in ("jax", "jaxlib", "numpy", "flax")
+                 if m in sys.modules]
+        assert not heavy, heavy
+    """)
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=REPO)
 
 
 def test_trace_jsonl_file_sink(tmp_path, monkeypatch):

@@ -25,6 +25,8 @@ import optax
 import pytest
 
 from distributedtensorflowexample_tpu.models import build_model
+from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+from distributedtensorflowexample_tpu.obs import trace as obs_trace
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.resilience.snapshot import (
     SnapshotStore)
@@ -766,6 +768,165 @@ def test_percentiles_and_drive_file(tmp_path):
     assert np.array_equal(a, make_prompt(17, 250, seed=3))
     assert not np.array_equal(a, make_prompt(18, 250, seed=3)) \
         or len(a) != len(make_prompt(18, 250, seed=3))
+
+
+# ---- the program's own spans and counters ---------------------------------
+
+@pytest.fixture(scope="module")
+def traced_run(lm_state):
+    """A small greedy run driven by ``batcher.step()`` on an engine of
+    its own (so the gauge counts this run's shapes): five requests of
+    known prompt lengths through three slots.  Returns the finished
+    requests, the tape entries the run wrote and the counters' moves."""
+    model, state = lm_state
+    engine = DecodeEngine(model, state.params, slots=3, cache_len=CACHE)
+    queue = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, queue, slo_ms=0.0)
+
+    def counters():
+        snap = obs_metrics.registry().snapshot()["counters"]
+        return {k: snap.get(
+            'serve_prefill_positions_total{kind="%s"}' % k, 0)
+            for k in ("prompt", "pad")}
+
+    before, n_before = counters(), len(obs_trace.tape())
+    lengths = [3, 5, 8, 9, 2]
+    # Boundary 1 admits 3, 5 and 8 tokens: all bucket 8, one [3, 8]
+    # block.  The others wait for a slot: 9 tokens (bucket 16) and 2
+    # (bucket 8) are admitted as the 2-token requests retire.
+    reqs = [queue.submit(list(range(1, n + 1)), max_new,
+                         rid=f"t{i}")
+            for i, (n, max_new) in enumerate(zip(lengths,
+                                                 [2, 2, 6, 3, 3]))]
+    while not all(r.done.is_set() for r in reqs):
+        assert batcher.step() > 0
+    assert obs_trace.tape_dropped() == 0 or len(obs_trace.tape()) \
+        == obs_trace.TAPE_LEN
+    after = counters()
+    return {"reqs": reqs, "engine": engine, "lengths": lengths,
+            "tape": obs_trace.tape()[n_before:],
+            "moved": {k: after[k] - before[k] for k in after}}
+
+
+def test_request_events_partition_its_life_under_one_rid(traced_run):
+    for req in traced_run["reqs"]:
+        mine = {e[0]: e for e in traced_run["tape"] if e[4] == req.rid}
+        assert set(mine) == {"serve_queue", "serve_prefill",
+                             "serve_decode"}, (req.rid, sorted(mine))
+        q, p, d = (mine[n] for n in ("serve_queue", "serve_prefill",
+                                     "serve_decode"))
+        assert q[1] == req.submit_t and d[2] == pytest.approx(
+            req.done_t, abs=1e-9)
+        # end to start: nothing of the request's life is left out or
+        # counted twice
+        assert q[2] == pytest.approx(p[1], abs=1e-9)
+        assert p[2] == pytest.approx(d[1], abs=1e-9)
+        assert p[1] == req.prefill_t and d[1] == req.first_token_t
+
+
+def test_request_stamps_are_ordered_and_admit_t_keeps_its_meaning(
+        traced_run):
+    for req in traced_run["reqs"]:
+        assert req.submit_t <= req.prefill_t <= req.first_token_t \
+            <= req.done_t
+        # admit_t is still stamped together with the first token (the
+        # benchmark finds the admitting prefill by it)
+        assert req.admit_t == req.first_token_t
+    # the late admissions waited in the queue, and the tape says so
+    waits = {e[4]: e[2] - e[1] for e in traced_run["tape"]
+             if e[0] == "serve_queue"}
+    assert waits["t3"] > waits["t0"] and waits["t4"] > waits["t0"]
+
+
+def test_every_engine_span_lies_inside_a_serve_step(traced_run):
+    tape = traced_run["tape"]
+    steps = [e for e in tape if e[0] == "serve.step"]
+    inner = [e for e in tape if e[0].startswith(("engine.", "serve."))
+             and e[0] != "serve.step"]
+    assert steps and {e[0] for e in inner} == {
+        "serve.admit", "serve.retire", "engine.prefill.pack",
+        "engine.prefill.dispatch", "engine.prefill.readback",
+        "engine.decode.dispatch", "engine.decode.readback"}
+    for e in inner:
+        assert e[3] == "serve.step", e
+        assert any(s[1] <= e[1] and e[2] <= s[2] for s in steps), e
+    # one decode dispatch and read-back, one admit and one retire a step
+    for name in ("engine.decode.dispatch", "engine.decode.readback",
+                 "serve.admit", "serve.retire"):
+        assert sum(e[0] == name for e in tape) == len(steps)
+    # steps do not overlap, and the tape closes them in order
+    assert all(a[2] <= b[1] for a, b in zip(steps, steps[1:]))
+
+
+def test_an_idle_poll_leaves_no_span_on_the_tape(engine):
+    """``run()`` polls an empty queue every 20 ms; those boundaries stay
+    off the ring (it is for the busy periods) — and a boundary that only
+    rejected a request is work, and stays on it."""
+    q = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, q, slo_ms=0.0)
+    n_before = len(obs_trace.tape())
+    for _ in range(3):
+        assert batcher.step() == 0
+    assert obs_trace.tape()[n_before:] == []
+    q.submit(list(range(1, CACHE + 8)), 4, rid="too-long")
+    assert batcher.step() == 0 and batcher.rejected[-1].rid == "too-long"
+    assert [e[0] for e in obs_trace.tape()[n_before:]] == [
+        "serve.admit", "serve.step"]
+
+
+def test_prefill_position_counters_equal_a_hand_count(traced_run):
+    # [3, 5, 8] in one [3, 8] block; then 9 -> [1, 16] and 2 -> [1, 8]
+    lengths = traced_run["lengths"]
+    assert traced_run["moved"]["prompt"] == sum(lengths) == 27
+    assert traced_run["moved"]["pad"] == (3 * 8 - 16) + (16 - 9) + (8 - 2)
+    packs = [e for e in traced_run["tape"]
+             if e[0] == "engine.prefill.pack"]
+    assert len(packs) == 3
+
+
+def test_prefill_programs_gauge_counts_distinct_shapes(traced_run):
+    engine = traced_run["engine"]
+    assert engine._warm_buckets == {(8, 3), (16, 1), (8, 1)}
+    gauge = obs_metrics.registry().snapshot()["gauges"][
+        "serve_prefill_programs"]
+    assert gauge["value"] == 3
+
+
+def test_latency_gauges_read_a_window_not_the_whole_tape(engine,
+                                                         monkeypatch):
+    from distributedtensorflowexample_tpu.serving import queue as squeue
+    monkeypatch.setattr(squeue, "GAUGE_WINDOW", 3)
+    q = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, q, slo_ms=0.0)
+    # An early bad episode: completions that took "100 s".
+    for i in range(2):
+        r = squeue.Request(rid=f"old{i}", prompt=np.array([1]), max_new=1,
+                           submit_t=0.0)
+        r.finish("ok", 100.0)
+        batcher.completed.append(r)
+    reqs = [q.submit([1, 2, 3], 1, rid=f"new{i}") for i in range(4)]
+    while not all(r.done.is_set() for r in reqs):
+        batcher.step()
+    p99 = obs_metrics.registry().snapshot()["gauges"][
+        "serve_latency_p99_ms"]["value"]
+    # read at the 5th and 6th completion over the newest 3: the old
+    # episode has left the gauge (the whole tape would still say 100 s)
+    assert p99 < 50_000, p99
+
+
+def test_decode_step_carries_the_named_scopes(engine):
+    """``attn``, ``head`` and ``cache_update`` name their operations in
+    the decode program (metadata only: what a device trace is split
+    by)."""
+    from distributedtensorflowexample_tpu.serving.engine import (
+        _decode_step)
+    text = _decode_step.lower(
+        engine.smodel, engine.params, engine._ck, engine._cv,
+        engine.last_tokens, engine.positions).as_text(debug_info=True)
+    for scope in ("attn", "head", "cache_update"):
+        assert f"/{scope}/" in text, scope
+    assert "block0.verify/attn/" in text
+    assert "block0.verify/cache_update/" in text
 
 
 def test_obs_never_imports_serving():
