@@ -247,6 +247,69 @@ def sharded_train_legs(meter: Meter, work: str, devices: dict) -> None:
           flush=True)
 
 
+def attention_sync_leg(meter: Meter, devices: dict) -> None:
+    """The sync_dp step (one GSPMD program over ``data``) at T = 1024 and
+    the 124M widths, on every device and on one: the blocked attention
+    kernels must engage in both (GSPMD cannot split a Mosaic call, so
+    under the mesh they run per shard), and the first steps' losses must
+    agree to the train cells' ``loss_gap``."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributedtensorflowexample_tpu.config import RunConfig
+    from distributedtensorflowexample_tpu.data.lm import (
+        make_synthetic_tokens)
+    from distributedtensorflowexample_tpu.engine import Engine, RunSpec
+    from distributedtensorflowexample_tpu.models import LM_VOCAB
+    from distributedtensorflowexample_tpu.models.transformer_lm import (
+        TransformerLM)
+    from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+
+    count, seq, rows, steps, layers = devices["device_count"], 1024, 8, 3, 4
+    tokens = make_synthetic_tokens(rows * steps, seq, LM_VOCAB, seed=25)
+    taken = obs_metrics.counter("lm_attention_blocks_total")
+
+    def losses(num_devices: int) -> list:
+        cfg = RunConfig(
+            batch_size=rows, global_batch=True, learning_rate=0.01,
+            momentum=0.9, seed=25, dataset="synthetic", resume=False,
+            num_devices=num_devices, dtype="bfloat16", steps_per_loop=1,
+            quantize="off", device_data="on", log_dir="")
+        spec = RunSpec(
+            model="lm_base", dataset="lm", config=cfg, token_data=True,
+            model_fn=lambda c: TransformerLM(
+                vocab_size=LM_VOCAB, n_layers=layers, d_model=768,
+                n_heads=12, d_ff=3072, max_len=seq,
+                dtype=jnp.dtype(c.dtype), remat=c.remat),
+            input_fn=lambda c, split: (tokens[:, :-1], tokens[:, 1:]))
+        build = Engine(spec).build(unroll=1)
+        require(build.mode == "sync_dp" and build.mesh.size == num_devices,
+                f"resolved {build.mode} on {build.mesh.size} devices")
+        before = {impl: taken.labels(impl=impl).value
+                  for impl in ("pallas", "einsum")}
+        state, out = build.state, []
+        with build.mesh:
+            for _ in range(steps):
+                state, metrics = build.step(state, next(build.ds))
+                out.append(float(metrics["loss"]))
+        require(taken.labels(impl="pallas").value - before["pallas"]
+                >= layers and taken.labels(impl="einsum").value
+                == before["einsum"],
+                f"the step on {num_devices} device(s) did not take the "
+                f"attention kernels in all {layers} blocks")
+        return out
+
+    with meter.leg("sync_attention_t1024",
+                   "Engine.build sync_dp, 124M widths") as facts:
+        wide, one = losses(count), losses(1)
+        gap = float(np.max(np.abs(np.subtract(wide, one))))
+        require(all(np.isfinite(wide)) and gap <= 5e-4,
+                f"losses on {count} devices {wide} and on one {one} "
+                f"differ by {gap:.2e} (limit 5e-4)")
+        facts.update(devices=count, loss_first=wide[0], loss_last=wide[-1],
+                     loss_gap_to_one_device=gap)
+
+
 # --- kernels ----------------------------------------------------------------
 
 def kernel_checks() -> list:
@@ -262,11 +325,15 @@ def kernel_checks() -> list:
     from distributedtensorflowexample_tpu.data.device_dataset import (
         apply_dequant_affine, make_dequant_affine)
     from distributedtensorflowexample_tpu.models import build_model
+    from distributedtensorflowexample_tpu.ops.attention import (
+        einsum_causal_attention)
     from distributedtensorflowexample_tpu.ops.losses import (
         softmax_cross_entropy_rows)
     from distributedtensorflowexample_tpu.ops.pallas import (
         fused_gather_dequant, fused_sgd_apply,
         fused_softmax_cross_entropy_rows)
+    from distributedtensorflowexample_tpu.ops.pallas.attention import (
+        blocked_causal_attention)
 
     def compare(name, kernel_fn, reference_fn, args, rtol, atol):
         lowered = jax.jit(kernel_fn).lower(*args)
@@ -328,7 +395,25 @@ def kernel_checks() -> list:
                     images[idx], s, b),
                 args, rtol=0.0, atol=0.0)
 
+    def causal_attention(b, t, h, dh):
+        # Forward and dq / dk / dv at a train cell's shapes, bf16 as the
+        # model runs it, against the einsum chain in the same precision
+        # (both within bf16's rounding of the f32 answer).
+        def with_grad(att):
+            def fn(q, k, v, w):
+                out, vjp = jax.vjp(att, q, k, v)
+                return (out,) + vjp(w)
+            return fn
+        args = tuple(jax.random.normal(key(i), (b, t, h, dh)).astype(
+            jnp.bfloat16) for i in (9, 10, 11, 12))
+        compare(f"causal_attention[{b},{t},{h},{dh}]",
+                with_grad(blocked_causal_attention),
+                with_grad(einsum_causal_attention), args,
+                rtol=2e-2, atol=4e-2)
+
     return [
+        ("causal_attention[4,1024,12,64]",
+         lambda: causal_attention(4, 1024, 12, 64)),
         ("softmax_ce[64,10]", lambda: cross_entropy(64, 10)),
         ("softmax_ce[2048,250]", lambda: cross_entropy(2048, 250)),
         ("fused_momentum_sgd[lm_base]", momentum_sgd),
@@ -483,6 +568,7 @@ def main() -> int:
         train_legs(meter, work, devices)
         if devices["device_count"] >= 4:
             sharded_train_legs(meter, work, devices)
+            attention_sync_leg(meter, devices)
         kernel_leg(meter)
         serve_leg(meter, work, devices, "serve_lm_base", [])
         if devices["device_count"] >= 4:

@@ -46,6 +46,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.ops.attention import causal_attention
+
 #: Default vocabulary — deliberately < 256 so (a) token splits store as
 #: uint8 in HBM (the quantized-data-path win: 4x less gather traffic
 #: than int32) and (b) random garbage bytes are detectably out-of-vocab
@@ -66,7 +68,8 @@ LM_SIZES = {
 
 class DecoderBlock(nn.Module):
     """Pre-LN decoder block: LN -> causal MHA -> residual, LN -> MLP ->
-    residual.  Attention is written as explicit batched einsums (two
+    residual.  Attention is ``ops.attention.causal_attention``: on the
+    CPU and for shapes that do not tile, explicit batched einsums (two
     dot-generals with batch dims) — the exact HLO shape the MFU flops
     audit (utils/profiling.hlo_flops_by_op) must price correctly."""
     d_model: int
@@ -85,24 +88,13 @@ class DecoderBlock(nn.Module):
         q = q.reshape(B, T, self.n_heads, Dh)
         k = k.reshape(B, T, self.n_heads, Dh)
         v = v.reshape(B, T, self.n_heads, Dh)
-        # "attn" names the score / softmax / weighted-sum operations in
-        # the device trace (flax scopes each Dense and LayerNorm by its
-        # module name already); metadata only.
+        # "attn" names the attention operations in the device trace (flax
+        # scopes each Dense and LayerNorm by its module name already);
+        # metadata only.  causal_attention takes the blocked kernels
+        # where the program is built for a TPU and the shapes tile, and
+        # the einsum chain everywhere else (ops/attention.py).
         with jax.named_scope("attn"):
-            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
-                Dh ** 0.5, self.dtype)
-            # Causal mask: position t attends to s <= t.  Built from iota
-            # at trace time — no resident [T, T] constant in HBM.
-            causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
-            scores = jnp.where(causal[None, None], scores,
-                               jnp.asarray(-1e9, scores.dtype))
-            # Softmax in f32: bf16 exp/normalize is where logit noise
-            # turns into loss noise; the [B,H,T,T] f32 probs are exactly
-            # the activation bytes remat="block" exists to not keep
-            # resident.
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            probs = probs.astype(self.dtype)
-            att = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, T, -1)
+            att = causal_attention(q, k, v).reshape(B, T, -1)
         att = nn.Dense(self.d_model, dtype=self.dtype, name="attn_out")(att)
         att = nn.Dropout(self.dropout_rate,
                          deterministic=not train)(att)

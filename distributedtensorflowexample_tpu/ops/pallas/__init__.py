@@ -10,6 +10,11 @@ no HBM round-trips between the fused stages, selectable per run
 for the update, ``RunConfig.dequant_impl="pallas"`` for the fused
 gather+dequant of a uint8-resident split).
 
+The LM block's blocked causal attention (``attention.py``) is the one
+kernel with no flag: ``ops.attention.causal_attention`` takes it from the
+backend and the shapes, and imports it from its module only then (this
+package's other kernels are imported eagerly below).
+
 All kernels run in interpret mode on CPU, so the same code path is
 unit-testable without a TPU (SURVEY.md §4 test strategy).
 """

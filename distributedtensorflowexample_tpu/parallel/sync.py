@@ -15,6 +15,8 @@ realloc per step.
 
 from __future__ import annotations
 
+import contextlib
+
 from typing import Callable
 
 import jax
@@ -39,6 +41,18 @@ HLO_CONTRACT = {
     "require_alias": True,
     "dtype_ceiling": "f32",
 }
+
+
+def global_view(mesh):
+    """Context for applying a model in global view (one GSPMD program
+    over ``mesh``): tells whatever is traced inside which mesh it is
+    being partitioned over, the way jax provides for
+    (``jax.sharding.get_abstract_mesh``).  ``ops.attention`` reads it to
+    run its kernels per shard — GSPMD cannot split a Mosaic call.  A
+    no-op without a mesh or on one device."""
+    if mesh is None or mesh.size <= 1:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
 def _per_example_rows(impl: Callable) -> Callable:
@@ -455,8 +469,9 @@ def _build_step_fn(label_smoothing: float = 0.0, ce_impl: str = "xla",
                 loss = compute_loss(logits, batch["label"], state.step)
             return loss, (logits, new_stats)
 
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
+        with global_view(mesh):
+            (loss, (logits, new_stats)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params)
         updates, new_opt_state = state.tx.update(grads, state.opt_state,
                                                  state.params)
         new_params = optax.apply_updates(state.params, updates)
@@ -717,7 +732,8 @@ def make_resident_eval(images, labels, batch_size: int = 1000,
             bx, by = xy
             if dequant is not None:
                 bx = dequantize_images(bx, dequant, impl)
-            logits = state.apply_fn(variables, bx, train=False)
+            with global_view(mesh):
+                logits = state.apply_fn(variables, bx, train=False)
             correct = jnp.sum(
                 (jnp.argmax(logits, axis=-1) == by).astype(jnp.int32))
             return total + correct, None
@@ -737,7 +753,14 @@ def evaluate(state: TrainState, images, labels, batch_size: int = 1000,
     Host-fed — see ``make_resident_eval`` for the device-resident path
     the trainers use by default.
     """
-    eval_step = make_eval_step()
+    jitted = make_eval_step()
+
+    def eval_step(state, batch):
+        # The mesh in context is part of jit's cache key: the one
+        # module-level program is traced again for another mesh.
+        with global_view(getattr(sharding, "mesh", None)):
+            return jitted(state, batch)
+
     n = len(labels)
     usable = (n // batch_size) * batch_size
     total_correct = 0

@@ -16,6 +16,7 @@ import pytest
 from jax.experimental import pallas as pl
 
 from distributedtensorflowexample_tpu import runtime
+from distributedtensorflowexample_tpu.ops.attention import causal_attention
 from distributedtensorflowexample_tpu.ops.pallas import (
     fused_gather_dequant, fused_sgd_apply, fused_softmax_cross_entropy_rows)
 from distributedtensorflowexample_tpu.ops.pallas.tiling import (
@@ -98,7 +99,10 @@ class _Launched(Exception):
     lambda: fused_gather_dequant(
         jnp.zeros((4, 2, 2, 1), jnp.uint8), jnp.zeros((2,), jnp.int32),
         jnp.ones((1,)), jnp.zeros((1,))),
-], ids=["cross_entropy", "sgd", "dequant"])
+    # through the model's own entry: on a TPU backend these shapes take
+    # the kernels, and they are handed to pallas_call compiled
+    lambda: causal_attention(*(jnp.zeros((2, 512, 2, 64), jnp.bfloat16),) * 3),
+], ids=["cross_entropy", "sgd", "dequant", "causal_attention"])
 def test_every_wrapper_launches_compiled_off_cpu(monkeypatch, launch):
     """On any platform but ``cpu`` each public wrapper hands pallas_call
     ``interpret=False`` — the kernel is compiled or the call fails, it is

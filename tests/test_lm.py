@@ -571,3 +571,107 @@ def test_compiled_program_audit_sections_on_lm_step():
         res["params_bytes_per_device"] + res["opt_state_bytes_per_device"]
     names = [r["op_name"] for r in audit["flops"]["top_ops"]]
     assert any("dot_general" in n for n in names)
+
+
+# ---- causal_attention: which implementation a call takes (PR 25) --------
+
+from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+from distributedtensorflowexample_tpu.ops import attention as attention_op
+
+
+def _inline_attention_before_pr25(q, k, v, dtype):
+    """DecoderBlock's attention as it stood inline until PR 25, kept
+    here as the pin: ``[B, T, H, Dh]`` -> ``[B, T, H * Dh]``."""
+    B, T, _, Dh = q.shape
+    scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.asarray(
+        Dh ** 0.5, dtype)
+    causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
+    scores = jnp.where(causal[None, None], scores,
+                       jnp.asarray(-1e9, scores.dtype))
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    probs = probs.astype(dtype)
+    return jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, T, -1)
+
+
+def _attention_counts():
+    got = obs_metrics.registry().snapshot()["counters"]
+    return {impl: got.get('lm_attention_blocks_total{impl="%s"}' % impl, 0)
+            for impl in ("pallas", "einsum")}
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4, SEQ, 2, 32), jnp.bfloat16),       # lm_tiny: never tiles
+    ((2, 512, 2, 64), jnp.bfloat16),       # tiles, but this is the CPU
+    ((2, 96, 4, 64), jnp.float32),         # T no multiple of the block
+])
+def test_causal_attention_on_cpu_is_the_einsum_chain_bitwise(shape, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+               for kk in keys)
+    before = _attention_counts()
+    got = jax.jit(lambda q, k, v: attention_op.causal_attention(
+        q, k, v).reshape(shape[0], shape[1], -1))(q, k, v)
+    want = jax.jit(lambda q, k, v: _inline_attention_before_pr25(
+        q, k, v, dtype))(q, k, v)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    after = _attention_counts()
+    assert after["einsum"] == before["einsum"] + 1
+    assert after["pallas"] == before["pallas"]
+
+
+@pytest.mark.parametrize("shape,takes", [
+    ((16, 1024, 12, 64), True),     # gpt2_124m.train_seq1024
+    ((24, 1024, 20, 64), True),     # gpt2_774m.train_seq1024
+    ((4, 2048, 8, 128), True),
+    ((8, 768, 12, 64), True),       # block 256
+    ((32, 128, 12, 64), False),     # lm_base: tiles, too short to win
+    ((16, 1000, 12, 64), False),    # no block divides T
+    ((16, 4096, 12, 64), False),    # longer than a cell holds in VMEM
+    ((16, 1024, 24, 32), False),    # head width
+    ((16, 1024, 3, 64), False),     # half a lane group left over
+])
+def test_takes_kernel_is_decided_by_backend_and_shape(shape, takes,
+                                                      monkeypatch):
+    assert not attention_op.takes_kernel(shape)          # the CPU never
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention_op.takes_kernel(shape) is takes
+
+
+@pytest.mark.parametrize("remat", ["none", "block"])
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_lm_attention_blocks_total_counts_layers_per_trace(impl, remat,
+                                                           monkeypatch):
+    from distributedtensorflowexample_tpu.models.transformer_lm import (
+        TransformerLM)
+    n_layers = 3
+    model = TransformerLM(n_layers=n_layers, d_model=128, n_heads=2,
+                          d_ff=256, max_len=128, remat=remat)
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    if impl == "pallas":
+        monkeypatch.setattr(attention_op, "takes_kernel", lambda s: True)
+    other = "einsum" if impl == "pallas" else "pallas"
+
+    def loss(p):
+        return jnp.sum(model.apply(p, tokens, train=True))
+
+    before = _attention_counts()
+    jax.eval_shape(jax.grad(loss), params)      # traced, never run
+    after = _attention_counts()
+    assert after[impl] - before[impl] == n_layers
+    assert after[other] == before[other]
+
+
+def test_importing_the_model_does_not_import_pallas():
+    # jax.experimental.pallas costs a second or two to import; a CPU
+    # run or a serving process, which never take a kernel, do not pay it.
+    code = ("import sys\n"
+            "import distributedtensorflowexample_tpu.models.transformer_lm\n"
+            "print('jax.experimental.pallas' in sys.modules)\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == "False"

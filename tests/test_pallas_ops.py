@@ -192,3 +192,131 @@ def test_fused_optimizer_rejected_in_async_mode(tmp_path):
                     log_dir=str(tmp_path / "logs"), resume=False)
     with pytest.raises(ValueError, match="fused_optimizer"):
         run_training(cfg, "softmax", "mnist")
+
+
+# ---- blocked causal attention (ops/pallas/attention.py) -----------------
+
+from distributedtensorflowexample_tpu.ops import attention as attention_op
+from distributedtensorflowexample_tpu.ops.attention import (
+    causal_attention, einsum_causal_attention)
+from distributedtensorflowexample_tpu.ops.pallas.attention import (
+    blocked_causal_attention)
+
+
+def _qkvw(heads=2, head_dim=64, batch=2, seq=256, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return [jax.random.normal(k, (batch, seq, heads, head_dim), jnp.float32)
+            for k in keys]
+
+
+def _weighted(att, w):
+    return lambda q, k, v: jnp.sum(att(q, k, v) * w)
+
+
+@pytest.mark.parametrize("heads,head_dim", [(2, 64), (4, 64), (1, 128)])
+@pytest.mark.parametrize("block", [128, 256])
+def test_attention_forward_matches_einsum_chain(block, heads, head_dim):
+    q, k, v, _ = _qkvw(heads, head_dim)
+    got = blocked_causal_attention(q, k, v, block=block)
+    np.testing.assert_allclose(got, einsum_causal_attention(q, k, v),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("heads,head_dim", [(2, 64), (1, 128)])
+@pytest.mark.parametrize("block", [128, 256])
+def test_attention_gradients_match_einsum_chain(block, heads, head_dim):
+    q, k, v, w = _qkvw(heads, head_dim, seed=1)
+    att = lambda q, k, v: blocked_causal_attention(q, k, v, block=block)
+    got = jax.grad(_weighted(att, w), (0, 1, 2))(q, k, v)
+    want = jax.grad(_weighted(einsum_causal_attention, w), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5,
+                                   err_msg=name)
+
+
+def test_attention_first_row_has_every_later_key_masked():
+    # Query 0 sees key 0 alone: its output is v[0] whatever the scores
+    # are (huge ones, so a leak of a masked key would show), and keys
+    # 1.. get no gradient from it.
+    q, k, v, _ = _qkvw(seed=2)
+    out = blocked_causal_attention(q * 30.0, k * 30.0, v, block=128)
+    np.testing.assert_allclose(out[:, 0], v[:, 0], rtol=1e-6, atol=1e-6)
+    assert np.isfinite(np.asarray(out)).all()
+    dk = jax.grad(lambda k: jnp.sum(
+        blocked_causal_attention(q, k, v, block=128)[:, 0]))(k)
+    assert float(jnp.max(jnp.abs(dk[:, 1:]))) == 0.0
+
+
+def test_attention_bf16_is_no_further_from_f32_than_the_einsum_chain():
+    q, k, v, _ = _qkvw(seed=3)
+    exact = einsum_causal_attention(q, k, v)
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    gap = lambda out: float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                            - exact)))
+    assert blocked_causal_attention(*low).dtype == jnp.bfloat16
+    assert gap(blocked_causal_attention(*low)) <= 1.25 * gap(
+        einsum_causal_attention(*low))
+
+
+@pytest.mark.parametrize("wrap", ["jit", "remat", "vmap", "shard_map",
+                                  "global_view"])
+def test_attention_under_transforms(wrap, monkeypatch):
+    from distributedtensorflowexample_tpu.parallel import (
+        batch_sharding, make_mesh)
+    from distributedtensorflowexample_tpu.parallel.sync import global_view
+    mesh = make_mesh()
+    batch = mesh.size if wrap in ("shard_map", "global_view") else 2
+    q, k, v, w = _qkvw(batch=batch, seq=128, seed=4)
+    att = blocked_causal_attention
+    if wrap == "jit":
+        run = jax.jit(jax.value_and_grad(_weighted(att, w), (0, 1, 2)))
+    elif wrap == "remat":
+        import flax.linen as nn
+
+        class Block(nn.Module):
+            @nn.compact
+            def __call__(self, q, k, v):
+                return att(q, k, v)
+
+        block = nn.remat(Block)()
+        run = jax.jit(jax.value_and_grad(_weighted(
+            lambda q, k, v: block.apply({}, q, k, v), w), (0, 1, 2)))
+    elif wrap == "vmap":
+        # The async step vmaps workers over the model: pallas_call's
+        # batching rule, forward and backward.
+        stacked = jax.vmap(att)
+        run = jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(
+            stacked(q[None], k[None], v[None])[0] * w), (0, 1, 2)))
+    elif wrap == "shard_map":
+        spec = jax.sharding.PartitionSpec("data")
+        sharded = jax.shard_map(att, mesh=mesh, in_specs=(spec,) * 3,
+                                out_specs=spec, check_vma=False)
+        run = jax.jit(jax.value_and_grad(_weighted(sharded, w), (0, 1, 2)))
+    else:
+        # What parallel/sync.py's plain step does: global view over the
+        # mesh, the kernel taken (forced here: the CPU never takes it),
+        # so causal_attention must shard it over the batch axis itself.
+        monkeypatch.setattr(attention_op, "takes_kernel", lambda s: True)
+        q, k, v, w = jax.device_put((q, k, v, w), batch_sharding(mesh))
+
+        def loss(q, k, v):
+            with global_view(mesh):
+                return _weighted(causal_attention, w)(q, k, v)
+
+        run = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))
+        assert "shard_map" in str(jax.make_jaxpr(loss)(q, k, v))
+    value, grads = run(q, k, v)
+    want, want_grads = jax.value_and_grad(
+        _weighted(einsum_causal_attention, w), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(value, want, rtol=1e-5)
+    for g, r in zip(grads, want_grads):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+def test_attention_refuses_shapes_that_do_not_tile():
+    q, k, v, _ = _qkvw(heads=2, head_dim=32, seq=128)
+    with pytest.raises(ValueError, match="does not tile"):
+        blocked_causal_attention(q, k, v)
+    q, k, v, _ = _qkvw(seq=96)
+    with pytest.raises(ValueError, match="does not tile"):
+        blocked_causal_attention(q, k, v)
