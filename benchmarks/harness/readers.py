@@ -4,8 +4,9 @@ file under ``benchmarks/metrics/`` names the rule it uses."""
 
 from __future__ import annotations
 
-from benchmarks.harness import flops, stats
+from benchmarks.harness import stats
 from benchmarks.harness import trace as tr
+from benchmarks.harness.peaks import roofline_seconds
 
 
 def device_idle_pct(run):
@@ -49,10 +50,9 @@ def decode_roofline_pct(run):
         return None
     tail = [b for b in run.facts["tail_boundaries"] if b.busy > 0]
     rows = sum(b.live_cache_rows for b in tail) / len(tail)
-    least, bound = flops.roofline_seconds(
-        flops.decode_step_flops(run.config, rows, run.facts["slots"]),
-        flops.decode_step_bytes(run.config, rows,
-                                run.facts["weight_bytes"]), run.peaks)
+    least, bound = roofline_seconds(
+        run.family.decode_step_flops(run.config, rows, run.facts["slots"]),
+        run.family.decode_step_bytes(run.config, rows), run.peaks)
     print(f"[bench] decode roofline: {bound}-bound, least "
           f"{1e3 * least:.3f} ms, device {1e3 * per_step:.3f} ms a step, "
           f"{rows:.0f} live cache rows", flush=True)

@@ -4,6 +4,7 @@ Stdlib only — it runs before jax is imported."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -13,6 +14,10 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+#: The modules a configuration's file names, by key, and the folder of
+#: each: its family (families/__init__.py says what one provides) and
+#: its plain reference, which imports nothing of the program.
+MODULES = {"family": "families", "reference": "reference"}
 
 
 class SchemaError(ValueError):
@@ -141,8 +146,27 @@ def check(bench: dict, root: str) -> None:
         _need(os.path.isfile(os.path.join(
             root, paths[0], "traffic", w["traffic"] + ".json")),
             f"traffic file of {name} does not exist")
-        src = _load(os.path.join(root, configs[w["config"]]["file"]))
-        _line(src.get("source"), f"source in {configs[w['config']]['file']}")
+
+    # Each configuration's file names its source, and the family and the
+    # plain reference it is run with: one module each, found by name.
+    for c in configs.values():
+        src = _load(os.path.join(root, c["file"]))
+        _line(src.get("source"), f"source in {c['file']}")
+        for key, folder in MODULES.items():
+            name = src.get(key)
+            _need(isinstance(name, str) and NAME.match(name) is not None,
+                  f"{c['file']} must name its {key}")
+            _need(os.path.isfile(os.path.join(
+                root, paths[0], folder, name + ".py")),
+                f"{c['file']}: {key} {name!r} has no module "
+                f"{paths[0]}/{folder}/{name}.py")
+
+
+def load_module(config: dict, key: str):
+    """The module ``config`` names under ``key`` ("family" or
+    "reference"), found by name: nothing else knows which there are."""
+    return importlib.import_module(
+        f"benchmarks.{MODULES[key]}.{config[key]}")
 
 
 def load_and_check(root: str) -> dict:

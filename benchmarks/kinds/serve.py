@@ -25,12 +25,10 @@ import time
 
 import numpy as np
 
-from benchmarks.harness import stats, tokens, weights
+from benchmarks.harness import schema, stats, tokens
 from benchmarks.harness.readers import memory_peak_bytes
-from benchmarks.harness.reference import load_reference
 from benchmarks.harness.spans import Spans
 from benchmarks.harness.tracing import TracedTail
-from benchmarks.kinds.model import transformer_lm
 
 
 #: What the benchmark notes after every boundary of the serving loop.
@@ -149,7 +147,7 @@ def run(run, devices) -> None:
         ContinuousBatcher, RequestQueue)
 
     run.stages.append(("program_import", time.monotonic() - t_import))
-    cfg = run.config
+    cfg, family = run.config, run.family
     seed = stats.seed31(run.seed)
     rng = np.random.default_rng([seed, 2])
     slots, cache_len = run.param("slots"), run.param("cache_len")
@@ -176,8 +174,8 @@ def run(run, devices) -> None:
                 p.due = float(due)          # seconds after the window opens
 
     with run.stage("weights"):
-        model = transformer_lm(cfg, dtype=jnp.bfloat16)
-        params = weights.init_params(cfg, seed)
+        model = family.build_model(cfg, dtype=jnp.bfloat16)
+        params = family.init_params(cfg, seed)
         jax.block_until_ready(params)
     with run.stage("engine"):
         engine = DecodeEngine(model, params, slots=slots,
@@ -312,8 +310,7 @@ def run(run, devices) -> None:
         flush=True)
     run.spans = spans
     run.facts.update(window=(t_open, t_close), slots=slots,
-                     boundaries=inside, weight_bytes=4 * weights.param_count(
-                         cfg))
+                     boundaries=inside)
     run.samples["decode_step_s"] = spans.durations(
         "serve_decode", (t_open, t_close))
     run.samples["prefill_s"] = spans.durations(
@@ -379,13 +376,12 @@ def run(run, devices) -> None:
     del engine, batcher, queue, params, model, submitted, live, plan
     gc.collect()
     jax.clear_caches()
-    ref_mod = load_reference(cfg)
+    ref_mod = schema.load_module(cfg, "reference")
     t0 = time.monotonic()
-    ref_params = weights.init_params(cfg, seed)
+    ref_params = family.init_params(cfg, seed)
     widest, n_tok = 0.0, 0
     for prompt, toks in served:
-        got = ref_mod.served_token_gaps(ref_params, prompt, toks, cfg,
-                                        cfg["n_positions"])
+        got = ref_mod.served_token_gaps(ref_params, prompt, toks, cfg)
         widest, n_tok = max(widest, got["widest"]), n_tok + got["tokens"]
     print(f"[bench] reference: {len(served)} requests, {n_tok} served "
           f"tokens in {time.monotonic() - t0:.2f} s (not counted in "
@@ -395,8 +391,8 @@ def run(run, devices) -> None:
         run.compare("served_logit_gap_widest", widest, limit)
     for precision in run.controls:
         low = max(ref_mod.served_token_gaps(
-            ref_params, prompt, toks, cfg, cfg["n_positions"],
-            control=precision)["widest"] for prompt, toks in served)
+            ref_params, prompt, toks, cfg, control=precision)["widest"]
+            for prompt, toks in served)
         run.control_verdicts[precision] = [
             ("served_logit_gap_widest", low, limit)]
 

@@ -15,12 +15,10 @@ import time
 
 import numpy as np
 
-from benchmarks.harness import stats, tokens, weights
+from benchmarks.harness import schema, stats, tokens
 from benchmarks.harness.readers import memory_peak_bytes
-from benchmarks.harness.reference import load_reference
 from benchmarks.harness.spans import Spans
 from benchmarks.harness.tracing import TracedTail
-from benchmarks.kinds.model import transformer_lm
 
 
 class _Probe:
@@ -71,12 +69,15 @@ def _momentum_trace(opt_state, params):
     return found[0]
 
 
+def leaf_gaps(got: np.ndarray, ref: np.ndarray) -> np.ndarray:
+    """Each leaf's gap between the program's norm and the reference's,
+    against the reference's norm of that leaf or of the median leaf,
+    whichever is larger (some gradients are all but 0)."""
+    return np.abs(got - ref) / np.maximum(ref, np.median(ref))
+
+
 def worst_leaf_gap(got: np.ndarray, ref: np.ndarray) -> float:
-    """The worst leaf's gap between the program's norm and the
-    reference's, against the reference's norm of that leaf or of the
-    median leaf, whichever is larger (some gradients are all but 0)."""
-    floor = np.maximum(ref, np.median(ref))
-    return float(np.max(np.abs(got - ref) / floor))
+    return float(np.max(leaf_gaps(got, ref)))
 
 
 def judge(program, ref: dict, limits: dict) -> list:
@@ -111,7 +112,7 @@ def run(run, devices) -> None:
         MetricsLogger)
 
     run.stages.append(("program_import", time.monotonic() - t_import))
-    cfg = run.config
+    cfg, family = run.config, run.family
     seed = stats.seed31(run.seed)
     T, B = run.param("seq_len"), run.param("batch_per_chip")
     lr, mom = run.param("learning_rate"), run.param("momentum")
@@ -131,7 +132,7 @@ def run(run, devices) -> None:
     spec = RunSpec(
         model=run.cell["config"], dataset="lm", config=run_cfg,
         token_data=True,
-        model_fn=lambda c: transformer_lm(
+        model_fn=lambda c: family.build_model(
             cfg, dropout_rate=c.dropout, dtype=jnp.dtype(c.dtype),
             remat=c.remat),
         input_fn=lambda c, split: (corpus[:, :-1], corpus[:, 1:]))
@@ -140,12 +141,14 @@ def run(run, devices) -> None:
     with run.stage("weights"):
         sharding = replicated_sharding(build.mesh)
         state = build.state.replace(
-            params=weights.init_params(cfg, seed, sharding))
+            params=family.init_params(cfg, seed, sharding))
         jax.block_until_ready(state.params)
+    leaf_names = [jax.tree_util.keystr(path) for path, _ in
+                  jax.tree_util.tree_flatten_with_path(state.params)[0]]
 
     leaf_norms = jax.jit(lambda tree: jnp.stack(
         [jnp.sqrt(jnp.sum(jnp.square(x))) for x in jax.tree.leaves(tree)]))
-    init = weights.init_fn(cfg)
+    init = family.init_fn(cfg)
     delta_from_seed = jax.jit(lambda p, s: jnp.stack(
         [jnp.sqrt(jnp.sum(jnp.square(a - b))) for a, b in zip(
             jax.tree.leaves(p), jax.tree.leaves(init(s)))]))
@@ -242,16 +245,27 @@ def run(run, devices) -> None:
     del state, build, loop, hooks, probe, leaf_norms, delta_from_seed, spec
     gc.collect()
     jax.clear_caches()
-    ref_mod = load_reference(cfg)
+    ref_mod = schema.load_module(cfg, "reference")
     t0 = time.monotonic()
     ref_args = dict(
-        make_params=lambda: weights.init_params(cfg, seed),
+        make_params=lambda: family.init_params(cfg, seed),
         batches=[corpus[r] for r in check_rows], cfg=cfg, learning_rate=lr,
         momentum=mom, rows_per_block=run.param("reference_rows_per_block"))
     ref = ref_mod.train_steps(**ref_args)
     print(f"[bench] reference: {check_steps} float32 steps in "
           f"{time.monotonic() - t0:.2f} s (not counted in setup_s)",
           flush=True)
+    for what, got, want in (
+            ("first_grad_norm", program[1], ref["first_grad_norms"]),
+            ("param_change_norm", program[2], ref["delta_norms"])):
+        # Which leaf the worst gap is: a refused run then names it.
+        gaps = leaf_gaps(got, want)
+        i = int(np.argmax(gaps))
+        print(f"[bench] {what}: worst leaf {leaf_names[i]} program "
+              f"{got[i]:.6g} reference {want[i]:.6g} (median leaf "
+              f"{np.median(want):.6g}); leaves over half the worst: "
+              f"{int(np.sum(gaps > gaps[i] / 2))} of {len(gaps)}",
+              flush=True)
     for what, value, limit in judge(program, ref, run.param("limits")):
         run.compare(what, value, limit)
     for precision in run.controls:

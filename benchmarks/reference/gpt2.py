@@ -199,14 +199,17 @@ def train_steps(make_params, batches, cfg: dict, *, learning_rate: float,
             "delta_norms": delta}
 
 
-def served_token_gaps(params, prompt, served, cfg: dict, pad_to: int,
+def served_token_gaps(params, prompt, served, cfg: dict,
+                      pad_to: int | None = None,
                       control: str | None = None) -> dict:
     """One teacher-forced pass over ``prompt`` followed by the tokens
-    that were ``served`` after it.  For every served token: how far its
-    reference logit lies below the reference's best at that position.
-    With ``control``, the token judged at each position is instead the
-    one the lower precision puts first there."""
+    that were ``served`` after it, padded to ``pad_to`` positions (the
+    configuration's longest sequence unless given).  For every served
+    token: how far its reference logit lies below the reference's best
+    at that position.  With ``control``, the token judged at each
+    position is instead the one the lower precision puts first there."""
     import numpy as np
+    pad_to = pad_to or cfg["n_positions"]
     seq = np.concatenate([np.asarray(prompt), np.asarray(served)])
     n_p, n_s = len(prompt), len(served)
     padded = np.zeros((1, pad_to), np.int32)

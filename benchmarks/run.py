@@ -6,9 +6,10 @@
 A new process every time: load, warm up, measure for ``--seconds``,
 check what the timed path produced against the plain reference, print
 one JSON object as the last line of stdout, exit.  Everything that
-belongs to one cell, one configuration, one traffic mix or one
-per-layer metric is a file found by its name (see PERF.md, "How to add
-a cell"): this file holds none of it.
+belongs to one cell, one configuration, one architecture (the
+configuration's family), one traffic mix or one per-layer metric is a
+file found by its name (see PERF.md, "Layout"): this file holds none of
+it.
 """
 
 from __future__ import annotations
@@ -80,11 +81,12 @@ class Run:
     """What one run knows; the kind's driver fills it and the per-layer
     readers read it."""
 
-    def __init__(self, *, cell_name, cell, config, traffic, seed, seconds,
-                 traced, chips, peaks, meter, controls=()):
+    def __init__(self, *, cell_name, cell, config, family, traffic, seed,
+                 seconds, traced, chips, peaks, meter, controls=()):
         self.cell_name = cell_name
         self.cell = cell            # benchmarks/workloads/<cell>.json
         self.config = config        # benchmarks/configs/<config>.json
+        self.family = family        # benchmarks/families/<family>.py
         self.traffic = traffic      # benchmarks/traffic/<traffic>.json
         self.seed = seed
         self.seconds = seconds
@@ -218,8 +220,10 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
         from benchmarks.harness.peaks import peaks_for
         peaks = peaks_for(kind)         # a device not in the table: error
 
-    run = Run(cell_name=workload, cell=cell, config=config, traffic=traffic,
-              seed=int(seed), seconds=float(seconds), traced=bool(traced),
+    run = Run(cell_name=workload, cell=cell, config=config,
+              family=schema.load_module(config, "family"), traffic=traffic,
+              seed=int(seed),
+              seconds=float(seconds), traced=bool(traced),
               chips=len(devices), peaks=peaks, meter=meter,
               controls=controls)
     run.stages.append(("import_and_devices", time.monotonic() - _T_START))

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.harness import tokens, weights
+from benchmarks.families import gpt2 as family
+from benchmarks.harness import tokens
 from benchmarks.kinds import train as train_kind
 from benchmarks.reference import gpt2
 from benchmarks.tests.conftest import (ROOT, TINY_CONFIG, TINY_SERVE,
@@ -43,7 +44,7 @@ def test_training_in_int8_is_not_correct(seed):
     rows = tokens.markov_tokens(12, 64, cfg["vocab_size"], seed)
     batches = [rows[0:4], rows[4:8], rows[8:12]]
     kw = dict(learning_rate=0.05, momentum=0.9)
-    make = lambda: weights.init_params(cfg, seed)     # noqa: E731
+    make = lambda: family.init_params(cfg, seed)     # noqa: E731
     ref = gpt2.train_steps(make, batches, cfg, **kw)
     low = gpt2.train_steps(make, batches, cfg, precision="int8", **kw)
     as_program = (low["losses"], low["first_grad_norms"], low["delta_norms"])
@@ -65,7 +66,7 @@ def test_serving_in_fp8_is_not_correct(seed):
     limit = _cell("gpt2_124m.serve_backlog")["params"]["limits"][
         "served_logit_gap_widest"]
     rng = np.random.default_rng(seed)
-    params = weights.init_params(cfg, seed)
+    params = family.init_params(cfg, seed)
     prompt = tokens.uniform_prompt(rng, 64, cfg["vocab_size"])
     served = tokens.uniform_prompt(rng, 190, cfg["vocab_size"])
     read = {p: gpt2.served_token_gaps(params, prompt, served, cfg, 256,

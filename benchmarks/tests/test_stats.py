@@ -7,7 +7,9 @@ import os
 import numpy as np
 import pytest
 
-from benchmarks.harness import flops, stats
+from benchmarks.families import gpt2
+from benchmarks.harness import stats
+from benchmarks.harness.peaks import roofline_seconds
 from benchmarks.tests.conftest import ROOT
 
 
@@ -61,18 +63,18 @@ def test_train_flops_of_gpt2_small_by_hand(small):
     # 2*2*768*3072 = 14,155,776; attention over (1024+1)/2 keys:
     # 4*768*512.5 = 1,574,400.  Head 2*768*50257 = 77,194,752.
     fwd = 12 * (14_155_776 + 1_574_400) + 77_194_752
-    assert flops.forward_flops_per_token(small, 1024) == fwd == 265_956_864
-    assert flops.train_flops_per_token(small, 1024) == 3 * fwd
+    assert gpt2.forward_flops_per_token(small, 1024) == fwd == 265_956_864
+    assert gpt2.train_flops_per_token(small, 1024) == 3 * fwd
 
 
 def test_decode_step_of_gpt2_small_by_hand(small):
     # 96 slots, 30,000 live rows: weights 12*14,155,776 + head per slot,
     # 4*768 per live row and layer.
     f = 96 * (12 * 14_155_776 + 77_194_752) + 12 * 4 * 768 * 30_000
-    assert flops.decode_step_flops(small, 30_000, 96) == f
+    assert gpt2.decode_step_flops(small, 30_000, 96) == f
     # f32 weights once (124,439,808 * 4) and K and V rows in bf16.
     b = 497_759_232 + 30_000 * 12 * 2 * 768 * 2
-    assert flops.decode_step_bytes(small, 30_000, 497_759_232) == b
+    assert gpt2.decode_step_bytes(small, 30_000) == b
     peaks = dict(bf16_flops_per_s=197e12, hbm_bytes_per_s=819e9)
-    t, bound = flops.roofline_seconds(f, b, peaks)
+    t, bound = roofline_seconds(f, b, peaks)
     assert bound == "bandwidth" and t == pytest.approx(b / 819e9)
