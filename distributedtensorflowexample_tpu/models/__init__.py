@@ -37,6 +37,25 @@ def build_model(name: str, **kw):
         raise ValueError(f"unknown model {name!r}; have {sorted(_REGISTRY)}") from None
 
 
+def build_model_from_config(config, **kw):
+    """A model from a published configuration (a dict, or the path of
+    its JSON file) by its ``model_type``: the one constructor the
+    benchmark's families, ``serving/promote.py`` and ``tools/serve_lm.py
+    --model_config`` share.  The architecture's module is imported here,
+    when a configuration asks for it, not with the package."""
+    if not isinstance(config, dict):
+        import json
+        with open(config) as f:
+            config = json.load(f)
+    kind = config.get("model_type")
+    if kind == "afmoe":
+        from distributedtensorflowexample_tpu.models.afmoe import build_afmoe
+        return build_afmoe(config, **kw)
+    raise ValueError(
+        f"no model is built from a configuration of model_type {kind!r} "
+        f"(have: afmoe; the GPT-2 ladder is built by size, LM_SIZES)")
+
+
 __all__ = ["SoftmaxRegression", "MnistCNN", "ResNet20", "ResNetCIFAR",
            "TransformerLM", "build_lm", "LM_SIZES", "LM_VOCAB",
-           "build_model"]
+           "build_model", "build_model_from_config"]

@@ -1,0 +1,406 @@
+"""The ``afmoe`` decoder (Arcee's Trinity family): a current LM block
+beside ``transformer_lm.py``'s GPT-2 one.
+
+A layer (d the hidden size, ``RMS(x; g) = x / sqrt(mean(x^2) + eps) * g``,
+no bias anywhere)::
+
+    a = RMS(h; g_in)
+    q, k, v, u = a Wq, a Wk, a Wv, a Wg       Hq query, Hkv key/value heads
+    q, k = RMS(q; g_q), RMS(k; g_k)           over each head's features
+    q, k = rope(q, k; position)               on a window layer only
+    p = softmax(q k^T / sqrt(Dh))             causal; a window layer sees
+                                              only the last `window` keys
+    h = h + RMS(((p v) * sigmoid(u)) Wo; g_post_attn)
+    m = RMS(h; g_pre_mlp)
+    f = ffn(m)                                a leading dense layer, or
+    f = ffn_shared(m) + sum_e w_e ffn_e(m)    top-k of a sigmoid router
+    h = h + RMS(f; g_post_mlp)
+
+with ``ffn(m; G, U, D) = (silu(m G) * (m U)) D``; the embedding is
+scaled by ``sqrt(d)``, the head is untied, logits are float32.
+
+**One definition of a block** (:class:`AfmoeBlock`), with the methods
+serving needs beside the training-shape forward: ``sequence`` (a whole
+sequence: the forward, and prefill, which also keeps the K/V it made)
+and ``step`` (a K-token window per slot against that slot's cache rows;
+plain decode is K == 1).  There is no serving twin: :class:`AfmoeLM`
+IS its own serving module (``serving_module()`` returns it), and states
+each layer's cache rows itself (``cache_rows``): a full-attention layer
+holds ``cache_len`` rows a slot, a window layer ``min(window,
+cache_len)`` rows as a ring, a position's row being ``position mod
+rows``.
+
+**The expert layer holds a share** (``ops/moe.py``): ``experts_held``
+experts from ``first_expert`` on, of the ``n_routed`` the router scores.
+What the absent experts would add is left out.  The whole model is the
+share with ``experts_held == n_routed``.
+
+Parameters are stored in ``param_dtype`` (bfloat16 in serving); norms,
+the router's scores, softmax and logits are computed in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from distributedtensorflowexample_tpu.ops import moe
+from distributedtensorflowexample_tpu.ops.attention import (
+    ATTN_BLOCK, grouped_attention)
+
+WINDOW, FULL = "sliding_attention", "full_attention"
+_MASKED = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeDims:
+    """Every size of the architecture (hashable: a flax field)."""
+    vocab_size: int
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    d_ff: int                   # the leading dense layers' width
+    d_expert: int               # each routed and the shared expert's width
+    layer_types: tuple          # WINDOW | FULL, one per layer
+    n_dense_layers: int
+    n_routed: int               # experts the router scores
+    experts_held: int           # experts this share computes ...
+    first_expert: int           # ... from this id on
+    top_k: int
+    n_shared: int
+    route_scale: float
+    route_norm: bool
+    window: int
+    rope_theta: float
+    eps: float
+    max_len: int
+    embed_scale: float          # sqrt(d_model) under muP, else 1
+    init_std: float = 0.02
+
+
+def _rms(x, g, eps):
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, positions, theta):
+    """Rotary positions on ``x [..., T, H, Dh]`` at ``positions [...,
+    T]``: the two halves of a head's features rotate as pairs."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions[..., None].astype(jnp.float32) * inv
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class AfmoeBlock(nn.Module):
+    """One layer: attention of its kind (``window`` 0 means full) and a
+    dense or an expert feed-forward."""
+    dims: AfmoeDims
+    window: int
+    experts: bool
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    attn_block: int = ATTN_BLOCK
+
+    def setup(self):
+        c, pd = self.dims, self.param_dtype
+        ones = nn.initializers.ones
+        w = nn.initializers.normal(c.init_std)
+        d, qd, kd = c.d_model, c.n_heads * c.head_dim, \
+            c.n_kv_heads * c.head_dim
+        P = self.param
+        for name in ("norm_in", "norm_post_attn", "norm_pre_mlp",
+                     "norm_post_mlp"):
+            setattr(self, name, P(name, ones, (d,), pd))
+        self.norm_q = P("norm_q", ones, (c.head_dim,), pd)
+        self.norm_k = P("norm_k", ones, (c.head_dim,), pd)
+        self.wq = P("wq", w, (d, qd), pd)
+        self.wk = P("wk", w, (d, kd), pd)
+        self.wv = P("wv", w, (d, kd), pd)
+        self.wg = P("wg", w, (d, qd), pd)
+        self.wo = P("wo", w, (qd, d), pd)
+        if not self.experts:
+            self.ffn = tuple(P(f"ffn_{n}", w, s, pd) for n, s in (
+                ("gate", (d, c.d_ff)), ("up", (d, c.d_ff)),
+                ("down", (c.d_ff, d))))
+            return
+        f, E, fs = c.d_expert, c.experts_held, c.n_shared * c.d_expert
+        self.router = P("router", w, (d, c.n_routed), pd)
+        self.router_bias = P("router_bias", nn.initializers.normal(0.01),
+                             (c.n_routed,), jnp.float32)
+        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
+            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
+        self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
+            ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+
+    # --- attention ---------------------------------------------------------
+    def _qkvu(self, h, positions):
+        """h [..., T, d], positions [..., T] -> q [..., T, Hq, Dh], k and
+        v [..., T, Hkv, Dh], the gate's input u [..., T, Hq Dh]."""
+        c, dt = self.dims, self.dtype
+        a = _rms(h, self.norm_in, c.eps)
+        heads = lambda x, n: x.reshape(*x.shape[:-1], n, c.head_dim)
+        q = heads(jnp.dot(a, self.wq.astype(dt)), c.n_heads)
+        k = heads(jnp.dot(a, self.wk.astype(dt)), c.n_kv_heads)
+        v = heads(jnp.dot(a, self.wv.astype(dt)), c.n_kv_heads)
+        u = jnp.dot(a, self.wg.astype(dt))
+        q, k = _rms(q, self.norm_q, c.eps), _rms(k, self.norm_k, c.eps)
+        if self.window:
+            q = _rope(q, positions, c.rope_theta)
+            k = _rope(k, positions, c.rope_theta)
+        return q, k, v, u
+
+    def _attn_out(self, h, o, u):
+        o = o.reshape(u.shape) * jax.nn.sigmoid(
+            u.astype(jnp.float32)).astype(o.dtype)
+        o = jnp.dot(o, self.wo.astype(self.dtype))
+        return h + _rms(o, self.norm_post_attn, self.dims.eps)
+
+    # --- feed-forward --------------------------------------------------------
+    def _ffn(self, h, live):
+        """h [..., d], live [...] or None -> (h', stats int32[3])."""
+        c, dt = self.dims, self.dtype
+        m = _rms(h, self.norm_pre_mlp, c.eps).reshape(-1, c.d_model)
+        cast = lambda ws: tuple(x.astype(dt) for x in ws)
+        if not self.experts:
+            f = moe.gated_ffn(m, *cast(self.ffn))
+            stats = jnp.zeros((len(moe.STATS),), jnp.int32)
+        else:
+            sel, w = moe.route(m, self.router.astype(dt), self.router_bias,
+                               top_k=c.top_k, route_scale=c.route_scale,
+                               route_norm=c.route_norm)
+            f, stats = moe.expert_ffn(
+                m, sel, w, *cast(self.held), first_expert=c.first_expert,
+                live=None if live is None else live.reshape(-1))
+            with jax.named_scope("moe.shared"):
+                f = f + moe.gated_ffn(m, *cast(self.shared))
+        return h + _rms(f.reshape(h.shape), self.norm_post_mlp, c.eps), stats
+
+    # --- the two shapes of work --------------------------------------------
+    def sequence(self, x, live=None):
+        """A whole sequence: x [B, T, d], live [B, T] (false on padding)
+        -> (x', k [B, T, Hkv, Dh], v, stats).  The training-shape forward
+        and prefill are this one method."""
+        T = x.shape[1]
+        q, k, v, u = self._qkvu(x, jnp.arange(T)[None])
+        with jax.named_scope("attn.window" if self.window else "attn.full"):
+            o = grouped_attention(q, k, v, window=self.window,
+                                  block=self.attn_block)
+        x, stats = self._ffn(self._attn_out(x, o, u), live)
+        return x, k, v, stats
+
+    def __call__(self, x):
+        return self.sequence(x)[0]
+
+    def step(self, x, ck, cv, pos):
+        """A K-token window per slot: x [S, K, d], this layer's cache
+        rows ck/cv [S, R, Hkv, Dh], pos [S] the position the window
+        starts at.  The window's K/V are written first, then read: query
+        j sees positions <= pos + j.
+
+        A full layer writes row = position.  A window layer's R rows are
+        a ring, row = position mod R: after the write, row r holds the
+        newest position <= pos congruent to r, and the query reads the
+        rows whose position is not negative (R <= window, so all of them
+        lie inside its window).  A ring takes ONE token a step: the keys
+        of a longer window would overwrite rows its earlier queries
+        still see, which is also why nothing that rolls a cache back
+        serves a model with window layers (serving/engine.py).  A slot at
+        ``pos == 0`` is parked: its tokens go to no expert."""
+        S, K, _ = x.shape
+        R = ck.shape[1]
+        c = self.dims
+        if self.window and K > 1:
+            raise ValueError(
+                f"a window layer's ring takes one token a step, not {K}: "
+                f"the later keys would overwrite rows the earlier queries "
+                f"still see")
+        positions = pos[:, None] + jnp.arange(K, dtype=pos.dtype)[None]
+        q, k, v, u = self._qkvu(x, positions)
+        q = q.reshape(S, K, c.n_kv_heads, -1, c.head_dim)
+        sl = jnp.arange(S)[:, None]
+        r = jnp.arange(R)
+        with jax.named_scope("cache_update"):
+            rows = jnp.mod(positions, R) if self.window else positions
+            ck = ck.at[sl, rows].set(k)
+            cv = cv.at[sl, rows].set(v)
+        with jax.named_scope("attn.window" if self.window else "attn.full"):
+            if self.window:             # the position row r holds: >= 0
+                ok = (positions - jnp.mod(positions - r[None], R)
+                      >= 0)[:, None]                            # [S,1,R]
+            else:
+                ok = r[None, None, :] <= positions[:, :, None]  # [S,K,R]
+            s = jnp.einsum("skhgd,srhd->shgkr", q, ck,
+                           preferred_element_type=jnp.float32)
+            s = jnp.where(ok[:, None, None], s * c.head_dim ** -0.5, _MASKED)
+            p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
+            o = jnp.einsum("shgkr,srhd->skhgd", p, cv)
+        live = jnp.broadcast_to((pos > 0)[:, None], (S, K))
+        x, stats = self._ffn(self._attn_out(x, o, u), live)
+        return x, ck, cv, stats
+
+
+class AfmoeLM(nn.Module):
+    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
+    programs ``DecodeEngine`` asks a model for."""
+    dims: AfmoeDims
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    attn_block: int = ATTN_BLOCK
+
+    # What DecodeEngine reads of any model.
+    vocab_size = property(lambda self: self.dims.vocab_size)
+    max_len = property(lambda self: self.dims.max_len)
+    n_layers = property(lambda self: len(self.dims.layer_types))
+    #: Positions one prefill program takes at most (DecodeEngine splits a
+    #: larger group): two prompts of the longest bucket the benchmark's
+    #: cell uses, 3.2 GB of activations at the published widths.
+    prefill_positions_max = 32768
+    #: Held experts x expert layers: what one step can touch at most.
+    expert_slots = property(lambda self: self.dims.experts_held * (
+        len(self.dims.layer_types) - self.dims.n_dense_layers))
+
+    def setup(self):
+        c, pd = self.dims, self.param_dtype
+        w = nn.initializers.normal(c.init_std)
+        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
+        self.blocks = [AfmoeBlock(
+            c, c.window if kind == WINDOW else 0,
+            i >= c.n_dense_layers, self.dtype, pd, self.attn_block,
+            name=f"block{i}") for i, kind in enumerate(c.layer_types)]
+        self.norm_f = self.param("norm_f", nn.initializers.ones,
+                                 (c.d_model,), pd)
+        self.head = self.param("head", w, (c.d_model, c.vocab_size), pd)
+
+    def _embed(self, tokens):
+        x = self.embed.astype(self.dtype)[tokens]
+        return x * jnp.asarray(self.dims.embed_scale, self.dtype)
+
+    def _logits(self, x):
+        with jax.named_scope("head"):
+            x = _rms(x, self.norm_f, self.dims.eps)
+            return jnp.dot(x, self.head.astype(self.dtype),
+                           preferred_element_type=jnp.float32)
+
+    def __call__(self, tokens, train: bool = False):
+        """The training-shape forward (``train`` is accepted for the
+        trainers' calling convention; the model has no dropout)."""
+        x = self._embed(tokens.astype(jnp.int32))
+        for blk in self.blocks:
+            x = blk(x)
+        return self._logits(x)
+
+    # --- what a model states to DecodeEngine -------------------------------
+    def serving_module(self):
+        return self
+
+    def cache_rows(self, cache_len: int) -> tuple:
+        """``(kind, rows)`` per layer: what one slot's cache holds."""
+        c = self.dims
+        return tuple(("window", min(c.window, cache_len)) if kind == WINDOW
+                     else ("full", cache_len) for kind in c.layer_types)
+
+    def init_cache(self, slots: int, cache_len: int) -> tuple:
+        """``(ck, cv)``, each one ``[slots, rows, Hkv, Dh]`` array a
+        layer."""
+        c = self.dims
+        make = lambda: tuple(
+            jnp.zeros((slots, rows, c.n_kv_heads, c.head_dim), self.dtype)
+            for _, rows in self.cache_rows(cache_len))
+        return make(), make()
+
+    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
+        """toks [B, P] (B prompts padded into one bucket), each written
+        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
+        lengths.  Returns (logits at each prompt's LAST position [B, V]
+        f32 — the head is never taken over the bucket — ck, cv, stats).
+        A window layer whose ring is shorter than the bucket keeps each
+        prompt's last ``rows`` real positions, each at ``position mod
+        rows``."""
+        B, P = toks.shape
+        live = jnp.arange(P)[None] < lengths[:, None]
+        x = self._embed(toks)
+        new_k, new_v, stats = [], [], 0
+        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
+            x, k, v, st = blk.sequence(x, live)
+            stats = stats + st
+            R = ck_l.shape[1]
+            with jax.named_scope("cache_update"):
+                if P > R:
+                    last = lengths[:, None] - 1
+                    at = last - jnp.mod(last - jnp.arange(R)[None], R)
+                    at = jnp.maximum(at, 0)[:, :, None, None]   # [B,R,1,1]
+                    k = jnp.take_along_axis(k, at, axis=1)
+                    v = jnp.take_along_axis(v, at, axis=1)
+                rows = min(P, R)
+                new_k.append(ck_l.at[slots_ix, :rows].set(k))
+                new_v.append(cv_l.at[slots_ix, :rows].set(v))
+        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+        return self._logits(last[:, 0]), tuple(new_k), tuple(new_v), stats
+
+    def verify(self, toks, positions, ck, cv):
+        """toks [S, K], positions [S] -> (logits [S, K, V] f32, ck, cv,
+        stats): the K-token step (see AfmoeBlock.step)."""
+        x = self._embed(toks)
+        new_k, new_v, stats = [], [], 0
+        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
+            x, k_l, v_l, st = blk.step(x, ck_l, cv_l, positions)
+            new_k.append(k_l)
+            new_v.append(v_l)
+            stats = stats + st
+        return self._logits(x), tuple(new_k), tuple(new_v), stats
+
+    def decode(self, tok, positions, ck, cv):
+        """tok [S] -> (logits [S, V], ck, cv, stats): the K == 1 window
+        of :meth:`verify`, not a second program."""
+        logits, ck, cv, stats = self.verify(tok[:, None], positions, ck, cv)
+        return logits[:, 0], ck, cv, stats
+
+
+def dims_from_config(cfg: dict) -> AfmoeDims:
+    """The sizes of a configuration in the source's own keys (an
+    ``afmoe`` ``config.json``).  A configuration that is one chip's
+    share of an expert-parallel deployment says so beside them:
+    ``num_experts`` is then the experts HELD, ``published.num_experts``
+    the router's width, and ``deployment.rank`` which share this is."""
+    published = cfg.get("published", {})
+    held = cfg["num_experts"]
+    d = cfg["hidden_size"]
+    return AfmoeDims(
+        vocab_size=cfg["vocab_size"], d_model=d,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        d_ff=cfg["intermediate_size"], d_expert=cfg["moe_intermediate_size"],
+        layer_types=tuple(cfg["layer_types"]),
+        n_dense_layers=cfg["num_dense_layers"],
+        n_routed=published.get("num_experts", held), experts_held=held,
+        first_expert=cfg.get("deployment", {}).get("rank", 0) * held,
+        top_k=cfg["num_experts_per_tok"],
+        n_shared=cfg["num_shared_experts"],
+        route_scale=cfg["route_scale"], route_norm=cfg["route_norm"],
+        window=cfg["sliding_window"], rope_theta=float(cfg["rope_theta"]),
+        eps=cfg["rms_norm_eps"], max_len=cfg["max_position_embeddings"],
+        embed_scale=d ** 0.5 if cfg.get("mup_enabled") else 1.0)
+
+
+def build_afmoe(config, *, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                attn_block: int = ATTN_BLOCK) -> AfmoeLM:
+    """The one constructor, from a configuration's dict
+    (``models.build_model_from_config`` reads the file and comes here:
+    the benchmark's family, ``serving/promote.py`` and
+    ``tools/serve_lm.py --model_config`` all do)."""
+    if len(config["layer_types"]) != config["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types names {len(config['layer_types'])} layers, "
+            f"num_hidden_layers says {config['num_hidden_layers']}")
+    return AfmoeLM(dims_from_config(config), dtype=dtype,
+                   param_dtype=param_dtype, attn_block=attn_block)

@@ -163,6 +163,14 @@ class TransformerLM(nn.Module):
         return logits + jnp.where(oov, jnp.float32(jnp.nan),
                                   jnp.float32(0.0))
 
+    def serving_module(self):
+        """What ``DecodeEngine`` serves this model with: its prefill /
+        token-step twin (``serving/engine.py: ServingLM``, which binds
+        this model's parameter tree unchanged and states the cache)."""
+        from distributedtensorflowexample_tpu.serving.engine import (
+            serving_lm_for)
+        return serving_lm_for(self)
+
 
 def build_lm(size: str, vocab_size: int = LM_VOCAB,
              dropout: float = 0.0, dtype: jnp.dtype = jnp.bfloat16,

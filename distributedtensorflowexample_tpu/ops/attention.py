@@ -105,3 +105,135 @@ def causal_attention(q, k, v):
                                out_specs=spec, axis_names={axis},
                                check_vma=False)
     return kernel(q, k, v)
+
+
+# --- grouped-query attention with an optional window ----------------------
+
+#: Query rows (and key rows) per tile of :func:`grouped_attention` once a
+#: sequence is longer than one tile.  A tile's scores are ``[B, H, 1024,
+#: 1024]`` float32: 201 MB for 48 heads, where the whole ``[H, T, T]`` of a
+#: 16,384-token prompt would be 51.5 GB.
+ATTN_BLOCK = 1024
+_MASKED = -1e30         # finite: a wholly masked tile gives no inf - inf
+
+
+def _visible(q_pos, k_pos, window):
+    """``[Tq, Tk]``: key position <= query position, and within the last
+    ``window`` positions where the layer has a window."""
+    ok = k_pos[None, :] <= q_pos[:, None]
+    if window:
+        ok &= q_pos[:, None] - k_pos[None, :] < window
+    return ok
+
+
+def _scores(q, k, ok):
+    """One tile of scores: ``q [B, Tq, Hkv, G, Dh]``, ``k [B, Tk, Hkv,
+    Dh]``, ``ok [Tq, Tk]`` -> float32 ``[B, Hkv, G, Tq, Tk]``, masked."""
+    s = jnp.einsum("bthgd,bshd->bhgts", q, k,
+                   preferred_element_type=jnp.float32)
+    return jnp.where(ok, s * q.shape[-1] ** -0.5, _MASKED)
+
+
+def takes_splash(q_shape: tuple, block: int) -> bool:
+    """Built for a TPU, longer than one tile, and shapes the kernel
+    tiles: whole blocks of positions, heads of whole lane groups."""
+    _, t, _, dh = q_shape
+    return (jax.default_backend() == "tpu" and t > block
+            and t % block == 0 and dh % 128 == 0)
+
+
+def splash_grouped_attention(q, k, v, *, window: int = 0,
+                             block: int = ATTN_BLOCK,
+                             interpret: bool = False):
+    """:func:`grouped_attention` by JAX's own TPU kernel (splash
+    attention: an online softmax over key blocks held in VMEM, blocks
+    the causal or window mask empties never visited), one call per
+    key/value head over its group of query heads.  The kernel applies
+    no scale: q is scaled first, in its own type."""
+    # Imported where the kernel is taken (jax.experimental.pallas costs
+    # every CPU run a second or two to import).
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as masks)
+    B, T, Hq, Dh = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    mask = (masks.LocalMask((T, T), (window - 1, 0), 0) if window
+            else masks.CausalMask((T, T)))
+    attend = kernel.make_splash_mqa_single_device(
+        masks.MultiHeadMask([mask] * G), interpret=interpret,
+        block_sizes=kernel.BlockSizes(block_q=block, block_kv=block,
+                                      block_kv_compute=block))
+    q = (q * Dh ** -0.5).astype(q.dtype).reshape(B, T, Hkv, G, Dh)
+    out = jax.vmap(jax.vmap(attend))(           # over B, then over Hkv
+        jnp.transpose(q, (0, 2, 3, 1, 4)),      # [B, Hkv, G, T, Dh]
+        jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, T, Hq, Dh)
+
+
+def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
+    """Causal softmax attention with fewer key/value heads than query
+    heads: ``q [B, T, Hq, Dh]``, ``k``/``v`` ``[B, T, Hkv, Dh]``, query
+    head i reading key/value head ``i // (Hq // Hkv)``; with ``window``
+    a query sees only the last ``window`` positions, itself included.
+    Returns ``[B, T, Hq, Dh]``.
+
+    One path that adapts to what the call can observe, as
+    :func:`causal_attention` does: a sequence of at most ``block``
+    positions is one tile of the einsum chain; a longer one goes to the
+    TPU's kernel where the program is built for a TPU and the shapes
+    tile (:func:`takes_splash`), and is otherwise walked in query tiles,
+    each against the key tiles it can see (all up to the diagonal, or
+    the few a window reaches), with a running max and sum.  Nothing
+    larger than a tile's scores exists in either; in the walk each
+    tile's scores still pass through HBM, in the kernel they stay in
+    VMEM.  Scores and the softmax are float32; the weighted sum takes
+    the probabilities in the operands' type.  Both are forward only (the
+    serving prefill and a forward at a training shape)."""
+    B, T, Hq, Dh = q.shape
+    Hkv = k.shape[2]
+    if takes_splash(q.shape, block):
+        return splash_grouped_attention(q, k, v, window=window, block=block)
+    q = q.reshape(B, T, Hkv, Hq // Hkv, Dh)
+    if T <= block:
+        pos = jnp.arange(T)
+        s = _scores(q, k, _visible(pos, pos, window))
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhgts,bshd->bthgd", p, v).reshape(B, T, Hq, Dh)
+
+    pad = -T % block
+    if pad:     # padded keys lie after every real query: never visible
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                   for a in (q, k, v))
+    G = Hq // Hkv
+
+    def rows(a, i):
+        return jax.lax.dynamic_slice_in_dim(a, i * block, block, axis=1)
+
+    def query_tile(i):
+        qi = rows(q, i)
+        q_pos = i * block + jnp.arange(block)
+
+        def key_tile(j, carry):
+            m, l, acc = carry
+            ok = _visible(q_pos, j * block + jnp.arange(block), window)
+            s = _scores(qi, rows(k, j), ok)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "bhgts,bshd->bhgtd", p.astype(v.dtype), rows(v, j),
+                preferred_element_type=jnp.float32)
+            return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+        first = (jnp.maximum(0, i * block - (window - 1)) // block
+                 if window else 0)
+        init = (jnp.full((B, Hkv, G, block), _MASKED, jnp.float32),
+                jnp.zeros((B, Hkv, G, block), jnp.float32),
+                jnp.zeros((B, Hkv, G, block, Dh), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(first, i + 1, key_tile, init)
+        return (acc / l[..., None]).astype(v.dtype)     # [B,Hkv,G,block,Dh]
+
+    out = jax.lax.map(query_tile, jnp.arange((T + pad) // block))
+    out = jnp.moveaxis(out, 0, 3)               # [B, Hkv, G, nq, block, Dh]
+    out = out.reshape(B, Hq, T + pad, Dh)[:, :, :T]
+    return jnp.swapaxes(out, 1, 2)

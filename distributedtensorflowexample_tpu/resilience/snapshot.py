@@ -208,6 +208,11 @@ class SnapshotStore:
                 f"snapshot {step} holds {len(loaded)} leaves; this run's "
                 f"state has {len(t_leaves)} — the model/optimizer changed "
                 f"since the snapshot was written")
+        # numpy's archive keeps the bytes of a bfloat16 leaf but not its
+        # type (it comes back as void): the template says what it was.
+        loaded = [r.view(t.dtype) if r.dtype.kind == "V"
+                  and r.dtype.itemsize == np.dtype(t.dtype).itemsize else r
+                  for t, r in zip(t_leaves, loaded)]
         restored_leaves = [
             jax.device_put(r, t.sharding) if isinstance(t, jax.Array) else r
             for t, r in zip(t_leaves, loaded)]

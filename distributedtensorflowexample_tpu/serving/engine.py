@@ -6,15 +6,40 @@ generates one token per live request per step, so the arithmetic that
 matters is (a) the prompt's one-time *prefill* (full causal attention,
 exactly the training forward) and (b) the steady-state *decode* step: a
 single-query attention against the K/V rows every earlier position
-already produced.  This module keeps those rows resident — two
-``[L, S, T, H, Dh]``-shaped buffers, one slot per concurrently-decoding
-request — and compiles ONE decode step whose cache arguments are
-DONATED: XLA aliases the updated cache onto the input buffers
-(``input_output_alias`` in the compiled header), so steady-state decode
-allocates nothing cache-shaped per step.  That claim is not folklore —
-:data:`DECODE_HLO_CONTRACT` is declared next to the step builder and
-checked on freshly compiled text by graftlint's HLO front
-(``analysis/hlo_lint.py``), the same way the ZeRO schedules are pinned.
+already produced.  This module keeps those rows resident and compiles
+ONE decode step whose cache arguments are DONATED.
+
+**The engine asks the model** for what it serves with
+(``model.serving_module()``): a flax module with ``prefill_into``,
+``verify`` and ``decode`` methods, which also states each layer's cache
+rows (``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer, kind
+``full`` or ``window``) and allocates them (``init_cache(slots,
+cache_len)`` -> ``(ck, cv)``).  The engine holds that pair, donates it
+to every program and rebinds what comes back; it never looks inside.
+Two layouts exist today:
+
+* ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
+  Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
+  window layer ``min(window, cache_len)`` rows as a ring (row = position
+  mod rows).  One shape for all layers would hold every window layer at
+  ``cache_len`` rows.
+* ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
+  stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
+  them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU
+  text by graftlint's HLO front), but on the TPU the step still copies
+  one whole layer's slice a layer a step (``ck[i]`` / ``jnp.stack``):
+  ``copy_time_pct.backlog`` measures those copies at 52% of GPT-2's
+  decode step (PERF.md section 5), ``copy_time_pct.mixed`` reads the new
+  model's.  They are measured and open, not removed here: the layout is
+  kept as it is because ``gpt2_124m.serve_backlog`` cannot yet measure an
+  engine twice as fast (PERF.md section 7 (4)).
+
+``read_rows`` / ``write_rows`` (the prefix cache) and ``verify_step`` /
+``extend`` (speculation, suffix extension) assume the stacked layout and
+a cache a rejected token can be rolled back from; on a ring a window's
+writes overwrite rows that a rollback would need.  For a model with
+window layers they refuse by name (:func:`refuse_window_layers`), as do
+``PrefixCache``, ``SpecDecoder`` and ``ShardedDecodeEngine``.
 
 Numerics: the serving modules mirror ``models/transformer_lm.py``
 sub-module for sub-module — same flax layers, same names (so a training
@@ -46,8 +71,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributedtensorflowexample_tpu.models.transformer_lm import (
-    TransformerLM)
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.obs.trace import hot_span
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
@@ -62,6 +85,25 @@ _PREFILL_PROGRAMS = obs_metrics.gauge(
     "serve_prefill_programs", "distinct (bucket, B) prefill shapes — one "
     "compiled program each — of the engine that last met a cold one (one "
     "series a process: beside a draft engine, whichever wrote last)")
+
+_CACHE_BYTES = obs_metrics.gauge(
+    "serve_cache_bytes", "bytes of the engine's K/V cache, by the kind of "
+    "layer that holds them (full = cache_len rows a slot, window = a ring)")
+_ROWS_READ = obs_metrics.counter(
+    "serve_cache_rows_read_total", "cache rows the busy slots' queries "
+    "attended, summed over decode steps and layers, by kind of layer (a "
+    "query at position p reads p + 1 rows of a full layer and min(p + 1, "
+    "rows) of a window layer's ring)")
+_MOE_PAIRS = obs_metrics.counter(
+    "moe_pairs_total", "(token, expert) pairs the live tokens of prefill "
+    "and decode programs routed, by where the expert is: held (computed "
+    "here) or absent (another share's)")
+_MOE_TOUCHED = obs_metrics.counter(
+    "moe_experts_touched_total", "held experts that got at least one "
+    "pair, summed over expert layers and decode steps")
+_MOE_SLOTS = obs_metrics.counter(
+    "moe_expert_slots_total", "held experts x expert layers, summed over "
+    "decode steps: what moe_experts_touched_total is a share of")
 
 #: The decode step's compiled-HLO contract (graftlint HLO front,
 #: analysis/hlo_lint.py `serving_suite`): the KV-cache donation actually
@@ -279,14 +321,56 @@ class ServingLM(nn.Module):
         logits, ck, cv = self.verify(tok[:, None], positions, ck, cv)
         return logits[:, 0], ck, cv
 
+    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
+        """toks [B, Pb] — B queued prompts in one bucketed forward;
+        slots_ix/lengths [B].  Each prompt's K/V rows scatter into its
+        own slot; returns the f32 logits at each prompt's true last
+        position (pad rows beyond it are never read), ck, cv."""
+        logits, k, v = self.prefill(toks)
+        ck = ck.at[:, slots_ix, :toks.shape[1]].set(k)
+        cv = cv.at[:, slots_ix, :toks.shape[1]].set(v)
+        last = jnp.take_along_axis(
+            logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+        return last, ck, cv
 
-def serving_lm_for(model: TransformerLM) -> ServingLM:
-    """The serving twin of a training model — every architecture field
-    copied, so the training param tree binds bit-for-bit."""
+    # --- what a serving module states to DecodeEngine ----------------------
+    def cache_rows(self, cache_len: int) -> tuple:
+        """``(kind, rows)`` per layer: every layer full."""
+        return (("full", cache_len),) * self.n_layers
+
+    def init_cache(self, slots: int, cache_len: int) -> tuple:
+        """The stacked pair ``[L, S, T, H, Dh]`` (the module docstring
+        says why this model keeps it)."""
+        shape = (self.n_layers, slots, cache_len, self.n_heads,
+                 self.d_model // self.n_heads)
+        return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
+
+
+def serving_lm_for(model) -> ServingLM:
+    """The serving twin of a ``TransformerLM`` — every architecture field
+    copied, so the training param tree binds bit-for-bit (what
+    ``TransformerLM.serving_module()`` returns)."""
     return ServingLM(vocab_size=model.vocab_size,
                      n_layers=model.n_layers, d_model=model.d_model,
                      n_heads=model.n_heads, d_ff=model.d_ff,
-                     max_len=model.max_len, dtype=model.dtype)
+                     max_len=model.max_len, dtype=model.dtype,
+                     parent=None)    # a module of its own, not a child
+
+
+def refuse_window_layers(model, what: str) -> None:
+    """``what`` needs every layer's cache to be the same ``cache_len``
+    rows, addressed by position; a model with window layers keeps rings
+    and is refused by name."""
+    rows = model.serving_module().cache_rows(1)
+    n = sum(kind == "window" for kind, _ in rows)
+    if n:
+        raise ModeRefusal(
+            f"{what} does not serve a model with window-attention layers "
+            f"({n} of this model's {len(rows)} keep a ring of the last "
+            f"positions, a row being position mod the ring's length): it "
+            f"reads, writes or rolls back cache rows by position, and a "
+            f"ring has overwritten the rows that needs — serve this model "
+            f"through DecodeEngine alone")
 
 
 def _prefill_buckets(cache_len: int, smallest: int = 8) -> tuple:
@@ -315,10 +399,20 @@ def _prefill_buckets(cache_len: int, smallest: int = 8) -> tuple:
 # equal-config engines share programs process-wide; donation stays on
 # the cache operands only.
 
+# A serving module's methods return ``(logits, ck, cv)`` and may add a
+# fourth, a small int32 vector of counts (``ops/moe.STATS``, summed over
+# layers): the greedy programs then return it behind the tokens, ONE
+# int32 array, so the host's one read-back brings both.
+
+def _with_stats(toks, rest: tuple):
+    return jnp.concatenate([toks.ravel(), *rest]) if rest else toks
+
+
 def _decode_step_fn(smodel, params, ck, cv, tok, pos):
-    logits, ck, cv = smodel.apply({"params": params}, tok, pos, ck, cv,
-                                  method=ServingLM.decode)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
+    logits, ck, cv, *stats = smodel.apply({"params": params}, tok, pos, ck,
+                                          cv, method="decode")
+    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return _with_stats(toks, stats), ck, cv
 
 
 _decode_step = jax.jit(_decode_step_fn, static_argnums=0,
@@ -331,13 +425,13 @@ def _decode_logits_step(smodel, params, ck, cv, tok, pos):
     # the fused argmax (greedy keeps its own program — and its pinned
     # HLO contract — untouched).
     return smodel.apply({"params": params}, tok, pos, ck, cv,
-                        method=ServingLM.decode)
+                        method="decode")[:3]
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=(2, 3))
 def _verify_window(smodel, params, ck, cv, toks, pos):
     logits, ck, cv = smodel.apply({"params": params}, toks, pos, ck, cv,
-                                  method=ServingLM.verify)
+                                  method="verify")[:3]
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
             logits, ck, cv)
 
@@ -345,16 +439,14 @@ def _verify_window(smodel, params, ck, cv, toks, pos):
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=(2, 3))
 def _prefill_bucketed(smodel, params, ck, cv, toks, slots_ix, lengths):
     # toks [B, Pb] — B queued prompts in one bucketed forward;
-    # slots_ix/lengths [B].  Each prompt's K/V rows scatter into its own
-    # slot; the "first generated token" is the argmax at each prompt's
-    # true last position (pad rows beyond it are never read).
-    logits, k, v = smodel.apply({"params": params}, toks,
-                                method=ServingLM.prefill)
-    ck = ck.at[:, slots_ix, :toks.shape[1]].set(k)
-    cv = cv.at[:, slots_ix, :toks.shape[1]].set(v)
-    last = jnp.take_along_axis(
-        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return (jnp.argmax(last, axis=-1).astype(jnp.int32), last, ck, cv)
+    # slots_ix/lengths [B].  The model writes each prompt's K/V rows into
+    # its own slot; the "first generated token" is the argmax at each
+    # prompt's true last position.
+    last, ck, cv, *stats = smodel.apply(
+        {"params": params}, toks, slots_ix, lengths, ck, cv,
+        method="prefill_into")
+    toks = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return _with_stats(toks, stats), last, ck, cv
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -380,7 +472,7 @@ class DecodeEngine:
     after every call the PREVIOUS cache handles are dead — the engine
     always rebinds, and no caller ever holds a cache reference."""
 
-    def __init__(self, model: TransformerLM, params, *,
+    def __init__(self, model, params, *,
                  slots: int = DEFAULT_SLOTS, cache_len: int = 128,
                  prefill_smallest: int = 8):
         if cache_len > model.max_len:
@@ -392,20 +484,30 @@ class DecodeEngine:
         if slots < 1:
             raise ValueError(f"slots {slots} must be >= 1")
         self.model = model
-        self.smodel = serving_lm_for(model)
+        # The model's own serving programs and cache: the engine copies
+        # no field of any architecture (module docstring).
+        self.smodel = model.serving_module()
         self.params = params
         self.slots = int(slots)
         self.cache_len = int(cache_len)
         self.vocab = int(model.vocab_size)
         self.buckets = _prefill_buckets(self.cache_len, prefill_smallest)
-        L = model.n_layers
-        H = model.n_heads
-        Dh = model.d_model // H
-        shape = (L, self.slots, self.cache_len, H, Dh)
-        self._ck = jnp.zeros(shape, model.dtype)
-        self._cv = jnp.zeros(shape, model.dtype)
-        self.cache_bytes = 2 * int(np.prod(shape)) * \
-            np.dtype(model.dtype).itemsize
+        self._ck, self._cv = self.smodel.init_cache(self.slots,
+                                                    self.cache_len)
+        layers = self.smodel.cache_rows(self.cache_len)
+        self.cache_bytes = sum(
+            x.nbytes for x in jax.tree.leaves((self._ck, self._cv)))
+        # Per kind of layer: (rows-read counter, layers, rows a slot
+        # holds in each); a row is as wide in every layer.
+        kinds: dict = {}
+        for kind, rows in layers:
+            kinds[kind] = (kinds.get(kind, (0, rows))[0] + 1, rows)
+        self._kinds = [(_ROWS_READ.labels(kind=kind), n, rows)
+                       for kind, (n, rows) in kinds.items()]
+        for kind, (n, rows) in kinds.items():
+            _CACHE_BYTES.labels(kind=kind).set(
+                self.cache_bytes * n * rows // sum(r for _, r in layers))
+        self.window_layers = kinds.get("window", (0, 0))[0]
         # Host-owned scalars-per-slot, uploaded per call (tiny): the
         # returned next-token array is the only per-step device output
         # besides the aliased caches.
@@ -467,7 +569,15 @@ class DecodeEngine:
             groups.setdefault(bucket, []).append((slot, prompt))
         out: dict = {}
         cold = False
-        for bucket, group in sorted(groups.items()):
+        # A model may cap the positions one prefill program takes (its
+        # activations beside a nearly full chip): a larger group goes as
+        # several programs of the same bucket.
+        most = getattr(self.smodel, "prefill_positions_max", None)
+        split = [(bucket, group[i:i + n])
+                 for bucket, group in sorted(groups.items())
+                 for n in [max(1, most // bucket) if most else len(group)]
+                 for i in range(0, len(group), n)]
+        for bucket, group in split:
             B = len(group)
             with hot_span("engine.prefill.pack"):
                 padded = np.zeros((B, bucket), np.int32)
@@ -483,7 +593,7 @@ class DecodeEngine:
                     self.smodel, self.params, self._ck, self._cv,
                     toks_in, slots_ix, lengths)
             with hot_span("engine.prefill.readback"):
-                toks = np.asarray(toks)
+                toks = self._count_pairs(np.asarray(toks), B)
                 last = np.asarray(last)
             for i, (slot, prompt) in enumerate(group):
                 self.positions[slot] = len(prompt)
@@ -513,16 +623,37 @@ class DecodeEngine:
                 self.smodel, self.params, self._ck, self._cv,
                 self.last_tokens, self.positions)
         with hot_span("engine.decode.readback"):
-            out = np.asarray(toks)
+            out = self._count_pairs(np.asarray(toks), self.slots,
+                                    decode=True)
         advance = (np.ones(self.slots, bool) if busy is None
                    else np.zeros(self.slots, bool))
         if busy is not None:
             advance[list(busy)] = True
+        self._count_rows_read(advance)
         self.last_tokens = np.where(advance, out, self.last_tokens) \
             .astype(np.int32)
         self.positions = self.positions + advance.astype(np.int32)
         self.decode_steps += 1
         return out
+
+    def _count_pairs(self, out: np.ndarray, n: int,
+                     decode: bool = False) -> np.ndarray:
+        """Split what a greedy program returned into its ``n`` tokens and
+        the model's counts behind them (``ops/moe.STATS``; none from a
+        model without experts), and add the counts to the counters."""
+        if len(out) > n:
+            held, absent, touched = (int(x) for x in out[n:])
+            _MOE_PAIRS.labels(where="held").inc(held)
+            _MOE_PAIRS.labels(where="absent").inc(absent)
+            if decode:
+                _MOE_TOUCHED.inc(touched)
+                _MOE_SLOTS.inc(self.smodel.expert_slots)
+        return out[:n]
+
+    def _count_rows_read(self, busy: np.ndarray) -> None:
+        reach = self.positions[busy] + 1        # rows a busy query sees
+        for counter, n, rows in self._kinds:
+            counter.inc(n * int(np.minimum(reach, rows).sum()))
 
     def decode_logits(self, busy=None) -> np.ndarray:
         """One decode step returning the f32 logits [S, V] instead of
@@ -552,6 +683,9 @@ class DecodeEngine:
         are garbage to discard).  Returns (greedy [S, K] int32,
         logits [S, K, V] f32).  Advances NOTHING — the caller owns
         accept/rollback bookkeeping via :meth:`set_slot`."""
+        if self.window_layers:
+            refuse_window_layers(self.model, "verify_step (speculation, "
+                                 "suffix extension)")
         with hot_span("engine.decode.dispatch"):
             g, logits, self._ck, self._cv = _verify_window(
                 self.smodel, self.params, self._ck, self._cv,
@@ -585,6 +719,8 @@ class DecodeEngine:
         device arrays [L, width, H, Dh] (the prefix-cache registration
         read).  Blocked to completion so the copies cannot race the
         next step's cache donation."""
+        if self.window_layers:
+            refuse_window_layers(self.model, "read_rows (the prefix cache)")
         k = self._ck[:, slot, :width]
         v = self._cv[:, slot, :width]
         return jax.block_until_ready(k), jax.block_until_ready(v)
@@ -592,6 +728,8 @@ class DecodeEngine:
     def write_rows(self, slot: int, k_rows, v_rows) -> None:
         """Import stored K/V rows into ``slot`` (the prefix-cache hit
         write); the caller then ``set_slot``s the real prefix length."""
+        if self.window_layers:
+            refuse_window_layers(self.model, "write_rows (the prefix cache)")
         self._ck, self._cv = _splice_rows(
             self._ck, self._cv, k_rows, v_rows, np.int32(slot))
 

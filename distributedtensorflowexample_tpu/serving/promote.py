@@ -120,9 +120,12 @@ def _template(model, tx, layout: str, meta: dict, sample_len: int):
 
 
 def promote(snapshot_dir: str, size: str, *, step: int | None = None,
-            tx=None, sample_len: int = 8) -> PromotedModel:
+            tx=None, sample_len: int = 8, model=None) -> PromotedModel:
     """Load the newest VALID snapshot of a graft-LM ``size`` from
-    ``snapshot_dir`` and return the full serving params.
+    ``snapshot_dir`` and return the full serving params.  ``model`` is
+    the model itself where it was built from a configuration file
+    (``models.build_model_from_config``) and not from the size ladder;
+    ``size`` is then the name its snapshots are stamped with.
 
     - newest-first with fallback: a torn/corrupt newest snapshot is
       discarded (counted on ``snapshot_fallbacks_total``) and the
@@ -153,7 +156,7 @@ def promote(snapshot_dir: str, size: str, *, step: int | None = None,
     if layout not in _LAYOUTS:
         raise ValueError(f"snapshot {step} declares unknown "
                          f"update_layout {layout!r} (one of {_LAYOUTS})")
-    model = build_model(size)
+    model = model or build_model(size)
     template, z3 = _template(model, tx or _default_tx(), layout, meta,
                              sample_len)
     state = store.restore(template, step=step)
@@ -269,13 +272,14 @@ def promote_sharded(snapshot_dir: str, size: str, *,
 
 
 def init_lm_snapshot(snapshot_dir: str, size: str, seed: int = 0,
-                     sample_len: int = 8) -> int:
+                     sample_len: int = 8, model=None) -> int:
     """Write a demo-grade snapshot: a seeded, untrained graft-LM state
     in the standard store format (the serving path exercises the FULL
     promotion machinery against it — validity checks, layout stamp,
     fallback).  Returns the snapshot step (0).  Idempotent: an existing
-    valid snapshot wins (save() dedupes by step)."""
-    model = build_model(size)
+    valid snapshot wins (save() dedupes by step).  ``model`` as in
+    :func:`promote`."""
+    model = model or build_model(size)
     state = TrainState.create(model, _default_tx(),
                               jnp.zeros((1, sample_len), jnp.int32),
                               seed=seed)

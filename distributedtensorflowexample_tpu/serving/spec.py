@@ -48,6 +48,8 @@ import numpy as np
 
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
+from distributedtensorflowexample_tpu.serving.engine import (
+    refuse_window_layers)
 
 _SPEC_ROUNDS = obs_metrics.counter(
     "serve_spec_rounds_total", "speculative draft+verify rounds")
@@ -77,6 +79,8 @@ class SpecDecoder:
                 "seam, which the params-stay-sharded engine "
                 "(--sharded_mesh) does not expose — speculative "
                 "decoding composes with the replicated path only")
+        for eng in (engine, draft_engine):
+            refuse_window_layers(eng.model, "--spec_draft (SpecDecoder)")
         if draft_engine.vocab != engine.vocab:
             raise ModeRefusal(
                 f"draft model vocab {draft_engine.vocab} != target "

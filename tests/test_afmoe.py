@@ -1,0 +1,442 @@
+"""The afmoe model (models/afmoe.py), its expert layer (ops/moe.py) and
+its path through DecodeEngine and ContinuousBatcher, against the plain
+reference (benchmarks/reference/afmoe.py) at tiny widths on the CPU,
+float32 compute so that the comparison is of the mathematics: a window of
+8 positions, so every context here wraps the rings several times."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import afmoe as ref
+from distributedtensorflowexample_tpu.models import build_model_from_config
+from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+from distributedtensorflowexample_tpu.ops import moe
+from distributedtensorflowexample_tpu.ops.attention import (
+    grouped_attention, splash_grouped_attention, takes_splash)
+from distributedtensorflowexample_tpu.refusal import ModeRefusal
+from distributedtensorflowexample_tpu.serving.engine import (
+    DECODE_HLO_CONTRACT, DecodeEngine)
+from distributedtensorflowexample_tpu.serving.queue import (
+    ContinuousBatcher, RequestQueue)
+
+TINY = dict(
+    model_type="afmoe", vocab_size=97, hidden_size=32,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    intermediate_size=64, moe_intermediate_size=16, num_hidden_layers=4,
+    num_dense_layers=1,
+    layer_types=["sliding_attention", "sliding_attention", "full_attention",
+                 "sliding_attention"],
+    num_experts=4, num_experts_per_tok=2, num_shared_experts=1,
+    route_scale=2.448, route_norm=True, sliding_window=8, rope_theta=10000,
+    rms_norm_eps=1e-5, max_position_embeddings=128, mup_enabled=True,
+    published={"num_experts": 16}, deployment={"rank": 1})
+TOL = 2e-5      # float32 against float32 at HIGHEST: summation order only
+
+
+def _model(attn_block=1024, **sizes):
+    return build_model_from_config({**TINY, **sizes}, dtype=jnp.float32,
+                                   param_dtype=jnp.float32,
+                                   attn_block=attn_block)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return _model().init(jax.random.PRNGKey(3),
+                         jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+@pytest.fixture(scope="module")
+def sequences():
+    return np.random.default_rng(5).integers(0, TINY["vocab_size"],
+                                             (4, 60)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def ref_logits(params, sequences):
+    return np.asarray(ref.forward(params, jnp.asarray(sequences), TINY))
+
+
+def _counter(series: str) -> float:
+    got = obs_metrics.registry().snapshot()["counters"].get(series)
+    return (got["value"] if isinstance(got, dict) else got) or 0
+
+
+# ---- the training-shape forward -------------------------------------------
+
+@pytest.mark.parametrize("attn_block", [1024, 8, 16])
+def test_forward_matches_the_reference(params, sequences, ref_logits,
+                                       attn_block):
+    """One tile (1024) and the tiled walk (8: the window's own length;
+    16: a window inside a tile) give the reference's logits."""
+    got = _model(attn_block).apply({"params": params}, jnp.asarray(sequences))
+    assert np.abs(np.asarray(got) - ref_logits).max() < TOL
+
+
+@pytest.mark.parametrize("window, T", [(0, 40), (8, 40), (8, 37), (5, 64)])
+def test_tiled_attention_is_the_one_tile_attention(window, T):
+    """The walk over query and key tiles (a sequence that a tile does not
+    divide is padded) against one tile over the whole sequence."""
+    rng = np.random.default_rng(T + window)
+    q = jnp.asarray(rng.normal(size=(2, T, 4, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, T, 2, 8)), jnp.float32)
+            for _ in range(2))
+    whole = grouped_attention(q, k, v, window=window, block=1024)
+    tiled = grouped_attention(q, k, v, window=window, block=8)
+    assert np.abs(np.asarray(whole - tiled)).max() < 1e-5
+
+
+@pytest.mark.parametrize("window", [0, 128, 200])
+def test_the_tpu_kernel_is_the_one_tile_attention(window):
+    """What a TPU program takes past one tile (JAX's splash attention,
+    interpreted here): fewer K/V heads than query heads, causal, and a
+    window that ends on a block's edge and inside a block."""
+    rng = np.random.default_rng(window)
+    q = jnp.asarray(rng.normal(size=(2, 384, 4, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 384, 2, 128)), jnp.float32)
+            for _ in range(2))
+    whole = grouped_attention(q, k, v, window=window, block=1024)
+    kernel = splash_grouped_attention(q, k, v, window=window, block=128,
+                                      interpret=True)
+    assert np.abs(np.asarray(whole - kernel)).max() < 1e-5
+
+
+@pytest.mark.parametrize("shape, block, takes", [
+    ((1, 16384, 48, 128), 1024, True), ((2, 2048, 48, 128), 1024, True),
+    ((1, 1024, 48, 128), 1024, False),      # one tile: the einsum chain
+    ((1, 2040, 48, 128), 1024, False),      # a block does not divide it
+    ((1, 4096, 4, 8), 1024, False)])        # heads narrower than a lane group
+def test_takes_splash_is_decided_by_backend_and_shape(shape, block, takes,
+                                                      monkeypatch):
+    assert not takes_splash(shape, block)               # the CPU never
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert takes_splash(shape, block) is takes
+
+
+# ---- prefill, then decode, through the engine ------------------------------
+
+def test_engine_prefill_then_decode_logits_match_the_reference(
+        params, sequences, ref_logits):
+    """Three slots; prompts shorter and longer than the window (the long
+    one leaves its ring holding the last 8 rows); 36 decode steps, every
+    ring wrapping four times; a request admitted mid-decode.  Every logit
+    the engine gives is the reference's full forward's at that position."""
+    engine = DecodeEngine(_model(), params, slots=3, cache_len=64)
+    assert [rows for _, rows in engine.smodel.cache_rows(64)] == [8, 8, 64, 8]
+    worst = 0.0
+
+    def admit(slot, row, length):
+        nonlocal worst
+        (_, last), = engine.prefill_many(
+            [(slot, sequences[row, :length], 1)]).values()
+        worst = max(worst, np.abs(last - ref_logits[row, length - 1]).max())
+        engine.set_slot(slot, int(sequences[row, length]), length)
+
+    where = {0: 0, 1: 1}                # slot -> row of `sequences`
+    admit(0, 0, 5)
+    admit(1, 1, 20)
+    for step in range(36):
+        if step == 11:
+            where[2] = 2
+            admit(2, 2, 13)
+        busy = sorted(where)
+        at = {s: int(engine.positions[s]) for s in busy}
+        logits = engine.decode_logits(busy=busy)
+        for s in busy:
+            worst = max(worst, np.abs(
+                logits[s] - ref_logits[where[s], at[s]]).max())
+            # teacher-forced: the next token is the sequence's own
+            engine.set_slot(s, int(sequences[where[s], at[s] + 1]),
+                            at[s] + 1)
+    assert int(engine.positions[1]) == 56 and worst < TOL, worst
+
+
+def test_a_prompt_longer_than_the_window_leaves_its_last_rows(params,
+                                                              sequences):
+    """After a 29-token prompt (bucket 32) a window layer's ring holds
+    positions 21..28, each at position mod 8, as 29 single steps leave
+    it."""
+    model = _model()
+    long = DecodeEngine(model, params, slots=2, cache_len=64)
+    long.prefill_many([(1, sequences[3, :29], 1)])
+    steps = DecodeEngine(model, params, slots=2, cache_len=64)
+    steps.prefill_many([(1, sequences[3, :1], 1)])
+    for t in range(1, 29):
+        steps.set_slot(1, int(sequences[3, t]), t)
+        steps.decode(busy=[1])
+    for layer in (0, 1, 3):
+        for a, b in ((long._ck, steps._ck), (long._cv, steps._cv)):
+            assert np.abs(np.asarray(a[layer][1] - b[layer][1])).max() < TOL
+
+
+def test_batcher_serves_the_references_tokens(params):
+    """Seven requests through RequestQueue and ContinuousBatcher on three
+    slots (so four are admitted mid-decode): every served token is the
+    reference's best at its position (gap 0 but for summation order)."""
+    engine = DecodeEngine(_model(), params, slots=3, cache_len=64)
+    queue = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, queue, slo_ms=0, eos_id=None)
+    rng = np.random.default_rng(11)
+    reqs = [queue.submit(rng.integers(0, 97, n).astype(np.int32), new,
+                         rid=f"r{i}")
+            for i, (n, new) in enumerate([(5, 30), (21, 25), (9, 12),
+                                          (33, 20), (3, 40), (14, 9),
+                                          (27, 18)])]
+    while not all(r.done.is_set() for r in reqs):
+        batcher.step()
+    for r in reqs:
+        assert r.outcome == "ok" and len(r.tokens) == r.max_new
+        gaps = ref.served_token_gaps(params, r.prompt, np.asarray(r.tokens),
+                                     TINY, pad_to=16)
+        assert gaps["widest"] < 1e-4 and gaps["tokens"] == r.max_new
+
+
+def test_a_window_of_k_tokens_is_k_single_steps(params, sequences):
+    """The K-token step (full layers): four tokens at once give the
+    logits of four steps of one, and leave the same cache.  A ring takes
+    one token a step and says so."""
+    model = _model(layer_types=["full_attention"] * 4)
+    ck, cv = model.init_cache(2, 64)
+    toks = jnp.asarray(sequences[:2, :12])
+    _, ck, cv, _ = model.apply({"params": params}, toks, jnp.arange(2),
+                               jnp.asarray([12, 9]), ck, cv,
+                               method="prefill_into")
+    pos = jnp.asarray([12, 9], jnp.int32)
+    nxt = jnp.asarray(sequences[:2, 20:24])
+    many, ck4, cv4, _ = model.apply({"params": params}, nxt, pos, ck, cv,
+                                    method="verify")
+    for j in range(4):
+        one, ck, cv, _ = model.apply({"params": params}, nxt[:, j], pos + j,
+                                     ck, cv, method="decode")
+        assert np.abs(np.asarray(one - many[:, j])).max() < TOL
+    for a, b in zip(jax.tree.leaves((ck, cv)), jax.tree.leaves((ck4, cv4))):
+        assert np.abs(np.asarray(a - b)).max() < TOL
+    rings = _model()
+    with pytest.raises(ValueError, match="one token a step"):
+        rings.apply({"params": params}, nxt, pos, *rings.init_cache(2, 64),
+                    method="verify")
+
+
+# ---- the expert layer ------------------------------------------------------
+
+def _layer_inputs(n=50, seed=2):
+    """A tiny expert layer's weights, uncut (16 experts), and n tokens."""
+    rng = np.random.default_rng(seed)
+    d, f, E = 32, 16, 16
+    normal = lambda *s: jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+    p = {"router": normal(d, E), "router_bias": normal(E) * 0.1,
+         "shared_gate": normal(d, f), "shared_up": normal(d, f),
+         "shared_down": normal(f, d), "experts_gate": normal(E, d, f),
+         "experts_up": normal(E, d, f), "experts_down": normal(E, f, d)}
+    return p, normal(n, d) * 5
+
+
+@pytest.mark.parametrize("rows", [2048, 8])
+def test_the_eight_shares_add_up_to_the_uncut_layer(monkeypatch, rows):
+    """Over all eight shares of a 16-expert layer: the parts the shares
+    give (each computed by the program's layer, told which two experts it
+    holds, in one block of sorted rows and in blocks of 8 that cut
+    through an expert's rows), with the shared expert counted once, are
+    the uncut reference's layer."""
+    p, m = _layer_inputs()
+    uncut = {**TINY, "num_experts": 16, "deployment": {"rank": 0}}
+    shared, routed = ref.expert_layer(m, p, uncut, ref.make_matmul("f32"))
+    sel, w = moe.route(m, p["router"], p["router_bias"], top_k=2,
+                       route_scale=2.448)
+    total, pairs = moe.gated_ffn(m, p["shared_gate"], p["shared_up"],
+                                 p["shared_down"]), 0
+    monkeypatch.setattr(moe, "BLOCK_ROWS", rows)
+    for rank in range(8):
+        held = slice(2 * rank, 2 * rank + 2)
+        part, stats = moe.expert_ffn(
+            m, sel, w, p["experts_gate"][held], p["experts_up"][held],
+            p["experts_down"][held], first_expert=2 * rank)
+        # ... and each share is the reference's share.
+        _, theirs = ref.expert_layer(
+            m, {**p, **{k: p[k][held] for k in (
+                "experts_gate", "experts_up", "experts_down")}},
+            {**TINY, "num_experts": 2, "deployment": {"rank": rank}},
+            ref.make_matmul("f32"))
+        assert np.abs(np.asarray(part - theirs)).max() < TOL
+        total, pairs = total + part, pairs + int(stats[0])
+        assert int(stats[0]) + int(stats[1]) == 50 * 2
+    assert pairs == 50 * 2              # every pair computed exactly once
+    assert np.abs(np.asarray(total - (shared + routed))).max() < 5e-5
+
+
+@pytest.mark.parametrize("rows", [2048, 16])
+def test_no_pair_is_dropped_when_every_token_routes_to_one_expert(
+        monkeypatch, rows):
+    """70 tokens, all on expert 5 (and on an absent one): 70 pairs on one
+    of four held experts, in one block and over five blocks of 16 rows
+    (of the nine that all 140 pairs would fill)."""
+    monkeypatch.setattr(moe, "BLOCK_ROWS", rows)
+    p, m = _layer_inputs(70)
+    sel = jnp.tile(jnp.asarray([[5, 12]], jnp.int32), (70, 1))
+    w = jnp.tile(jnp.asarray([[0.7, 0.3]], jnp.float32), (70, 1))
+    held = slice(4, 8)
+    got, stats = moe.expert_ffn(
+        m, sel, w, p["experts_gate"][held], p["experts_up"][held],
+        p["experts_down"][held], first_expert=4)
+    want = 0.7 * moe.gated_ffn(m, p["experts_gate"][5], p["experts_up"][5],
+                               p["experts_down"][5])
+    assert np.abs(np.asarray(got - want)).max() < TOL
+    assert stats.tolist() == [70, 70, 1]
+
+
+def test_the_counts_are_what_a_hand_made_routing_says():
+    """Six tokens, two of them padding; held experts 4..7."""
+    p, m = _layer_inputs(6)
+    sel = jnp.asarray([[4, 5], [4, 9], [0, 15], [7, 4], [5, 6], [6, 1]],
+                      jnp.int32)
+    live = jnp.asarray([True, True, True, True, False, False])
+    held = slice(4, 8)
+    _, stats = moe.expert_ffn(
+        m, sel, jnp.ones((6, 2), jnp.float32), p["experts_gate"][held],
+        p["experts_up"][held], p["experts_down"][held], first_expert=4,
+        live=live)
+    # live pairs on 4..7: (4,5) (4) () (7,4) = 5, on absent: 3; experts
+    # 4, 5 and 7 got a pair, 6 only from padding.
+    assert stats.tolist() == [5, 3, 3]
+
+
+def test_the_engines_counters_follow_the_programs_counts(params, sequences):
+    """``moe_pairs_total`` adds up to tokens x top-k x expert layers,
+    ``moe_expert_slots_total`` to held experts x expert layers a decode
+    step, and ``serve_cache_rows_read_total`` to the rows the busy slots'
+    positions reach, a ring cutting them at 8."""
+    names = ['moe_pairs_total{where="held"}',
+             'moe_pairs_total{where="absent"}', "moe_expert_slots_total",
+             "moe_experts_touched_total",
+             'serve_cache_rows_read_total{kind="full"}',
+             'serve_cache_rows_read_total{kind="window"}']
+    before = [_counter(n) for n in names]
+    engine = DecodeEngine(_model(), params, slots=3, cache_len=64)
+    engine.prefill_many([(0, sequences[0, :5], 1), (2, sequences[1, :19], 1)])
+    engine.decode(busy=[0, 2])          # positions 5 and 19
+    engine.decode(busy=[2])             # position 20; slot 0 still live
+    held, absent, slots, touched, full, window = (
+        _counter(n) - b for n, b in zip(names, before))
+    # prefill: 24 prompt tokens; two decode steps of two live slots (a
+    # slot not advanced still holds a request); 2 choices, 3 expert layers
+    assert held + absent == (24 + 2 + 2) * 2 * 3
+    assert slots == 2 * 4 * 3 and 0 < touched <= slots
+    assert full == (6 + 20) + 21                    # one full layer
+    assert window == 3 * ((6 + 8) + 8)              # three rings of 8
+    gauges = obs_metrics.registry().snapshot()["gauges"]
+    row = 2 * 2 * 8 * 4                             # K and V, f32 here
+    assert gauges['serve_cache_bytes{kind="full"}']["value"] == 3 * 64 * row
+    assert gauges['serve_cache_bytes{kind="window"}']["value"] == \
+        3 * 3 * 8 * row
+    assert engine.cache_bytes == 3 * (64 + 3 * 8) * row
+
+
+# ---- what refuses, and what holds -----------------------------------------
+
+def _engine(params, **kw):
+    return DecodeEngine(_model(), params, slots=2, cache_len=32, **kw)
+
+
+@pytest.mark.parametrize("what", ["PrefixCache", "SpecDecoder",
+                                  "ShardedDecodeEngine", "read_rows",
+                                  "write_rows", "verify_step", "extend"])
+def test_what_assumes_one_row_shape_refuses_window_layers_by_name(params,
+                                                                  what):
+    from distributedtensorflowexample_tpu.serving.prefix import PrefixCache
+    from distributedtensorflowexample_tpu.serving.sharded import (
+        ShardedDecodeEngine)
+    from distributedtensorflowexample_tpu.serving.spec import SpecDecoder
+    engine = _engine(params)
+    calls = {
+        "PrefixCache": lambda: PrefixCache(engine),
+        "SpecDecoder": lambda: SpecDecoder(engine, _engine(params)),
+        "ShardedDecodeEngine": lambda: ShardedDecodeEngine(
+            engine.model, (), None),
+        "read_rows": lambda: engine.read_rows(0, 4),
+        "write_rows": lambda: engine.write_rows(0, None, None),
+        "verify_step": lambda: engine.verify_step(
+            np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)),
+        "extend": lambda: engine.extend(0, [1, 2], 3),
+    }
+    with pytest.raises(ModeRefusal, match="window-attention layers"):
+        calls[what]()
+
+
+def test_a_model_of_full_layers_only_is_not_refused(params):
+    """The refusal is of rings, not of the architecture."""
+    from distributedtensorflowexample_tpu.serving.engine import (
+        refuse_window_layers)
+    refuse_window_layers(_model(layer_types=["full_attention"] * 4), "x")
+    with pytest.raises(ModeRefusal):
+        refuse_window_layers(_model(), "x")
+
+
+def test_a_cache_longer_than_the_models_positions_is_refused(params):
+    with pytest.raises(ModeRefusal, match="exceeds"):
+        DecodeEngine(_model(), params, slots=2, cache_len=129)
+
+
+def test_the_decode_program_honours_the_hlo_contract(params):
+    """Donation aliased for every layer's cache, no copy of a donated
+    buffer, no collective, nothing wider than f32 — and the jitted
+    function's name has ``decode_step`` in it (the benchmark finds the
+    program's device time by that)."""
+    from distributedtensorflowexample_tpu.analysis.hlo_lint import (
+        check_contract)
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    engine = _engine(params)
+    assert check_contract(engine.decode_hlo(), DECODE_HLO_CONTRACT) == []
+    assert "decode_step" in eng._decode_step.__name__
+    text = eng._decode_step.lower(
+        engine.smodel, engine.params, engine._ck, engine._cv,
+        engine.last_tokens, engine.positions).as_text(debug_info=True)
+    for scope in ("moe.route", "moe.experts", "moe.shared", "attn.window",
+                  "attn.full", "cache_update", "head"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_gpt2_goes_through_the_same_engine_with_every_layer_full():
+    from distributedtensorflowexample_tpu.models import build_model
+    model = build_model("lm_tiny")
+    p = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
+        "params"]
+    engine = DecodeEngine(model, p, slots=2, cache_len=16)
+    assert engine.window_layers == 0
+    assert engine.smodel.cache_rows(16) == (("full", 16),) * 2
+    assert engine._ck.shape == (2, 2, 16, 2, 32)    # the stacked pair
+
+
+# ---- one constructor, from a configuration file ----------------------------
+
+def test_the_cli_serves_the_model_from_a_configuration_file(tmp_path,
+                                                            params):
+    """``tools/serve_lm.py --model_config`` builds the model by the
+    constructor the benchmark's family calls, initialises a snapshot,
+    promotes it and drives requests through the batcher."""
+    import importlib.util
+    import os
+    path = tmp_path / "tiny_afmoe.json"
+    path.write_text(json.dumps(TINY))
+    built = build_model_from_config(str(path), dtype=jnp.float32,
+                                    param_dtype=jnp.float32)
+    assert built == _model()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "serve_lm_cli", os.path.join(root, "tools", "serve_lm.py"))
+    cli = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cli)
+    results = tmp_path / "results.jsonl"
+    rc = cli.main(["--model_config", str(path), "--snapshot",
+                   str(tmp_path / "snap"), "--init_if_missing", "--drive",
+                   "5", "--drive_max_new", "12", "--max_len", "64",
+                   "--slots", "2", "--results", str(results)])
+    assert rc == 0
+    rows = [json.loads(line) for line in results.read_text().splitlines()]
+    assert len(rows) == 5 and all(len(r["tokens"]) == 12 for r in rows)
+    # ... and what assumes one row shape is refused by name: exit 2.
+    assert cli.main(["--model_config", str(path), "--snapshot",
+                     str(tmp_path / "snap"), "--prefix_cache", "4",
+                     "--drive", "1", "--max_len", "64"]) == 2

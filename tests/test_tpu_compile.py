@@ -99,3 +99,67 @@ def test_sync_step_over_four_chips_runs_the_kernels_per_shard(
     assert text.count("tpu_custom_call") == calls_per_layer * layers
     assert " all-gather(" not in text and " all-to-all(" not in text
     assert not re.search(r"\[\d+,12,1024,1024\]", text)
+
+
+# ---- the afmoe share at the benchmark cell's own sizes ---------------------
+
+def _trinity_programs(topo, slots=32, cache_len=16384):
+    """The model of benchmarks/configs/trinity_large_ep8.json with shapes
+    for its parameters and for a 32-slot, 16,384-row engine's cache, all
+    on the described chip."""
+    import os
+    from distributedtensorflowexample_tpu.models import (
+        build_model_from_config)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "trinity_large_ep8.json"))
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ck, cv = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, cache_len)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+    return model, params, ck, cv, i32
+
+
+def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(topo,
+                                                                 uncached):
+    """The decode program of the cell (32 slots, 16,384 rows): the
+    grouped products are the TPU's own kernel (three a layer, four
+    expert layers), every layer's cache is aliased onto its input, and
+    the program's temporaries are small beside 12.9 GB of weights and
+    cache."""
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    model, params, ck, cv, i32 = _trinity_programs(topo)
+    compiled = eng._decode_step.lower(model, params, ck, cv, i32(32),
+                                      i32(32)).compile()
+    mem = compiled.memory_analysis()
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9
+    assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert compiled.as_text().count('op_name="ragged-dot-none"') >= 12
+
+
+@pytest.mark.parametrize("batch, bucket", [(1, 256), (2, 16384)])
+def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
+                                                   bucket, monkeypatch):
+    """The smallest and the fullest prefill program of the cell: no
+    ``[H, T, T]`` scores (a 16,384-token prompt's would be 51.5 GB), the
+    head at the last position only, and weights, cache and the program's
+    activations together inside the chip's 16.9 GB.  Past one tile the
+    attention of every layer is the TPU's kernel (the backend is the
+    CPU's here, so the test says "built for a TPU" itself)."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _trinity_programs(topo)
+    compiled = eng._prefill_bucketed.lower(
+        model, params, ck, cv, i32(batch, bucket), i32(batch),
+        i32(batch)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
+    assert mem.temp_size_in_bytes < 3.5e9
+    assert ("splash" in compiled.as_text()) == (
+        bucket > attention_op.ATTN_BLOCK)

@@ -58,6 +58,12 @@ def main(argv: list[str] | None = None) -> int:
                         "$SERVE_SNAPSHOT)")
     p.add_argument("--size", default="lm_tiny",
                    help="graft-LM size the snapshot holds (LM_SIZES)")
+    p.add_argument("--model_config", default="",
+                   help="serve a model built from a published "
+                        "configuration's JSON file (its model_type names "
+                        "the architecture: afmoe) instead of a --size of "
+                        "the ladder; snapshots are stamped with the "
+                        "file's name")
     p.add_argument("--slots", type=int, default=0,
                    help="concurrent decode slots (default $SERVE_SLOTS "
                         "or 4)")
@@ -175,6 +181,15 @@ def main(argv: list[str] | None = None) -> int:
     snapshot = args.snapshot or serve_snapshot_default()
     if not snapshot:
         p.error("--snapshot (or SERVE_SNAPSHOT) is required")
+    model = None
+    if args.model_config:
+        if args.sharded_mesh > 0 or args.spec_draft:
+            p.error("--model_config serves through DecodeEngine alone "
+                    "(no --sharded_mesh, no --spec_draft)")
+        from distributedtensorflowexample_tpu.models import (
+            build_model_from_config)
+        model = build_model_from_config(args.model_config)
+        args.size = "config:" + os.path.basename(args.model_config)
     slots = args.slots or serve_slots_default()
     slo_ms = serve_slo_ms_default() if args.slo_ms < 0 else args.slo_ms
     port = serve_port_default() if args.http < 0 else args.http
@@ -201,7 +216,8 @@ def main(argv: list[str] | None = None) -> int:
         from distributedtensorflowexample_tpu.resilience.snapshot import (
             SnapshotStore)
         if SnapshotStore(snapshot).latest_valid() is None:
-            init_lm_snapshot(snapshot, args.size, seed=args.seed)
+            init_lm_snapshot(snapshot, args.size, seed=args.seed,
+                             model=model)
             print(f"serve_lm: initialized demo snapshot in {snapshot}",
                   file=sys.stderr, flush=True)
 
@@ -222,7 +238,7 @@ def main(argv: list[str] | None = None) -> int:
             mode_desc = f", sharded D={pm.layout.num_devices} (params " \
                         f"resident at 1/{pm.layout.num_devices})"
         else:
-            pm = promote(snapshot, args.size)
+            pm = promote(snapshot, args.size, model=model)
             engine = DecodeEngine(pm.model, pm.params, slots=slots,
                                   cache_len=args.max_len)
             snap_layout = pm.layout
