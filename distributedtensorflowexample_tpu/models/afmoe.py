@@ -49,10 +49,9 @@ import jax.numpy as jnp
 
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
-    ATTN_BLOCK, grouped_attention)
+    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention)
 
 WINDOW, FULL = "sliding_attention", "full_attention"
-_MASKED = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,7 +214,14 @@ class AfmoeBlock(nn.Module):
         of a longer window would overwrite rows its earlier queries
         still see, which is also why nothing that rolls a cache back
         serves a model with window layers (serving/engine.py).  A slot at
-        ``pos == 0`` is parked: its tokens go to no expert."""
+        ``pos == 0`` is parked: its tokens go to no expert.
+
+        Either way the rows a query sees LEAD the layer's rows, so the
+        read is one op for both kinds, ``ops.attention.decode_attention``
+        with the count of visible rows: for one token a slot on a TPU the
+        ragged kernel, which fetches a slot's visible rows and no others
+        (a parked slot costs one block); the einsum chain over all ``R``
+        rows, dead ones masked, for a K > 1 window and on the CPU."""
         S, K, _ = x.shape
         R = ck.shape[1]
         c = self.dims
@@ -228,22 +234,15 @@ class AfmoeBlock(nn.Module):
         q, k, v, u = self._qkvu(x, positions)
         q = q.reshape(S, K, c.n_kv_heads, -1, c.head_dim)
         sl = jnp.arange(S)[:, None]
-        r = jnp.arange(R)
         with jax.named_scope("cache_update"):
             rows = jnp.mod(positions, R) if self.window else positions
             ck = ck.at[sl, rows].set(k)
             cv = cv.at[sl, rows].set(v)
         with jax.named_scope("attn.window" if self.window else "attn.full"):
-            if self.window:             # the position row r holds: >= 0
-                ok = (positions - jnp.mod(positions - r[None], R)
-                      >= 0)[:, None]                            # [S,1,R]
-            else:
-                ok = r[None, None, :] <= positions[:, :, None]  # [S,K,R]
-            s = jnp.einsum("skhgd,srhd->shgkr", q, ck,
-                           preferred_element_type=jnp.float32)
-            s = jnp.where(ok[:, None, None], s * c.head_dim ** -0.5, _MASKED)
-            p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-            o = jnp.einsum("shgkr,srhd->skhgd", p, cv)
+            # The visible rows lead: a full layer's are 0..position; a
+            # ring's row r holds a position >= 0 iff r <= position or the
+            # ring has wrapped, and then every row does.
+            o = decode_attention(q, ck, cv, jnp.minimum(positions + 1, R))
         live = jnp.broadcast_to((pos > 0)[:, None], (S, K))
         x, stats = self._ffn(self._attn_out(x, o, u), live)
         return x, ck, cv, stats
@@ -308,6 +307,12 @@ class AfmoeLM(nn.Module):
         c = self.dims
         return tuple(("window", min(c.window, cache_len)) if kind == WINDOW
                      else ("full", cache_len) for kind in c.layer_types)
+
+    def decode_fetch_block(self, rows: int) -> int:
+        """Rows the decode step's attention fetches at a time from a
+        layer that holds ``rows`` a slot; 0 where it reads them all."""
+        c = self.dims
+        return decode_fetch_block(rows, c.n_kv_heads, c.head_dim)
 
     def init_cache(self, slots: int, cache_len: int) -> tuple:
         """``(ck, cv)``, each one ``[slots, rows, Hkv, Dh]`` array a

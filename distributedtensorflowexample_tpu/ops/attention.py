@@ -19,6 +19,20 @@ plain step and the eval steps) says so the way jax provides for,
 ``jax.shard_map`` over the batch axis, as ``pallas_ce`` does.  Inside a
 ``shard_map`` body (the bucketed, ZeRO-3 and async steps) the axis is
 already manual and the kernel is called as it is.
+
+**The serving block's two shapes of work** (``models/afmoe.py``) decide
+the same way.  :func:`grouped_attention` is a whole sequence (prefill):
+JAX's splash kernel past one tile on a TPU, a tiled walk elsewhere.
+:func:`decode_attention` is the token step, one query per slot against
+that slot's cache rows: on a TPU, where the cache's rows are whole tiles,
+the ragged kernel (``ops/pallas/decode_attention.py``), which fetches
+each slot's visible rows block by block and nothing past them; the
+einsum chain over every row the layer holds, dead rows masked
+afterwards, everywhere else (the CPU, shapes that do not tile), so every
+tier-1 run keeps its bits.  ``serve_decode_attention_total{impl=...}``
+says which was taken, once per call traced (one call per layer), and
+:func:`decode_fetch_block` tells the engine's
+``serve_cache_rows_fetched_total`` how many rows that is.
 """
 
 from __future__ import annotations
@@ -33,6 +47,10 @@ _BLOCKS = obs_metrics.counter(
     "lm_attention_blocks_total",
     "causal_attention calls traced (one per DecoderBlock), by the "
     "implementation taken: pallas | einsum")
+_DECODE = obs_metrics.counter(
+    "serve_decode_attention_total",
+    "decode_attention calls traced (one per layer of a token-step "
+    "program), by the implementation taken: ragged | einsum")
 
 
 def einsum_causal_attention(q, k, v):
@@ -237,3 +255,58 @@ def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
     out = jnp.moveaxis(out, 0, 3)               # [B, Hkv, G, nq, block, Dh]
     out = out.reshape(B, Hq, T + pad, Dh)[:, :, :T]
     return jnp.swapaxes(out, 1, 2)
+
+
+# --- the token step: one query per slot against its cache rows ------------
+
+def _ragged():
+    # Imported where the kernel can be taken (see _blocked).
+    from distributedtensorflowexample_tpu.ops.pallas import decode_attention
+    return decode_attention
+
+
+def decode_fetch_block(rows: int, n_kv_heads: int, head_dim: int) -> int:
+    """Cache rows :func:`decode_attention` fetches at a time from a
+    layer of ``rows`` rows a slot — a slot's visible rows rounded up to
+    it are what a step reads — or 0 where it takes the einsum chain,
+    which reads every row the layer holds.  The predicate: built for a
+    TPU, and the cache's rows tile."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return _ragged().fetch_block(rows, n_kv_heads, head_dim)
+
+
+def einsum_decode_attention(q, ck, cv, lengths):
+    """The reference chain, the code ``AfmoeBlock.step`` carried inline
+    until PR 29: float32 scores over every row the layer holds, the rows
+    from ``lengths`` on masked, float32 softmax, the weighted sum in the
+    cache's type."""
+    ok = jnp.arange(ck.shape[1]) < lengths[..., None]           # [S,K,R]
+    s = jnp.einsum("skhgd,srhd->shgkr", q, ck,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(ok[:, None, None], s * q.shape[-1] ** -0.5, _MASKED)
+    p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
+    return jnp.einsum("shgkr,srhd->skhgd", p, cv)
+
+
+def decode_attention(q, ck, cv, lengths):
+    """The token step's attention: ``q [S, K, Hkv, G, Dh]`` (a K-token
+    window a slot, plain decode is K == 1; ``G`` query heads a key/value
+    head), ``ck``/``cv`` ``[S, R, Hkv, Dh]`` as the engine holds them,
+    ``lengths [S, K]`` the count of LEADING rows each query sees
+    (``1..R``).  Returns ``[S, K, Hkv, G, Dh]``.
+
+    One function, two regimes chosen from what the call can observe: for
+    one token a slot, where :func:`decode_fetch_block` finds a block,
+    the ragged kernel fetches ``ceil(length / block)`` blocks of a
+    slot's rows and no others, with a running max and sum; the einsum
+    chain reads all ``R`` and masks.  Scores and softmax are float32 in
+    both, the probabilities are cast to the cache's type before the
+    second product."""
+    _, K, Hkv, _, Dh = q.shape
+    if K > 1 or not decode_fetch_block(ck.shape[1], Hkv, Dh):
+        _DECODE.labels(impl="einsum").inc()
+        return einsum_decode_attention(q, ck, cv, lengths)
+    _DECODE.labels(impl="ragged").inc()
+    return _ragged().ragged_decode_attention(
+        q[:, 0], ck, cv, lengths[:, 0])[:, None]

@@ -1,0 +1,150 @@
+"""Ragged decode attention (the token step's ``attn`` scope in
+``models/afmoe.py``): one query token per slot against that slot's cache
+rows, fetching only the rows the query can see.
+
+Layout.  The cache arrives as the engine holds it, ``[S, R, Hkv, Dh]``,
+and is only VIEWED as ``[S, R * Hkv, Dh]``: with ``Hkv`` a multiple of 8
+and ``Dh`` of 128 a cache row is whole ``(8, 128)`` tiles in HBM, so the
+view is the same bytes and XLA makes no copy of a 2 GB array.  A row of
+the view is one (position, K/V head) pair.
+
+One matmul for every head.  A block of ``block`` positions is ``[block *
+Hkv, Dh]``; the slot's ``Hq = Hkv * G`` query heads multiply all of it,
+``s = q k^T`` of shape ``[Hq, block * Hkv]`` with the positions on the
+lanes, and the columns of another K/V head are masked with the dead
+rows.  The MXU pays for the keys it is handed, not for the query rows
+(48 of them ride one pass), so the eightfold wider product costs what
+eight per-head products would, and no head is ever sliced out of a tile.
+``p v`` over the same columns then lands each query head's output in its
+own row: masked columns weigh nothing.
+
+Ragged.  ``lengths[s]`` (scalar-prefetched) is the count of LEADING rows
+slot ``s`` sees.  The grid is ``(S, R / block)``; a step past the slot's
+``ceil(length / block)`` blocks maps to the block before it, which the
+pipeline therefore does not fetch again, and computes nothing.
+
+Precision is the einsum chain's: operands in the cache's type, scores
+and the running max / sum in float32, probabilities cast to the cache's
+type for the weighted sum, float32 accumulator.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributedtensorflowexample_tpu.ops.pallas.tiling import (
+    LANES, SUBLANES, resolve_interpret)
+
+#: Cache rows per block, largest first: the first that divides the
+#: layer's rows is taken.  Swept on the v5e at 32 slots x 8 K/V heads x
+#: 128, lengths as the benchmark's mixed cell holds them (PERF.md §6,
+#: PR 29): a full layer of 16,384 rows, 30% live, 1.06 ms at 512, 1.12 at
+#: 256, 1.11 at 1,024 (the einsum chain 3.48); a ring of 4,096 rows, 81%
+#: live, 0.63 at 512 and 256, 0.65 at 1,024 (0.74).  A smaller block pays
+#: more grid steps, a larger one fetches more rows past a slot's last.
+BLOCKS = (512, 256, 128)
+_NEG = -1e30          # masked score / initial max: finite, so no inf - inf
+
+
+def pick_block(rows: int, block: int | None = None) -> int | None:
+    """Rows per block for a layer of ``rows`` rows, or None where no
+    block divides it."""
+    for cand in ((block,) if block else BLOCKS):
+        if rows % cand == 0:
+            return cand
+    return None
+
+
+def fetch_block(rows: int, n_kv_heads: int, head_dim: int) -> int:
+    """Rows per block where the kernel takes these shapes without a copy
+    of the cache — a cache row is whole (8, 128) tiles, and a block
+    divides the rows — else 0."""
+    if n_kv_heads % SUBLANES or head_dim % LANES:
+        return 0
+    return pick_block(rows) or 0
+
+
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            block: int, n_kv_heads: int, group: int, scale: float):
+    slot, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[slot]
+    n_blocks = (length + block - 1) // block
+    hq, cols = q_ref.shape[0], k_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j < n_blocks)
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [Hq, cols]
+        # A column is a (row, K/V head) pair; a query head reads the
+        # columns of its own K/V head among the slot's live rows.
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // group
+        own = (col % n_kv_heads) == head                        # [Hq, cols]
+        live = col // n_kv_heads < length - j * block           # [1, cols]
+        s = jnp.where(own & live, s, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+        m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
+
+        @pl.when(j == n_blocks - 1)
+        def _():
+            o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def ragged_decode_attention(q, ck, cv, lengths, *, block: int | None = None,
+                            interpret: bool | None = None):
+    """``softmax(q k^T / sqrt(Dh)) v`` per slot over the slot's first
+    ``lengths[s]`` cache rows: ``q [S, Hkv, G, Dh]``, ``ck``/``cv`` ``[S,
+    R, Hkv, Dh]``, ``lengths [S]`` int32 in ``1..R``.  Returns ``[S, Hkv,
+    G, Dh]`` in the cache's type.  Jitted here, so the layers of one
+    program that share a shape share one trace."""
+    S, Hkv, G, Dh = q.shape
+    R = ck.shape[1]
+    block = pick_block(R, block)
+    if block is None:
+        raise ValueError(f"no block of {BLOCKS} divides {R} cache rows")
+    Hq = Hkv * G
+
+    def kv_map(s, j, lens):
+        # Past the slot's last live block: the same block again, which
+        # the pipeline does not fetch twice.
+        return s, jnp.minimum(j, (lens[s] + block - 1) // block - 1), 0
+
+    kv_spec = pl.BlockSpec((None, block * Hkv, Dh), kv_map)
+    q_spec = pl.BlockSpec((None, Hq, Dh), lambda s, j, lens: (s, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, block=block, n_kv_heads=Hkv, group=G,
+                          scale=Dh ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, R // block),
+            in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((Hq, 1), jnp.float32),
+                            pltpu.VMEM((Hq, 1), jnp.float32),
+                            pltpu.VMEM((Hq, Dh), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, Hq, Dh), cv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+        name="ragged_decode_attention",
+    )(jnp.clip(lengths.astype(jnp.int32), 1, R), q.reshape(S, Hq, Dh),
+      ck.reshape(S, R * Hkv, Dh), cv.reshape(S, R * Hkv, Dh))
+    return out.reshape(S, Hkv, G, Dh)

@@ -94,6 +94,12 @@ _ROWS_READ = obs_metrics.counter(
     "attended, summed over decode steps and layers, by kind of layer (a "
     "query at position p reads p + 1 rows of a full layer and min(p + 1, "
     "rows) of a window layer's ring)")
+_ROWS_FETCHED = obs_metrics.counter(
+    "serve_cache_rows_fetched_total", "cache rows the decode steps' "
+    "attention fetched, summed over steps and layers, by kind of layer: "
+    "every slot's visible rows rounded up to the block the model's "
+    "attention fetches by (decode_fetch_block), or every row the layer "
+    "holds where it reads them all")
 _MOE_PAIRS = obs_metrics.counter(
     "moe_pairs_total", "(token, expert) pairs the live tokens of prefill "
     "and decode programs routed, by where the expert is: held (computed "
@@ -497,12 +503,18 @@ class DecodeEngine:
         layers = self.smodel.cache_rows(self.cache_len)
         self.cache_bytes = sum(
             x.nbytes for x in jax.tree.leaves((self._ck, self._cv)))
-        # Per kind of layer: (rows-read counter, layers, rows a slot
-        # holds in each); a row is as wide in every layer.
+        # Per kind of layer: (rows-read counter, rows-fetched counter,
+        # layers, rows a slot holds in each, rows the model's decode
+        # attention fetches at a time or 0 for all of them); a row is as
+        # wide in every layer.
         kinds: dict = {}
         for kind, rows in layers:
             kinds[kind] = (kinds.get(kind, (0, rows))[0] + 1, rows)
-        self._kinds = [(_ROWS_READ.labels(kind=kind), n, rows)
+        fetch_block = getattr(self.smodel, "decode_fetch_block",
+                              lambda rows: 0)
+        self._kinds = [(_ROWS_READ.labels(kind=kind),
+                        _ROWS_FETCHED.labels(kind=kind), n, rows,
+                        fetch_block(rows))
                        for kind, (n, rows) in kinds.items()]
         for kind, (n, rows) in kinds.items():
             _CACHE_BYTES.labels(kind=kind).set(
@@ -651,9 +663,13 @@ class DecodeEngine:
         return out[:n]
 
     def _count_rows_read(self, busy: np.ndarray) -> None:
-        reach = self.positions[busy] + 1        # rows a busy query sees
-        for counter, n, rows in self._kinds:
-            counter.inc(n * int(np.minimum(reach, rows).sum()))
+        reach = self.positions + 1              # rows a slot's query sees
+        for read, fetched, n, rows, block in self._kinds:
+            seen = np.minimum(reach, rows)
+            read.inc(n * int(seen[busy].sum()))
+            # An idle slot's query is computed too: its rows are fetched.
+            fetched.inc(n * (int((-(-seen // block) * block).sum())
+                             if block else self.slots * rows))
 
     def decode_logits(self, busy=None) -> np.ndarray:
         """One decode step returning the f32 logits [S, V] instead of

@@ -163,3 +163,53 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     assert mem.temp_size_in_bytes < 3.5e9
     assert ("splash" in compiled.as_text()) == (
         bucket > attention_op.ATTN_BLOCK)
+
+
+# ---- the token step's ragged attention (PR 29) ------------------------------
+
+@pytest.mark.parametrize("rows", [16384, 4096])     # the full layer, a ring
+def test_decode_attention_compiles_to_one_kernel_for_v5e(topo, uncached,
+                                                         monkeypatch, rows):
+    """The op at the cell's shapes: one Mosaic call, and the cache goes
+    to it as the engine holds it — the program has no temporary, so no
+    relaid copy of a 1 GB operand."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    cache = sds((32, rows, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(attention_op.decode_attention).lower(
+        sds((32, 1, 8, 6, 128), jnp.bfloat16), cache, cache,
+        sds((32, 1), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
+        topo, uncached, monkeypatch):
+    """The cell's decode program built for a TPU: five ragged kernels
+    (one a layer), the cache's donation still aliased, and no copy of a
+    cache-sized array anywhere in it."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _trinity_programs(topo)
+    # jax caches a function's trace by its arguments, not by the backend
+    # the op was told: a new function, so the test above leaves no trace.
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(32), i32(32)).compile()
+    text = compiled.as_text()
+    # (XLA:TPU's own ragged_dot is a tpu_custom_call too: count ours.)
+    kernels = [line for line in text.splitlines()
+               if re.search(r"%ragged_decode_attention\S* = \S+ custom-call\(",
+                            line)]
+    assert len(kernels) == 5
+    assert all('custom_call_target="tpu_custom_call"' in k for k in kernels)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert not re.search(r"bf16\[32,(16384|4096|131072|32768),[^\]]*\]\S* "
+                         r"copy\(", text)
