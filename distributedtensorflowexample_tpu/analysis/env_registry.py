@@ -138,9 +138,9 @@ ENV_REGISTRY: dict[str, str] = {
         "(resilience/scheduler.py; default 0.25)."),
     "SERVE_LOAD_CLIENTS": (
         "Default closed-loop client thread count for serve_lm --drive "
-        "and bench_serving.py sweeps (serving/loadgen.py; default 2)."),
+        "(serving/loadgen.py; default 2)."),
     "SERVE_LOAD_REQUESTS": (
-        "Default request count one drive/bench point issues "
+        "Default request count one drive issues "
         "(serving/loadgen.py; default 16)."),
     "SERVE_PORT": (
         "Request-front port for the serving worker's POST /generate + "
@@ -156,9 +156,8 @@ ENV_REGISTRY: dict[str, str] = {
         "Default concurrent decode slots for the serving worker "
         "(serving/engine.py; default 4)."),
     "SERVE_SNAPSHOT": (
-        "Default SnapshotStore directory tools/serve_lm.py and "
-        "bench_serving.py promote when --snapshot is not passed "
-        "(serving/promote.py)."),
+        "Default SnapshotStore directory tools/serve_lm.py promotes "
+        "when --snapshot is not passed (serving/promote.py)."),
     "SIM_MAX_VIRTUAL_S": (
         "Hard ceiling on total virtual seconds one sim run may "
         "advance — a livelocked scenario (eviction ping-pong, a gate "

@@ -28,9 +28,10 @@ enforced by runtime probe or reviewer memory:
 * ``keep-in-sync`` — paired ``KEEP-IN-SYNC(<id>) digest=<hex12>`` ...
   ``KEEP-IN-SYNC-END(<id>)`` regions must exist in >= 2 files and all
   carry the digest of the pair's current content, so drift between
-  mirrored tables (e.g. the capture-phase tables in
-  tools/bench_capture.sh vs tools/supervise.py) fails the gate
-  instead of waiting for an on-chip window to expose it.
+  mirrored tables (e.g. the scheduler's event names in
+  resilience/scheduler.py vs tools/obs_query.py's renderers) fails
+  the gate.  Markers are read from code (``.py``, ``.sh``): prose that
+  cites a marker is not a region.
 * ``engine-owns-wiring`` — the PR 19 front-end contract: raw
   step-wiring names (the ``parallel/`` step builders, worker/opt-state
   re-layout constructors, ``shard_map``) may be imported or referenced
@@ -577,7 +578,7 @@ def collect_sync_blocks(repo_root: str) -> tuple[list[_SyncBlock],
                                                  list[Finding]]:
     blocks: list[_SyncBlock] = []
     findings: list[Finding] = []
-    for path in _walk_files(repo_root, (".py", ".sh", ".md")):
+    for path in _walk_files(repo_root, (".py", ".sh")):
         rel = _rel(path, repo_root)
         try:
             with open(path, encoding="utf-8") as f:
@@ -705,11 +706,6 @@ WIRING_ALLOWLIST = {
     "__graft_entry__.py":
         "driver compile-check entry: exercises the raw step builders "
         "as the pre-Engine dry-run surface",
-    "bench_collectives.py":
-        "raw-collective microbench — measures shard_map collectives "
-        "themselves, beneath any trainer",
-    "bench_serving.py":
-        "builds row-layout serving fixtures for the decode bench",
     "tools/faultline.py":
         "fault-injection drills drive a minimal raw step on purpose",
 }

@@ -91,8 +91,9 @@ class RunConfig:
     bucket_grads: str = ""          # "" | auto | <bytes> — fuse the
                                     # per-parameter gradient all-reduces
                                     # into knee-sized buckets (one psum
-                                    # per bucket; auto = the measured
-                                    # collective knee, bench_collectives).
+                                    # per bucket; auto = the collective
+                                    # knee fitted on XLA:CPU, never timed
+                                    # on chips: parallel/bucketing.py).
                                     # With --shard_update: the explicit
                                     # per-bucket reduce-scatter + sharded
                                     # update + all-gather ZeRO-1 schedule.
@@ -112,15 +113,15 @@ class RunConfig:
                                     # (bucket i+1's all-gather issues
                                     # while bucket i's compute runs);
                                     # false = strictly serial gathers
-                                    # (the A/B control bench_lm times).
+                                    # (the A/B control).
                                     # Pure scheduling — bitwise-same
 
     # --- hand-written TPU kernels (ops/pallas) ---
     pallas_ce: bool = False         # fused Pallas loss head in the train step
     fused_optimizer: bool = False   # fused Pallas momentum-SGD apply; measured
                                     # 2.3x SLOWER than XLA's fused apply on a
-                                    # v5e chip (flatten/unflatten HBM traffic,
-                                    # see BASELINE.md round-2) — kept opt-in
+                                    # v5e chip (flatten/unflatten HBM traffic:
+                                    # ops/pallas/sgd.py) — kept opt-in
                                     # as the kernel-authoring reference
 
     # --- input pipeline ---
@@ -238,8 +239,8 @@ _FLAG_HELP = {
                     "all-reduces into buckets of at most this many bytes "
                     "(strictly fewer, larger collectives; same gradient "
                     "math — see DESIGN.md §15). auto = sized from the "
-                    "measured collective knee (bench_collectives.py; "
-                    "BUCKET_GRADS_AUTO_BYTES overrides). Composes with "
+                    "collective knee of an XLA:CPU fit "
+                    "(BUCKET_GRADS_AUTO_BYTES overrides). Composes with "
                     "--shard_update into the explicit per-bucket "
                     "reduce-scatter + sharded-update + all-gather ZeRO-1 "
                     "schedule; in async mode buckets the worker-average "
@@ -262,7 +263,7 @@ _FLAG_HELP = {
                      "(at most two gathered buckets in flight — the "
                      "double buffer); false chains the gathers strictly "
                      "serially. Scheduling only, bitwise-identical "
-                     "results — the overlap A/B bench_lm.py measures",
+                     "results — the overlap A/B control",
     "pallas_ce": "fused Pallas cross-entropy head",
     "fused_optimizer": "fused Pallas momentum-SGD (measured 2.3x slower "
                        "than XLA on v5e — kept as kernel reference; "

@@ -13,10 +13,9 @@ host-augmented run.
 The per-image crop+flip is expressed as two one-hot SELECTOR MATMULS
 (one picking output rows, one picking-and-optionally-reversing output
 columns), not as ``vmap(dynamic_slice)``: XLA lowers the vmap'd dynamic
-crop to a SERIAL per-image while loop on TPU — the round-5 trace
-(PROFILE_auto_r05.json window) measured it at ~4.4 ms/step on ResNet-20's
-batch-256 input, and the same-window A/B (AB_augment_r05.json) runs the
-selector form at batch-gemm speed.  The selection is exact routing:
+crop to a SERIAL per-image while loop on TPU — a chip trace of 2026-08
+measured it at ~4.4 ms/step on ResNet-20's batch-256 input; the selector
+form runs at batch-gemm speed.  The selection is exact routing:
 every output pixel is ``1.0 * one input pixel``.  uint8 pixels are exact
 in bfloat16 (integers <= 255 fit its 8-bit mantissa), so one bf16 matmul
 pair suffices; float32 pixels are split into three bf16 components

@@ -9,10 +9,10 @@ parity between any two paths is a property of this module, not a
 coincidence to re-verify per call site.
 
 The canonical form is the fused AFFINE map ``f32(u) * scale + bias`` with
-ONE rounding (an FMA): that is what XLA emits for the jnp expression, and
-it is the fastest dequant measured on chip (AB_quantize_r05.json: 1,963
-steps/s/chip vs 479.6 for the round-4 LUT-gather default it replaces —
-the 4.1x "dequant tax" this module's round-5 redesign kills).  The host
+ONE rounding (an FMA): that is what XLA:TPU emits for the jnp expression,
+and it is the fastest dequant measured on chip (one chip window of
+2026-08: 1,962.6 steps/s/chip vs 479.6 for the LUT-gather default it
+replaced — PERF.md "History").  The host
 reference reproduces the single rounding exactly in float64: for byte
 inputs and these constants the f64 product and sum are exact, so the one
 f32 cast at the end IS the fma rounding.  ``affine_matches_lut`` verifies

@@ -66,11 +66,10 @@ from distributedtensorflowexample_tpu.data.dequant import (
 #:   onehot  one-hot @ LUT matmul — bitwise by construction on any
 #:           backend (each dot has exactly one nonzero term); the
 #:           fallback for non-affine-representable splits
-#:   lut     lut[u] elementwise gather — the round-4 default this PR
-#:           demotes: measured ~10 ns/element on TPU (PROFILE_auto_r05,
-#:           56% of the ResNet step; headline 479.6 vs 1,962.6 steps/s
-#:           same-window).  Kept ONLY as a named diagnostic so the bench
-#:           can keep attesting the tax.
+#:   lut     lut[u] elementwise gather — once the default: measured
+#:           ~10 ns/element on TPU (56% of the ResNet step; 479.6 vs
+#:           1,962.6 steps/s/chip in one chip window of 2026-08, PERF.md
+#:           "History").  Kept ONLY as a named diagnostic.
 #:   pallas  fused row-gather + affine dequant in one Pallas kernel
 #:           (ops/pallas/dequant.py) — gathers uint8 rows and emits the
 #:           float32 batch in a single HBM pass
@@ -117,7 +116,7 @@ def resolve_dequant_impl(spec: str | None, dequant_impl: str = "auto",
     """The ONE resolution rule for which in-step dequant kernel runs —
     shared by the train path (``DeviceDataset``), eval
     (``parallel.sync.make_resident_eval``), the host-fed path
-    (``dequant_host_batch``) and the bench, so no pair of consumers can
+    (``dequant_host_batch``), so no pair of consumers can
     silently resolve differently (the train/eval-asymmetry hazard).
 
     ``auto`` lowers to the affine fast path when the split's 256-entry
@@ -150,11 +149,10 @@ def apply_dequant_lut(u8: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
     """uint8 pixels -> float32 through a [256] / [256, C] LUT, expressed
     as a one-hot matmul so it runs on the MXU.
 
-    The obvious ``lut[idx]`` gather is catastrophically slow on TPU: the
-    round-5 on-chip trace (PROFILE_auto_r05.json window) measured it at
-    ~10 ns/element — 8.2 ms/step on ResNet-20's batch, 56% of the whole
-    step; the same-window A/B (AB_quantize_r05.json) put the headline at
-    479 steps/s with the gather vs 1,620 with this form.
+    The obvious ``lut[idx]`` gather is catastrophically slow on TPU: a
+    chip trace of 2026-08 measured it at ~10 ns/element — 8.2 ms/step on
+    ResNet-20's batch, 56% of the whole step; the A/B of the same window
+    read 479 steps/s with the gather vs 1,620 with this form.
 
     Exactness: the one-hot rows are exact {0,1} in bfloat16 and each
     output element's dot product has exactly ONE nonzero term, so the
@@ -193,12 +191,10 @@ def apply_dequant_lut(u8: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
 
 def apply_dequant_gather(u8: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
     """uint8 pixels -> float32 via an ELEMENTWISE ``lut[u]`` gather — the
-    round-4 default the round-5 window measured as the dequant tax
-    (PROFILE_auto_r05: ~10 ns/element, 56% of the ResNet step;
-    AB_quantize_r05: headline 479.6 steps/s/chip vs 1,962.6 affine in
-    the same window).  Retained ONLY as the ``dequant_impl="lut"``
-    diagnostic so the bench can keep the regression attested; nothing
-    resolves to it automatically."""
+    former default, measured as the dequant tax in one chip window of
+    2026-08 (~10 ns/element, 56% of the ResNet step; 479.6 steps/s/chip
+    vs 1,962.6 affine).  Retained ONLY as the ``dequant_impl="lut"``
+    diagnostic; nothing resolves to it automatically."""
     idx = u8.astype(jnp.int32)
     if lut.ndim == 1:
         return jnp.take(lut, idx, axis=0)
@@ -278,9 +274,9 @@ class DeviceDataset:
         (``DEQUANT_IMPLS``; resolution rule: ``resolve_dequant_impl``).
         The default ``"auto"`` lowers to the fused AFFINE fast path —
         verified bitwise against the 256-entry LUT at quantize time, true
-        for both shipped loader specs (AB_quantize_r05.json, same-window:
-        affine 1,962.6 steps/s/chip vs 479.6 for the round-4 LUT-gather
-        default, vs 1,654 float32-resident) — and falls back to the
+        for both shipped loader specs (one chip window of 2026-08:
+        affine 1,962.6 steps/s/chip vs 479.6 for the LUT-gather default
+        it replaced, vs 1,654 float32-resident) — and falls back to the
         bitwise one-hot form only for a split whose host arithmetic an
         affine map cannot reproduce.
 
@@ -288,8 +284,8 @@ class DeviceDataset:
         (``data["lut"]`` or ``data["dq_scale"]/["dq_bias"]``) and the
         device gather dispatches on the pytree structure, so no call
         site can forget to dequantize.  The RESOLVED impl is recorded on
-        ``self.dequant_impl`` (None when nothing dequantizes) so bench
-        records can attest which kernel actually ran.
+        ``self.dequant_impl`` (None when nothing dequantizes) so a run
+        can say which kernel actually ran.
 
         ``data_sharding="sharded"`` (VERDICT r4 #8) shards the resident
         split ROW-WISE over the mesh's data axis instead of replicating
@@ -350,7 +346,7 @@ class DeviceDataset:
             if q is not None:
                 images, self.dequant = q
         # The in-step kernel, resolved ONCE here (the same rule eval and
-        # the host-fed path use) and recorded for bench attestation.
+        # the host-fed path use) and recorded for the run's summary.
         self.dequant_impl: str | None = (
             resolve_dequant_impl(self.dequant, dequant_impl, quantize)
             if self.dequant is not None else None)

@@ -77,7 +77,7 @@ def load_mnist(data_dir: str, split: str = "train",
       requested — no warning.
     - ``"fallback"``: real if present, else synthetic with a LOUD
       once-per-split warning (for harnesses that must run with or
-      without the bytes, e.g. bench.py on a data-less chip host).
+      without the bytes, e.g. on a data-less chip host).
     """
     if source not in ("real", "synthetic", "fallback"):
         raise ValueError(f"unknown source {source!r}")

@@ -8,13 +8,12 @@ replication-mode layout passes → hooks → loop → final eval — now owned
 by ONE object driven by a declarative
 :class:`~distributedtensorflowexample_tpu.engine.spec.RunSpec`.
 ``Engine(spec).build()`` is the same construction stack cut down to the
-bench surface: dataset + state + compiled step, no hooks, no eval, no
-checkpoint — what bench.py/bench_lm.py used to hand-wire per knob
-config.  Both paths MOVED here from trainers/common.py and the bench
-builders with operation order preserved (seed usage, state-creation
-order, layout passes), so loss tapes and collective multisets are
-bitwise-identical to the pre-engine wiring (tests/test_engine.py pins
-this per mode).
+measuring surface: dataset + state + compiled step, no hooks, no eval,
+no checkpoint (benchmarks/kinds/train.py, chip_smoke.py).  Both paths
+MOVED here from trainers/common.py with operation order preserved (seed
+usage, state-creation order, layout passes), so loss tapes and
+collective multisets are bitwise-identical to the pre-engine wiring
+(tests/test_engine.py pins this per mode).
 
 The replication strategies themselves still live in parallel/ — the
 Engine selects and composes them (spec.MODES declares each mode's
@@ -417,14 +416,14 @@ class Engine:
                 jax.ShapeDtypeStruct(tuple(sample_shape), dtype))
         return out
 
-    # --- the bench surface ---------------------------------------------
+    # --- the measuring surface -----------------------------------------
 
     def build(self, mesh=None, unroll: int = 1) -> EngineBuild:
         """Dataset + laid-out state + compiled step for one knob config
-        — the construction stack bench.py/bench_lm.py used to hand-wire,
-        with no hooks, no eval, no checkpointing (the harness measures
-        the step, the trainer surface supervises it).  Train split only;
-        ``unroll`` is the lax.scan fusion the bench sweeps."""
+        — the trainer's construction stack with no hooks, no eval, no
+        checkpointing (the harness measures the step, the trainer
+        surface supervises it).  Train split only; ``unroll`` is the
+        lax.scan fusion."""
         cfg = self.spec.config
         enable_compilation_cache()
         if mesh is None:
@@ -934,10 +933,9 @@ class Engine:
                     # The worker-average psums are cond-gated on the
                     # period: the module-weight inventory counts them at
                     # every step, so SUSTAINED wire traffic is the totals
-                    # divided by the period (bench_scaling's
-                    # amortized_bytes_per_step approximation, documented
-                    # there: the every-step scalar-metrics psum pair —
-                    # 8 B — is amortized along with it).  The per-op
+                    # divided by the period (an approximation: the
+                    # every-step scalar-metrics psum pair — 8 B — is
+                    # amortized along with it).  The per-op
                     # gauges keep the raw compiled schedule; only the
                     # cumulative counters amortize.
                     collectives = dict(

@@ -70,8 +70,7 @@ class DecoderBlock(nn.Module):
     """Pre-LN decoder block: LN -> causal MHA -> residual, LN -> MLP ->
     residual.  Attention is ``ops.attention.causal_attention``: on the
     CPU and for shapes that do not tile, explicit batched einsums (two
-    dot-generals with batch dims) — the exact HLO shape the MFU flops
-    audit (utils/profiling.hlo_flops_by_op) must price correctly."""
+    dot-generals with batch dims)."""
     d_model: int
     n_heads: int
     d_ff: int

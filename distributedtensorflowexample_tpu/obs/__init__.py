@@ -44,10 +44,10 @@ Four cooperating pieces, each usable alone:
   ``/metrics`` (Prometheus text), ``/health`` (the §16 contract),
   ``/flight`` (on-demand recorder dump), and ``/ledger/tail``.
 
-Deliberately **stdlib-only**: importing obs never pulls jax, so
-bench.py's record-survival contract (its SIGTERM handler must be live
-before the first heavyweight import) and the supervisor's lightweight
-process both instrument themselves for free.
+Deliberately **stdlib-only**: importing obs never pulls jax, so a
+process whose SIGTERM handler must be live before its first heavyweight
+import, and the supervisor's lightweight process, both instrument
+themselves for free.
 """
 
 from distributedtensorflowexample_tpu.obs.anomaly import (  # noqa: F401

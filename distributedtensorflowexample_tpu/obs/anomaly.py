@@ -364,14 +364,3 @@ def detect_skew(ranks: dict, lag_steps: int = 3,
         else:
             out["why"][r] = f"lagging {lag} steps (no slowness evidence)"
     return out
-
-
-def spread_fraction(samples) -> float:
-    """(max - min) / max over positive samples — the bench family's
-    measurement-instability sentinel (a wide repeat spread marks the
-    window, and the record, as noisy before a ratchet compares it)."""
-    vals = [s for s in samples
-            if isinstance(s, (int, float)) and s > 0]
-    if len(vals) < 2:
-        return 0.0
-    return (max(vals) - min(vals)) / max(vals)

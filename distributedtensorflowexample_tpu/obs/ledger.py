@@ -1,7 +1,7 @@
 """Append-only run ledger — every run on the box leaves queryable rows.
 
-Until round 12 the repo's cross-run record was a pile of files: 20+
-``BENCH_*``/``SCALING_*`` JSONs, per-run flight dumps, per-run journals.
+Until round 12 the repo's cross-run record was a pile of files:
+per-run record JSONs, per-run flight dumps, per-run journals.
 Each is a fine *per-run* postmortem, but nothing answered "what ran on
 this box, with which config, and how did it end" without a shell glob
 and a human.  The ledger is that missing layer: one ``RUNS.jsonl``
@@ -33,7 +33,7 @@ on a box for months without anyone babysitting it.
 
 Stdlib-only like the rest of ``obs/`` (the package import guard in
 tests/test_ledger.py walks every module): importing the ledger never
-pulls jax, so bench's handler-before-import ordering holds.
+pulls jax.
 """
 
 from __future__ import annotations

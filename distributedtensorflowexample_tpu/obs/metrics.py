@@ -155,7 +155,7 @@ class _Family:
     def series(self):
         """(series_key, child) pairs, canonically sorted.  The key set
         is copied UNDER the lock: a snapshot may run on another thread
-        (bench's watchdog dumping a flight) while the observed thread
+        (a watchdog dumping a flight) while the observed thread
         registers a new labeled series, and iterating the live dict
         there would raise mid-dump and silently cost the postmortem.
         The eager unlabeled child (the lock-free bare-op fast path) is

@@ -3,9 +3,9 @@ profile-chosen kernel; shipped by the round-5 dequant-tax fix).
 
 The device-resident input path reads its minibatch as ``take(split, idx)``
 followed by an elementwise dequant.  XLA materializes the gathered uint8
-minibatch in HBM between the two — the round-trip PROFILE_auto_r05.json
-charges to the input path (82% of the ResNet-20 step, measured/roofline
-0.12).  This kernel fuses the two: the scalar-prefetched index vector
+minibatch in HBM between the two — the round-trip a chip trace of
+2026-08 charged to the input path (82% of the ResNet-20 step).  This
+kernel fuses the two: the scalar-prefetched index vector
 drives the BlockSpec index map, so each grid step DMAs ONE uint8 source
 sample HBM->VMEM and writes its dequantized float32 sample straight to
 the output batch — uint8 bytes cross HBM exactly once, and no uint8

@@ -20,8 +20,8 @@ compile-time static.
 Segment boundaries inside the flat buffer need no masking: the pad tail's
 gradient is zero, so its momentum stays zero and its params stay put.
 
-MEASURED ON-CHIP (v5e, round 2 — BASELINE.md): 675 steps/s vs 1,543 for
-the XLA apply on the same MNIST-CNN window — a 2.3x net slowdown.  The
+MEASURED ON-CHIP (v5e, 2026-07, one window; the record is not kept): 675
+steps/s vs 1,543 for the XLA apply on MNIST-CNN — a 2.3x net slowdown.  The
 single kernel launch is cheap; what XLA never pays is the per-step
 ``_flatten_leaves``/``_unflatten_like`` round-trip (~50 MB of extra HBM
 traffic for a 3.3M-param model: build p_flat + g_flat, write both outputs,

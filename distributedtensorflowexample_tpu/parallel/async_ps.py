@@ -183,8 +183,8 @@ def _build_shard_map_step(num_workers: int, period: int,
     own workers' parameter copies — zero collectives between averaging
     points, by construction.
 
-    Why not let GSPMD partition the ``vmap`` body?  Measured on the
-    8-device mesh (bench_scaling --mode async, round 2): the vmapped conv
+    Why not let GSPMD partition the ``vmap`` body?  Seen in the compiled
+    module on the 8-device CPU mesh: the vmapped conv
     lowers to one grouped convolution whose worker axis is folded into the
     channel dim, and the SPMD partitioner then ALL-GATHERS the worker-tiled
     conv weights and activations (4 all-gathers sized like the gathered

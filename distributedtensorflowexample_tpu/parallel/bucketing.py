@@ -6,8 +6,7 @@ all-reduces for the 8-leaf mnist_cnn step) — every one pays the fixed
 per-collective latency alpha.  arXiv:1810.11112's characterization says
 collective cost is ``t(S) = alpha + S/beta`` with a message-size knee at
 ``alpha*beta``: below the knee latency dominates and fusing messages is
-nearly free throughput.  ``bench_collectives.py`` measures alpha/beta/knee
-for this stack; this module acts on it.
+nearly free throughput.  This module acts on it.
 
 Two modes, selected by ``--shard_update``:
 
@@ -72,13 +71,11 @@ import optax
 from distributedtensorflowexample_tpu.parallel.mesh import DATA_AXIS
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 
-# --bucket_grads auto: sized from the measured CPU-mesh all-reduce knee
-# (bench_collectives.py: 8-device psum knee 244 KB at r2=0.99,
-# suggested_bucket_bytes ~954 KB = 4x knee, where the alpha/latency share
-# of t(S) = alpha + S/beta is down to ~20% — BENCH_collectives_cpu_r06.
-# json + DESIGN.md §15).  Chip-remeasurable: the capture window's
-# --real phase re-fits the knee, and BUCKET_GRADS_AUTO_BYTES overrides
-# without a code change.
+# --bucket_grads auto: from an XLA:CPU fit, never timed on chips (ROADMAP
+# Queue 1 item 7) — the 8-virtual-device psum knee came out at 244 KB
+# (r2=0.99), and 4x the knee, where the alpha/latency share of
+# t(S) = alpha + S/beta is down to ~20%, rounds to 1 MiB (DESIGN.md §15).
+# BUCKET_GRADS_AUTO_BYTES overrides without a code change.
 DEFAULT_BUCKET_BYTES = 1 << 20
 
 # Compiled-schedule contracts, checked by analysis/hlo_lint.py against

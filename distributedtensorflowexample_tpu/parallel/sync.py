@@ -187,8 +187,8 @@ def make_device_gather(batch_size: int, steps_per_epoch: int,
     pytree (the affine fast path for both shipped loader specs);
     ``pallas`` fuses the row gather and the affine dequant into ONE
     kernel pass (ops/pallas/dequant.py — replicated datasets only);
-    ``lut`` forces the elementwise-gather diagnostic the bench uses to
-    keep the round-5 dequant tax attested.
+    ``lut`` forces the elementwise-gather diagnostic (the dequant tax
+    of PERF.md "History").
 
     ``data_sharding="sharded"`` pairs with a row-sharded
     ``DeviceDataset(data_sharding="sharded")``: each device gathers its

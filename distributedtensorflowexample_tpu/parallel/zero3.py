@@ -25,7 +25,7 @@ stage 3) to params and grads:
   so the full tree never exists as persistent state — the compiler
   memory analysis shows it in ``temp_bytes``, not ``argument_bytes``
   (the measured form of the 1/D claim: see
-  ``utils/profiling.compiled_program_audit``'s residency section).
+  ``utils/profiling.state_residency_per_device``).
 
 * **Grads reduce-scattered per bucket, BY CONSTRUCTION**: the gather is
   differentiated through — ``jax.lax.all_gather``'s transpose IS
@@ -44,10 +44,10 @@ stage 3) to params and grads:
   ahead of their consumers: gather i+1 issues while bucket i's leaves
   are being consumed, the classic double buffer.  ``overlap=False``
   chains on bucket i-1 instead (strictly serial gathers) — the A/B
-  control ``bench_lm.py`` measures.  XLA:CPU dispatches synchronously,
-  so the CPU wall-clock pair only proves the schedule compiles both
-  ways; the overlap win itself is armed for the next TPU window
-  (BASELINE_SELF.json), where the latency-hiding scheduler turns the
+  control.  XLA:CPU dispatches synchronously, so a CPU wall-clock pair
+  only proves the schedule compiles both ways; the overlap win itself
+  has never been timed on chips (ROADMAP names ``--zero3_overlap`` as a
+  debt).  There the latency-hiding scheduler can turn the
   independent AG-prefetch chain into async collectives hidden under
   block compute — graft-LM's block ladder supplies the gather points
   (leaves flatten embed → block0..blockN → ln_f, so knee-sized buckets

@@ -10,9 +10,9 @@ kind                models
                     at an exact step boundary via the process's real
                     signal path, so the loop's cooperative-stop +
                     save-on-exit machinery is what gets exercised
-``wedge``           a dispatch that blocks without raising (the
-                    ``bench._probe_backend`` 300-s hang / round-3
-                    mid-run backend loss) — a boundary sleep that
+``wedge``           a dispatch that blocks without raising (a backend
+                    probe that hangs, a mid-run backend loss) — a
+                    boundary sleep that
                     starves the supervisor's heartbeat
 ``nan_loss``        numeric blowup: the covered FLOAT batch is poisoned
                     so the loss goes non-finite (NaNGuardHook fails fast

@@ -33,8 +33,8 @@ regression``          snapshot); the relaunch resumes bitwise from the
 ``canary_regression`` **canary_rollback** — revert a canary promotion
                       (serving/promote.Canary) to the baseline snapshot
 ``serve_overload`` /  **scale_up** / **scale_down** — resize the serve
-``serve_underload``   replica fleet against the measured SLO knee
-                      (SERVE_lm record): offered load over the fleet's
+``serve_underload``   replica fleet against the SLO knee it is
+                      given: offered load over the fleet's
                       in-SLO capacity grows it, sustained idle shrinks
                       it, both clamped to [min, max] replicas
 ====================  ====================================================
@@ -170,9 +170,10 @@ def lr_drop_enabled() -> bool:
 
 
 def newest_heal_record(root: str = "") -> str:
-    """Path of the newest checked-in MTTR drill record
-    (``HEAL_*_r<NN>.json`` at the repo root — round number sorts
-    lexicographically), or ``""`` when none exists."""
+    """Path of the newest MTTR drill record (``HEAL_*_r<NN>.json`` at
+    the repo root, as ``tools/heal_drill.py --out`` writes one — round
+    number sorts lexicographically), or ``""`` when none exists (the
+    repo ships none)."""
     if not root:
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
@@ -743,8 +744,8 @@ class ServeWatcher:
 class AutoscaleWatcher:
     """Scrape the serve fleet's offered load (``stats_fn`` →
     ``{"offered_per_s", "replicas", ...}``) against the measured SLO
-    knee — the best in-SLO per-replica throughput a SERVE_lm record
-    proved (``throughput_vs_slo``) — and emit ``serve_overload`` while
+    knee — the best in-SLO per-replica throughput a load sweep
+    proved — and emit ``serve_overload`` while
     offered load exceeds the fleet's in-SLO capacity
     (``replicas × knee × headroom``) and ``serve_underload`` while the
     fleet idles under ``low_water`` of it.  Both directions carry their

@@ -7,7 +7,7 @@ separates the job description from its placement.  Until round 14 this
 repo had every ingredient — gang supervision with a loss-free 143
 preemption protocol (resilience/fleet.py), an elastic rank-loss path
 nothing exercised as policy, a queryable run ledger (obs/ledger.py) and
-bench-family trajectories that predict a job's cost — but no component
+measured step rates that predict a job's cost — but no component
 turning faults and load into *decisions*.  This module is that
 component: a crash-tolerant queue of heterogeneous jobs (train / bench
 / faultline drill / future serving load tests) admitted against
@@ -15,8 +15,9 @@ measured cost, packed onto the available device mesh, and supervised
 with robustness as policy:
 
 - **admission against measured cost** — a job's step time is predicted
-  from its BENCH_trajectory.json family (the newest round's
-  ``*steps_per_sec`` metric, conservatively the slowest), falling back
+  from its family's rows in the trajectory file it is given (the
+  newest round's ``*steps_per_sec`` metric, conservatively the
+  slowest), falling back
   to the job's declared estimate; the prediction prices the admission
   row and, unless the job pins its own wall timeout, derives the
   fleet's per-attempt deadline (``cost_margin`` x predicted).
@@ -175,7 +176,7 @@ class Job:
     ranks: int = 1                 # gang width = device demand
     priority: int | None = None    # lower = more urgent; None = by kind
     steps: int | None = None       # work size, for the cost prediction
-    family: str = ""               # BENCH_trajectory family for cost
+    family: str = ""               # trajectory-file family for cost
     est_step_time_s: float | None = None   # declared fallback estimate
     retries: int = 1               # scheduler-level requeues (crashes)
     fleet_retries: int = 1         # gang restarts INSIDE one placement
@@ -231,10 +232,11 @@ class Job:
 # --- the cost model --------------------------------------------------------
 
 def trajectory_rows(path: str) -> list[dict]:
-    """The checked-in BENCH_trajectory.json: one JSON line per bench
-    family per round (tools/bench_ratchet.py --trajectory).  Missing or
-    torn lines read as no data — cost prediction degrades to declared
-    estimates, never raises."""
+    """A trajectory file (a deployment input; the repo ships none and
+    nothing in it writes one): one JSON line per job family per round,
+    ``{"family", "round", "file", "metrics": {"*steps_per_sec": ...}}``.
+    A missing file or torn lines read as no data — cost prediction
+    degrades to declared estimates, never raises."""
     rows: list[dict] = []
     try:
         with open(path) as f:
@@ -285,28 +287,6 @@ def predict_cost(job: Job, trajectory_path: str = "") -> dict:
                  if step_time and job.steps else None)
     return {"step_time_s": (round(step_time, 6) if step_time else None),
             "predicted_s": predicted, "source": source}
-
-
-def load_collective_fit(path: str, devices: int) -> dict | None:
-    """Read the fitted ``t(S) = alpha + S/beta`` psum line for the
-    nearest measured device count out of a BENCH_collectives record
-    (``knees.psum.<devices>.{alpha_s, beta_bytes_per_s}``) — the price
-    model for moving a victim's snapshot state across slices.  Missing
-    or malformed records read as "no fit" (pricing degrades to
-    unpriced), never raise."""
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-        knees = rec["detail"]["knees"]["psum"]
-        fits = {int(k): v for k, v in knees.items()}
-        nearest = min(fits, key=lambda d: (abs(d - devices), d))
-        fit = fits[nearest]
-        return {"alpha_s": float(fit["alpha_s"]),
-                "beta_bytes_per_s": float(fit["beta_bytes_per_s"]),
-                "fit_devices": nearest, "file": os.path.basename(path)}
-    except (OSError, KeyError, TypeError, ValueError,
-            json.JSONDecodeError):
-        return None
 
 
 # --- per-job runtime state -------------------------------------------------
@@ -378,7 +358,7 @@ class Scheduler:
             self.slices = {"mesh": devices}
         self._multi = slices is not None
         self.devices = devices
-        # The fitted collective model (load_collective_fit) pricing a
+        # The fitted collective model ({alpha_s, beta_bytes_per_s}) pricing a
         # cross-slice eviction: the victim's snapshot state may have to
         # move slices on relaunch, t(S) = alpha + S/beta per rank.
         self.collective_fit = collective_fit

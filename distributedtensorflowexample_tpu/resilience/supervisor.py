@@ -12,7 +12,7 @@ and watches two liveness signals the rounds-3-5 outage proved necessary:
   a wedged dispatch stops — the one failure a wall deadline alone either
   kills too early or notices too late.
 
-Exit-code protocol (shared with bench.py and trainers/common.py):
+Exit-code protocol (shared with trainers/common.py):
 
 ====  ====================================================================
 rc    meaning / supervisor reaction
@@ -21,18 +21,16 @@ rc    meaning / supervisor reaction
 143   preempted-with-save (SIGTERM honored, checkpoint written) —
       restart immediately; the child's own ``--resume`` picks up the
       latest snapshot
-3     watchdog: backend provably wedged (bench.py's os._exit(3)) — do
+3     watchdog: backend provably wedged (the child's os._exit(3)) — do
       NOT retry; surface "wedged" so a task queue can stop burning the
       window on chip-bound work
 else  crash — retry with jittered exponential backoff, bounded
 ====  ====================================================================
 
-The task queue is the productized replacement for bench_capture.sh's
-inline phase ordering: tasks run in priority order, every state change
-is journaled (JSON lines, append-only), and a supervisor restarted after
+The task queue: tasks run in priority order, every state change is
+journaled (JSON lines, append-only), and a supervisor restarted after
 its own death replays the journal and resumes exactly where the previous
-one died — a 9-minute recovery window converts the contract headline
-first, and the next window picks up from the first unfinished phase.
+one died, from the first unfinished task.
 """
 
 from __future__ import annotations
@@ -84,8 +82,8 @@ def _log(msg: str) -> None:
 
 
 def kill_process_group(proc: subprocess.Popen, grace_s: float) -> None:
-    """SIGTERM the whole group, grace, then SIGKILL — THE one escalation
-    (tpu_watch.sh's shape), shared by the single-child supervisor and
+    """SIGTERM the whole group, grace, then SIGKILL — THE one
+    escalation, shared by the single-child supervisor and
     the fleet gang teardown (resilience/fleet.py) so the grace
     semantics — the window a trainer's SIGTERM handler has to write its
     final checkpoint — can't drift between the two."""
@@ -277,7 +275,7 @@ class Supervisor:
                                 start_new_session=True)
         # The child lives in its OWN session (so the watchdog's killpg
         # can't suicide the supervisor) — which means a SIGTERM aimed at
-        # the SUPERVISOR's group (tpu_watch.sh's stale-capture kill)
+        # the SUPERVISOR's group (a watcher's stale-run kill)
         # does not reach it.  Forward: on SIGTERM, kill the child group
         # and report, so a watcher group-kill can never orphan a live
         # chip-holding phase behind a dead supervisor.
@@ -309,11 +307,10 @@ class Supervisor:
                     # Armed only once the FIRST beat lands: heartbeat
                     # participation is the child's opt-in (run_training
                     # and faultline install HeartbeatHook when
-                    # SUPERVISE_HEARTBEAT is exported; bench.py does
-                    # not).  Measuring from spawn instead would turn the
-                    # heartbeat timeout into a hard wall clock for every
-                    # beat-less child — killing a healthy bench deep in
-                    # its legitimate probe-retry budget.  A child wedged
+                    # SUPERVISE_HEARTBEAT is exported; other children
+                    # do not).  Measuring from spawn instead would turn
+                    # the heartbeat timeout into a hard wall clock for
+                    # every beat-less child.  A child wedged
                     # BEFORE its first beat is the wall timeout's job.
                     try:
                         hb_age = (time.time()
@@ -424,13 +421,11 @@ class Supervisor:
                 entrypoint=name, attempt=attempt, pid=os.getpid())
             tmp = f"{stdout_path}.tmp" if stdout_path else None
             out = open(tmp, "wb") if tmp else None
-            # Append mode: one log accumulates every attempt's prose,
-            # like bench_capture.sh's `2>> "$LOG"`.
+            # Append mode: one log accumulates every attempt's prose.
             err = open(stderr_path, "ab") if stderr_path else None
             try:
                 # No stdout artifact but a log sink: archive stdout in
-                # the log too (bench_capture.sh's `>> "$LOG" 2>&1` for
-                # the bytes-audit table) instead of dropping it.
+                # the log too instead of dropping it.
                 rc, reason = self._run_once(argv, env, out or err, err,
                                             heartbeat_path, wall)
             finally:
@@ -439,7 +434,7 @@ class Supervisor:
                 if err:
                     err.close()
             if tmp:
-                # keep() semantics from bench_capture.sh: every line was
+                # keep() semantics: every line was
                 # flushed as it completed, so a non-empty partial file is
                 # a valid partial capture; an empty one must not clobber
                 # a previous attempt's output.
@@ -573,9 +568,8 @@ class TaskQueue:
                                         rc=res.returncode)
                 results[task.name] = "wedged"
             else:
-                # Keep going — bench_capture.sh also runs later phases
-                # after a non-wedge failure (each phase's partial output
-                # is already kept).
+                # Keep going: later tasks run after a non-wedge failure
+                # (each task's partial output is already kept).
                 self._sup.journal.write("task_failed", task=task.name,
                                         rc=res.returncode)
                 results[task.name] = "failed"

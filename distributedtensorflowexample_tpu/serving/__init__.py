@@ -19,8 +19,7 @@ training stack's snapshots promote into:
   prefill, a latency-SLO admission knob, p50/p99/tokens-per-sec through
   the ``obs/`` registry;
 - :mod:`~distributedtensorflowexample_tpu.serving.loadgen` — the
-  closed-loop load generator behind ``bench_serving.py``'s
-  throughput-vs-SLO curves;
+  closed-loop load generator behind ``tools/serve_lm.py --drive``;
 - :mod:`~distributedtensorflowexample_tpu.serving.frontend` — the
   opt-in (``SERVE_PORT``) stdlib HTTP request front.
 

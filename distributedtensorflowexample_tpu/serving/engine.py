@@ -135,7 +135,7 @@ DEFAULT_SLOTS = 4
 
 def serve_slots_default() -> int:
     """``SERVE_SLOTS``: default concurrent decode slots for
-    tools/serve_lm.py and bench_serving.py (CLI flags override)."""
+    tools/serve_lm.py (the CLI flag overrides)."""
     try:
         return max(1, int(os.environ.get("SERVE_SLOTS", "")))
     except ValueError:

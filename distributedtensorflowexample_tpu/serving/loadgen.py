@@ -6,9 +6,9 @@ submitting one request, waiting for its completion, then immediately
 submitting the next — self-limits to the system's actual service rate,
 so sweeping K traces out the throughput/latency trade directly:
 tokens/sec climbs with K until the slots saturate, then p50/p99 climb
-instead.  With the SLO admission knob on, the same sweep yields the
-throughput-vs-SLO curve ``bench_serving.py`` records (in-SLO goodput vs
-the rejection rate at each operating point).
+instead.  With the SLO admission knob on, the same sweep yields a
+throughput-vs-SLO curve (in-SLO goodput vs the rejection rate at each
+operating point).
 
 Determinism: prompts are generated from a seeded RNG keyed by request
 index, so request #17 is byte-identical across runs, placements, and
@@ -37,7 +37,7 @@ _DEF_REQUESTS = 16
 
 def load_clients_default() -> int:
     """``SERVE_LOAD_CLIENTS``: default closed-loop client thread count
-    for serve_lm --drive and bench_serving (CLI flags override)."""
+    for serve_lm --drive (the CLI flag overrides)."""
     try:
         return max(1, int(os.environ.get("SERVE_LOAD_CLIENTS", "")))
     except ValueError:

@@ -52,14 +52,14 @@ def _log(msg: str) -> None:
 
 
 def serve_snapshot_default() -> str:
-    """``SERVE_SNAPSHOT``: the snapshot directory tools/serve_lm.py and
-    bench_serving.py load when ``--snapshot`` is not passed — empty
-    means the flag is required."""
+    """``SERVE_SNAPSHOT``: the snapshot directory tools/serve_lm.py
+    loads when ``--snapshot`` is not passed — empty means the flag is
+    required."""
     return os.environ.get("SERVE_SNAPSHOT", "")
 
 
 def _default_tx():
-    # The repo-wide training default (trainers, faultline, bench_lm):
+    # The repo-wide training default (trainers, faultline):
     # promotion templates must mirror what the snapshot writers ran.
     return optax.sgd(0.1, momentum=0.9)
 

@@ -17,8 +17,8 @@ priced at admission time — wait so far + a prefill estimate + max_new x
 the decode-step EWMA — and one that can no longer finish inside the SLO
 is REJECTED loudly (counted, latency-stamped) instead of admitted to
 miss.  Under overload a closed-loop client sees fast rejections and the
-in-SLO goodput stays measurable; that rejection edge is exactly the
-knee ``bench_serving.py``'s throughput-vs-SLO curves sweep out.
+in-SLO goodput stays measurable; that rejection edge is the knee a
+throughput-vs-SLO sweep traces out.
 
 Shutdown is the trainer's loss-free TERM protocol, re-read for serving:
 on ``drain()`` the batcher stops admitting, decodes every in-flight

@@ -10,8 +10,8 @@ decode step all-gathers each bucket's row *inside* the program just
 before its einsums consume the leaves — the gathered tree is a
 step-local TEMPORARY the compiler frees after last use, so persistent
 params residency is exactly 1/D (measured from live shardings:
-:meth:`ShardedDecodeEngine.params_residency`, the same instrument as
-BENCH_lm_cpu_r12's claim).
+:meth:`ShardedDecodeEngine.params_residency`, the method of
+``utils/profiling.state_residency_per_device``).
 
 The gather schedule is zero3's own: one tiled all-gather per bucket,
 issue order pinned by the ``_tie`` double-buffer chain (bucket i's
@@ -272,9 +272,9 @@ class ShardedDecodeEngine:
         return lowered.compile().as_text()
 
     def params_residency(self) -> dict:
-        """The 1/D claim from LIVE shardings (the BENCH_lm_cpu_r12
-        instrument's method: bytes of the addressable shard vs bytes of
-        the logical array) — rows are ``[D*W_b]`` sharded one row per
+        """The 1/D claim from LIVE shardings (bytes of the addressable
+        shard vs bytes of the logical array) — rows are ``[D*W_b]``
+        sharded one row per
         device, so ``frac_per_device`` is exactly ``1/D``, and a silent
         replication regression shows up as 1.0, not as folklore."""
         total = 0

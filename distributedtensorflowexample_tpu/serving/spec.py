@@ -19,9 +19,7 @@ total rejection the round still emits g_0, exactly one plain decode
 step's worth, so speculation never decodes SLOWER in steps, only in
 draft-side work).  Output is therefore bitwise plain greedy by
 construction — the oracle tests in tests/test_serving.py pin it against
-solo greedy runs (including a bench-shaped mixed-bucket churn workload),
-and ``bench_serving.py`` counts any divergence on a ``*_mismatch``
-column the ratchet holds at zero.
+solo greedy runs (including a mixed-bucket churn workload).
 
 Cache discipline: the verify scatter lands the window's K/V at rows
 ``p..p+k``, so accepted rows hold the right tokens' K/V by the accept

@@ -96,11 +96,11 @@ class SimWorld:
         if not serve:
             return
         knee = float(serve["knee_per_replica"])
-        # Cooldown default: seeded from the newest measured HEAL_*
-        # MTTR record (2x the worst proven detect->recovered tail)
-        # rather than a hardcoded constant — a scenario that names
-        # cooldown_s still wins, and the seed is deterministic (the
-        # record is checked in), so same-seed runs stay bitwise.
+        # Cooldown default: seeded from the newest HEAL_* MTTR record
+        # at the repo root (2x the worst proven detect->recovered
+        # tail), HEAL_COOLDOWN_S where there is none (the repo ships
+        # none) — a scenario that names cooldown_s still wins, and
+        # either way same-seed runs on one tree stay bitwise.
         cooldown_s = serve.get("cooldown_s")
         if cooldown_s is None:
             cooldown_s = heal_mod.mttr_seeded_cooldown_s()

@@ -17,8 +17,8 @@ Row families:
   (flap/cooldown/budget/noop): proof the guardrails BOUND under storm.
 * **must-be-zero invariants** — ``sim_fleet_steps_lost`` (snapshot
   resume forgot work) and ``sim_wal_unbalanced_violations`` (a
-  ``sched_intent`` whose effect never landed) end in the suffixes
-  ``tools/bench_ratchet.py`` refuses to let regress above zero.
+  ``sched_intent`` whose effect never landed): ``tools/sim_run.py``
+  exits 1 when either is above zero.
 """
 
 from __future__ import annotations

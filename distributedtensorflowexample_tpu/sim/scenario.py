@@ -21,8 +21,8 @@ Schema (all times in virtual seconds from sim start)::
         "headroom": 0.85, "low_water": 0.35,
         "flap_n": 2, "flap_window_s": 60, "cooldown_s": 60,
         "budget": 8                   # cooldown_s omitted -> seeded
-      },                              # from the measured HEAL_* MTTR
-                                      # record (remediate.
+      },                              # from a HEAL_* MTTR record if
+                                      # one is there (remediate.
                                       # mttr_seeded_cooldown_s)
       "events": [                     # the scripted world
         {"at": 120, "kind": "host_loss", "job": "t1", "rank": 3}, ...
@@ -162,8 +162,8 @@ def load_scenario(source) -> Scenario:
     if serve is not None and not serve.get("knee_per_replica"):
         raise ValueError(
             f"scenario {name}: serve.knee_per_replica is required — "
-            f"the autoscale policy prices capacity from the measured "
-            f"SLO knee (SERVE_lm record), not a guess")
+            f"the autoscale policy prices capacity from a measured "
+            f"SLO knee, not a guess")
     return Scenario(
         name=name,
         seed=int(payload.get("seed") or 0),

@@ -46,10 +46,10 @@ def build_spec(argv=None) -> RunSpec:
                      momentum=0.9, dataset="lm", dropout=0.0,
                      log_every=100)
     if ns.size == "lm_base":
-        # Measurement-driven defaults (BENCH_lm_cpu_r08.json A/B matrix
-        # at lm_base/D=4): remat=block cut the per-device temp arena
-        # 24.6% at bit-equal forward math (no measurable CPU cost
-        # beyond contention noise), and bucket_grads fused 104
+        # Defaults from an XLA:CPU A/B at lm_base/D=4 (compiled-module
+        # numbers, never timed on chips: ROADMAP Queue 1 item 7):
+        # remat=block cut the per-device temp arena 24.6% at bit-equal
+        # forward math, and bucket_grads fused 104
         # per-parameter all-reduces into 68 knee-sized ones at
         # unchanged math.  Both are parity-safe knobs; --shard_update
         # stays opt-in because it changes the checkpoint's

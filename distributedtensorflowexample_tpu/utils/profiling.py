@@ -1,4 +1,4 @@
-"""Trace capture + compiled-module bytes attribution.
+"""Trace capture + accounting from a compiled module's optimized HLO.
 
 SURVEY.md §5 maps the reference's (absent, library-default) tracing row to
 ``jax.profiler`` + TensorBoard.  Entry points:
@@ -8,11 +8,13 @@ SURVEY.md §5 maps the reference's (absent, library-default) tracing row to
 * :class:`ProfilerHook` — a training :class:`~..training.hooks.Hook` that
   captures steps ``(start_step, start_step + num_steps]`` of the live loop,
   which is how "why is steps/sec low" questions get answered on real chips.
-* :func:`hlo_bytes_by_op` / :func:`bytes_audit` /
-  :func:`cost_and_bytes_audit` — decompose XLA cost-analysis
-  ``bytes_accessed`` per HLO op for any compiled step (the PR-2 tentpole:
-  the aggregate number alone cannot say WHICH traffic caps arithmetic
-  intensity, and it over-counts gathers — see ``bytes_audit``).
+* :func:`entry_walk` — one parse of an optimized-HLO text, walked from
+  ENTRY with execution weights; ``analysis/hlo_lint.py`` checks its
+  contracts on it.
+* :func:`collective_inventory` / :func:`collective_inventory_of` — which
+  collectives a compiled step carries, with their bytes and replica groups.
+* :func:`state_residency_per_device` — a train state's resident bytes per
+  device, from the live shardings (the measured form of the ZeRO 1/D claims).
 """
 
 from __future__ import annotations
@@ -89,25 +91,9 @@ class ProfilerHook(Hook):
 
 
 # ---------------------------------------------------------------------------
-# Per-op bytes attribution from optimized HLO text (PR-2 tentpole).
-#
-# XLA's ``compiled.cost_analysis()["bytes accessed"]`` is one aggregate; the
-# round-5 on-chip record hung the repo's weakest number (0.82 flop/byte for
-# the ResNet-20 step) on it with no way to say WHICH ops carry the bytes.
-# The optimized HLO text has everything needed to decompose it: every
-# instruction line carries its output shape AND its operands' shapes inline,
-# so per-instruction bytes = output + operands — the exact convention
-# HloCostAnalysis uses (fusion internals free, operands counted at full
-# size).  Parsed totals match ``cost_analysis()`` to <0.1% on the programs
-# the tests pin.
-#
-# The decomposition also exposes an artifact the aggregate hides: a fused
-# row GATHER from a device-resident split counts the WHOLE split array as
-# an operand (e.g. the 153.6 MB uint8 CIFAR split for a 786 KB minibatch
-# read), so ``bytes_accessed`` wildly over-states true HBM traffic for
-# resident-data programs.  ``effective_bytes`` re-prices gather-category
-# ops at rows-actually-touched (output size), which is the honest
-# denominator for bandwidth rooflines.
+# Optimized-HLO text parse.  Every instruction line carries its output
+# shape AND its operands' shapes inline, so bytes per instruction =
+# output + operands — the convention HloCostAnalysis uses.
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -127,40 +113,6 @@ _CALLS_RE = re.compile(r"(calls|to_apply|body|condition|true_computation"
 # N-ary conditionals print their targets as a brace list instead of
 # named fields: `branch_computations={%b0, %b1, ...}`.
 _BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
-_OPNAME_RE = re.compile(r'op_name="([^"]*)"')
-
-# No memory traffic of their own: parameters/constants are inputs counted
-# at their consumers; tuples/GTE are aliasing.
-_SKIP_OPS = frozenset({
-    "parameter", "constant", "tuple", "get-tuple-element", "after-all",
-    "partition-id", "replica-id", "add-dependency", "opt-barrier"})
-# Recursed into (their bodies carry the traffic), never counted themselves:
-# operands pass by reference.
-_CONTROL_OPS = frozenset({"while", "call", "conditional"})
-
-_CATEGORY = {
-    "convolution": "conv", "dot": "matmul",
-    "all-reduce": "collective", "all-gather": "collective",
-    "reduce-scatter": "collective", "collective-permute": "collective",
-    "all-to-all": "collective",
-    "gather": "gather", "scatter": "gather", "dynamic-slice": "gather",
-    "dynamic-update-slice": "gather",
-    "transpose": "layout", "copy": "layout", "reshape": "layout",
-    "bitcast": "layout", "concatenate": "layout", "slice": "layout",
-    "pad": "layout", "reverse": "layout",
-    "convert": "cast", "bitcast-convert": "cast",
-    "reduce": "reduce", "reduce-window": "reduce",
-    "select-and-scatter": "reduce",
-    "rng": "rng", "rng-bit-generator": "rng",
-    "custom-call": "custom",
-}
-# A fusion is classified by the highest-priority opcode it fuses — the op
-# that explains why the traffic exists (a conv fusion's converts are the
-# conv's boundary, not a standalone cast pass).
-_FUSION_PRIORITY = (
-    "convolution", "dot", "all-reduce", "all-gather", "reduce-scatter",
-    "scatter", "gather", "dynamic-update-slice", "dynamic-slice",
-    "reduce-window", "reduce", "rng-bit-generator", "transpose", "convert")
 
 
 def _shape_bytes(token: str) -> int:
@@ -207,14 +159,6 @@ def _split_computations(hlo_text: str):
     return comps, entry
 
 
-def _fusion_category(instrs) -> str:
-    ops = {i[2] for i in instrs}
-    for p in _FUSION_PRIORITY:
-        if p in ops:
-            return _CATEGORY.get(p, "elementwise")
-    return "elementwise"
-
-
 def _operand_token(line: str, start: int) -> str:
     """The operand list of an instruction line: everything inside the
     call parens opened at ``start`` (shapes are printed inline per
@@ -239,8 +183,7 @@ def _computation_weights(comps: dict, entry: str, unroll: int) -> dict:
     programs is the ``lax.scan`` over fused train steps, whose trip count
     IS the unroll).  Fusion ``calls=`` and reduce ``to_apply=``
     computations stay excluded — their internals don't touch memory (or
-    the wire) separately.  Shared by the bytes audit and the collective
-    inventory so both instruments normalize per-step identically."""
+    the wire) separately."""
     weights: dict[str, int] = defaultdict(int)
 
     def visit(name: str, weight: int) -> None:
@@ -270,237 +213,13 @@ def entry_walk(hlo_text: str, unroll: int = 1) -> tuple[dict, str | None,
     optimized-HLO text.  ``computations`` maps name -> instruction
     tuples ``(name, out_token, opcode, raw_line, operand_start)``;
     ``entry_name`` is None when the text has no ENTRY (weights then
-    empty).  Callers: the bytes/flops audits and collective inventory
-    below, and ``analysis/hlo_lint.py``'s contract checks — one parse,
-    one opinion about what the module contains."""
+    empty).  Callers: the collective inventory below and
+    ``analysis/hlo_lint.py``'s contract checks — one parse, one opinion
+    about what the module contains."""
     comps, entry = _split_computations(hlo_text)
     if entry is None:
         return comps, None, {}
     return comps, entry, _computation_weights(comps, entry, unroll)
-
-
-def hlo_bytes_by_op(hlo_text: str, unroll: int = 1) -> list:
-    """Per-instruction bytes rows from optimized HLO text.
-
-    Control flow is walked from ENTRY (see :func:`_computation_weights`).
-
-    Returns rows sorted by bytes descending; each row is a dict with
-    ``bytes`` (weighted, whole module), ``effective_bytes`` (gather
-    operands re-priced at rows-touched — see module comment), ``category``,
-    ``opcode``, ``name``, ``out`` (output shape token) and ``op_name``
-    (source metadata — the flax module path for model ops).
-    """
-    comps, entry, weights = entry_walk(hlo_text, unroll)
-    if entry is None:
-        return []
-
-    rows = []
-    for comp, weight in weights.items():
-        for name, out_tok, opcode, line, args_at in comps.get(comp, ()):
-            if opcode in _SKIP_OPS or opcode in _CONTROL_OPS:
-                continue
-            operands = _operand_token(line, args_at)
-            out_b = _shape_bytes(out_tok)
-            op_bytes = [_shape_bytes(s.group(0))
-                        for s in _SHAPE_RE.finditer(operands)]
-            raw = (out_b + sum(op_bytes)) * weight
-            if opcode == "fusion":
-                target = None
-                for kind, t in _CALLS_RE.findall(line):
-                    if kind == "calls":
-                        target = t
-                cat = _fusion_category(comps.get(target, ()))
-            else:
-                cat = _CATEGORY.get(opcode, "elementwise")
-            effective = raw
-            if cat == "gather" and op_bytes:
-                # The cost convention charges an indexed read/write for its
-                # WHOLE operand; the data actually moved is one output's
-                # worth of rows.  Re-price the largest operand at output
-                # size (dynamic-update-slice keeps its full-output write —
-                # conservative, it aliases in place).
-                big = max(op_bytes)
-                effective = raw - max(0, big - out_b) * weight
-            mm = _OPNAME_RE.search(line)
-            rows.append({"bytes": raw, "effective_bytes": effective,
-                         "category": cat, "opcode": opcode, "name": name,
-                         "out": out_tok.strip(),
-                         "op_name": mm.group(1) if mm else ""})
-    rows.sort(key=lambda r: -r["bytes"])
-    return rows
-
-
-def bytes_audit(hlo_text: str, unroll: int = 1, top_k: int = 12) -> dict:
-    """Summarize :func:`hlo_bytes_by_op` into the audit record bench and
-    the CLI tool emit: whole-module and per-step totals (raw + effective),
-    per-category decomposition, and the ``top_k`` single ops.
-
-    ``per_step`` divides by ``unroll`` so records from differently-fused
-    programs compare directly."""
-    rows = hlo_bytes_by_op(hlo_text, unroll=unroll)
-    by_cat: dict[str, float] = defaultdict(float)
-    by_cat_eff: dict[str, float] = defaultdict(float)
-    total = eff = 0
-    for r in rows:
-        by_cat[r["category"]] += r["bytes"]
-        by_cat_eff[r["category"]] += r["effective_bytes"]
-        total += r["bytes"]
-        eff += r["effective_bytes"]
-    u = max(1, unroll)
-    top = [{"bytes_per_step": round(r["bytes"] / u),
-            "category": r["category"], "opcode": r["opcode"],
-            # keep records compact: the tail of the op_name is the
-            # module-path part a reader needs
-            "op_name": r["op_name"][-80:], "out": r["out"][:60]}
-           for r in rows[:top_k]]
-    return {
-        "bytes_total": total, "bytes_effective_total": eff,
-        "bytes_per_step": round(total / u),
-        "bytes_effective_per_step": round(eff / u),
-        "phantom_gather_bytes_per_step": round((total - eff) / u),
-        "by_category_per_step": {k: round(v / u) for k, v in
-                                 sorted(by_cat.items(),
-                                        key=lambda kv: -kv[1])},
-        "by_category_effective_per_step": {
-            k: round(v / u) for k, v in
-            sorted(by_cat_eff.items(), key=lambda kv: -kv[1])},
-        "top_ops": top,
-    }
-
-
-# ---------------------------------------------------------------------------
-# Dot-general / convolution FLOP accounting (the MFU denominator).
-#
-# The bytes audit prices memory traffic; nothing priced the ARITHMETIC —
-# the aggregate ``cost_analysis()["flops"]`` lumps matmul flops together
-# with elementwise/softmax/reduce noise, so an MFU number derived from it
-# over-counts the numerator's useful work and can drift silently with
-# any elementwise refactor.  The optimized HLO has what is needed to
-# price the MXU work exactly: every ``dot`` line prints its output shape,
-# operand shapes, AND ``lhs_contracting_dims`` inline — including the
-# batched dot-generals attention einsums lower to — so
-#
-#     dot flops = 2 * prod(output dims) * prod(contracting dims)
-#
-# covers plain matmuls, batch-dim matmuls ([B,H,T,Dh] x [B,H,Dh,S]) and
-# the vocab head identically (2 flops per MAC, HloCostAnalysis's own
-# convention — golden-pinned in tests).  Convolutions price as
-# 2 * out_elems * kernel_elems / out_channels (the per-output-element
-# MAC count; feature groups cancel out of that ratio).  Dots fused into
-# a fusion are priced from the fused computation at the fusion's weight.
-# NOT covered: backend custom-calls (e.g. oneDNN conv rewrites) — absent
-# from the programs the goldens pin; a custom-call carries no dim
-# metadata to price.
-
-_DOT_LHS_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
-_CONV_DIM_LABELS_RE = re.compile(r"dim_labels=([\w?]+)_([\w?]+)->([\w?]+)")
-
-
-def _first_shape_dims(token: str) -> list[int]:
-    """Dims of the FIRST ``dtype[d0,...]`` shape in *token*."""
-    m = _SHAPE_RE.search(token)
-    if not m:
-        return []
-    return [int(d) for d in m.group(2).split(",") if d]
-
-
-def _prod(dims) -> int:
-    n = 1
-    for d in dims:
-        n *= d
-    return n
-
-
-def _instr_flops(opcode: str, out_tok: str, line: str,
-                 args_at: int) -> int | None:
-    """FLOPs of one dot/convolution instruction line (None = not one)."""
-    if opcode == "dot":
-        m = _DOT_LHS_CONTRACT_RE.search(line)
-        if not m:
-            return None
-        operands = _operand_token(line, args_at)
-        lhs = _first_shape_dims(operands)
-        contract = [int(d) for d in m.group(1).split(",") if d]
-        k = _prod(lhs[i] for i in contract if i < len(lhs))
-        return 2 * _prod(_first_shape_dims(out_tok)) * k
-    if opcode == "convolution":
-        mm = _CONV_DIM_LABELS_RE.search(line)
-        if not mm:
-            return None
-        out_dims = _first_shape_dims(out_tok)
-        out_labels = mm.group(3)
-        f_pos = out_labels.find("f")
-        if f_pos < 0 or f_pos >= len(out_dims):
-            return None
-        operands = _operand_token(line, args_at)
-        shapes = [[int(d) for d in s.split(",") if d]
-                  for _, s in _SHAPE_RE.findall(operands)]
-        if len(shapes) < 2:
-            return None
-        kernel_elems = _prod(shapes[1])
-        out_ch = max(1, out_dims[f_pos])
-        return 2 * _prod(out_dims) * kernel_elems // out_ch
-    return None
-
-
-def hlo_flops_by_op(hlo_text: str, unroll: int = 1) -> list:
-    """Per-instruction dot/convolution FLOP rows from optimized HLO text
-    (weighted like :func:`hlo_bytes_by_op`: control flow walked from
-    ENTRY, scan bodies by trip count; dots INSIDE a fusion priced from
-    the fused computation at the fusion's weight)."""
-    comps, entry, weights = entry_walk(hlo_text, unroll)
-    if entry is None:
-        return []
-
-    def fused_rows(target: str, weight: int, via: str):
-        out = []
-        for name, out_tok, opcode, line, args_at in comps.get(target, ()):
-            fl = _instr_flops(opcode, out_tok, line, args_at)
-            if fl:
-                mm = _OPNAME_RE.search(line)
-                out.append({"flops": fl * weight, "opcode": opcode,
-                            "name": name, "fusion": via,
-                            "out": out_tok.strip()[:60],
-                            "op_name": mm.group(1) if mm else ""})
-        return out
-
-    rows = []
-    for comp, weight in weights.items():
-        for name, out_tok, opcode, line, args_at in comps.get(comp, ()):
-            if opcode == "fusion":
-                for kind, t in _CALLS_RE.findall(line):
-                    if kind == "calls":
-                        rows.extend(fused_rows(t, weight, name))
-                continue
-            fl = _instr_flops(opcode, out_tok, line, args_at)
-            if fl:
-                mm = _OPNAME_RE.search(line)
-                rows.append({"flops": fl * weight, "opcode": opcode,
-                             "name": name, "fusion": "",
-                             "out": out_tok.strip()[:60],
-                             "op_name": mm.group(1) if mm else ""})
-    rows.sort(key=lambda r: -r["flops"])
-    return rows
-
-
-def flops_audit(hlo_text: str, unroll: int = 1, top_k: int = 8) -> dict:
-    """Summarize :func:`hlo_flops_by_op` into the MFU-denominator record:
-    per-step dot/conv flops (``per_step`` divides by ``unroll``, the
-    bytes-audit convention) plus the ``top_k`` heaviest ops."""
-    rows = hlo_flops_by_op(hlo_text, unroll=unroll)
-    u = max(1, unroll)
-    dot = sum(r["flops"] for r in rows if r["opcode"] == "dot")
-    conv = sum(r["flops"] for r in rows if r["opcode"] == "convolution")
-    top = [{"flops_per_step": round(r["flops"] / u),
-            "opcode": r["opcode"], "op_name": r["op_name"][-80:],
-            "out": r["out"]} for r in rows[:top_k]]
-    return {
-        "matmul_flops_per_step": round(dot / u),
-        "conv_flops_per_step": round(conv / u),
-        "flops_per_step": round((dot + conv) / u),
-        "op_count_per_step": round(len(rows) / u, 4),
-        "top_ops": top,
-    }
 
 
 def state_residency_per_device(state) -> dict:
@@ -512,7 +231,7 @@ def state_residency_per_device(state) -> dict:
     arguments, so these bytes are what ``memory_analysis().
     argument_size_in_bytes`` charges for the state (the data split and
     perm ride the same argument total; gradients are step-local and
-    live in ``temp_bytes``, which the audit below reports alongside)."""
+    live in ``temp_bytes``)."""
     def shard_bytes(tree) -> int:
         total = 0
         for leaf in jax.tree.leaves(tree):
@@ -534,86 +253,12 @@ def state_residency_per_device(state) -> dict:
             "state_bytes_per_device": params + opt + stats}
 
 
-def compiled_program_audit(step, args, unroll: int = 1,
-                           top_k: int = 12) -> dict:
-    """ONE lower+compile serving every per-program instrument: the
-    aggregate cost keys (flops / bytes_accessed), the per-op bytes
-    audit, the dot/conv flops audit (the MFU denominator), the
-    collective inventory, the compiler's own memory analysis
-    (``temp_bytes`` is the per-device temp/activation arena — the
-    peak-memory number the remat A/B measures), and — when ``args[0]``
-    is a train state — its per-device residency split
-    (:func:`state_residency_per_device`, the measured 1/D claim for the
-    ZeRO knobs).  Each section degrades to ``{}`` independently, the
-    shared contract of the single-purpose helpers above."""
-    out = {"cost": {}, "bytes": {}, "flops": {}, "collectives": {},
-           "memory": {}, "residency": {}}
-    try:
-        st = args[0] if args else None
-        if st is not None and hasattr(st, "params") \
-                and hasattr(st, "opt_state"):
-            out["residency"] = state_residency_per_device(st)
-    except Exception:
-        pass
-    try:
-        compiled = step.lower(*args).compile()
-    except Exception:
-        return out
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
-        for key, name in (("flops", "flops"),
-                          ("bytes accessed", "bytes_accessed")):
-            if key in ca:
-                out["cost"][name] = float(ca[key]) / max(1, unroll)
-    except Exception:
-        pass
-    try:
-        txt = compiled.as_text()
-    except Exception:
-        txt = ""
-    if txt:
-        try:
-            out["bytes"] = bytes_audit(txt, unroll=unroll, top_k=top_k)
-        except Exception:
-            pass
-        try:
-            out["flops"] = flops_audit(txt, unroll=unroll)
-        except Exception:
-            pass
-        try:
-            out["collectives"] = collective_inventory(txt, unroll=unroll)
-        except Exception:
-            pass
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            out["memory"] = {
-                "temp_bytes": int(ma.temp_size_in_bytes),
-                "argument_bytes": int(ma.argument_size_in_bytes),
-                "output_bytes": int(ma.output_size_in_bytes),
-                "alias_bytes": int(ma.alias_size_in_bytes),
-                "generated_code_bytes": int(
-                    ma.generated_code_size_in_bytes),
-            }
-    except Exception:
-        pass
-    return out
-
-
 # ---------------------------------------------------------------------------
-# Per-collective accounting (the comms twin of the bytes audit).
-#
-# The bytes audit says WHICH ops carry the HBM traffic; nothing said which
-# collectives carry the wire traffic — the sync trainer's gradient
-# all-reduce and the --shard_update reduce-scatter/all-gather schedule were
-# invisible (test_device_data.py could only assert the collective SET).
-# The optimized HLO names every collective with its shapes and replica
-# groups inline, so the same parse that prices bytes can inventory the
-# wire: per-instruction rows, a per-step multiset, and totals that tie out
-# EXACTLY against the bytes audit's "collective" category (same text, same
-# weights, same out+operands convention).
+# Per-collective accounting: which collectives carry the wire traffic —
+# the sync trainer's gradient all-reduce, the --shard_update
+# reduce-scatter/all-gather schedule.  The optimized HLO names every
+# collective with its shapes and replica groups inline: per-instruction
+# rows, a per-step multiset, and totals in the out+operands convention.
 
 _COLLECTIVE_OPCODES = frozenset({
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -632,10 +277,8 @@ def collective_inventory(hlo_text: str, unroll: int = 1) -> dict:
     — one wire transfer, not two), ``count`` (execution weight, whole
     module — scan bodies weighted by trip count), ``out_bytes`` /
     ``operand_bytes`` per execution, ``accounting_bytes`` (out +
-    operands, the HloCostAnalysis convention the bytes audit uses — the
-    number that ties out against ``bytes_audit``'s "collective"
-    category), and ``replica_groups`` (the partition literal: which
-    devices reduce together).
+    operands, the HloCostAnalysis convention), and ``replica_groups``
+    (the partition literal: which devices reduce together).
 
     The summary normalizes by ``unroll`` so records from
     differently-fused programs compare directly:
@@ -646,8 +289,7 @@ def collective_inventory(hlo_text: str, unroll: int = 1) -> dict:
       into a measurement)
     * ``total_*_per_step`` rollups.
 
-    ``out_bytes`` is the per-op OUTPUT size (the convention
-    ``bench_scaling.collective_traffic`` reports); for a same-size
+    ``out_bytes`` is the per-op OUTPUT size; for a same-size
     all-reduce output==operand, for all-gather output is the gathered
     size, for reduce-scatter the scattered shard.  Collectives inside a
     ``conditional`` (e.g. the async worker average, gated on the period)
@@ -713,49 +355,13 @@ def collective_inventory(hlo_text: str, unroll: int = 1) -> dict:
 
 def collective_inventory_of(step, args, unroll: int = 1) -> dict:
     """Lower+compile a jitted *step* once and inventory its collectives.
-    Degrades to ``{}`` when the backend can't lower/expose the module
-    (same contract as :func:`cost_and_bytes_audit`).  NOTE: an AOT
-    ``lower().compile()`` does NOT populate the jit's own executable
-    cache on this jax pin, so calling this costs one extra compile of
-    the program — callers gate it (OBS_COLLECTIVES=1, bench phases)
-    rather than paying it on every run."""
+    Degrades to ``{}`` when the backend can't lower/expose the module.
+    NOTE: an AOT ``lower().compile()`` does NOT populate the jit's own
+    executable cache on this jax pin, so calling this costs one extra
+    compile of the program — callers gate it (OBS_COLLECTIVES=1) rather
+    than paying it on every run."""
     try:
         compiled = step.lower(*args).compile()
         return collective_inventory(compiled.as_text(), unroll=unroll)
     except Exception:
         return {}
-
-
-def cost_and_bytes_audit(step, args, unroll: int = 1, top_k: int = 12,
-                         audit: bool = True) -> tuple[dict, dict]:
-    """Lower+compile a jitted *step* ONCE and return
-    ``(cost, audit)``: per-step flops/bytes from XLA's own cost analysis
-    plus the per-op audit.  THE one implementation of the cost-key
-    extraction — ``bench._cost_per_step`` delegates here — so the
-    aggregate numbers in every record come from the same code path.
-    Either half degrades to ``{}`` independently — backends differ in
-    what they expose; ``audit=False`` skips the HLO-text parse for
-    callers that only want the aggregates."""
-    cost: dict = {}
-    table: dict = {}
-    try:
-        compiled = step.lower(*args).compile()
-    except Exception:
-        return cost, table
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
-        for key, name in (("flops", "flops"),
-                          ("bytes accessed", "bytes_accessed")):
-            if key in ca:
-                cost[name] = float(ca[key]) / max(1, unroll)
-    except Exception:
-        pass
-    if audit:
-        try:
-            table = bytes_audit(compiled.as_text(), unroll=unroll,
-                                top_k=top_k)
-        except Exception:
-            pass
-    return cost, table

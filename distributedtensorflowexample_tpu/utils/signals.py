@@ -1,9 +1,4 @@
-"""Scoped signal-handler installation (trainer preemption path).
-
-bench.py keeps its own inline copy of this pattern ON PURPOSE: importing
-any package module pulls in jax, and bench's record-survival contract
-requires its SIGTERM handler live BEFORE the first package import.  Keep
-the two restore semantics in sync."""
+"""Scoped signal-handler installation (trainer preemption path)."""
 
 from __future__ import annotations
 

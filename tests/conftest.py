@@ -17,38 +17,23 @@ from distributedtensorflowexample_tpu.runtime import (
 
 # In-process CPU collectives need every virtual device's thread in flight
 # at once; a participant that never arrives would hang the run, so the
-# rendezvous gets a deadline (XLA aborts the process when it passes) and
-# the run_training-heavy files execute in isolated subprocesses with
-# abort-only retry (tests/test_isolated.py) so one abort cannot kill the
-# suite.  XLA_FLAGS is parsed at first BACKEND INIT, not at import, so
-# appending after `import jax` is in time.  An UNKNOWN name in XLA_FLAGS
-# is a fatal abort whose message pytest's capture eats (rc=1, no output):
+# rendezvous gets a deadline (XLA aborts the process when it passes).
+# XLA_FLAGS is parsed at first BACKEND INIT, not at import, so appending
+# after `import jax` is in time.  An UNKNOWN name in XLA_FLAGS is a fatal
+# abort whose message pytest's capture eats (rc=1, no output):
 # tests/test_utils.py pins that the backend accepts exactly these flags.
 if "--xla_cpu_collective_call" not in os.environ.get("XLA_FLAGS", ""):
-    # idempotent: the isolated-subprocess inner runs inherit the outer
-    # value and must not append duplicates
+    # idempotent: xdist workers inherit the controller's value
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + cpu_collective_flags(warn_s=60, terminate_s=300))
 
-from isolation_list import ISOLATED_FILES
-
-# The device-heavy files run via tests/test_isolated.py (subprocess +
-# abort-only retry) in a full-suite run; DISTTF_INNER_PYTEST=1 marks the
-# inner invocation, which collects them normally.
-if os.environ.get("DISTTF_INNER_PYTEST") != "1":
-    collect_ignore = list(ISOLATED_FILES)
-
 jax.config.update("jax_platforms", "cpu")
-# 8 virtual devices normally.  DISTTF_TEST_DEVICES overrides: the
-# isolation wrapper retries an ABORTED inner run at 4 devices — same
-# mesh/psum/sharding code path, narrower rendezvous.
-jax.config.update("jax_num_cpu_devices",
-                  int(os.environ.get("DISTTF_TEST_DEVICES", "8")))
+jax.config.update("jax_num_cpu_devices", 8)
 # Persistent compilation cache: the suite is compile-dominated (dozens of
-# jit programs, recompiled in every isolated subprocess), and the cache
-# is keyed by HLO+flags+topology, so the 8-virtual-device programs hit
-# across inner runs and across consecutive suite runs.  The one rule
+# jit programs), and the cache is keyed by HLO+flags+topology, so the
+# 8-virtual-device programs hit across xdist workers and across
+# consecutive suite runs.  The one rule
 # (runtime.enable_compilation_cache): JAX_COMPILATION_CACHE_DIR if set,
 # else <checkout>/.jax_cache.
 enable_compilation_cache()
@@ -57,17 +42,6 @@ enable_compilation_cache()
 # window.  Purely a test-environment knob — the TPU runtime throttles its
 # own queue.
 jax.config.update("jax_cpu_enable_async_dispatch", False)
-
-
-def pytest_collection_modifyitems(config, items):
-    """Run the isolated-subprocess wrappers (tests/test_isolated.py) LAST.
-    Each wrapper is a full pytest subprocess that pays its own backend
-    start and, on a cold cache, its own compiles, so they dominate wall
-    time.  Running the cheap inline tests first means a time-bounded
-    suite run (the tier-1 harness kills at a fixed deadline) reports
-    every fast test's verdict instead of losing them behind a
-    mid-alphabet compile stall."""
-    items.sort(key=lambda it: it.fspath.basename == "test_isolated.py")
 
 
 @pytest.fixture()

@@ -1,8 +1,7 @@
 """What keeps a CPU or interpret-mode run from passing for a chip run:
 chip_smoke.py refuses to run without a TPU, the compile cache follows one
-rule, the Pallas wrappers interpret on the ``cpu`` platform only, the
-trainers say what device they got, and bench_serving's parent stays off
-jax until its serve_lm child has exited (one process per chip).
+rule, the Pallas wrappers interpret on the ``cpu`` platform only, and
+the trainers say what device they got.
 """
 
 import os
@@ -126,21 +125,3 @@ def test_trainer_names_its_device(tmp_path, capsys):
     assert summary["platform"] == "cpu"
     assert summary["device_kind"] == jax.devices()[0].device_kind
     assert "devices: 1 x cpu (cpu)" in capsys.readouterr().out
-
-
-def test_bench_serving_parent_stays_off_jax_until_the_child_ran(tmp_path):
-    """--real: the supervised serve_lm child needs the chip, so the
-    parent must not have initialized a backend when it starts (it used to
-    call jax.default_backend() and init_lm_snapshot first)."""
-    code = (
-        "import bench_serving\n"
-        "from jax._src import xla_bridge\n"
-        "def child(args, snapshot, workdir):\n"
-        "    print('PARENT_INITIALIZED', "
-        "xla_bridge.backends_are_initialized(), flush=True)\n"
-        "    raise SystemExit(7)\n"
-        "bench_serving._supervised_headline = child\n"
-        f"bench_serving.main(['--real', '--workdir', {str(tmp_path)!r}])\n")
-    r = _run([sys.executable, "-c", code], REPO, JAX_PLATFORMS="cpu")
-    assert r.returncode == 7, (r.stdout, r.stderr)
-    assert "PARENT_INITIALIZED False" in r.stdout

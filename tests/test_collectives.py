@@ -1,10 +1,9 @@
 """Collective accounting + bucketed gradient collectives (PR 6).
 
-The comms twin of tests/test_bytes.py: the HLO collective inventory
-(utils/profiling.collective_inventory) is gated against the bytes audit's
-own "collective" category (same text, same weights — exact), the golden
-per-trainer multisets generalize test_device_data.py's collective-set
-assertion into pinned measurements, and the ``--bucket_grads`` schedules
+The HLO collective inventory (utils/profiling.collective_inventory) is
+pinned on synthetic text, the golden per-trainer multisets generalize
+test_device_data.py's collective-set assertion into pinned
+measurements, and the ``--bucket_grads`` schedules
 are parity-gated (bitwise where the program permits — softmax, both
 modes — and the shard_update allclose standard for conv models, same
 reason: summation order, not math).
@@ -14,7 +13,6 @@ training loops.
 """
 
 import os
-import subprocess
 import sys
 import time
 
@@ -24,7 +22,6 @@ import numpy as np
 import optax
 import pytest
 
-import bench_collectives
 from distributedtensorflowexample_tpu.data import DeviceDataset
 from distributedtensorflowexample_tpu.data.synthetic import make_synthetic
 from distributedtensorflowexample_tpu.models import build_model
@@ -37,7 +34,7 @@ from distributedtensorflowexample_tpu.parallel.sync import (
     make_indexed_train_step)
 from distributedtensorflowexample_tpu.training.state import TrainState
 from distributedtensorflowexample_tpu.utils.profiling import (
-    bytes_audit, collective_inventory, collective_inventory_of)
+    collective_inventory, collective_inventory_of)
 
 pytestmark = pytest.mark.collectives
 
@@ -97,34 +94,6 @@ def test_collective_inventory_parsing():
     assert groups["rs"] == "[1,8]<=[8]"
     assert not any(r["name"] == "ard" for r in inv["ops"])
     assert collective_inventory("")["multiset"] == {}
-
-
-def test_inventory_ties_out_against_bytes_audit_and_cost():
-    """The acceptance gate: the inventory's accounting bytes EQUAL the
-    bytes audit's "collective" category (the HLO-metadata tie-out is
-    exact — same parse, same out+operands convention), and the audit
-    total tracks XLA's cost_analysis at the PR-2 standard (15% on
-    small programs; agreement tightens with size, <0.1% at batch-256
-    ResNet — see tests/test_bytes.py)."""
-    mesh = make_mesh()
-    x, y = _data()
-    ds = DeviceDataset(x, y, 64, mesh=mesh, seed=0)
-    state = _state(build_model("softmax"), optax.sgd(0.1, momentum=0.9))
-    step = make_indexed_train_step(64, ds.steps_per_epoch, mesh=mesh,
-                                   num_slots=ds.num_slots)
-    with mesh:
-        compiled = step.lower(state, ds.peek()).compile()
-        hlo = compiled.as_text()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
-    inv = collective_inventory(hlo)
-    audit = bytes_audit(hlo)
-    assert inv["total_accounting_bytes_per_step"] == \
-        audit["by_category_per_step"]["collective"]
-    if "bytes accessed" in ca:       # backend-dependent key, like PR 2
-        assert abs(audit["bytes_total"] - ca["bytes accessed"]) \
-            <= 0.15 * ca["bytes accessed"]
 
 
 # ---- golden per-trainer multisets (the generalized collective-set
@@ -389,8 +358,8 @@ def test_zero3_golden_inventory_prefetch_order_and_bitwise_parity():
 def test_zero3_lm_tiny_multi_bucket_golden_inventory():
     """The per-bucket schedule at lm_tiny: a sub-knee bucket cap splits
     the tree into several buckets — the compiled module carries exactly
-    one AG + one RS PER BUCKET (the prefetch ladder bench_lm measures
-    at lm_base), metrics on the fused pair, gradient reduction bytes
+    one AG + one RS PER BUCKET (the prefetch ladder), metrics on the
+    fused pair, gradient reduction bytes
     conserved up to the row padding."""
     from distributedtensorflowexample_tpu.data.lm import load_lm
     from distributedtensorflowexample_tpu.parallel.zero3 import Zero3Layout
@@ -621,72 +590,6 @@ def test_plan_buckets_and_padding():
     # an over-cap leaf still gets its own bucket, never split
     assert plan_buckets([mk(10_000)], 4) == [[0]]
     assert bucket_padding_bytes([mk(10), mk(16)], 8) == 6 * 4
-
-
-# ---- the characterization bench ---------------------------------------
-
-def test_knee_fit_and_bucket_suggestion():
-    """fit_latency_bandwidth recovers an exact alpha/beta, tolerates
-    noise, and degrades (knee None) instead of fitting garbage."""
-    alpha, beta = 2e-4, 5e8
-    sizes = [4096.0 * 4 ** k for k in range(6)]
-    times = [alpha + s / beta for s in sizes]
-    fit = bench_collectives.fit_latency_bandwidth(sizes, times)
-    assert abs(fit["alpha_s"] - alpha) < 1e-9
-    assert abs(fit["beta_bytes_per_s"] - beta) / beta < 1e-6
-    assert abs(fit["knee_bytes"] - alpha * beta) <= 1
-    assert fit["r2"] > 0.9999
-    noisy = [t * (1 + 0.05 * (-1) ** i) for i, t in enumerate(times)]
-    assert bench_collectives.fit_latency_bandwidth(sizes, noisy)[
-        "knee_bytes"] > 0
-    assert bench_collectives.fit_latency_bandwidth([1], [1])[
-        "knee_bytes"] is None
-    assert bench_collectives.fit_latency_bandwidth(
-        sizes, list(reversed(times)))["knee_bytes"] is None  # negative slope
-    assert bench_collectives.suggest_bucket_bytes(None) is None
-    assert bench_collectives.suggest_bucket_bytes(1) == 256 << 10   # clamp
-    assert bench_collectives.suggest_bucket_bytes(1 << 30) == 64 << 20
-    assert bench_collectives.suggest_bucket_bytes(250_000) == 1_000_000
-
-
-def test_sentinel_record_shape(tmp_path):
-    """The down-backend sentinel is a BENCH-family line a capture can
-    archive: provisional, probe attempts preserved, never mistakable
-    for a measurement."""
-    import argparse
-    out = tmp_path / "coll.json"
-    bench_collectives._sentinel(
-        argparse.Namespace(json=str(out)), ["t+0s: probe timed out"])
-    import json
-    rec = json.load(open(out))
-    assert rec["unit"] == "unavailable"
-    assert rec["detail"]["provisional"] is True
-    assert rec["detail"]["probe_attempts"]
-
-
-def test_bench_collectives_cli_smoke():
-    """One tiny real sweep through the CLI (forced 8-device CPU mesh):
-    JSON-lines points + a family summary + the --json artifact with the
-    CPU labeling that keeps curves honest."""
-    import json
-    out = "/tmp/test_bench_collectives.json"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench_collectives.py", "--sizes", "4096,65536",
-         "--submeshes", "8", "--collectives", "psum", "--repeats", "2",
-         "--json", out],
-        cwd=REPO, env=env, capture_output=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-800:]
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l]
-    points = [l for l in lines if "collective" in l]
-    assert len(points) == 2
-    assert all(p["platform"] == "cpu" for p in points)
-    rec = json.load(open(out))
-    assert rec["metric"] == "collective_allreduce_knee_bytes"
-    assert rec["detail"]["forced_cpu_mesh"] is True
-    assert rec["detail"]["chip"] is False
-    assert "NEVER read as chip numbers" in rec["detail"]["note"]
-    assert rec["detail"]["knees"]["psum"]["8"] is not None
 
 
 # ---- obs wiring --------------------------------------------------------

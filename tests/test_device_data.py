@@ -161,8 +161,7 @@ def test_run_training_device_data_end_to_end(tmp_path, small_synthetic):
     r2 + three round-3 load runs, always this test).  Fused windows cut
     the rendezvous count ~10x without weakening what the test pins —
     train/eval/checkpoint/resume epoch alignment; per-step dispatch
-    semantics are covered by the single-step tests above and on real
-    hardware by bench.py."""
+    semantics are covered by the single-step tests above."""
     from distributedtensorflowexample_tpu.config import RunConfig
     from distributedtensorflowexample_tpu.trainers.common import run_training
 
@@ -584,12 +583,8 @@ def test_sharded_gather_adds_no_collectives():
     IDENTICAL to the replicated-storage step's (the one fused gradient
     all-reduce), no all-gather/all-to-all introduced by the row-sharded
     operands."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench_scaling import collective_traffic
+    from distributedtensorflowexample_tpu.utils.profiling import (
+        collective_inventory_of)
 
     mesh = make_mesh()
     x, y = _data(512)
@@ -605,9 +600,9 @@ def test_sharded_gather_adds_no_collectives():
                                        num_slots=ds.num_slots,
                                        data_sharding=data_sharding)
         with mesh:
-            hlo = step.lower(state, ds.peek()).compile().as_text()
-        return {op: c for op, c in collective_traffic(hlo).items()
-                if c["count"]}
+            inv = collective_inventory_of(step, (state, ds.peek()))
+        assert inv, "the step did not lower"
+        return inv["per_step"]
 
     repl = compiled_traffic("replicated")
     shard = compiled_traffic("sharded")
@@ -757,7 +752,6 @@ def test_quantized_training_bitwise_parity():
 def test_quantized_gather_reduces_bytes_accessed():
     """The point of the uint8 store: the compiled step touches
     substantially fewer bytes (the gather reads 1/4 the data)."""
-    import bench
     x, y = _data(512)
     mesh = make_mesh()
     model = build_model("softmax")
@@ -772,8 +766,10 @@ def test_quantized_gather_reduces_bytes_accessed():
                                        unroll_steps=4,
                                        num_slots=ds.num_slots)
         with mesh:
-            return bench._cost_per_step(step, state, ds.peek(), 4)
+            ca = step.lower(state, ds.peek()).compile().cost_analysis()
+        return (ca[0] if isinstance(ca, (list, tuple)) else ca)[
+            "bytes accessed"]
 
     c_u, c_f = cost("auto"), cost("off")
-    assert c_u.get("bytes_accessed") and c_f.get("bytes_accessed")
-    assert c_u["bytes_accessed"] < 0.75 * c_f["bytes_accessed"], (c_u, c_f)
+    assert c_u and c_f
+    assert c_u < 0.75 * c_f, (c_u, c_f)

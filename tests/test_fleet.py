@@ -6,8 +6,7 @@ Inline on purpose: the gang children here are stdlib-only scripts
 (milliseconds each, no jax import), so the whole file's verdicts land
 inside the tier-1 budget.  The jax-heavy end-to-end drill (2-rank
 mnist_cnn, rank-targeted kill, bitwise resume parity) lives in
-tests/test_fleet_drill.py, which runs as an isolated subprocess
-(tests/isolation_list.py).
+tests/test_fleet_drill.py.
 """
 
 import json

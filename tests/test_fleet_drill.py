@@ -6,9 +6,7 @@ uninterrupted run, with per-rank flights + the fleet journal
 cross-checking the restart count and the agreed step.
 
 Each rank is a real OS process running tools/faultline.py (a fresh jax
-import per child), so this file runs as an isolated subprocess during
-full-suite runs (tests/isolation_list.py) — wall-time containment, not
-abort risk.
+import per child), so this file is the suite's longest (~150 s).
 """
 
 import glob

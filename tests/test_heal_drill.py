@@ -4,10 +4,9 @@ FleetSupervisor, the remediation engine watching real health files and
 ledger rows, real actuators — and the healed timeline proved BITWISE
 against an uninterrupted reference run (steps_lost == 0).
 
-Runs on the fast softmax workload (the lm_tiny battery generates the
-checked-in HEAL_lm_cpu_r16.json record); each child is a fresh jax
-subprocess, so this file runs as an isolated subprocess during
-full-suite runs (tests/isolation_list.py) — wall-time containment.
+Runs on the fast softmax workload (tools/heal_drill.py runs the lm_tiny
+battery); each child is a fresh jax subprocess, so this file is among
+the suite's longest.
 """
 
 import io

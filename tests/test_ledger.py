@@ -2,15 +2,12 @@
 tentpole): the cross-run ledger's append/torn-tail/rotation semantics
 and row-schema goldens, the live HTTP scrape surface against a real
 serving thread (including the fleet's HTTP-scrape-with-file-fallback
-monitor path), obs_query list/show/diff/trajectory CLI smokes, the
-bench_ratchet --trajectory artifact, obs_report's --ledger section,
-the whole-package stdlib-only import guard, and the overhead guard
-keeping ledger sampling + serve idle cost under the MetricsHook budget
-(< 1% of the CPU bench step).
+monitor path), obs_query list/show/diff CLI smokes, obs_report's
+--ledger section, the whole-package stdlib-only import guard, and the
+overhead guard keeping ledger sampling + serve idle cost under the
+MetricsHook budget (< 1% of the CPU step it is measured beside).
 
-Deliberately INLINE (not in tests/isolation_list.py): single-device,
-no collectives — these verdicts must land ahead of the isolated
-wrappers inside the tier-1 budget.
+Single-device, no collectives.
 """
 
 import json
@@ -445,103 +442,6 @@ def test_obs_query_list_show_diff_smoke(tmp_path):
     assert diff["loss_tail"]["same_trajectory"] is False
     md = _obs_query("diff", "--ledger", path, "aaa", "bbb")
     assert "| seed | 0 | 1 |" in md
-
-
-def test_obs_query_trajectory_smoke(tmp_path):
-    rec_dir = tmp_path / "records"
-    rec_dir.mkdir()
-    for rnd, value in ((1, 100.0), (2, 140.0)):
-        (rec_dir / f"BENCH_fam_r{rnd:02d}.json").write_text(json.dumps({
-            "metric": "fam_steps_per_sec", "value": value,
-            "unit": "steps/sec/chip", "detail": {"platform": "cpu"}})
-            + "\n")
-    payload = json.loads(_obs_query("trajectory", "--records_dir",
-                                    str(rec_dir), "--format", "json"))
-    assert [(r["family"], r["round"]) for r in payload] == [
-        ("BENCH_fam", 1), ("BENCH_fam", 2)]
-    assert payload[1]["metrics"] == {"fam_steps_per_sec": 140.0}
-    md = _obs_query("trajectory", "--records_dir", str(rec_dir))
-    assert "## BENCH_fam r02" in md
-
-
-# --- bench_ratchet --trajectory artifact -----------------------------------
-
-def test_bench_ratchet_trajectory_rows_and_checked_in_artifact(tmp_path):
-    sys.path.insert(0, TOOLS)
-    try:
-        import bench_ratchet
-    finally:
-        sys.path.remove(TOOLS)
-    rec_dir = tmp_path / "records"
-    rec_dir.mkdir()
-    (rec_dir / "BENCH_x_r01.json").write_text(
-        json.dumps({"metric": "m", "value": 1.0, "unit": "u",
-                    "detail": {"platform": "cpu"}}) + "\n"
-        # provisional lines never enter the trajectory
-        + json.dumps({"metric": "m2", "value": 0.0,
-                      "unit": "unavailable", "detail": {}}) + "\n")
-    # A pretty-printed SINGLE-JSON record file (bench_collectives'
-    # indent=1 shape): per-line parsing yields nothing, and the family
-    # must NOT silently vanish from the trajectory/ratchet.
-    (rec_dir / "BENCH_coll_r02.json").write_text(json.dumps(
-        {"metric": "knee_bytes", "value": 244160.0, "unit": "bytes",
-         "detail": {"platform": "cpu"}}, indent=1) + "\n")
-    (rec_dir / "SCALING_r01_sync.json").write_text(
-        json.dumps({"devices": 2, "steps_per_sec": 3.5}) + "\n")
-    (rec_dir / "BASELINE_SELF.json").write_text(
-        json.dumps({"note": "text ignored", "m": 2.0}))
-    out = rec_dir / "BENCH_trajectory.json"
-    n = bench_ratchet.write_trajectory(str(rec_dir), str(out))
-    rows = [json.loads(line) for line in
-            out.read_text().splitlines()]
-    assert n == len(rows) == 4
-    by_family = {r["family"]: r for r in rows}
-    assert by_family["BENCH_x"]["metrics"] == {"m": 1.0}
-    assert by_family["BENCH_coll"]["metrics"] == {"knee_bytes": 244160.0}
-    assert by_family["SCALING_sync"]["metrics"] == {
-        "2dev_steps_per_sec": 3.5}
-    assert by_family["BASELINE_SELF"]["metrics"] == {"m": 2.0}
-    assert by_family["BASELINE_SELF"]["round"] is None
-    # Regeneration is deterministic AND the artifact is never its own
-    # source (a second build over a dir already holding the output
-    # produces identical rows).
-    assert bench_ratchet.write_trajectory(str(rec_dir), str(out)) == 4
-    assert [json.loads(line) for line in
-            out.read_text().splitlines()] == rows
-    # The checked-in repo artifact matches a regeneration from the
-    # checked-in records — the "canonical view" claim, kept honest:
-    # adding a record file means re-running bench_ratchet --trajectory.
-    repo_rows = bench_ratchet.build_trajectory(REPO)
-    with open(os.path.join(REPO, "BENCH_trajectory.json")) as f:
-        checked_in = [json.loads(line) for line in f.read().splitlines()]
-    assert checked_in == repo_rows
-
-
-def test_bench_ratchet_recognizes_zero3_lm_rows():
-    """PR 12: the zero3 bench rows ride the lm family like any other —
-    the checked-in BENCH_lm_cpu_r12.json parses into metric records
-    (the residency-shrink line and the overlap wall-clock pair), and
-    the regenerated trajectory's lm r12 row carries them, so the
-    ratchet compares them across rounds exactly like the r08 columns
-    (the byte-identical-regeneration gate above covers determinism)."""
-    sys.path.insert(0, TOOLS)
-    try:
-        import bench_ratchet
-    finally:
-        sys.path.remove(TOOLS)
-    recs = bench_ratchet.load_records(
-        [os.path.join(REPO, "BENCH_lm_cpu_r12.json")])
-    metrics = {r["metric"]: r for r in recs}
-    assert "lm_base_zero3_state_residency_shrink_x" in metrics
-    assert "lm_base_zero3_overlap_speedup_x" in metrics
-    shrink = metrics["lm_base_zero3_state_residency_shrink_x"]
-    assert shrink["value"] == 4.0          # 1/D at D=4, measured
-    assert shrink["detail"]["state_bytes_per_device_zero3"] * 4 == \
-        shrink["detail"]["state_bytes_per_device_base"]
-    row = next(r for r in bench_ratchet.build_trajectory(REPO)
-               if r["family"] == "BENCH_lm_cpu" and r["round"] == 12)
-    assert "lm_base_zero3_state_residency_shrink_x" in row["metrics"]
-    assert "lm_base_zero3_overlap_speedup_x" in row["metrics"]
 
 
 # --- obs_report --ledger ----------------------------------------------------

@@ -1,11 +1,10 @@
 """graft-LM flagship workload (PR 8): model/data/trainer wiring, knob
-parity at lm_tiny, the OOV-poison -> NaNGuard path, and the bench/ratchet
-surface.
+parity at lm_tiny, and the OOV-poison -> NaNGuard path.
 
-Inline and tier-1-safe: lm_tiny at short sequences, single-digit fused
-dispatches per test (the test_collectives discipline).  lm_base-scale
-work is bench_lm.py's job (and the one param-count check here uses
-eval_shape — no 57M-param init ever runs in tier-1).
+Tier-1-safe: lm_tiny at short sequences, single-digit fused
+dispatches per test (the test_collectives discipline).  The one
+param-count check here uses eval_shape — no 57M-param init ever runs
+in tier-1.
 
 Golden collective multisets for the LM trainer live in
 tests/test_collectives.py next to the other per-trainer goldens.
@@ -36,6 +35,8 @@ from distributedtensorflowexample_tpu.parallel.bucketing import (
 from distributedtensorflowexample_tpu.parallel.sync import (
     make_indexed_train_step, make_resident_eval)
 from distributedtensorflowexample_tpu.training.state import TrainState
+from distributedtensorflowexample_tpu.utils.profiling import (
+    state_residency_per_device)
 
 pytestmark = pytest.mark.lm
 
@@ -55,6 +56,10 @@ def _state(mesh, batch, seq=SEQ, tx=None, **kw):
     model = build_model("lm_tiny", **kw)
     return TrainState.create_sharded(model, tx or _tx(), (batch, seq), 0,
                                      replicated_sharding(mesh))
+
+
+def _opt_state_bytes_per_device(state) -> int:
+    return state_residency_per_device(state)["opt_state_bytes_per_device"]
 
 
 def _digest(tree) -> str:
@@ -290,9 +295,8 @@ def test_composed_zero1_schedule_parity_and_state_residency():
     s_z = _state(mesh, 32)
     s_z = s_z.replace(opt_state=init_bucketed_opt_state(
         _tx(), s_z.params, DEFAULT_BUCKET_BYTES, mesh))
-    import bench_lm
-    repl = bench_lm.optstate_bytes_per_device(s_ref.opt_state)
-    shard = bench_lm.optstate_bytes_per_device(s_z.opt_state)
+    repl = _opt_state_bytes_per_device(s_ref)
+    shard = _opt_state_bytes_per_device(s_z)
     assert shard <= repl / D * 1.05 + 64        # 1/D (+row padding)
     s_ref, m_ref, s_z, m_z = _run_pair(mesh, ref, s_ref, z1, s_z)
     _assert_close(s_ref.params, s_z.params)
@@ -303,12 +307,10 @@ def test_zero3_schedule_parity_and_full_state_residency():
     schedule trains the same model (allclose standard — the shard_map
     backward reassociates the einsum chain, same as every other knob)
     while params AND optimizer moments live as 1/D bucket rows — the
-    full-state residency win bench_lm measures at lm_base, structurally
-    pinned here.  Overlap on/off is checked bitwise-equal in
-    tests/test_zero3.py; this gate uses the default double buffer."""
+    full-state residency win, structurally pinned here.  Overlap on/off
+    is checked bitwise-equal in tests/test_zero3.py; this gate uses the
+    default double buffer."""
     from distributedtensorflowexample_tpu.parallel.zero3 import Zero3Layout
-    from distributedtensorflowexample_tpu.utils.profiling import (
-        state_residency_per_device)
     mesh = make_mesh()
     D = mesh.size
     x, y = _data(seed=3)
@@ -354,9 +356,8 @@ def test_shard_update_constraint_form_parity():
     s_su = _state(mesh, 32, tx=cross_replica_update_sharding(_tx(), mesh))
     s_su = s_su.replace(opt_state=jax.device_put(
         s_su.opt_state, update_shardings(s_su.opt_state, mesh)))
-    import bench_lm
-    assert bench_lm.optstate_bytes_per_device(s_su.opt_state) < \
-        bench_lm.optstate_bytes_per_device(s_ref.opt_state)
+    assert _opt_state_bytes_per_device(s_su) < \
+        _opt_state_bytes_per_device(s_ref)
     s_ref, m_ref, s_su, m_su = _run_pair(mesh, ref, s_ref, su, s_su,
                                          calls=1)
     _assert_close(s_ref.params, s_su.params)
@@ -440,137 +441,6 @@ def test_faultline_lm_corrupt_batch_trips_nan_guard(tmp_path):
     assert "non-finite loss" in line["error"]
     # The healthy prefix made it to the tape; the poisoned step did not.
     assert all(np.isfinite(l) for _, l in line["losses"])
-
-
-# ---- bench_lm + ratchet surface -----------------------------------------
-
-def test_bench_lm_compile_only_ab_and_record(tmp_path):
-    """bench_lm at lm_tiny, base+remat knobs, compile-only A/B: emits
-    the tokens/sec + MFU lines with the flops-audit denominator, a
-    positive remat activation saving, and a ratchet-parseable JSON-lines
-    artifact."""
-    import bench_lm
-    out = tmp_path / "BENCH_lm_cpu_r99.json"
-    rc = bench_lm.main(["--throughput_size", "lm_tiny", "--size",
-                        "lm_tiny", "--batch_per_chip", "2", "--steps",
-                        "2", "--unroll", "1", "--repeats", "1",
-                        "--seq_len", "16", "--ab_batch_per_chip", "2",
-                        "--ab_steps", "0", "--knobs", "base,remat",
-                        "--json", str(out)])
-    assert rc == 0
-    recs = [json.loads(l) for l in out.read_text().splitlines()]
-    by_metric = {r["metric"]: r for r in recs}
-    tput = by_metric["lm_tiny_tokens_per_sec_per_chip"]
-    assert tput_positive(tput)
-    d = tput["detail"]
-    assert d["token_storage"] == "uint8"
-    assert d["model_flops_per_step_per_device"] > 0
-    assert d["bytes_audit"]["bytes_per_step"] > 0
-    mfu = by_metric["lm_tiny_mfu"]
-    assert mfu["value"] > 0
-    assert mfu["detail"]["model_flops_per_step_per_device"] == \
-        d["model_flops_per_step_per_device"]
-    # MFU = per-device flops x rate / per-chip peak (no second /n).
-    assert mfu["value"] == pytest.approx(
-        d["model_flops_per_step_per_device"] * d["steps_per_sec"]
-        / mfu["detail"]["peak_flops"], rel=1e-4)
-    sav = by_metric["lm_tiny_remat_activation_savings_frac"]
-    assert 0 < sav["value"] < 1
-    assert by_metric["lm_tiny_knob_ab_matrix"]["detail"]["matrix"][
-        "remat"]["memory"]["temp_bytes"] > 0
-
-
-def tput_positive(rec):
-    return rec["unit"] == "tokens/sec/chip" and rec["value"] > 0
-
-
-def test_bench_lm_sentinel_record_shape(tmp_path):
-    """--real with the backend down must land a provisional sentinel
-    (the capture queue keeps moving), never hang or write a measured-
-    looking record — the bench_collectives discipline."""
-    import argparse
-
-    import bench_lm
-    path = tmp_path / "sentinel.json"
-    bench_lm._sentinel(argparse.Namespace(json=str(path)),
-                       ["t+0s: probe timed out"])
-    rec = json.loads(path.read_text())
-    assert rec["unit"] == "unavailable"
-    assert rec["detail"]["provisional"] is True
-    assert rec["detail"]["probe_attempts"]
-
-
-@pytest.mark.timeline
-def test_bench_ratchet_recognizes_lm_family(tmp_path):
-    """The satellite: BENCH_lm_* records ratchet like the headline
-    family — per-(metric, platform) prior-vs-newest comparison, the
-    armed_predictions_round11_lm block reported, regressions gated."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_ratchet
-    finally:
-        sys.path.pop(0)
-
-    def rec(value, spread=0.0):
-        return json.dumps({
-            "metric": "lm_small_tokens_per_sec_per_chip", "value": value,
-            "unit": "tokens/sec/chip", "vs_baseline": 1.0,
-            "detail": {"platform": "cpu", "spread_frac": spread,
-                       "repeats": [value]}}) + "\n"
-
-    # Rounds PAST the armed round (11): armed blocks report only records
-    # newer than the round that armed them.
-    (tmp_path / "BENCH_lm_cpu_r12.json").write_text(rec(1000.0))
-    (tmp_path / "BENCH_lm_cpu_r13.json").write_text(rec(1100.0))
-    (tmp_path / "BASELINE_SELF.json").write_text(json.dumps({
-        "armed_predictions_round11_lm": {"note": "lm chip predictions"}}))
-    import io
-    from contextlib import redirect_stdout
-    buf = io.StringIO()
-    with redirect_stdout(buf):
-        rc = bench_ratchet.main(["--records_dir", str(tmp_path), "--json"])
-    verdict = json.loads(buf.getvalue())
-    assert rc == 0 and verdict["unexplained"] == 0
-    armed = {a["key"]: a for a in verdict["armed_predictions"]}
-    assert "armed_predictions_round11_lm" in armed
-    assert "lm_small_tokens_per_sec_per_chip" in \
-        armed["armed_predictions_round11_lm"]["newer_records"]
-    # An unexplained lm regression gates exactly like the headline's.
-    (tmp_path / "BENCH_lm_cpu_r14.json").write_text(rec(500.0))
-    with redirect_stdout(io.StringIO()):
-        rc = bench_ratchet.main(["--records_dir", str(tmp_path), "--json"])
-    assert rc == 1
-
-
-def test_compiled_program_audit_sections_on_lm_step():
-    """One compile, every instrument: cost keys, bytes audit, the
-    dot-flops MFU denominator (>= half of XLA's aggregate flops on this
-    dot-dominated step), collectives, and the memory analysis the remat
-    A/B reads."""
-    from distributedtensorflowexample_tpu.utils.profiling import (
-        compiled_program_audit)
-    x, y = _data(n=64, seq=16)
-    ds = DeviceDataset(x, y, 16, token_data=True)
-    state = TrainState.create(build_model("lm_tiny"), _tx(),
-                              jnp.zeros((16, 16), jnp.int32))
-    step = make_indexed_train_step(16, ds.steps_per_epoch,
-                                   num_slots=ds.num_slots)
-    audit = compiled_program_audit(step, (state, ds.peek()))
-    assert audit["flops"]["flops_per_step"] > 0
-    assert audit["flops"]["conv_flops_per_step"] == 0
-    if audit["cost"].get("flops"):
-        share = audit["flops"]["flops_per_step"] / audit["cost"]["flops"]
-        assert 0.5 <= share <= 1.0, share
-    assert audit["bytes"]["bytes_per_step"] > 0
-    assert audit["memory"]["temp_bytes"] > 0
-    # the PR-12 residency section: live-sharding split of the donated
-    # state arguments (replicated here: full-size per device)
-    res = audit["residency"]
-    assert res["params_bytes_per_device"] > 0
-    assert res["state_bytes_per_device"] == \
-        res["params_bytes_per_device"] + res["opt_state_bytes_per_device"]
-    names = [r["op_name"] for r in audit["flops"]["top_ops"]]
-    assert any("dot_general" in n for n in names)
 
 
 # ---- causal_attention: which implementation a call takes (PR 25) --------

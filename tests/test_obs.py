@@ -10,9 +10,7 @@ preemption leaves flight dumps whose step counter, retry count, and
 last span match the supervisor journal and the snapshot manifest, and
 tools/obs_report.py renders the lot without error.
 
-Deliberately INLINE (not in tests/isolation_list.py): single-device,
-no collectives — these verdicts must land ahead of the isolated
-wrappers inside the tier-1 budget.
+Single-device, no collectives.
 """
 
 import glob
@@ -896,7 +894,7 @@ def test_detect_skew_laggard_vs_straggler():
 
 
 @timeline_mark
-def test_plateau_nan_sentinels_and_spread_fraction():
+def test_plateau_and_nan_sentinels():
     det = obs_anomaly.PlateauSentinel(window=3, min_delta=1e-3)
     for s, loss in enumerate((1.0, 0.9, 0.8, 0.7), start=1):
         assert not det.observe(loss, step=s)     # still improving
@@ -918,14 +916,6 @@ def test_plateau_nan_sentinels_and_spread_fraction():
     assert rh.observe_loss(4, float("nan")) == ["nan_loss"]
     assert rh.observe_loss(5, float("nan")) == []        # latched
     assert rh.flags["nan_loss"] == {"firing": True, "fired_step": 4}
-
-    assert obs_anomaly.spread_fraction([100.0, 80.0]) == pytest.approx(0.2)
-    assert obs_anomaly.spread_fraction([50.0]) == 0.0
-    assert obs_anomaly.spread_fraction([]) == 0.0
-    # tolerant-reader contract: a malformed record (string repeats,
-    # None) must not crash the ratchet's verdict protocol
-    assert obs_anomaly.spread_fraction(["1.2", None, 100.0, 80.0]) == \
-        pytest.approx(0.2)
 
 
 @timeline_mark
@@ -1165,94 +1155,6 @@ def test_anomaly_hook_fires_counters_health_and_flight(tmp_path, sink,
     assert health["flags"]["nan_loss"] == {"firing": True, "fired_step": 8}
     z = reg.snapshot()["gauges"]["anomaly_step_time_z"]["value"]
     assert z > 4.0
-
-
-def _bench_ratchet():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_ratchet
-    finally:
-        sys.path.pop(0)
-    return bench_ratchet
-
-
-def _write_record(path, value, metric="steps_per_sec_per_chip", **detail):
-    rec = {"metric": metric, "value": value, "unit": "steps/s/chip",
-           "detail": detail}
-    with open(path, "a") as f:
-        f.write(json.dumps(rec) + "\n")
-
-
-@timeline_mark
-def test_bench_ratchet_explains_variance_gates_regressions(tmp_path,
-                                                           capsys):
-    """The trajectory guard: a raw drop with the window-normalized
-    vs_roofline held is chip variance (explained); roofline regressed or
-    absent is UNEXPLAINED (exit 1); a self-noisy measurement
-    (spread_frac over --noise) or a documented OUTAGE round can never
-    gate."""
-    rt = _bench_ratchet()
-    d = str(tmp_path)
-    floor = str(tmp_path / "floor.json")
-    json.dump({"dots_passed_floor": 220}, open(floor, "w"))
-    _write_record(os.path.join(d, "BENCH_x_r01.json"), 100.0,
-                  vs_roofline=0.50, platform="chip")
-    # sentinel lines are not measurements
-    with open(os.path.join(d, "BENCH_x_r01.json"), "a") as f:
-        f.write(json.dumps({"metric": "steps_per_sec_per_chip",
-                            "unit": "unavailable"}) + "\n")
-    _write_record(os.path.join(d, "BENCH_x_r02.json"), 50.0,
-                  vs_roofline=0.55, platform="chip")
-    common = ["--records_dir", d, "--floor_file", floor]
-    assert rt.main(common + ["--json"]) == 0     # roofline held: explained
-    verdict = json.loads(capsys.readouterr().out)
-    assert verdict["findings"][0]["severity"] == "explained"
-    assert "vs_roofline held" in verdict["findings"][0]["why"]
-
-    _write_record(os.path.join(d, "BENCH_x_r03.json"), 40.0,
-                  vs_roofline=0.20, platform="chip")
-    assert rt.main(common + ["--json"]) == 1     # roofline regressed too
-    verdict = json.loads(capsys.readouterr().out)
-    worst = [f for f in verdict["findings"] if f["severity"] == "regression"]
-    assert worst and "vs_roofline also regressed" in worst[0]["why"]
-
-    # the same drop measured noisily cannot gate
-    _write_record(os.path.join(d, "BENCH_x_r04.json"), 40.0,
-                  vs_roofline=0.20, platform="chip",
-                  repeats=[10.0, 40.0])          # spread 0.75 > 0.25
-    assert rt.main(common + ["--json"]) == 0
-    verdict = json.loads(capsys.readouterr().out)
-    assert all(f["severity"] != "regression" for f in verdict["findings"])
-
-    # a checked-in outage postmortem adjudicates its whole round
-    _write_record(os.path.join(d, "BENCH_x_r05.json"), 30.0,
-                  platform="chip")
-    open(os.path.join(d, "OUTAGE_r05.md"), "w").write("degraded window")
-    assert rt.main(common + ["--json"]) == 0
-    verdict = json.loads(capsys.readouterr().out)
-    assert any("documented outage" in f["why"]
-               for f in verdict["findings"])
-
-
-@timeline_mark
-def test_bench_ratchet_floor_gates_and_ratchets_upward_only(tmp_path,
-                                                            capsys):
-    rt = _bench_ratchet()
-    floor = str(tmp_path / "floor.json")
-    json.dump({"dots_passed_floor": 220}, open(floor, "w"))
-    common = ["--records_dir", str(tmp_path), "--floor_file", floor]
-    assert rt.main(common + ["--dots", "220"]) == 0
-    assert rt.main(common + ["--dots", "219"]) == 1      # below the floor
-    out = capsys.readouterr().out
-    assert "FLOOR VIOLATION" in out
-    assert rt.main(common + ["--raise_floor", "219"]) == 1   # refuses down
-    assert json.load(open(floor))["dots_passed_floor"] == 220
-    assert rt.main(common + ["--raise_floor", "224"]) == 0
-    assert json.load(open(floor))["dots_passed_floor"] == 224
-    # the repo's checked-in floor file is the tool's default target
-    checked_in = json.load(open(os.path.join(REPO, "tests",
-                                             "tier1_floor.json")))
-    assert checked_in["dots_passed_floor"] >= 220
 
 
 @timeline_mark

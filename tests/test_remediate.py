@@ -8,8 +8,7 @@ Inline on purpose: the policy engine is stdlib+obs, the watchers read
 plain JSON files, and the one jax-touching test (rollback pinning over
 a real SnapshotStore) uses the cheap softmax state — verdicts land
 inside the tier-1 budget.  The end-to-end fleet drills (faultline
-children, bitwise-resume parity) live in tests/test_heal_drill.py,
-which runs as an isolated subprocess (tests/isolation_list.py).
+children, bitwise-resume parity) live in tests/test_heal_drill.py.
 """
 
 import io
@@ -607,94 +606,6 @@ def test_obs_query_why_renders_heal_rows(tmp_path):
     out = buf.getvalue()
     assert "action rollback FAILED (nan_loss): boom" in out
     assert "self-healed 1x (evict)" in out      # still only the evict
-
-
-# ---- the HEAL_* record family on the ratchet -----------------------------
-
-def _ratchet():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_ratchet
-    finally:
-        sys.path.pop(0)
-    return bench_ratchet
-
-
-def test_bench_ratchet_heal_family_and_zero_invariant(tmp_path):
-    """HEAL_* rides the trajectory like any family; mttd/mttr gate
-    lower-is-better (the *_ms rule), and a nonzero *_lost is an
-    UNEXPLAINED finding regardless of tolerance."""
-    bench_ratchet = _ratchet()
-    rec = tmp_path / "HEAL_lm_cpu_r16.json"
-    rows = [
-        {"metric": "heal_nan_mttd_ms", "value": 420.0, "unit": "ms",
-         "platform": "cpu", "detail": {"platform": "cpu"}},
-        {"metric": "heal_nan_steps_lost", "value": 0, "unit": "steps",
-         "platform": "cpu", "detail": {"platform": "cpu"}},
-    ]
-    rec.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    recs = bench_ratchet.load_records([str(rec)])
-    assert {r["metric"] for r in recs} == {"heal_nan_mttd_ms",
-                                           "heal_nan_steps_lost"}
-    assert bench_ratchet._lower_is_better("heal_nan_mttd_ms")
-    assert bench_ratchet.check_zero_invariants(recs) == []
-    # the trajectory builder folds the family in
-    traj = bench_ratchet.build_trajectory(str(tmp_path))
-    fam = [r for r in traj if r["family"] == "HEAL_lm_cpu"]
-    assert len(fam) == 1 and fam[0]["round"] == 16
-    assert fam[0]["metrics"]["heal_nan_steps_lost"] == 0
-    # a lost step is an invariant violation, not a tolerance question
-    bad = dict(rows[1], value=2)
-    rec.write_text(json.dumps(rows[0]) + "\n" + json.dumps(bad) + "\n")
-    findings = bench_ratchet.check_zero_invariants(
-        bench_ratchet.load_records([str(rec)]))
-    assert len(findings) == 1
-    assert findings[0]["severity"] == "regression"
-    assert "must-be-zero" in findings[0]["why"]
-    # the invariant gates the NEWEST record only: a later round that
-    # fixed the loss clears the red instead of staying red forever
-    fixed = tmp_path / "HEAL_lm_cpu_r17.json"
-    fixed.write_text(json.dumps(dict(rows[1], value=0)) + "\n")
-    assert bench_ratchet.check_zero_invariants(
-        bench_ratchet.load_records([str(rec), str(fixed)])) == []
-    # and a documented-outage window is explained, like the ratchet
-    findings = bench_ratchet.check_zero_invariants(
-        bench_ratchet.load_records([str(rec)]), outages={16})
-    assert len(findings) == 1
-    assert findings[0]["severity"] == "explained"
-    # and a *_ms latency regression beyond tolerance gates as usual
-    older = tmp_path / "HEAL_lm_cpu_r15.json"
-    older.write_text(json.dumps(
-        {"metric": "heal_nan_mttd_ms", "value": 100.0, "unit": "ms",
-         "platform": "cpu", "detail": {"platform": "cpu"}}) + "\n")
-    findings = bench_ratchet.compare_records(
-        bench_ratchet.load_records([str(older), str(rec)]),
-        tolerance=0.10, noise=0.25)
-    assert any(f["metric"] == "heal_nan_mttd_ms"
-               and f["severity"] == "regression" for f in findings)
-
-
-def test_checked_in_heal_record_invariants():
-    """The measured drill record ships with the repo: every *_lost line
-    is zero, every drill contributed, and the trajectory artifact
-    carries the family."""
-    bench_ratchet = _ratchet()
-    path = os.path.join(REPO, "HEAL_lm_cpu_r16.json")
-    assert os.path.exists(path), "HEAL_lm_cpu_r16.json missing"
-    recs = bench_ratchet.load_records([path])
-    by_metric = {r["metric"]: r for r in recs}
-    for drill in ("slow_rank", "nan", "host_loss"):
-        assert by_metric[f"heal_{drill}_steps_lost"]["value"] == 0
-        assert by_metric[f"heal_{drill}_mttr_ms"]["value"] > 0
-        assert by_metric[f"heal_{drill}_mttd_ms"]["value"] is not None
-        assert by_metric[f"heal_{drill}_steps_lost"]["detail"][
-            "bitwise_resume"] is True
-    assert by_metric["heal_serve_slo_requests_lost"]["value"] == 0
-    assert by_metric["heal_canary_requests_lost"]["value"] == 0
-    assert bench_ratchet.check_zero_invariants(recs) == []
-    with open(os.path.join(REPO, "BENCH_trajectory.json")) as f:
-        fams = [json.loads(l)["family"] for l in f if l.strip()]
-    assert "HEAL_lm_cpu" in fams
 
 
 # ---- run_remediated with stdlib children ---------------------------------

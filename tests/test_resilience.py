@@ -8,9 +8,7 @@ step-by-step metric trajectory — to an uninterrupted run of the same
 total steps, on CPU, with the torn-write and poisoned-state edges
 refusing to restore rather than silently diverging.
 
-These tests are deliberately INLINE (not in tests/isolation_list.py):
-single-device, no collectives, and the resume-parity gate must land
-ahead of the isolated wrappers inside the tier-1 budget.
+Single-device, no collectives.
 """
 
 import json
@@ -541,65 +539,9 @@ def test_heartbeat_hook_touches_at_boundaries(tmp_path, sgd_step):
     assert os.path.exists(hb)
 
 
-# --- supervised capture queue (tools/supervise.py) -------------------------
+# --- tools/supervise.py ----------------------------------------------------
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_supervise_capture_queue_shape(monkeypatch, tmp_path):
-    """The capture queue mirrors bench_capture.sh: artifact-value phase
-    order, env-knob surface, bytes-audit chip independence, phase-4
-    freshness gate."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import supervise
-    finally:
-        sys.path.pop(0)
-    monkeypatch.setenv("OUT", str(tmp_path / "out.json"))
-    # start_ts slightly in the past: real captures write OUT minutes
-    # after start, and this host's fs truncates mtimes to seconds
-    tasks = supervise._capture_tasks(start_ts=time.time() - 5)
-    names = [t.name for t in sorted(tasks, key=lambda t: t.priority)]
-    assert names == ["headline_bench", "profile", "bytes_audit_cpu",
-                     "collectives", "lm", "full_bench", "cli_trainer"]
-    by_name = {t.name: t for t in tasks}
-    assert by_name["headline_bench"].env["BENCH_HEADLINE_ONLY"] == "1"
-    assert not by_name["bytes_audit_cpu"].needs_chip
-    # collectives phase: --real (the chip re-fit), keep() post promoting
-    # the .tmp artifact, sentinel-capable so it can't wedge the queue
-    assert "--real" in by_name["collectives"].argv
-    assert by_name["collectives"].post is not None
-    # lm phase (2d): same --real/keep()/sentinel discipline as 2c
-    assert "--real" in by_name["lm"].argv
-    assert by_name["lm"].post is not None
-    assert "bench_lm.py" in " ".join(by_name["lm"].argv)
-    assert by_name["cli_trainer"].wall_timeout_s > 0
-    # gate: no fresh measured OUT -> phase 4 must not run
-    assert by_name["cli_trainer"].gate() is False
-    with open(tmp_path / "out.json", "w") as f:
-        f.write('{"unit": "steps/sec/chip"}')
-    assert by_name["cli_trainer"].gate() is True
-    # journal-resumed window: OUT predates start_ts but full_bench is
-    # done_prior — the gate must still pass (it IS this capture's
-    # artifact), else phase 4 becomes permanently unobtainable
-    old = time.time() - 3600
-    os.utime(tmp_path / "out.json", (old, old))
-    resumed = supervise._capture_tasks(start_ts=time.time() - 5,
-                                       full_bench_done_prior=True)
-    gates = {t.name: t for t in resumed}
-    assert gates["cli_trainer"].gate() is True
-    stale = supervise._capture_tasks(start_ts=time.time() - 5)
-    assert {t.name: t for t in stale}["cli_trainer"].gate() is False
-    # journal rotation predicate: an ENDED capture run (complete or
-    # wedged) must rotate; a mid-run death (no capture_end) must resume
-    ended = tmp_path / "ended.jsonl"
-    ended.write_text('{"event": "task_done", "task": "headline_bench"}\n'
-                     '{"event": "capture_end", "results": {}}\n')
-    midrun = tmp_path / "midrun.jsonl"
-    midrun.write_text('{"event": "task_done", "task": "headline_bench"}\n')
-    assert supervise._capture_ended(str(ended)) is True
-    assert supervise._capture_ended(str(midrun)) is False
-    assert supervise._capture_ended(str(tmp_path / "absent.jsonl")) is False
 
 
 def test_supervise_cli_generic_mode(tmp_path):

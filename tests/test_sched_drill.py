@@ -9,9 +9,7 @@ with every decision answerable afterwards from ledger rows alone
 (``obs_query why``).
 
 Each job rank is a real OS process running tools/faultline.py (a fresh
-jax import per child), so this file runs as an isolated subprocess
-during full-suite runs (tests/isolation_list.py) — wall-time
-containment, not abort risk.
+jax import per child).
 """
 
 import glob

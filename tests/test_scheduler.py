@@ -7,8 +7,7 @@ verb that answers for every decision from ledger rows alone.
 Inline on purpose: every gang child here is a stdlib-only script
 (milliseconds each, no jax import), so the whole file's verdicts land
 inside the tier-1 budget.  The jax-heavy end-to-end drill (faultline
-jobs, bitwise eviction-resume parity) lives in tests/test_sched_drill.py,
-which runs as an isolated subprocess (tests/isolation_list.py).
+jobs, bitwise eviction-resume parity) lives in tests/test_sched_drill.py.
 """
 
 import json
@@ -117,7 +116,7 @@ def test_tick_env_knob(monkeypatch):
 # ---- the cost model ------------------------------------------------------
 
 def test_predict_cost_trajectory_then_declared(tmp_path):
-    traj = tmp_path / "BENCH_trajectory.json"
+    traj = tmp_path / "trajectory.json"
     traj.write_text(
         json.dumps({"family": "BENCH_lm_cpu", "round": 8,
                     "file": "BENCH_lm_cpu_r08.json",
@@ -713,32 +712,30 @@ def test_host_down_tombstone_expiry(tmp_path):
     assert fleet.host_down(1) is True
 
 
-# ---- queue-completion record rides the ratchet ---------------------------
+# ---- queue-completion record ---------------------------------------------
 
-def test_bench_ratchet_recognizes_sched_queue_family(tmp_path):
-    """tools/schedule.py --record writes the bench-record dialect, and
-    bench_ratchet's trajectory builder folds the SCHED_queue family in
-    next to the BENCH_* families."""
+def test_schedule_record_is_one_json_line_per_metric(tmp_path):
+    """tools/schedule.py --record: one JSON line per metric, each
+    self-labelled platform=cpu, jobs/min from the makespan."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
-        import bench_ratchet
         import schedule as schedule_cli
     finally:
         sys.path.pop(0)
     summary = {"status": "ok", "counts": {"done": 8},
                "makespan_s": 120.0, "evictions": 1, "shrinks": 1,
                "grows": 1, "retries": 1, "jobs": {"a": "done"}}
-    rec_path = tmp_path / "SCHED_queue_cpu_r14.json"
+    rec_path = tmp_path / "queue_record.json"
     schedule_cli.write_record(str(rec_path), summary, devices=4)
-    recs = bench_ratchet.load_records([str(rec_path)])
-    assert {r["metric"] for r in recs} == {"sched_queue_jobs_done",
-                                           "sched_queue_jobs_per_min"}
-    assert all(bench_ratchet._platform(r) == "cpu" for r in recs)
-    rows = bench_ratchet.build_trajectory(str(tmp_path))
-    fam = [r for r in rows if r["family"] == "SCHED_queue_cpu"]
-    assert len(fam) == 1 and fam[0]["round"] == 14
-    assert fam[0]["metrics"]["sched_queue_jobs_done"] == 8
-    assert fam[0]["metrics"]["sched_queue_jobs_per_min"] == 4.0
+    recs = [json.loads(line) for line in
+            rec_path.read_text().splitlines()]
+    by_metric = {r["metric"]: r for r in recs}
+    assert set(by_metric) == {"sched_queue_jobs_done",
+                              "sched_queue_jobs_per_min"}
+    assert all(r["platform"] == "cpu" for r in recs)
+    assert by_metric["sched_queue_jobs_done"]["value"] == 8
+    assert by_metric["sched_queue_jobs_per_min"]["value"] == 4.0
+    assert by_metric["sched_queue_jobs_done"]["detail"]["devices"] == 4
 
 
 # ---- fleet-level request_stop (the eviction primitive) -------------------

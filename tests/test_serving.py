@@ -261,31 +261,6 @@ def test_oversized_request_refused_not_fatal(engine):
     assert batcher.stats()["rejected"]["refused"] == 1
 
 
-def test_ratchet_latency_metrics_gate_in_the_right_direction(tmp_path):
-    """``*_ms`` metrics are lower-is-better: the ratchet must flag a
-    latency INCREASE and stay quiet on an improvement — the inverse of
-    every throughput family."""
-    import sys as _sys
-    _sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_ratchet
-    finally:
-        _sys.path.pop(0)
-
-    def rec(value, rnd):
-        return {"metric": "serve_x_p99_ms", "value": value,
-                "detail": {"platform": "cpu", "spread_frac": 0.0},
-                "_file": f"SERVE_x_cpu_r{rnd:02d}.json", "_round": rnd}
-
-    worse = bench_ratchet.compare_records(
-        [rec(10.0, 1), rec(16.0, 2)], tolerance=0.10, noise=0.25)
-    assert len(worse) == 1 and worse[0]["severity"] == "regression"
-    assert worse[0]["drop_frac"] == pytest.approx(0.6)
-    better = bench_ratchet.compare_records(
-        [rec(10.0, 1), rec(7.0, 2)], tolerance=0.10, noise=0.25)
-    assert better == []
-
-
 def test_oov_request_refused_by_name(engine):
     queue = RequestQueue(engine.vocab)
     with pytest.raises(ModeRefusal, match="out-of-vocab"):

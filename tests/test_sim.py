@@ -475,33 +475,3 @@ def test_battery_record_and_determinism_gate(tmp_path):
         assert by_name[f"sim_{name}_wal_unbalanced_violations"] == 0
     assert by_name["sim_epidemic10k_evictions"] >= 1
     assert by_name["sim_servespike_autoscale_actions"] >= 2
-
-
-# ---- the record family rides the ratchet ---------------------------------
-
-def test_bench_ratchet_recognizes_sim_family(tmp_path):
-    """SIM_* records load, their *_violations metrics are must-be-zero
-    (a nonzero value fails the zero-invariant check), and the
-    trajectory builder folds the family in."""
-    bench_ratchet = _tool("bench_ratchet")
-    rec = tmp_path / "SIM_fleet_cpu_r18.json"
-    rows = [
-        {"metric": "sim_fleet10k_ranks", "value": 10000,
-         "unit": "ranks", "platform": "cpu", "detail": None},
-        {"metric": "sim_fleet10k_determinism_violations", "value": 0,
-         "unit": "runs", "platform": "cpu", "detail": None},
-    ]
-    rec.write_text("".join(json.dumps(r, sort_keys=True) + "\n"
-                           for r in rows))
-    recs = bench_ratchet.load_records([str(rec)])
-    assert {r["metric"] for r in recs} == {
-        "sim_fleet10k_ranks", "sim_fleet10k_determinism_violations"}
-    assert bench_ratchet.check_zero_invariants(recs) == []
-    recs[1]["value"] = 1
-    bad = bench_ratchet.check_zero_invariants(recs)
-    assert bad and "determinism_violations" in bad[0]["metric"]
-    assert bad[0]["severity"] == "regression"
-    traj = bench_ratchet.build_trajectory(str(tmp_path))
-    fam = [r for r in traj if r["family"] == "SIM_fleet_cpu"]
-    assert len(fam) == 1 and fam[0]["round"] == 18
-    assert fam[0]["metrics"]["sim_fleet10k_ranks"] == 10000

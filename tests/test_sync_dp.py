@@ -34,7 +34,7 @@ def _batch(mesh, n=64, shape=(28, 28, 1), seed=0):
 def test_virtual_device_mesh():
     mesh = make_mesh()
     assert mesh.size == jax.device_count()
-    assert jax.device_count() >= 4   # DISTTF_TEST_DEVICES retry floor
+    assert jax.device_count() == 8   # tests/conftest.py
 
 
 def test_train_step_runs_sharded():

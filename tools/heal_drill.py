@@ -2,9 +2,10 @@
 """heal_drill — measured self-healing drills: inject each fault class,
 let the remediation policy engine (resilience/remediate.py) detect and
 heal it, and record time-to-detect / time-to-heal / work-lost (must be
-zero) as a HEAL_* bench-record family.
+zero), one JSON line per metric.
 
-  # the full drill battery -> HEAL_lm_cpu_r16.json:
+  # the full drill battery (a HEAL_*.json at the repo root seeds
+  # remediate.mttr_seeded_cooldown_s):
   python tools/heal_drill.py --out HEAL_lm_cpu_r16.json
   # one drill, fast model (CI-sized):
   python tools/heal_drill.py --drill slow_rank --model softmax --out /tmp/h.json

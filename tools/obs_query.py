@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""obs_query — cross-run queries over the run ledger and the bench
-record families: list runs, diff two runs, render metric trajectories.
+"""obs_query — cross-run queries over the run ledger: list runs, show
+one, diff two, explain a job's scheduler decisions.
 
   # what ran (and how it ended), newest last:
   python tools/obs_query.py list --ledger /tmp/fleet/RUNS.jsonl
@@ -14,8 +14,6 @@ record families: list runs, diff two runs, render metric trajectories.
   # why did the scheduler preempt/shrink/quarantine this job
   # (tools/schedule.py's sched_* decision rows, ledger-only):
   python tools/obs_query.py why bench1 --ledger /tmp/sched/RUNS.jsonl
-  # the bench trajectory, per family per round:
-  python tools/obs_query.py trajectory --format md
 
 Rows come from ``obs/ledger.py``'s RUNS.jsonl (``OBS_LEDGER``; the
 fleet supervisor writes <workdir>/RUNS.jsonl by default): ``run_start``
@@ -25,9 +23,6 @@ pile of per-run files never could — "these two runs differ HOW": the
 config keys that changed (run_start carries the resolved config), the
 final-counter deltas (run_end carries cumulative counters), loss-tail
 digests (same trajectory or not), outcome and anomaly flags.
-``trajectory`` pivots the ``BENCH_*``/``SCALING_*``/``BASELINE_SELF``
-records through tools/bench_ratchet.py's builder — the same rows the
-checked-in ``BENCH_trajectory.json`` artifact holds.
 
 Stdlib-only and read-only (like obs_report): safe mid-outage, and
 ``--format json`` makes every view machine-consumable.
@@ -500,39 +495,19 @@ def cmd_why(args) -> int:
     return 0
 
 
-# --- trajectory ------------------------------------------------------------
-
-def cmd_trajectory(args) -> int:
-    import bench_ratchet
-    rows = bench_ratchet.build_trajectory(args.records_dir)
-    if args.family:
-        rows = [r for r in rows if args.family in r["family"]]
-    md = [f"# Bench trajectory — {len(rows)} family-round row(s)", ""]
-    for row in rows:
-        rnd = "—" if row["round"] is None else f"r{row['round']:02d}"
-        md += [f"## {row['family']} {rnd} (`{row['file']}`, "
-               f"{'/'.join(row['platforms'])})", "",
-               _table(["metric", "value"],
-                      [[f"`{k}`", v]
-                       for k, v in sorted(row["metrics"].items())]), ""]
-    _emit(rows, "\n".join(md), args.format)
-    return 0
-
-
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     sub = p.add_subparsers(dest="cmd", required=True)
 
-    def add_common(sp, ledger: bool = True):
+    def add_common(sp):
         sp.add_argument("--format", default="md", choices=["md", "json"])
-        if ledger:
-            # `or`: a present-but-EMPTY export means "ledger disabled"
-            # everywhere else (fleet, maybe_begin) — fall through to
-            # the ./RUNS.jsonl default the help text promises.
-            sp.add_argument("--ledger", default=os.environ.get(
-                "OBS_LEDGER") or "RUNS.jsonl",
-                help="RUNS.jsonl path (default: $OBS_LEDGER, else "
-                     "./RUNS.jsonl)")
+        # `or`: a present-but-EMPTY export means "ledger disabled"
+        # everywhere else (fleet, maybe_begin) — fall through to
+        # the ./RUNS.jsonl default the help text promises.
+        sp.add_argument("--ledger", default=os.environ.get(
+            "OBS_LEDGER") or "RUNS.jsonl",
+            help="RUNS.jsonl path (default: $OBS_LEDGER, else "
+                 "./RUNS.jsonl)")
 
     sp = sub.add_parser("list", help="run table + agreements")
     add_common(sp)
@@ -563,18 +538,8 @@ def main(argv: list[str] | None = None) -> int:
                                 "tools/schedule.py's queue")
     sp.set_defaults(fn=cmd_why)
 
-    sp = sub.add_parser("trajectory", help="per-family per-round bench "
-                                           "metric trajectories")
-    add_common(sp, ledger=False)
-    sp.add_argument("--records_dir", default=_REPO)
-    sp.add_argument("--family", default="",
-                    help="substring filter on the family")
-    sp.set_defaults(fn=cmd_trajectory)
-
     args = p.parse_args(argv)
-    if getattr(args, "ledger", None) is not None \
-            and args.cmd != "trajectory" \
-            and not os.path.exists(args.ledger) \
+    if not os.path.exists(args.ledger) \
             and not os.path.exists(args.ledger + ".1"):
         p.error(f"ledger {args.ledger} does not exist (pass --ledger or "
                 f"export OBS_LEDGER)")

@@ -17,7 +17,7 @@ A job dict names what to run (`argv`, with ``{rank}``/``{num_ranks}``
 substituted per rank), how wide (`ranks`), how urgent (`priority`, or
 an SLO class via `kind` — serve=0 < train=10 < bench=20 < drill=30,
 overridable with SCHED_SLO_PRIORITIES), and what it costs: `family`
-points at a BENCH_trajectory.json bench family whose measured
+names a family of rows in the --trajectory file whose measured
 steps/sec predicts the job's step time (fallback: `est_step_time_s`),
 and the prediction prices admission and derives the per-attempt wall
 deadline.  Each placement runs under the gang supervisor
@@ -32,9 +32,8 @@ rank groups are swept, and unfinished jobs requeue.  Every decision is
 also a ``sched_*`` row in <workdir>/RUNS.jsonl (obs/ledger.py) — the
 query surface ``tools/obs_query.py why`` reads.
 
-``--record PATH`` writes a queue-completion record (JSON lines, the
-bench-record dialect) that tools/bench_ratchet.py folds into the
-trajectory as the SCHED_queue family.
+``--record PATH`` writes a queue-completion record (JSON lines, one
+per metric).
 
 Exit codes: 0 every job done (refusals are operator errors, reported
 but not fatal), 3 some job quarantined (backend wedged), 1 failures,
@@ -139,9 +138,7 @@ def demo_queue(workdir: str, steps: int = 12,
 
 
 def write_record(path: str, summary: dict, devices: int) -> None:
-    """Queue-completion record, bench-record dialect: one JSON line per
-    metric so tools/bench_ratchet.py's load_records/trajectory builder
-    reads it like any other family (SCHED_queue_*)."""
+    """Queue-completion record: one JSON line per metric."""
     detail = {"platform": "cpu", "devices": devices,
               "status": summary["status"], "counts": summary["counts"],
               "makespan_s": summary["makespan_s"],
@@ -197,11 +194,13 @@ def main(argv: list[str] | None = None) -> int:
                         "cost, when the job pins no wall_timeout_s")
     p.add_argument("--trajectory",
                    default=os.path.join(_REPO, "BENCH_trajectory.json"),
-                   help="BENCH_trajectory.json for measured step-time "
-                        "predictions ('' = declared estimates only)")
+                   help="trajectory file for measured step-time "
+                        "predictions (resilience/scheduler."
+                        "trajectory_rows; absent or '' = declared "
+                        "estimates only)")
     p.add_argument("--record", default="",
-                   help="write the queue-completion record (JSON lines, "
-                        "SCHED_queue family) here")
+                   help="write the queue-completion record (JSON "
+                        "lines) here")
     p.add_argument("--seed", type=int, default=0,
                    help="backoff-jitter seed (tests)")
     args = p.parse_args(argv)

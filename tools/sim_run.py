@@ -5,15 +5,15 @@ full record kit.
 
   # one scenario file -> record rows on stdout, artifacts in --workdir:
   python tools/sim_run.py scenario.json --workdir /tmp/sim
-  # the built-in 10,000-rank battery -> SIM_fleet_cpu_r18.json:
-  python tools/sim_run.py --battery --out SIM_fleet_cpu_r18.json
+  # the built-in 10,000-rank battery -> one record file:
+  python tools/sim_run.py --battery --out /tmp/sim/SIM_fleet_cpu.json
 
 Outputs per run:
 
-- **record rows** (bench-record dialect, one JSON line per metric) —
-  queue-wait percentiles, preemption-storm peak, MTTR tails,
-  suppression counts, and the must-be-zero invariants
-  (``*_steps_lost``, ``*_violations``) tools/bench_ratchet.py ratchets.
+- **record rows** (one JSON line per metric) — queue-wait
+  percentiles, preemption-storm peak, MTTR tails, suppression counts,
+  and the must-be-zero invariants (``*_steps_lost``, ``*_violations``:
+  exit 1 when one is above zero).
 - **the ledger + WAL the real code wrote** (``RUNS.jsonl``,
   ``sched/sched.jsonl``) — query them with ``tools/obs_query.py why
   --job <j>`` exactly like a live run's.
@@ -61,13 +61,13 @@ from distributedtensorflowexample_tpu.resilience import (  # noqa: E402
 from distributedtensorflowexample_tpu.sim import (  # noqa: E402
     SimWorld, load_scenario, sim_metrics)
 
-#: The measured serve SLO knee (SERVE_lm_cpu_r15.json,
-#: serve_lm_tiny_throughput_vs_slo): best in-SLO per-replica goodput.
+#: A serve SLO knee, best in-SLO per-replica goodput: lm_tiny on the
+#: CPU (a drill input, not a device number).
 SERVE_KNEE_TOK_S = 3779.67
 
-#: The fitted psum collective knee at 8 devices
-#: (BENCH_collectives_cpu_r06.json detail.knees.psum["8"]) — prices
-#: cross-slice snapshot migration in eviction plans.
+#: A fitted psum line at 8 virtual CPU devices (a drill input, not a
+#: device number) — prices cross-slice snapshot migration in eviction
+#: plans.
 COLLECTIVE_FIT = {"alpha_s": 0.00035273878968362894,
                   "beta_bytes_per_s": 692186226.9354594}
 
@@ -76,18 +76,17 @@ def _log(msg: str) -> None:
     print(f"sim_run: {msg}", file=sys.stderr, flush=True)
 
 
-# --- the built-in battery (the SIM_fleet record's scenarios) ---------------
+# --- the built-in battery --------------------------------------------------
 
 def battery_scenarios() -> list[dict]:
     """Four storms against 10,000 simulated ranks on a 4-slice mesh:
     a host-loss wave, a straggler epidemic, a serve-traffic spike, and
     a quarantine cascade.  Deterministic by construction — everything
-    below is literal except the serve cooldown, which seeds from the
-    CHECKED-IN measured-MTTR record (same bytes every run)."""
+    below is literal except the serve cooldown, which seeds from a
+    HEAL_* MTTR record at the repo root where there is one
+    (HEAL_COOLDOWN_S otherwise; same bytes every run on one tree)."""
     slices = {"podA": 2600, "podB": 2600, "podC": 2600, "podD": 2600}
-    # Post-action quiet period anchored on the worst measured recovery
-    # tail (HEAL_* record) instead of the old hardcoded 60 s — see
-    # remediate.mttr_seeded_cooldown_s.
+    # Post-action quiet period: remediate.mttr_seeded_cooldown_s.
     cooldown_s = heal_mod.mttr_seeded_cooldown_s()
 
     def fleet_jobs(tag, *, n=24, steps=1200, elastic=True):
