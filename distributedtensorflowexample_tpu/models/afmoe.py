@@ -308,6 +308,41 @@ class AfmoeLM(nn.Module):
         return tuple(("window", min(c.window, cache_len)) if kind == WINDOW
                      else ("full", cache_len) for kind in c.layer_types)
 
+    def prefill_buckets(self, cache_len: int):
+        """The lengths a prompt is padded to, one prefill program each,
+        from this model's own sizes; ``None`` (the engine's powers of
+        two) where the window or the cache is shorter than one attention
+        tile.  Every bucket is a program that set-up loads and that
+        stays on the chip, and every padded position is computed for
+        nothing, so the ladder is fine where padding costs most:
+
+        * one bucket up to a tile (``attn_block`` positions, where
+          ``grouped_attention`` changes path anyway), powers of two from
+          there to the window;
+        * above the window, where a position costs its matmuls and a
+          whole window of keys and a power of two would pad up to half
+          of the largest programs, each power of two and its
+          one-and-a-half: padding at most a third, two buckets an octave
+          however long the cache;
+        * every bucket whole tiles (``ops/attention.takes_splash`` asks
+          for that), ``cache_len`` last whatever it is.
+
+        PERF.md section 6 (PR 31) has what each choice measured."""
+        tile = self.attn_block
+        if min(self.dims.window, cache_len) < tile:
+            return None
+        w = -(-self.dims.window // tile)            # the window, in tiles
+        tiles, t = set(), 1
+        while t < w:
+            tiles.add(t)
+            t *= 2
+        t = w
+        while t * tile < cache_len:
+            tiles.update((t, -(-3 * t // 2)))
+            t *= 2
+        return tuple(sorted(t * tile for t in tiles
+                            if t * tile < cache_len)) + (cache_len,)
+
     def decode_fetch_block(self, rows: int) -> int:
         """Rows the decode step's attention fetches at a time from a
         layer that holds ``rows`` a slot; 0 where it reads them all."""

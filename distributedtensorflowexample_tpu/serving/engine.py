@@ -16,6 +16,9 @@ rows (``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer, kind
 ``full`` or ``window``) and allocates them (``init_cache(slots,
 cache_len)`` -> ``(ck, cv)``).  The engine holds that pair, donates it
 to every program and rebinds what comes back; it never looks inside.
+A module may also state the ladder of lengths its prompts are padded to
+(``prefill_buckets(cache_len)``); without one the engine pads to the
+next power of two (:func:`_prefill_buckets`).
 Two layouts exist today:
 
 * ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
@@ -384,7 +387,9 @@ def _prefill_buckets(cache_len: int, smallest: int = 8) -> tuple:
     to ``cache_len`` (inclusive as the final bucket).  Each bucket is
     one compiled prefill program; a prompt pads to the smallest bucket
     that fits, so N distinct prompt lengths cost log(N) compiles, not
-    N."""
+    N.  This is the DEFAULT ladder: a serving module whose positions
+    cost unevenly states its own (``prefill_buckets(cache_len)``, see
+    ``AfmoeLM``), and ``DecodeEngine`` takes that one instead."""
     out = []
     b = smallest
     while b < cache_len:
@@ -497,7 +502,11 @@ class DecodeEngine:
         self.slots = int(slots)
         self.cache_len = int(cache_len)
         self.vocab = int(model.vocab_size)
-        self.buckets = _prefill_buckets(self.cache_len, prefill_smallest)
+        # The padding ladder is the model's where it states one (None:
+        # it leaves the choice here), the powers of two otherwise.
+        stated = getattr(self.smodel, "prefill_buckets", lambda n: None)
+        self.buckets = (stated(self.cache_len)
+                        or _prefill_buckets(self.cache_len, prefill_smallest))
         self._ck, self._cv = self.smodel.init_cache(self.slots,
                                                     self.cache_len)
         layers = self.smodel.cache_rows(self.cache_len)

@@ -5,6 +5,7 @@ float32 compute so that the comparison is of the mathematics: a window of
 8 positions, so every context here wraps the rings several times."""
 
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -170,6 +171,92 @@ def test_a_prompt_longer_than_the_window_leaves_its_last_rows(params,
     for layer in (0, 1, 3):
         for a, b in ((long._ck, steps._ck), (long._cv, steps._cv)):
             assert np.abs(np.asarray(a[layer][1] - b[layer][1])).max() < TOL
+
+
+# ---- the padding ladder the model states -----------------------------------
+
+#: The ladder of benchmarks/configs/trinity_large_ep8.json in its cell
+#: (tiles of 1,024, a window of 4,096, 16,384 rows a slot).
+CELL_LADDER = (1024, 2048, 4096, 6144, 8192, 12288, 16384)
+
+
+@pytest.mark.parametrize("tile, window, cache_len, ladder", [
+    (1024, 4096, 16384, CELL_LADDER),
+    (1024, 4096, 8192, (1024, 2048, 4096, 6144, 8192)),
+    (1024, 4096, 3000, (1024, 2048, 3000)),     # a cache inside the window
+    (1024, 1024, 4096, (1024, 2048, 3072, 4096)),   # a window of one tile
+    (512, 3000, 8192, (512, 1024, 2048, 3072, 4608, 6144, 8192)),
+    (1024, 4096, 40000, CELL_LADDER + (24576, 32768, 40000)),
+    (8, 16, 64, (8, 16, 24, 32, 48, 64)),
+    (8, 16, 60, (8, 16, 24, 32, 48, 60)),
+    (1024, 8, 64, None), (1024, 4096, 1000, None), (16, 8, 64, None)])
+def test_the_stated_ladder(tile, window, cache_len, ladder):
+    """One bucket up to a tile, powers of two to the window, above it
+    each power of two and its one-and-a-half (whole tiles), ``cache_len``
+    last; a window or a cache shorter than a tile leaves the ladder to
+    the engine."""
+    got = _model(tile, sliding_window=window,
+                 max_position_embeddings=1 << 16).prefill_buckets(cache_len)
+    assert got == ladder
+    if ladder:
+        assert all(a < b for a, b in zip(got, got[1:]))
+        assert got[0] == tile and got[-1] == cache_len
+        assert all(b % tile == 0 for b in got[:-1])
+
+
+def test_the_cells_configuration_states_the_cells_ladder():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "trinity_large_ep8.json"))
+    assert model.prefill_buckets(16384) == CELL_LADDER
+    # the fullest program is still two prompts of the longest bucket
+    assert max(b * max(1, model.prefill_positions_max // b)
+               for b in CELL_LADDER) == 2 * 16384
+
+
+def test_a_window_below_a_tile_gets_the_engines_powers_of_two(params):
+    """Every other model of this file: tiles of 1,024, a window of 8."""
+    engine = DecodeEngine(_model(), params, slots=2, cache_len=64)
+    assert engine.buckets == (8, 16, 32, 64)
+    assert DecodeEngine(_model(), params, slots=2, cache_len=48,
+                        prefill_smallest=16).buckets == (16, 32, 48)
+
+
+def test_a_bucket_the_ring_does_not_divide_prefills_and_decodes(params,
+                                                                sequences):
+    """Tiles of 8 and a window of 16 state the ladder 8, 16, 24, 32, 48,
+    64: a 20-token prompt pads to 24 positions, neither a power of two
+    nor a multiple of the ring of 16 it is longer than (the ring keeps
+    positions 4..19, each at position mod 16), a 37-token one to 48.
+    Prefill and 22 decode steps, the rings wrapping, give the float32
+    reference's logits; the pad counter reads the hand count."""
+    cfg = {**TINY, "sliding_window": 16}
+    want = np.asarray(ref.forward(params, jnp.asarray(sequences), cfg))
+    engine = DecodeEngine(_model(8, sliding_window=16), params, slots=3,
+                          cache_len=64)
+    assert engine.buckets == (8, 16, 24, 32, 48, 64)
+    assert [rows for _, rows in engine.smodel.cache_rows(64)] == [
+        16, 16, 64, 16]
+    lengths = {0: 5, 1: 20, 2: 37}
+    assert [engine.bucket_for(n, 1) for n in lengths.values()] == [8, 24, 48]
+    pad = 'serve_prefill_positions_total{kind="pad"}'
+    prompt = 'serve_prefill_positions_total{kind="prompt"}'
+    before = _counter(pad), _counter(prompt)
+    got = engine.prefill_many([(s, sequences[s, :n], 1)
+                               for s, n in lengths.items()])
+    assert _counter(pad) - before[0] == (8 - 5) + (24 - 20) + (48 - 37)
+    assert _counter(prompt) - before[1] == 5 + 20 + 37
+    worst = max(np.abs(got[s][1] - want[s, n - 1]).max()
+                for s, n in lengths.items())
+    for s, n in lengths.items():
+        engine.set_slot(s, int(sequences[s, n]), n)
+    for _ in range(22):
+        at = {s: int(engine.positions[s]) for s in lengths}
+        logits = engine.decode_logits(busy=sorted(lengths))
+        for s in lengths:
+            worst = max(worst, np.abs(logits[s] - want[s, at[s]]).max())
+            engine.set_slot(s, int(sequences[s, at[s] + 1]), at[s] + 1)
+    assert int(engine.positions[2]) == 59 and worst < TOL, worst
 
 
 def test_batcher_serves_the_references_tokens(params):

@@ -167,6 +167,21 @@ def test_prefill_bucket_table_and_refusals(engine):
                      cache_len=engine.model.max_len + 1)
 
 
+@pytest.mark.parametrize("cache_len, smallest, ladder", [
+    (CACHE, 8, (8, 16, 32)), (48, 8, (8, 16, 32, 48)),
+    (64, 16, (16, 32, 64)), (8, 8, (8,))])
+def test_a_serving_lm_states_no_ladder_and_keeps_the_powers_of_two(
+        lm_state, cache_len, smallest, ladder):
+    """``ServingLM`` has no ``prefill_buckets``: GPT-2's engines pad to
+    the next power of two, the cache's length last, as they always did
+    (a model that states a ladder: tests/test_afmoe.py)."""
+    model, state = lm_state
+    engine = DecodeEngine(model, state.params, slots=1, cache_len=cache_len,
+                          prefill_smallest=smallest)
+    assert not hasattr(engine.smodel, "prefill_buckets")
+    assert engine.buckets == ladder
+
+
 # ---- continuous batching -------------------------------------------------
 
 def test_request_admitted_mid_decode_completes_bitwise(lm_state, engine):

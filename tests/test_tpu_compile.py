@@ -142,15 +142,17 @@ def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(topo,
     assert compiled.as_text().count('op_name="ragged-dot-none"') >= 12
 
 
-@pytest.mark.parametrize("batch, bucket", [(1, 256), (2, 16384)])
+@pytest.mark.parametrize("batch, bucket", [(1, 256), (2, 16384), (1, 6144)])
 def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
                                                    bucket, monkeypatch):
-    """The smallest and the fullest prefill program of the cell: no
-    ``[H, T, T]`` scores (a 16,384-token prompt's would be 51.5 GB), the
-    head at the last position only, and weights, cache and the program's
-    activations together inside the chip's 16.9 GB.  Past one tile the
-    attention of every layer is the TPU's kernel (the backend is the
-    CPU's here, so the test says "built for a TPU" itself)."""
+    """A prefill program below the cell's ladder, the fullest one, and a
+    bucket of the ladder that is no power of two (six tiles, a window
+    and a half): no ``[H, T, T]`` scores (a 16,384-token prompt's would
+    be 51.5 GB), the head at the last position only, and weights, cache
+    and the program's activations together inside the chip's 16.9 GB.
+    Past one tile the attention of every layer is the TPU's kernel, not
+    the tiled walk's loop (the backend is the CPU's here, so the test
+    says "built for a TPU" itself)."""
     from distributedtensorflowexample_tpu.ops import attention as attention_op
     from distributedtensorflowexample_tpu.serving import engine as eng
     monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
@@ -163,6 +165,7 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     assert mem.temp_size_in_bytes < 3.5e9
     assert ("splash" in compiled.as_text()) == (
         bucket > attention_op.ATTN_BLOCK)
+    assert (bucket in model.prefill_buckets(16384)) == (bucket > 256)
 
 
 # ---- the token step's ragged attention (PR 29) ------------------------------
