@@ -51,9 +51,14 @@ def build_model_from_config(config, **kw):
     if kind == "afmoe":
         from distributedtensorflowexample_tpu.models.afmoe import build_afmoe
         return build_afmoe(config, **kw)
+    if kind == "qwen3_next":
+        from distributedtensorflowexample_tpu.models.qwen3_next import (
+            build_qwen3_next)
+        return build_qwen3_next(config, **kw)
     raise ValueError(
         f"no model is built from a configuration of model_type {kind!r} "
-        f"(have: afmoe; the GPT-2 ladder is built by size, LM_SIZES)")
+        f"(have: afmoe, qwen3_next; the GPT-2 ladder is built by size, "
+        f"LM_SIZES)")
 
 
 __all__ = ["SoftmaxRegression", "MnistCNN", "ResNet20", "ResNetCIFAR",

@@ -265,15 +265,17 @@ def _ragged():
     return decode_attention
 
 
-def decode_fetch_block(rows: int, n_kv_heads: int, head_dim: int) -> int:
+def decode_fetch_block(rows: int, n_kv_heads: int, head_dim: int,
+                       flat: bool = False) -> int:
     """Cache rows :func:`decode_attention` fetches at a time from a
     layer of ``rows`` rows a slot — a slot's visible rows rounded up to
     it are what a step reads — or 0 where it takes the einsum chain,
     which reads every row the layer holds.  The predicate: built for a
-    TPU, and the cache's rows tile."""
+    TPU, and the cache's rows tile (``flat``: the model keeps them as
+    ``[S, rows * Hkv, Dh]``, which tiles whatever ``Hkv`` is)."""
     if jax.default_backend() != "tpu":
         return 0
-    return _ragged().fetch_block(rows, n_kv_heads, head_dim)
+    return _ragged().fetch_block(rows, n_kv_heads, head_dim, flat)
 
 
 def einsum_decode_attention(q, ck, cv, lengths):
@@ -292,9 +294,11 @@ def einsum_decode_attention(q, ck, cv, lengths):
 def decode_attention(q, ck, cv, lengths):
     """The token step's attention: ``q [S, K, Hkv, G, Dh]`` (a K-token
     window a slot, plain decode is K == 1; ``G`` query heads a key/value
-    head), ``ck``/``cv`` ``[S, R, Hkv, Dh]`` as the engine holds them,
-    ``lengths [S, K]`` the count of LEADING rows each query sees
-    (``1..R``).  Returns ``[S, K, Hkv, G, Dh]``.
+    head), ``ck``/``cv`` ``[S, R, Hkv, Dh]`` as the engine holds them —
+    or flat, ``[S, R * Hkv, Dh]``, as a model keeps them whose K/V heads
+    are fewer than a tile's sublanes —, ``lengths [S, K]`` the count of
+    LEADING rows each query sees (``1..R``).  Returns ``[S, K, Hkv, G,
+    Dh]``.
 
     One function, two regimes chosen from what the call can observe: for
     one token a slot, where :func:`decode_fetch_block` finds a block,
@@ -303,9 +307,13 @@ def decode_attention(q, ck, cv, lengths):
     chain reads all ``R`` and masks.  Scores and softmax are float32 in
     both, the probabilities are cast to the cache's type before the
     second product."""
-    _, K, Hkv, _, Dh = q.shape
-    if K > 1 or not decode_fetch_block(ck.shape[1], Hkv, Dh):
+    S, K, Hkv, _, Dh = q.shape
+    flat = ck.ndim == 3
+    R = ck.shape[1] // Hkv if flat else ck.shape[1]
+    if K > 1 or not decode_fetch_block(R, Hkv, Dh, flat):
         _DECODE.labels(impl="einsum").inc()
+        if flat:
+            ck, cv = (c.reshape(S, R, Hkv, Dh) for c in (ck, cv))
         return einsum_decode_attention(q, ck, cv, lengths)
     _DECODE.labels(impl="ragged").inc()
     return _ragged().ragged_decode_attention(

@@ -5,10 +5,14 @@ computes the part of the result its own experts give.  What the absent
 experts would have added is left out (their chips add it in the
 deployment; nothing here stands in for them or for their traffic).
 
-Routing (:func:`route`) is the sigmoid-score form: ``s = sigmoid(m @
-Wr)`` in float32 over every expert, the top ``k`` of ``s + bias`` are
-selected, and the weights are the selected scores themselves (the bias
-only selects), normalised over the ``k`` and scaled.
+Routing (:func:`route`) has two forms, which a configuration names as
+architecture data (``score_func``), both in float32 over every expert.
+The sigmoid-score form: ``s = sigmoid(m @ Wr)``, the top ``k`` of ``s +
+bias`` are selected, and the weights are the selected scores themselves
+(the bias only selects), normalised over the ``k`` and scaled.  The
+softmax form: ``s = softmax(m @ Wr)`` over all the experts, the top
+``k`` of ``s`` (this form has no bias), the weights the selected
+probabilities, normalised over the ``k``.
 
 The experts (:func:`expert_ffn`) are SiLU-gated, ``(silu(x @ G) * (x @
 U)) @ D``.  All (token, expert) pairs are sorted by expert, those on held
@@ -43,15 +47,21 @@ STATS = ("pairs_held", "pairs_absent", "experts_touched")
 
 
 def route(m, router_kernel, router_bias, *, top_k: int, route_scale: float,
-          route_norm: bool = True):
+          route_norm: bool = True, score_func: str = "sigmoid"):
     """``m [N, d]`` -> ``(sel [N, k] int32, w [N, k] f32)``: the experts
     each token selects among ALL ``router_kernel.shape[1]`` and the
-    weight of each.  Scores in float32 (the products of bfloat16 operands
-    are exact there)."""
+    weight of each, by the form ``score_func`` names (``router_bias``
+    None: nothing but the scores selects).  Scores in float32 (the
+    products of bfloat16 operands are exact there)."""
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown score_func {score_func!r}")
     with jax.named_scope("moe.route"):
-        s = jax.nn.sigmoid(jnp.dot(m, router_kernel,
-                                   preferred_element_type=jnp.float32))
-        _, sel = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+        s = jnp.dot(m, router_kernel, preferred_element_type=jnp.float32)
+        s = (jax.nn.sigmoid(s) if score_func == "sigmoid"
+             else jax.nn.softmax(s, axis=-1))
+        _, sel = jax.lax.top_k(
+            s if router_bias is None
+            else s + router_bias.astype(jnp.float32), top_k)
         w = jnp.take_along_axis(s, sel, axis=-1)
         if route_norm:
             w = w / jnp.sum(w, axis=-1, keepdims=True)

@@ -6,7 +6,10 @@ Layout.  The cache arrives as the engine holds it, ``[S, R, Hkv, Dh]``,
 and is only VIEWED as ``[S, R * Hkv, Dh]``: with ``Hkv`` a multiple of 8
 and ``Dh`` of 128 a cache row is whole ``(8, 128)`` tiles in HBM, so the
 view is the same bytes and XLA makes no copy of a 2 GB array.  A row of
-the view is one (position, K/V head) pair.
+the view is one (position, K/V head) pair.  A model with fewer K/V
+heads than a tile has sublanes (2 heads of 256) keeps its rows in that
+view in the first place, ``[S, R * Hkv, Dh]``, and hands them over as
+they are: nothing else of the kernel depends on ``Hkv``.
 
 One matmul for every head.  A block of ``block`` positions is ``[block *
 Hkv, Dh]``; the slot's ``Hq = Hkv * G`` query heads multiply all of it,
@@ -60,11 +63,13 @@ def pick_block(rows: int, block: int | None = None) -> int | None:
     return None
 
 
-def fetch_block(rows: int, n_kv_heads: int, head_dim: int) -> int:
+def fetch_block(rows: int, n_kv_heads: int, head_dim: int,
+                flat: bool = False) -> int:
     """Rows per block where the kernel takes these shapes without a copy
-    of the cache — a cache row is whole (8, 128) tiles, and a block
-    divides the rows — else 0."""
-    if n_kv_heads % SUBLANES or head_dim % LANES:
+    of the cache — a cache row is whole (8, 128) tiles, or the cache is
+    kept ``flat`` as ``[S, rows * Hkv, Dh]``, and a block divides the
+    rows — else 0."""
+    if (not flat and n_kv_heads % SUBLANES) or head_dim % LANES:
         return 0
     return pick_block(rows) or 0
 
@@ -114,11 +119,12 @@ def ragged_decode_attention(q, ck, cv, lengths, *, block: int | None = None,
                             interpret: bool | None = None):
     """``softmax(q k^T / sqrt(Dh)) v`` per slot over the slot's first
     ``lengths[s]`` cache rows: ``q [S, Hkv, G, Dh]``, ``ck``/``cv`` ``[S,
-    R, Hkv, Dh]``, ``lengths [S]`` int32 in ``1..R``.  Returns ``[S, Hkv,
-    G, Dh]`` in the cache's type.  Jitted here, so the layers of one
-    program that share a shape share one trace."""
+    R, Hkv, Dh]`` (or flat, ``[S, R * Hkv, Dh]``), ``lengths [S]`` int32
+    in ``1..R``.  Returns ``[S, Hkv, G, Dh]`` in the cache's type.
+    Jitted here, so the layers of one program that share a shape share
+    one trace."""
     S, Hkv, G, Dh = q.shape
-    R = ck.shape[1]
+    R = ck.shape[1] // Hkv if ck.ndim == 3 else ck.shape[1]
     block = pick_block(R, block)
     if block is None:
         raise ValueError(f"no block of {BLOCKS} divides {R} cache rows")

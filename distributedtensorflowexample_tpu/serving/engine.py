@@ -12,20 +12,38 @@ ONE decode step whose cache arguments are DONATED.
 **The engine asks the model** for what it serves with
 (``model.serving_module()``): a flax module with ``prefill_into``,
 ``verify`` and ``decode`` methods, which also states each layer's cache
-rows (``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer, kind
-``full`` or ``window``) and allocates them (``init_cache(slots,
-cache_len)`` -> ``(ck, cv)``).  The engine holds that pair, donates it
-to every program and rebinds what comes back; it never looks inside.
+(``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer) and allocates it
+(``init_cache(slots, cache_len)`` -> ``(ck, cv)``).  The engine holds
+that pair, donates it to every program and rebinds what comes back; it
+never looks inside.  There are three kinds of layer:
+
+* ``full``: ``cache_len`` K/V rows a slot, a row a position;
+* ``window``: a ring of the last ``rows`` positions;
+* ``state``: NO rows (``rows`` is 0) but a recurrent state whose size
+  does not depend on ``cache_len``.  It is read AND written whole every
+  step; a stale one cannot be masked as a stale row is, so the module's
+  prefill overwrites an admitted slot's, and its token step leaves a
+  parked slot's (position 0) as it is.  A module with such layers says
+  what a slot holds in each layer (``cache_slot_bytes(cache_len)``):
+  ``serve_cache_bytes{kind}`` and ``serve_state_bytes_total{whose}``
+  come from that; for the other modules every row is as wide in every
+  layer and the engine splits the cache's bytes by rows.
+
 A module may also state the ladder of lengths its prompts are padded to
 (``prefill_buckets(cache_len)``); without one the engine pads to the
 next power of two (:func:`_prefill_buckets`).
-Two layouts exist today:
+Three layouts exist today:
 
 * ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
   Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
   window layer ``min(window, cache_len)`` rows as a ring (row = position
   mod rows).  One shape for all layers would hold every window layer at
   ``cache_len`` rows.
+* ``models/qwen3_next.py``'s model (its own serving module too): one
+  array a layer in each of ``ck`` and ``cv``; an attention layer's are
+  its K and V rows, kept flat as ``[S, cache_len * Hkv, Dh]``, a Gated
+  DeltaNet layer's its recurrent state ``[S, Hv, Dk, Dv]`` float32 and
+  its convolution's last inputs.
 * ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
   stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
   them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU
@@ -40,9 +58,11 @@ Two layouts exist today:
 ``read_rows`` / ``write_rows`` (the prefix cache) and ``verify_step`` /
 ``extend`` (speculation, suffix extension) assume the stacked layout and
 a cache a rejected token can be rolled back from; on a ring a window's
-writes overwrite rows that a rollback would need.  For a model with
-window layers they refuse by name (:func:`refuse_window_layers`), as do
-``PrefixCache``, ``SpecDecoder`` and ``ShardedDecodeEngine``.
+writes overwrite rows that a rollback would need, and a recurrent state
+that has taken a token cannot give it back at all.  For a model with
+window or state layers they refuse by name
+(:func:`refuse_cache_without_rows_by_position`), as do ``PrefixCache``,
+``SpecDecoder`` and ``ShardedDecodeEngine``.
 
 Numerics: the serving modules mirror ``models/transformer_lm.py``
 sub-module for sub-module — same flax layers, same names (so a training
@@ -90,8 +110,14 @@ _PREFILL_PROGRAMS = obs_metrics.gauge(
     "series a process: beside a draft engine, whichever wrote last)")
 
 _CACHE_BYTES = obs_metrics.gauge(
-    "serve_cache_bytes", "bytes of the engine's K/V cache, by the kind of "
-    "layer that holds them (full = cache_len rows a slot, window = a ring)")
+    "serve_cache_bytes", "bytes of the engine's cache, by the kind of "
+    "layer that holds them (full = cache_len K/V rows a slot, window = a "
+    "ring of rows, state = no rows but a recurrent state of a fixed size)")
+_STATE_BYTES = obs_metrics.counter(
+    "serve_state_bytes_total", "bytes of recurrent state the decode steps "
+    "moved, summed over steps: state layers x bytes a slot x 2 (a state "
+    "is read and written whole), over the busy slots and over all of "
+    "them (an idle slot's is moved too: the program has one shape)")
 _ROWS_READ = obs_metrics.counter(
     "serve_cache_rows_read_total", "cache rows the busy slots' queries "
     "attended, summed over decode steps and layers, by kind of layer (a "
@@ -366,20 +392,37 @@ def serving_lm_for(model) -> ServingLM:
                      parent=None)    # a module of its own, not a child
 
 
-def refuse_window_layers(model, what: str) -> None:
+#: Why a kind of layer whose cache is not ``cache_len`` rows by position
+#: cannot be served by what reads, writes or rolls back such rows:
+#: (what the layers are called, what they keep, what that breaks).
+_NO_ROWS_BY_POSITION = {
+    "window": (
+        "window-attention layers",
+        "keep a ring of the last positions, a row being position mod the "
+        "ring's length",
+        "a ring has overwritten the rows that needs"),
+    "state": (
+        "recurrent-state layers",
+        "keep no cache rows but a state that every token rewrites whole",
+        "a state that has taken a token can neither be cut at a position "
+        "nor give the token back"),
+}
+
+
+def refuse_cache_without_rows_by_position(model, what: str) -> None:
     """``what`` needs every layer's cache to be the same ``cache_len``
-    rows, addressed by position; a model with window layers keeps rings
-    and is refused by name."""
+    rows, addressed by position; a model with window layers keeps rings,
+    one with state layers keeps no rows at all, and either is refused by
+    name."""
     rows = model.serving_module().cache_rows(1)
-    n = sum(kind == "window" for kind, _ in rows)
-    if n:
-        raise ModeRefusal(
-            f"{what} does not serve a model with window-attention layers "
-            f"({n} of this model's {len(rows)} keep a ring of the last "
-            f"positions, a row being position mod the ring's length): it "
-            f"reads, writes or rolls back cache rows by position, and a "
-            f"ring has overwritten the rows that needs — serve this model "
-            f"through DecodeEngine alone")
+    for kind, (called, keep, breaks) in _NO_ROWS_BY_POSITION.items():
+        n = sum(k == kind for k, _ in rows)
+        if n:
+            raise ModeRefusal(
+                f"{what} does not serve a model with {called} ({n} of this "
+                f"model's {len(rows)} {keep}): it reads, writes or rolls "
+                f"back cache rows by position, and {breaks} — serve this "
+                f"model through DecodeEngine alone")
 
 
 def _prefill_buckets(cache_len: int, smallest: int = 8) -> tuple:
@@ -512,10 +555,10 @@ class DecodeEngine:
         layers = self.smodel.cache_rows(self.cache_len)
         self.cache_bytes = sum(
             x.nbytes for x in jax.tree.leaves((self._ck, self._cv)))
-        # Per kind of layer: (rows-read counter, rows-fetched counter,
-        # layers, rows a slot holds in each, rows the model's decode
-        # attention fetches at a time or 0 for all of them); a row is as
-        # wide in every layer.
+        # Per kind of layer that holds rows: (rows-read counter,
+        # rows-fetched counter, layers, rows a slot holds in each, rows
+        # the model's decode attention fetches at a time or 0 for all of
+        # them).
         kinds: dict = {}
         for kind, rows in layers:
             kinds[kind] = (kinds.get(kind, (0, rows))[0] + 1, rows)
@@ -524,11 +567,27 @@ class DecodeEngine:
         self._kinds = [(_ROWS_READ.labels(kind=kind),
                         _ROWS_FETCHED.labels(kind=kind), n, rows,
                         fetch_block(rows))
-                       for kind, (n, rows) in kinds.items()]
-        for kind, (n, rows) in kinds.items():
-            _CACHE_BYTES.labels(kind=kind).set(
-                self.cache_bytes * n * rows // sum(r for _, r in layers))
-        self.window_layers = kinds.get("window", (0, 0))[0]
+                       for kind, (n, rows) in kinds.items()
+                       if kind != "state"]
+        # The bytes of each kind: the module's own count of what a slot
+        # holds in each layer, or (no module without state layers states
+        # one) the cache's bytes split by rows, a row as wide everywhere.
+        stated = getattr(self.smodel, "cache_slot_bytes", None)
+        by_kind = dict.fromkeys(kinds, 0)
+        if stated is not None:
+            for (kind, _), held in zip(layers, stated(self.cache_len)):
+                by_kind[kind] += self.slots * held
+        else:
+            for kind, (n, rows) in kinds.items():
+                by_kind[kind] = (self.cache_bytes * n * rows
+                                 // sum(r for _, r in layers))
+        for kind, held in by_kind.items():
+            _CACHE_BYTES.labels(kind=kind).set(held)
+        # What one slot's states cost a decode step: read and written.
+        self._state_bytes_slot = 2 * by_kind.get("state", 0) // self.slots
+        #: Layers whose cache is not ``cache_len`` rows by position.
+        self.layers_without_rows_by_position = sum(
+            n for kind, (n, _) in kinds.items() if kind != "full")
         # Host-owned scalars-per-slot, uploaded per call (tiny): the
         # returned next-token array is the only per-step device output
         # besides the aliased caches.
@@ -651,6 +710,11 @@ class DecodeEngine:
         if busy is not None:
             advance[list(busy)] = True
         self._count_rows_read(advance)
+        if self._state_bytes_slot:
+            _STATE_BYTES.labels(whose="busy").inc(
+                self._state_bytes_slot * int(advance.sum()))
+            _STATE_BYTES.labels(whose="all").inc(
+                self._state_bytes_slot * self.slots)
         self.last_tokens = np.where(advance, out, self.last_tokens) \
             .astype(np.int32)
         self.positions = self.positions + advance.astype(np.int32)
@@ -708,9 +772,9 @@ class DecodeEngine:
         are garbage to discard).  Returns (greedy [S, K] int32,
         logits [S, K, V] f32).  Advances NOTHING — the caller owns
         accept/rollback bookkeeping via :meth:`set_slot`."""
-        if self.window_layers:
-            refuse_window_layers(self.model, "verify_step (speculation, "
-                                 "suffix extension)")
+        if self.layers_without_rows_by_position:
+            refuse_cache_without_rows_by_position(
+                self.model, "verify_step (speculation, suffix extension)")
         with hot_span("engine.decode.dispatch"):
             g, logits, self._ck, self._cv = _verify_window(
                 self.smodel, self.params, self._ck, self._cv,
@@ -744,8 +808,9 @@ class DecodeEngine:
         device arrays [L, width, H, Dh] (the prefix-cache registration
         read).  Blocked to completion so the copies cannot race the
         next step's cache donation."""
-        if self.window_layers:
-            refuse_window_layers(self.model, "read_rows (the prefix cache)")
+        if self.layers_without_rows_by_position:
+            refuse_cache_without_rows_by_position(
+                self.model, "read_rows (the prefix cache)")
         k = self._ck[:, slot, :width]
         v = self._cv[:, slot, :width]
         return jax.block_until_ready(k), jax.block_until_ready(v)
@@ -753,8 +818,9 @@ class DecodeEngine:
     def write_rows(self, slot: int, k_rows, v_rows) -> None:
         """Import stored K/V rows into ``slot`` (the prefix-cache hit
         write); the caller then ``set_slot``s the real prefix length."""
-        if self.window_layers:
-            refuse_window_layers(self.model, "write_rows (the prefix cache)")
+        if self.layers_without_rows_by_position:
+            refuse_cache_without_rows_by_position(
+                self.model, "write_rows (the prefix cache)")
         self._ck, self._cv = _splice_rows(
             self._ck, self._cv, k_rows, v_rows, np.int32(slot))
 

@@ -40,7 +40,7 @@ import numpy as np
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.serving.engine import (
-    refuse_window_layers)
+    refuse_cache_without_rows_by_position)
 
 _PREFIX_LOOKUPS = obs_metrics.counter(
     "serve_prefix_lookups_total",
@@ -78,7 +78,8 @@ class PrefixCache:
                     "engine (--sharded_mesh) does not expose — its "
                     "cache rows shard over the slot axis; prefix "
                     "sharing composes with the replicated path only")
-        refuse_window_layers(engine.model, "--prefix_cache (PrefixCache)")
+        refuse_cache_without_rows_by_position(
+            engine.model, "--prefix_cache (PrefixCache)")
         if capacity < 1:
             raise ValueError(f"prefix-cache capacity {capacity} must "
                              f"be >= 1")

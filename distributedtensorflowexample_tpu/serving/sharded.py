@@ -48,8 +48,8 @@ from distributedtensorflowexample_tpu.parallel.zero3 import (
     Zero3Layout, _tie)
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.serving.engine import (
-    DEFAULT_SLOTS, ServingLM, _prefill_buckets, refuse_window_layers,
-    serving_lm_for)
+    DEFAULT_SLOTS, ServingLM, _prefill_buckets,
+    refuse_cache_without_rows_by_position, serving_lm_for)
 
 #: The sharded decode step's compiled-HLO contract (graftlint HLO
 #: front, next to the replicated path's DECODE_HLO_CONTRACT): donated
@@ -81,7 +81,8 @@ class ShardedDecodeEngine:
                  *, slots: int = DEFAULT_SLOTS, cache_len: int = 128,
                  prefill_smallest: int = 8, overlap: bool = True):
         from jax.sharding import NamedSharding, PartitionSpec as P
-        refuse_window_layers(model, "--sharded_mesh (ShardedDecodeEngine)")
+        refuse_cache_without_rows_by_position(
+            model, "--sharded_mesh (ShardedDecodeEngine)")
         if cache_len > model.max_len:
             raise ModeRefusal(
                 f"--max_len {cache_len} exceeds the model's positional "

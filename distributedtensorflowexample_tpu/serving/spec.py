@@ -47,7 +47,7 @@ import numpy as np
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.serving.engine import (
-    refuse_window_layers)
+    refuse_cache_without_rows_by_position)
 
 _SPEC_ROUNDS = obs_metrics.counter(
     "serve_spec_rounds_total", "speculative draft+verify rounds")
@@ -78,7 +78,8 @@ class SpecDecoder:
                 "(--sharded_mesh) does not expose — speculative "
                 "decoding composes with the replicated path only")
         for eng in (engine, draft_engine):
-            refuse_window_layers(eng.model, "--spec_draft (SpecDecoder)")
+            refuse_cache_without_rows_by_position(
+                eng.model, "--spec_draft (SpecDecoder)")
         if draft_engine.vocab != engine.vocab:
             raise ModeRefusal(
                 f"draft model vocab {draft_engine.vocab} != target "

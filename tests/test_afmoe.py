@@ -455,10 +455,10 @@ def test_what_assumes_one_row_shape_refuses_window_layers_by_name(params,
 def test_a_model_of_full_layers_only_is_not_refused(params):
     """The refusal is of rings, not of the architecture."""
     from distributedtensorflowexample_tpu.serving.engine import (
-        refuse_window_layers)
-    refuse_window_layers(_model(layer_types=["full_attention"] * 4), "x")
+        refuse_cache_without_rows_by_position as refuse)
+    refuse(_model(layer_types=["full_attention"] * 4), "x")
     with pytest.raises(ModeRefusal):
-        refuse_window_layers(_model(), "x")
+        refuse(_model(), "x")
 
 
 def test_a_cache_longer_than_the_models_positions_is_refused(params):
@@ -491,7 +491,7 @@ def test_gpt2_goes_through_the_same_engine_with_every_layer_full():
     p = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
         "params"]
     engine = DecodeEngine(model, p, slots=2, cache_len=16)
-    assert engine.window_layers == 0
+    assert engine.layers_without_rows_by_position == 0
     assert engine.smodel.cache_rows(16) == (("full", 16),) * 2
     assert engine._ck.shape == (2, 2, 16, 2, 32)    # the stacked pair
 

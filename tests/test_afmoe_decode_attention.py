@@ -132,7 +132,8 @@ def _force_ragged(monkeypatch):
     """Take the kernel (interpreted: the backend is the CPU's) wherever a
     block of 16 or 8 rows divides a layer's rows."""
     monkeypatch.setattr(ragged, "BLOCKS", (16, 8))
-    fetch = lambda rows, n_kv_heads, head_dim: ragged.pick_block(rows) or 0
+    fetch = lambda rows, n_kv_heads, head_dim, flat=False: (
+        ragged.pick_block(rows) or 0)
     monkeypatch.setattr(attention_op, "decode_fetch_block", fetch)
     monkeypatch.setattr(afmoe_model, "decode_fetch_block", fetch)
 

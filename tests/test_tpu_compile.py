@@ -216,3 +216,82 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
     assert mem.temp_size_in_bytes < 0.5e9
     assert not re.search(r"bf16\[32,(16384|4096|131072|32768),[^\]]*\]\S* "
                          r"copy\(", text)
+
+
+# ---- the qwen3_next share at the benchmark cell's own sizes (PR 34) ---------
+
+def _qwen3_next_programs(topo, slots=256, cache_len=4096):
+    """The model of benchmarks/configs/qwen3_next_ep8.json with shapes for
+    its parameters and for a 256-slot, 4,096-row engine's cache — K/V
+    rows of two layers, recurrent and convolution states of six — all on
+    the described chip."""
+    import os
+    from distributedtensorflowexample_tpu.models import (
+        build_model_from_config)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "qwen3_next_ep8.json"))
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ck, cv = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, cache_len)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+    return model, params, ck, cv, i32
+
+
+def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
+                                                          monkeypatch):
+    """The cell's decode program built for a TPU: the ragged kernel in
+    both attention layers over the flat rows of two K/V heads, the
+    recurrence's kernel in the six Gated DeltaNet layers, every
+    layer's rows AND states aliased onto their inputs (7.6 GB: the
+    recurrent state is updated in place), no copy of a state- or
+    cache-sized array, and small temporaries beside 11.5 GB of weights
+    and cache."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _qwen3_next_programs(topo)
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(256), i32(256)).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if re.search(r"%ragged_decode_attention\S* = \S+ custom-call\(",
+                            line)]
+    assert len(kernels) == 2
+    steps = [line for line in text.splitlines()
+             if re.search(r"%gated_delta_step\S* = .* custom-call\(", line)]
+    assert len(steps) == 6              # one a Gated DeltaNet layer
+    mem = compiled.memory_analysis()
+    state = 32 * 128 * 128 * 4 + 3 * 8192 * 2
+    assert mem.alias_size_in_bytes == 256 * (
+        2 * 2 * 4096 * 2 * 256 * 2 + 6 * state)
+    assert 11.5e9 < mem.argument_size_in_bytes < 11.6e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert not re.search(r"f32\[256,32,128,128\]\S* copy\(", text)
+    assert not re.search(r"bf16\[256,8192,256\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("batch, bucket", [(2, 256), (1, 1024), (2, 4096)])
+def test_qwen3_next_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
+                                                      bucket, monkeypatch):
+    """The ladder's first bucket, one tile (the einsum chain's last) and
+    the fullest program: the chunked scan and its triangular solve
+    compile, attention past one tile is the TPU's kernel at heads of 256,
+    and weights, cache and activations fit the chip."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _qwen3_next_programs(topo)
+    compiled = jax.jit(lambda *args: eng._prefill_bucketed.__wrapped__(
+        model, *args), donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(batch, bucket), i32(batch), i32(batch)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert ("splash" in compiled.as_text()) == (
+        bucket > attention_op.ATTN_BLOCK)
+    assert bucket in model.prefill_buckets(4096)
