@@ -166,7 +166,7 @@ class AfmoeBlock(nn.Module):
 
     # --- feed-forward --------------------------------------------------------
     def _ffn(self, h, live):
-        """h [..., d], live [...] or None -> (h', stats int32[3])."""
+        """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
         m = _rms(h, self.norm_pre_mlp, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
@@ -179,6 +179,7 @@ class AfmoeBlock(nn.Module):
                                route_norm=c.route_norm)
             f, stats = moe.expert_ffn(
                 m, sel, w, *cast(self.held), first_expert=c.first_expert,
+                experts_known=self.router.shape[1],
                 live=None if live is None else live.reshape(-1))
             with jax.named_scope("moe.shared"):
                 f = f + moe.gated_ffn(m, *cast(self.shared))

@@ -260,7 +260,7 @@ class Qwen3NextBlock(nn.Module):
 
     # --- feed-forward ------------------------------------------------------
     def _ffn(self, h, live):
-        """h [..., d], live [...] or None -> (h', stats int32[3])."""
+        """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
         m = _rms0(h, self.norm_post, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
@@ -269,6 +269,7 @@ class Qwen3NextBlock(nn.Module):
                            score_func="softmax")
         f, stats = moe.expert_ffn(
             m, sel, w, *cast(self.held), first_expert=c.first_expert,
+            experts_known=self.router.shape[1],
             live=None if live is None else live.reshape(-1))
         with jax.named_scope("moe.shared"):
             gate = jax.nn.sigmoid(jnp.dot(

@@ -18,16 +18,31 @@ The experts (:func:`expert_ffn`) are SiLU-gated, ``(silu(x @ G) * (x @
 U)) @ D``.  All (token, expert) pairs are sorted by expert, those on held
 experts first; pairs on absent experts and pairs of dead tokens (padding,
 parked slots) sort last, into no group.  The sorted rows are walked in
-blocks of :data:`BLOCK_ROWS`, only as many blocks as the held pairs fill
-(a loop whose trip count the routing decides): each block gathers its
-tokens, goes through three grouped products (``jax.lax.ragged_dot``,
-which XLA:TPU lowers to a grouped-matmul kernel that reads only the
-experts a block has rows for but multiplies every row it is handed —
-which is why it is handed held pairs only), and adds its weighted rows
-onto their tokens.  No pair is ever dropped: the walk is as long as the
-held pairs need, so any imbalance — every token on one expert — fits.
-The same code serves prefill (thousands of pairs, a few blocks) and
-decode (a few dozen pairs, one block, no loop).
+blocks, only as many blocks as the held pairs fill (a loop whose trip
+count the routing decides): each block gathers its tokens, goes through
+three grouped products (``jax.lax.ragged_dot``, which XLA:TPU lowers to
+a grouped-matmul kernel that reads only the experts a block has rows for
+but multiplies every row it is handed, in row tiles it chooses from the
+block's size — which is why it is handed held pairs only, and no more
+rows than they are likely to need), and adds its weighted rows onto
+their tokens.  No pair is ever dropped: the walk is as long as the held
+pairs need, so any imbalance — every token on one expert — fits, in more
+trips.
+
+**How many rows a block has** (:func:`block_rows`) follows from what a
+call observes — its pairs, the experts held and the experts the router
+knows: twice the pairs that even routing puts on held experts, in whole
+tiles of :data:`ROW_TILE`, at most :data:`BLOCK_ROWS`.  A call whose
+pairs all fit that block runs it once, with no loop: a token step of a
+few dozen slots (32 slots x 4 picks over 32 of 256 experts: 128 rows).
+Every other call loops: a token step of many slots (256 x 10 picks over
+64 of 512 experts: 2,560 pairs, ~320 of them held, blocks of 640 rows,
+one trip unless the routing is skewed), and prefill (thousands of held
+pairs, a few blocks of ``BLOCK_ROWS``; a short bucket gets the block
+its own pairs call for).  On a TPU v5e a product over 64 experts of
+``[2048, 512]`` with ~5 rows an expert takes 0.39 ms in a block of 2,048
+rows and 0.21 ms in one of 640, where reading the experts once takes
+0.16 (PERF.md section 6, PR 35).
 """
 
 from __future__ import annotations
@@ -35,15 +50,32 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-#: Sorted (token, expert) rows that go through the grouped products at a
-#: time.  At d = f = 3072 a block's gathered rows, its two hidden arrays
-#: and its output are 2048 x 4 x 3072 x 2 B = 50 MB; a block spans the
-#: few experts its rows belong to, so over a walk each touched expert's
-#: weights are read about once.
+#: The most sorted (token, expert) rows that go through the grouped
+#: products at a time: the cap of :func:`block_rows`, reached by the
+#: longer prefill buckets (from 1,024 positions at top-10 over 64 of 512
+#: experts, from 2,048 at top-4 over 32 of 256).  At d = f = 3072 such
+#: a block's gathered rows, its two hidden arrays and its output are
+#: 2048 x 4 x 3072 x 2 B = 50 MB; it spans the few experts its rows
+#: belong to, so over a walk each touched expert's weights are read
+#: about once.
 BLOCK_ROWS = 2048
 
+#: A block is a whole number of these rows (a lane tile; the grouped
+#: products' kernels walk rows in multiples of it).
+ROW_TILE = 128
+
 #: What :func:`expert_ffn` counts, in this order, as one int32 vector.
-STATS = ("pairs_held", "pairs_absent", "experts_touched")
+STATS = ("pairs_held", "pairs_absent", "experts_touched", "rows_walked")
+
+
+def block_rows(pairs: int, held: int, known: int) -> int:
+    """Sorted rows a block hands the grouped products when ``pairs``
+    (token, expert) pairs are routed over ``known`` experts of which
+    ``held`` are here: twice the pairs even routing puts on held experts,
+    in whole row tiles, never more than :data:`BLOCK_ROWS` nor than there
+    are pairs."""
+    room = -(-2 * pairs * held // known)
+    return min(BLOCK_ROWS, pairs, -(-room // ROW_TILE) * ROW_TILE)
 
 
 def route(m, router_kernel, router_bias, *, top_k: int, route_scale: float,
@@ -73,14 +105,16 @@ def _swiglu(g, u, dtype):
             * u.astype(jnp.float32)).astype(dtype)
 
 
-def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int, live=None):
+def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int,
+               experts_known: int, live=None):
     """The held experts' part of the layer: ``m [N, d]``, ``sel``/``w``
-    from :func:`route`, ``gate``/``up`` ``[E, d, f]`` and ``down`` ``[E,
-    f, d]`` the held experts ``first_expert .. first_expert + E - 1``,
-    ``live [N]`` false for tokens that are padding.  Returns ``(y [N,
-    d], stats int32[3])`` — the pairs computed here, the live pairs left
-    to absent experts, and the held experts that got at least one pair
-    (:data:`STATS`)."""
+    from :func:`route` over ``experts_known`` experts, ``gate``/``up``
+    ``[E, d, f]`` and ``down`` ``[E, f, d]`` the held experts
+    ``first_expert .. first_expert + E - 1``, ``live [N]`` false for
+    tokens that are padding.  Returns ``(y [N, d], stats int32[4])`` —
+    the pairs computed here, the live pairs left to absent experts, the
+    held experts that got at least one pair, and the sorted rows the walk
+    handed to the grouped products (:data:`STATS`)."""
     N, k = sel.shape
     E = gate.shape[0]
     with jax.named_scope("moe.experts"):
@@ -92,9 +126,14 @@ def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int, live=None):
         sizes = jnp.sum(key[:, None] == jnp.arange(E, dtype=key.dtype)[None],
                         axis=0, dtype=jnp.int32)        # pairs per expert
         held = jnp.sum(sizes)
+        rows = block_rows(N * k, E, experts_known)
+        # One block where every pair fits it, else as many as the held
+        # pairs fill.
+        one_block = N * k <= rows
+        blocks = 1 if one_block else (held + rows - 1) // rows
         stats = jnp.stack([held, jnp.sum(alive & ~here, dtype=jnp.int32),
-                           jnp.sum(sizes > 0, dtype=jnp.int32)])
-        rows = min(BLOCK_ROWS, N * k)
+                           jnp.sum(sizes > 0, dtype=jnp.int32),
+                           blocks * rows])
         order = jnp.argsort(key, stable=True)           # held pairs first
         order = jnp.pad(order, (0, -(N * k) % rows))
         ends = jnp.cumsum(sizes)
@@ -121,10 +160,10 @@ def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int, live=None):
             return y.at[token].add(o)
 
         y = jnp.zeros(m.shape, jnp.float32)
-        if N * k <= rows:
+        if one_block:
             y = block(0, y)
         else:
-            y = jax.lax.fori_loop(0, (held + rows - 1) // rows, block, y)
+            y = jax.lax.fori_loop(0, blocks, block, y)
         return y.astype(m.dtype), stats
 
 

@@ -133,6 +133,10 @@ _MOE_PAIRS = obs_metrics.counter(
     "moe_pairs_total", "(token, expert) pairs the live tokens of prefill "
     "and decode programs routed, by where the expert is: held (computed "
     "here) or absent (another share's)")
+_MOE_ROWS = obs_metrics.counter(
+    "moe_rows_walked_total", "sorted rows the expert walks of prefill and "
+    "decode programs handed to the grouped products (blocks walked x rows "
+    "a block): what moe_pairs_total{where=held} fills")
 _MOE_TOUCHED = obs_metrics.counter(
     "moe_experts_touched_total", "held experts that got at least one "
     "pair, summed over expert layers and decode steps")
@@ -727,9 +731,10 @@ class DecodeEngine:
         the model's counts behind them (``ops/moe.STATS``; none from a
         model without experts), and add the counts to the counters."""
         if len(out) > n:
-            held, absent, touched = (int(x) for x in out[n:])
+            held, absent, touched, walked = (int(x) for x in out[n:])
             _MOE_PAIRS.labels(where="held").inc(held)
             _MOE_PAIRS.labels(where="absent").inc(absent)
+            _MOE_ROWS.inc(walked)
             if decode:
                 _MOE_TOUCHED.inc(touched)
                 _MOE_SLOTS.inc(self.smodel.expert_slots)

@@ -340,7 +340,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(monkeypatch, rows):
         held = slice(2 * rank, 2 * rank + 2)
         part, stats = moe.expert_ffn(
             m, sel, w, p["experts_gate"][held], p["experts_up"][held],
-            p["experts_down"][held], first_expert=2 * rank)
+            p["experts_down"][held], first_expert=2 * rank,
+            experts_known=16)
         # ... and each share is the reference's share.
         _, theirs = ref.expert_layer(
             m, {**p, **{k: p[k][held] for k in (
@@ -367,11 +368,86 @@ def test_no_pair_is_dropped_when_every_token_routes_to_one_expert(
     held = slice(4, 8)
     got, stats = moe.expert_ffn(
         m, sel, w, p["experts_gate"][held], p["experts_up"][held],
-        p["experts_down"][held], first_expert=4)
+        p["experts_down"][held], first_expert=4, experts_known=16)
     want = 0.7 * moe.gated_ffn(m, p["experts_gate"][5], p["experts_up"][5],
                                p["experts_down"][5])
     assert np.abs(np.asarray(got - want)).max() < TOL
-    assert stats.tolist() == [70, 70, 1]
+    assert stats.tolist() == [70, 70, 1, 128 if rows == 2048 else 5 * 16]
+
+
+def test_no_pair_is_dropped_when_a_whole_token_step_routes_to_one_expert():
+    """Qwen3-Next's token step at its worst: 256 slots x 10 picks, all
+    2,560 pairs on ONE of 64 held experts (of 512).  The rule sizes the
+    block for even routing (640 rows), so the walk takes four trips and
+    every pair is computed."""
+    rng = np.random.default_rng(5)
+    normal = lambda *s: jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+    gate, up, down = normal(64, 32, 16), normal(64, 32, 16), normal(64, 16, 32)
+    m = normal(256, 32) * 5
+    assert moe.block_rows(2560, 64, 512) == 640
+    got, stats = moe.expert_ffn(
+        m, jnp.full((256, 10), 5, jnp.int32),
+        jnp.full((256, 10), 0.1, jnp.float32), gate, up, down,
+        first_expert=0, experts_known=512)
+    want = moe.gated_ffn(m, gate[5], up[5], down[5])
+    assert np.abs(np.asarray(got - want)).max() < 5e-5
+    assert stats.tolist() == [2560, 0, 1, 4 * 640]
+
+
+#: (pairs = tokens x top-k, experts held, experts the router knows) at the
+#: benchmark cells' real shapes, and the rows of a block.
+BLOCKS = {
+    "trinity token step, 32 slots": (32 * 4, 32, 256, 128),
+    "trinity prefill (1024, 1)": (1024 * 4, 32, 256, 1024),
+    "trinity prefill (8192, 1)": (8192 * 4, 32, 256, 2048),
+    "trinity prefill (4096, 2)": (2 * 4096 * 4, 32, 256, 2048),
+    "qwen3_next token step, 256 slots": (256 * 10, 64, 512, 640),
+    "qwen3_next prefill (256, 1)": (256 * 10, 64, 512, 640),
+    "qwen3_next prefill (1024, 1)": (1024 * 10, 64, 512, 2048),
+    "qwen3_next prefill (4096, 2)": (2 * 4096 * 10, 64, 512, 2048),
+}
+
+
+@pytest.mark.parametrize("shape", BLOCKS)
+def test_a_block_is_twice_the_pairs_even_routing_holds_here(shape):
+    """The rule at the cells' shapes: twice the expected held pairs in
+    whole row tiles, capped by ``BLOCK_ROWS`` and by the pairs there
+    are."""
+    pairs, held, known, want = BLOCKS[shape]
+    assert moe.block_rows(pairs, held, known) == want
+    assert want % moe.ROW_TILE == 0 and want <= moe.BLOCK_ROWS
+
+
+@pytest.mark.parametrize("tile", [8, 16, 64, 128])
+def test_the_layer_is_the_same_whichever_block_the_rule_picks(monkeypatch,
+                                                              tile):
+    """200 tokens x 3 picks over 32 experts, 4 held: even routing puts 75
+    pairs here, so the rule picks blocks of 152, 160, 192 and 256 rows
+    for row tiles of 8 to 128 (one to several trips); the result and the
+    first three counts do not depend on it, and the fourth is the trips x
+    the rows."""
+    rng = np.random.default_rng(11)
+    normal = lambda *s: jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+    gate, up, down = normal(4, 32, 16), normal(4, 32, 16), normal(4, 16, 32)
+    m = normal(200, 32) * 5
+    # A skewed routing: expert 9 (held: 8..11) is picked by every token.
+    sel = jnp.asarray(np.stack([np.full(200, 9), rng.integers(0, 8, 200),
+                                rng.integers(10, 32, 200)], 1), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (200, 3)), jnp.float32)
+    live = jnp.arange(200) < 190
+    run = lambda: moe.expert_ffn(m, sel, w, gate, up, down, first_expert=8,
+                                 experts_known=32, live=live)
+    monkeypatch.setattr(moe, "ROW_TILE", 600)       # one block: every pair
+    want, counts = run()
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    rows = moe.block_rows(600, 4, 32)
+    assert rows == -(-150 // tile) * tile
+    got, stats = run()
+    assert np.abs(np.asarray(got - want)).max() < 5e-5
+    held = int(counts[0])
+    assert held > rows or tile == 128               # several trips
+    assert stats.tolist() == counts.tolist()[:3] + [-(-held // rows) * rows]
+    assert counts.tolist()[3] == 600
 
 
 def test_the_counts_are_what_a_hand_made_routing_says():
@@ -384,20 +460,21 @@ def test_the_counts_are_what_a_hand_made_routing_says():
     _, stats = moe.expert_ffn(
         m, sel, jnp.ones((6, 2), jnp.float32), p["experts_gate"][held],
         p["experts_up"][held], p["experts_down"][held], first_expert=4,
-        live=live)
+        experts_known=16, live=live)
     # live pairs on 4..7: (4,5) (4) () (7,4) = 5, on absent: 3; experts
     # 4, 5 and 7 got a pair, 6 only from padding.
-    assert stats.tolist() == [5, 3, 3]
+    assert stats.tolist() == [5, 3, 3, 12]       # one block of all 12 pairs
 
 
 def test_the_engines_counters_follow_the_programs_counts(params, sequences):
     """``moe_pairs_total`` adds up to tokens x top-k x expert layers,
-    ``moe_expert_slots_total`` to held experts x expert layers a decode
-    step, and ``serve_cache_rows_read_total`` to the rows the busy slots'
-    positions reach, a ring cutting them at 8."""
+    ``moe_rows_walked_total`` to the rows of the blocks the walks handed
+    to the grouped products, ``moe_expert_slots_total`` to held experts x
+    expert layers a decode step, and ``serve_cache_rows_read_total`` to
+    the rows the busy slots' positions reach, a ring cutting them at 8."""
     names = ['moe_pairs_total{where="held"}',
              'moe_pairs_total{where="absent"}', "moe_expert_slots_total",
-             "moe_experts_touched_total",
+             "moe_experts_touched_total", "moe_rows_walked_total",
              'serve_cache_rows_read_total{kind="full"}',
              'serve_cache_rows_read_total{kind="window"}']
     before = [_counter(n) for n in names]
@@ -405,12 +482,16 @@ def test_the_engines_counters_follow_the_programs_counts(params, sequences):
     engine.prefill_many([(0, sequences[0, :5], 1), (2, sequences[1, :19], 1)])
     engine.decode(busy=[0, 2])          # positions 5 and 19
     engine.decode(busy=[2])             # position 20; slot 0 still live
-    held, absent, slots, touched, full, window = (
+    held, absent, slots, touched, walked, full, window = (
         _counter(n) - b for n, b in zip(names, before))
     # prefill: 24 prompt tokens; two decode steps of two live slots (a
     # slot not advanced still holds a request); 2 choices, 3 expert layers
     assert held + absent == (24 + 2 + 2) * 2 * 3
     assert slots == 2 * 4 * 3 and 0 < touched <= slots
+    # every program's pairs fit one block: prompts padded to buckets of 8
+    # and 32 positions, two steps of three slots (a parked slot's pairs
+    # sort past the held ones but are rows of the block)
+    assert walked == (8 + 32 + 2 * 3) * 2 * 3
     assert full == (6 + 20) + 21                    # one full layer
     assert window == 3 * ((6 + 8) + 8)              # three rings of 8
     gauges = obs_metrics.registry().snapshot()["gauges"]

@@ -374,7 +374,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         held = slice(2 * rank, 2 * rank + 2)
         part, stats = moe.expert_ffn(
             m, sel, w, p["experts_gate"][held], p["experts_up"][held],
-            p["experts_down"][held], first_expert=2 * rank)
+            p["experts_down"][held], first_expert=2 * rank,
+            experts_known=16)
         _, theirs = ref.expert_layer(       # ... the reference's share
             m, {**p, **{k: p[k][held] for k in (
                 "experts_gate", "experts_up", "experts_down")}},

@@ -101,6 +101,13 @@ def test_sync_step_over_four_chips_runs_the_kernels_per_shard(
     assert not re.search(r"\[\d+,12,1024,1024\]", text)
 
 
+def _grouped_products(text: str) -> list:
+    """``(rows, columns)`` of every grouped product's result in a
+    compiled program's text (XLA:TPU's ``ragged_dot`` kernel)."""
+    return [(int(r), int(c)) for r, c in re.findall(
+        r"%ragged-dot-none\S* = bf16\[(\d+),(\d+)\]", text)]
+
+
 # ---- the afmoe share at the benchmark cell's own sizes ---------------------
 
 def _trinity_programs(topo, slots=32, cache_len=16384):
@@ -211,6 +218,10 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
                             line)]
     assert len(kernels) == 5
     assert all('custom_call_target="tpu_custom_call"' in k for k in kernels)
+    # 32 slots x 4 picks are one block of 128 sorted rows, no loop: the
+    # walk's rule (ops/moe.block_rows) leaves this step as it was.
+    assert _grouped_products(text) == [(128, 3072)] * 12
+    assert "moe.experts/while" not in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
     assert mem.temp_size_in_bytes < 0.5e9
@@ -246,8 +257,9 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
                                                           monkeypatch):
     """The cell's decode program built for a TPU: the ragged kernel in
     both attention layers over the flat rows of two K/V heads, the
-    recurrence's kernel in the six Gated DeltaNet layers, every
-    layer's rows AND states aliased onto their inputs (7.6 GB: the
+    recurrence's kernel in the six Gated DeltaNet layers, the experts'
+    grouped products over blocks sized to the pairs this share holds,
+    every layer's rows AND states aliased onto their inputs (7.6 GB: the
     recurrent state is updated in place), no copy of a state- or
     cache-sized array, and small temporaries beside 11.5 GB of weights
     and cache."""
@@ -266,6 +278,12 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
     steps = [line for line in text.splitlines()
              if re.search(r"%gated_delta_step\S* = .* custom-call\(", line)]
     assert len(steps) == 6              # one a Gated DeltaNet layer
+    # 256 slots x 10 picks = 2,560 pairs, ~320 of them on the 64 held
+    # experts: the walk hands the grouped products blocks of 640 sorted
+    # rows (ops/moe.block_rows), not prefill's 2,048.
+    products = _grouped_products(text)
+    assert sorted(set(products)) == [(640, 512), (640, 2048)]
+    assert len(products) == 3 * 8
     mem = compiled.memory_analysis()
     state = 32 * 128 * 128 * 4 + 3 * 8192 * 2
     assert mem.alias_size_in_bytes == 256 * (
