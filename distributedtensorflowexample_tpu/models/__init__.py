@@ -55,10 +55,14 @@ def build_model_from_config(config, **kw):
         from distributedtensorflowexample_tpu.models.qwen3_next import (
             build_qwen3_next)
         return build_qwen3_next(config, **kw)
+    if kind == "bailing_hybrid":
+        from distributedtensorflowexample_tpu.models.bailing_hybrid import (
+            build_bailing_hybrid)
+        return build_bailing_hybrid(config, **kw)
     raise ValueError(
         f"no model is built from a configuration of model_type {kind!r} "
-        f"(have: afmoe, qwen3_next; the GPT-2 ladder is built by size, "
-        f"LM_SIZES)")
+        f"(have: afmoe, bailing_hybrid, qwen3_next; the GPT-2 ladder is "
+        f"built by size, LM_SIZES)")
 
 
 __all__ = ["SoftmaxRegression", "MnistCNN", "ResNet20", "ResNetCIFAR",

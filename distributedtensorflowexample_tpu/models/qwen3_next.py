@@ -81,7 +81,8 @@ import jax.numpy as jnp
 from distributedtensorflowexample_tpu.ops import linear_attention as la
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
-    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention)
+    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention,
+    tile_ladder)
 
 F32 = jnp.float32
 
@@ -429,16 +430,7 @@ class Qwen3NextLM(nn.Module):
         the chunked scan for whole chunks, which a tile is), ``cache_len``
         last.  ``None`` (the engine's powers of two) for a cache shorter
         than that first bucket."""
-        tile = self.attn_block
-        if cache_len <= 256:
-            return None
-        small = [b for b in (256, 512, 1024) if b < min(tile, cache_len)]
-        tiles = [t * tile for t in (1, 2, 3) if t * tile < cache_len]
-        t = 4
-        while t * tile < cache_len:
-            tiles.append(t * tile)
-            t *= 2
-        return tuple(small + tiles) + (cache_len,)
+        return tile_ladder(cache_len, self.attn_block)
 
     def decode_fetch_block(self, rows: int) -> int:
         """Rows the decode step's attention fetches at a time from a

@@ -33,6 +33,19 @@ tier-1 run keeps its bits.  ``serve_decode_attention_total{impl=...}``
 says which was taken, once per call traced (one call per layer), and
 :func:`decode_fetch_block` tells the engine's
 ``serve_cache_rows_fetched_total`` how many rows that is.
+
+**Latent attention** (a layer whose cache is ONE compressed row a
+position, shared by every head) has the same two shapes.  A sequence is
+attended in the EXPANDED form — the model expands each row to per-head
+keys and values, the keys wider than the values, and
+:func:`grouped_attention` takes them —; the token step in the ABSORBED
+form, :func:`latent_decode_attention`: the query is taken into the
+row's space, the row is the key of every head and its leading features
+the value of every head, so a slot's rows are read once and never
+expanded — on a TPU by the ragged kernel's sibling
+(``ops/pallas/decode_attention.latent_decode_attention``), elsewhere
+by the einsum chain.  ``lm_latent_attention_total{impl=...}`` counts
+the calls traced, by form.
 """
 
 from __future__ import annotations
@@ -50,7 +63,13 @@ _BLOCKS = obs_metrics.counter(
 _DECODE = obs_metrics.counter(
     "serve_decode_attention_total",
     "decode_attention calls traced (one per layer of a token-step "
-    "program), by the implementation taken: ragged | einsum")
+    "program), by the implementation taken: ragged | latent | einsum")
+_LATENT = obs_metrics.counter(
+    "lm_latent_attention_total",
+    "latent-attention calls traced (one per latent layer of a program), by "
+    "the form taken: expanded (a sequence: per-head keys and values made "
+    "from the rows) | absorbed (one token a slot against the rows "
+    "themselves)")
 
 
 def einsum_causal_attention(q, k, v):
@@ -152,12 +171,31 @@ def _scores(q, k, ok):
     return jnp.where(ok, s * q.shape[-1] ** -0.5, _MASKED)
 
 
-def takes_splash(q_shape: tuple, block: int) -> bool:
+def takes_splash(q_shape: tuple, block: int, v_dim: int | None = None) -> bool:
     """Built for a TPU, longer than one tile, and shapes the kernel
-    tiles: whole blocks of positions, heads of whole lane groups."""
+    tiles: whole blocks of positions, value heads of whole lane groups
+    (``v_dim``; the query's width where not given).  Query and key heads
+    that are no whole lane groups are padded to them with zeros."""
     _, t, _, dh = q_shape
     return (jax.default_backend() == "tpu" and t > block
-            and t % block == 0 and dh % 128 == 0)
+            and t % block == 0 and (v_dim or dh) % 128 == 0)
+
+
+def tile_ladder(cache_len: int, tile: int = ATTN_BLOCK):
+    """A ladder of prefill lengths for a model whose prompts cost their
+    matmuls and little else below one attention tile: powers of two from
+    256 up to a tile, then whole tiles (:func:`takes_splash` asks for
+    that) — one, two, three, then doubling —, ``cache_len`` last.
+    ``None`` for a cache no longer than the first bucket."""
+    if cache_len <= 256:
+        return None
+    small = [b for b in (256, 512, 1024) if b < min(tile, cache_len)]
+    tiles = [t * tile for t in (1, 2, 3) if t * tile < cache_len]
+    t = 4
+    while t * tile < cache_len:
+        tiles.append(t * tile)
+        t *= 2
+    return tuple(small + tiles) + (cache_len,)
 
 
 def splash_grouped_attention(q, k, v, *, window: int = 0,
@@ -167,33 +205,42 @@ def splash_grouped_attention(q, k, v, *, window: int = 0,
     attention: an online softmax over key blocks held in VMEM, blocks
     the causal or window mask empties never visited), one call per
     key/value head over its group of query heads.  The kernel applies
-    no scale: q is scaled first, in its own type."""
+    no scale: q is scaled first, in its own type.  Query/key heads of
+    192 features (latent attention's expanded form) go as 256, the last
+    64 zeros: the scores are the same."""
     # Imported where the kernel is taken (jax.experimental.pallas costs
     # every CPU run a second or two to import).
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
     B, T, Hq, Dh = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     G = Hq // Hkv
+    q = (q * Dh ** -0.5).astype(q.dtype)
+    if Dh % 128:
+        q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, -Dh % 128),))
+                for a in (q, k))
+        Dh = q.shape[3]
     mask = (masks.LocalMask((T, T), (window - 1, 0), 0) if window
             else masks.CausalMask((T, T)))
     attend = kernel.make_splash_mqa_single_device(
         masks.MultiHeadMask([mask] * G), interpret=interpret,
         block_sizes=kernel.BlockSizes(block_q=block, block_kv=block,
                                       block_kv_compute=block))
-    q = (q * Dh ** -0.5).astype(q.dtype).reshape(B, T, Hkv, G, Dh)
+    q = q.reshape(B, T, Hkv, G, Dh)
     out = jax.vmap(jax.vmap(attend))(           # over B, then over Hkv
         jnp.transpose(q, (0, 2, 3, 1, 4)),      # [B, Hkv, G, T, Dh]
         jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, T, Hq, Dh)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, T, Hq, Dv)
 
 
 def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
     """Causal softmax attention with fewer key/value heads than query
-    heads: ``q [B, T, Hq, Dh]``, ``k``/``v`` ``[B, T, Hkv, Dh]``, query
-    head i reading key/value head ``i // (Hq // Hkv)``; with ``window``
-    a query sees only the last ``window`` positions, itself included.
-    Returns ``[B, T, Hq, Dh]``.
+    heads: ``q [B, T, Hq, Dh]``, ``k`` ``[B, T, Hkv, Dh]``, ``v`` ``[B,
+    T, Hkv, Dv]`` (``Dv`` is ``Dh`` in most models; latent attention's
+    expanded keys are wider than its values), query head i reading
+    key/value head ``i // (Hq // Hkv)``; with ``window`` a query sees
+    only the last ``window`` positions, itself included.  Returns ``[B,
+    T, Hq, Dv]``.
 
     One path that adapts to what the call can observe, as
     :func:`causal_attention` does: a sequence of at most ``block``
@@ -208,15 +255,15 @@ def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
     the probabilities in the operands' type.  Both are forward only (the
     serving prefill and a forward at a training shape)."""
     B, T, Hq, Dh = q.shape
-    Hkv = k.shape[2]
-    if takes_splash(q.shape, block):
+    Hkv, Dv = k.shape[2], v.shape[3]
+    if takes_splash(q.shape, block, Dv):
         return splash_grouped_attention(q, k, v, window=window, block=block)
     q = q.reshape(B, T, Hkv, Hq // Hkv, Dh)
     if T <= block:
         pos = jnp.arange(T)
         s = _scores(q, k, _visible(pos, pos, window))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhgts,bshd->bthgd", p, v).reshape(B, T, Hq, Dh)
+        return jnp.einsum("bhgts,bshd->bthgd", p, v).reshape(B, T, Hq, Dv)
 
     pad = -T % block
     if pad:     # padded keys lie after every real query: never visible
@@ -247,13 +294,13 @@ def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
                  if window else 0)
         init = (jnp.full((B, Hkv, G, block), _MASKED, jnp.float32),
                 jnp.zeros((B, Hkv, G, block), jnp.float32),
-                jnp.zeros((B, Hkv, G, block, Dh), jnp.float32))
+                jnp.zeros((B, Hkv, G, block, Dv), jnp.float32))
         _, l, acc = jax.lax.fori_loop(first, i + 1, key_tile, init)
-        return (acc / l[..., None]).astype(v.dtype)     # [B,Hkv,G,block,Dh]
+        return (acc / l[..., None]).astype(v.dtype)     # [B,Hkv,G,block,Dv]
 
     out = jax.lax.map(query_tile, jnp.arange((T + pad) // block))
-    out = jnp.moveaxis(out, 0, 3)               # [B, Hkv, G, nq, block, Dh]
-    out = out.reshape(B, Hq, T + pad, Dh)[:, :, :T]
+    out = jnp.moveaxis(out, 0, 3)               # [B, Hkv, G, nq, block, Dv]
+    out = out.reshape(B, Hq, T + pad, Dv)[:, :, :T]
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -318,3 +365,50 @@ def decode_attention(q, ck, cv, lengths):
     _DECODE.labels(impl="ragged").inc()
     return _ragged().ragged_decode_attention(
         q[:, 0], ck, cv, lengths[:, 0])[:, None]
+
+
+# --- latent attention: one shared row a position ----------------------------
+
+def latent_fetch_block(rows: int, width: int, v_dim: int) -> int:
+    """Cache rows :func:`latent_decode_attention` fetches at a time from
+    a latent layer of ``rows`` rows a slot, or 0 where it takes the
+    einsum chain, which reads every row (:func:`decode_fetch_block`'s
+    contract)."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return _ragged().latent_fetch_block(rows, width, v_dim)
+
+
+def latent_decode_attention(q, rows, lengths, *, v_dim: int, scale: float):
+    """The token step of a latent-attention layer, absorbed form: ``q [S,
+    H, D]`` (each head's query taken into the rows' space), ``rows [S, R,
+    D]`` as the engine holds them — ONE row a position: the key of every
+    head, and its first ``v_dim`` features the value of every head —,
+    ``lengths [S]`` the count of LEADING rows each slot's query sees.
+    Returns ``softmax(scale q rows^T) rows[..., :v_dim]``, ``[S, H,
+    v_dim]``.  Scores and softmax are float32, the probabilities are
+    cast to the rows' type for the second product, as in
+    :func:`decode_attention`; a slot's live rows only are read where
+    :func:`latent_fetch_block` finds a block."""
+    _LATENT.labels(impl="absorbed").inc()
+    R = rows.shape[1]
+    if latent_fetch_block(R, rows.shape[2], v_dim):
+        _DECODE.labels(impl="latent").inc()
+        return _ragged().latent_decode_attention(q, rows, lengths,
+                                                 v_dim=v_dim, scale=scale)
+    _DECODE.labels(impl="einsum").inc()
+    s = jnp.einsum("shd,srd->shr", q, rows,
+                   preferred_element_type=jnp.float32)
+    ok = jnp.arange(R)[None] < lengths[:, None]                 # [S, R]
+    p = jax.nn.softmax(jnp.where(ok[:, None], s * scale, _MASKED), axis=-1)
+    return jnp.einsum("shr,srd->shd", p.astype(rows.dtype),
+                      rows[..., :v_dim])
+
+
+def latent_expanded_attention(q, k, v, *, block: int = ATTN_BLOCK):
+    """A whole sequence of a latent-attention layer, expanded form: the
+    model has made per-head keys ``k [B, T, H, Dh]`` and values ``v [B,
+    T, H, Dv]`` from the rows; :func:`grouped_attention` with the call
+    counted."""
+    _LATENT.labels(impl="expanded").inc()
+    return grouped_attention(q, k, v, block=block)

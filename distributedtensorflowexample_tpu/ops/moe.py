@@ -12,7 +12,13 @@ bias`` are selected, and the weights are the selected scores themselves
 (the bias only selects), normalised over the ``k`` and scaled.  The
 softmax form: ``s = softmax(m @ Wr)`` over all the experts, the top
 ``k`` of ``s`` (this form has no bias), the weights the selected
-probabilities, normalised over the ``k``.
+probabilities, normalised over the ``k``.  Either form may be limited
+to groups (``n_group`` > 1): the experts lie in ``n_group`` equal
+groups in id order, a group's score is the sum of its two best
+selecting scores, only the ``topk_group`` best groups stay, and the top
+``k`` are taken among their experts — a token then reaches the chips of
+at most ``topk_group`` groups where each group is one chip's experts.
+``n_group`` 1 is no limit, and the same program as before there was one.
 
 The experts (:func:`expert_ffn`) are SiLU-gated, ``(silu(x @ G) * (x @
 U)) @ D``.  All (token, expert) pairs are sorted by expert, those on held
@@ -79,11 +85,13 @@ def block_rows(pairs: int, held: int, known: int) -> int:
 
 
 def route(m, router_kernel, router_bias, *, top_k: int, route_scale: float,
-          route_norm: bool = True, score_func: str = "sigmoid"):
+          route_norm: bool = True, score_func: str = "sigmoid",
+          n_group: int = 1, topk_group: int = 1):
     """``m [N, d]`` -> ``(sel [N, k] int32, w [N, k] f32)``: the experts
     each token selects among ALL ``router_kernel.shape[1]`` and the
     weight of each, by the form ``score_func`` names (``router_bias``
-    None: nothing but the scores selects).  Scores in float32 (the
+    None: nothing but the scores selects), among the ``topk_group`` best
+    of ``n_group`` groups where ``n_group`` > 1.  Scores in float32 (the
     products of bfloat16 operands are exact there)."""
     if score_func not in ("sigmoid", "softmax"):
         raise ValueError(f"unknown score_func {score_func!r}")
@@ -91,9 +99,14 @@ def route(m, router_kernel, router_bias, *, top_k: int, route_scale: float,
         s = jnp.dot(m, router_kernel, preferred_element_type=jnp.float32)
         s = (jax.nn.sigmoid(s) if score_func == "sigmoid"
              else jax.nn.softmax(s, axis=-1))
-        _, sel = jax.lax.top_k(
-            s if router_bias is None
-            else s + router_bias.astype(jnp.float32), top_k)
+        c = s if router_bias is None else s + router_bias.astype(jnp.float32)
+        if n_group > 1:
+            groups = c.reshape(c.shape[0], n_group, -1)
+            best = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+            last = jax.lax.top_k(best, topk_group)[0][:, -1:]   # [N, 1]
+            c = jnp.where((best >= last)[:, :, None], groups,
+                          -jnp.inf).reshape(c.shape)
+        _, sel = jax.lax.top_k(c, top_k)
         w = jnp.take_along_axis(s, sel, axis=-1)
         if route_norm:
             w = w / jnp.sum(w, axis=-1, keepdims=True)

@@ -154,3 +154,94 @@ def ragged_decode_attention(q, ck, cv, lengths, *, block: int | None = None,
     )(jnp.clip(lengths.astype(jnp.int32), 1, R), q.reshape(S, Hq, Dh),
       ck.reshape(S, R * Hkv, Dh), cv.reshape(S, R * Hkv, Dh))
     return out.reshape(S, Hkv, G, Dh)
+
+
+# --- the latent sibling: ONE shared row a position, key and value ----------
+
+def latent_fetch_block(rows: int, width: int, v_dim: int) -> int:
+    """Rows per block where :func:`latent_decode_attention` takes a cache
+    of ``rows`` rows of ``width`` features whose first ``v_dim`` are the
+    values: the values whole lane groups and a block dividing the rows —
+    else 0."""
+    if v_dim % LANES or width < v_dim:
+        return 0
+    return pick_block(rows) or 0
+
+
+def _latent_kernel(len_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   block: int, scale: float):
+    slot, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[slot]
+    n_blocks = (length + block - 1) // block
+    v_dim = o_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j < n_blocks)
+    def _():
+        c = c_ref[...]                                          # [block, D]
+        s = jax.lax.dot_general(
+            q_ref[...], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [H, block]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        s = jnp.where(col < length - j * block, s, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc_ref[...] + jnp.dot(
+            p.astype(c.dtype), c[:, :v_dim],
+            preferred_element_type=jnp.float32)
+        m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
+
+        @pl.when(j == n_blocks - 1)
+        def _():
+            o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "scale", "block",
+                                             "interpret"))
+def latent_decode_attention(q, rows, lengths, *, v_dim: int, scale: float,
+                            block: int | None = None,
+                            interpret: bool | None = None):
+    """Single-query attention of ``H`` heads over ONE shared row a
+    position (latent attention's absorbed form): ``q [S, H, D]``,
+    ``rows [S, R, D]`` — a row is the key of every head, and its first
+    ``v_dim`` features the value of every head —, ``lengths [S]`` int32
+    in ``1..R`` the count of LEADING rows a slot's query sees.  Returns
+    ``softmax(scale q rows^T) rows[..., :v_dim]``, ``[S, H, v_dim]`` in
+    the rows' type.  The ragged kernel's walk: a slot's
+    ``ceil(length / block)`` blocks are fetched, each ONCE for scores
+    and values alike, and no others."""
+    S, H, D = q.shape
+    R = rows.shape[1]
+    block = pick_block(R, block)
+    if block is None:
+        raise ValueError(f"no block of {BLOCKS} divides {R} cache rows")
+
+    def row_map(s, j, lens):
+        return s, jnp.minimum(j, (lens[s] + block - 1) // block - 1), 0
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, block=block, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, R // block),
+            in_specs=[pl.BlockSpec((None, H, D), lambda s, j, lens: (s, 0, 0)),
+                      pl.BlockSpec((None, block, D), row_map)],
+            out_specs=pl.BlockSpec((None, H, v_dim),
+                                   lambda s, j, lens: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, v_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, v_dim), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+        name="latent_decode_attention",
+    )(jnp.clip(lengths.astype(jnp.int32), 1, R), q, rows)
+    return out

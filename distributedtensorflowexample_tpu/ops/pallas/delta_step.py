@@ -12,6 +12,15 @@ a GB of state): rows ``k``, ``q``, ``beta v``, and ``exp(g)`` and
 ``exp(g) beta`` broadcast over the row.  A slot that is not live gets
 ``exp(g) = 1`` and ``beta = 0``: its state comes back as it went in.
 
+The decay's two shapes (``ops/linear_attention.py``) are two bodies of
+the one kernel, chosen from ``g``'s rank when the call is traced.  With
+a decay a head the lines above hold as they are.  With a decay a key
+channel (``g [S, H, Dk]``) the tile's fourth row is ``exp(g)`` itself,
+one value a channel, and its fifth ``beta``; the row becomes a column as
+``k`` and ``q`` do, the state is scaled by it row by row once, and the
+scaled state serves both sums and the update: ``S^T k`` and ``S^T q``
+are then of ``Diag(exp(g)) S``.
+
 Inside, per head: a row vector lies along the lanes, and the products
 with the state need ``k`` and ``q`` along the SUBLANES (``S[i, j] *
 k[i]``); a row becomes a column by masking it with the identity and
@@ -47,7 +56,7 @@ def tiles(heads: int, dk: int, dv: int) -> bool:
     return dk == LANES and dv == LANES and heads % HEADS == 0
 
 
-def _kernel(x_ref, s_ref, o_ref, s_out_ref):
+def _kernel(x_ref, s_ref, o_ref, s_out_ref, *, by_channel: bool):
     dk = s_ref.shape[1]
     eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
@@ -58,12 +67,18 @@ def _kernel(x_ref, s_ref, o_ref, s_out_ref):
         k, q, bv, a, ab = (x[i:i + 1] for i in range(5))    # rows
         S = s_ref[h]                                        # [Dk, Dv]
         k_col = column(k)
+        kq = jnp.sum(k * q, axis=1, keepdims=True)
+        if by_channel:      # a: exp(g) a channel; ab: beta over the row
+            S = column(a) * S
         sk = jnp.sum(S * k_col, axis=0, keepdims=True)      # S^T k
         sq = jnp.sum(S * column(q), axis=0, keepdims=True)  # S^T q
         d = bv - ab * sk
-        o_ref[h:h + 1, :] = a * sq + jnp.sum(k * q, axis=1,
-                                             keepdims=True) * d
-        s_out_ref[h] = a * S + k_col * d
+        if by_channel:      # S is the decayed state already
+            o_ref[h:h + 1, :] = sq + kq * d
+            s_out_ref[h] = S + k_col * d
+        else:
+            o_ref[h:h + 1, :] = a * sq + kq * d
+            s_out_ref[h] = a * S + k_col * d
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",),
@@ -71,24 +86,28 @@ def _kernel(x_ref, s_ref, o_ref, s_out_ref):
 def delta_step(q, k, v, g, beta, state, live=None, *,
                interpret: bool | None = None):
     """:func:`ops.linear_attention.recurrent_step`'s contract: ``q``/``k``
-    ``[S, H, Dk]``, ``v`` ``[S, H, Dv]``, ``g``/``beta`` ``[S, H]``,
-    ``state`` ``[S, H, Dk, Dv]`` float32 (updated in place), ``live [S]``
-    -> ``(o [S, H, Dv] float32, state')``."""
+    ``[S, H, Dk]``, ``v`` ``[S, H, Dv]``, ``beta`` ``[S, H]``, ``g`` ``[S,
+    H]`` or ``[S, H, Dk]``, ``state`` ``[S, H, Dk, Dv]`` float32 (updated
+    in place), ``live [S]`` -> ``(o [S, H, Dv] float32, state')``."""
     S, H, Dk = q.shape
     Dv = v.shape[-1]
+    by_channel = g.ndim == q.ndim
     a = jnp.exp(g.astype(F32))
     beta = beta.astype(F32)
     if live is not None:
-        a = jnp.where(live[:, None], a, 1.0)
+        a = jnp.where(live.reshape((S,) + (1,) * (a.ndim - 1)), a, 1.0)
         beta = jnp.where(live[:, None], beta, 0.0)
     row = lambda s: jnp.broadcast_to(s[..., None], (S, H, LANES))
     x = jnp.stack([k.astype(F32), q.astype(F32),
-                   beta[..., None] * v.astype(F32), row(a), row(a * beta)]
+                   beta[..., None] * v.astype(F32)]
+                  + ([a, row(beta)] if by_channel
+                     else [row(a), row(a * beta)])
                   + [jnp.zeros((S, H, LANES), F32)] * (SUBLANES - 5), axis=2)
     vec = pl.BlockSpec((None, HEADS, Dv), lambda s, h: (s, h, 0))
     mat = pl.BlockSpec((None, HEADS, Dk, Dv), lambda s, h: (s, h, 0, 0))
     o, state = pl.pallas_call(
-        _kernel, grid=(S, H // HEADS),
+        functools.partial(_kernel, by_channel=by_channel),
+        grid=(S, H // HEADS),
         in_specs=[pl.BlockSpec((None, HEADS, SUBLANES, LANES),
                                lambda s, h: (s, h, 0, 0)), mat],
         out_specs=[vec, mat],

@@ -15,10 +15,14 @@ ONE decode step whose cache arguments are DONATED.
 (``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer) and allocates it
 (``init_cache(slots, cache_len)`` -> ``(ck, cv)``).  The engine holds
 that pair, donates it to every program and rebinds what comes back; it
-never looks inside.  There are three kinds of layer:
+never looks inside.  There are four kinds of layer:
 
 * ``full``: ``cache_len`` K/V rows a slot, a row a position;
 * ``window``: a ring of the last ``rows`` positions;
+* ``latent``: ``cache_len`` rows a slot addressed by position as
+  ``full``'s are, but ONE compressed row a position that every head
+  shares as key and as value (latent attention), and no V array: 576
+  features where 32 heads of keys and values would be 10,240;
 * ``state``: NO rows (``rows`` is 0) but a recurrent state whose size
   does not depend on ``cache_len``.  It is read AND written whole every
   step; a stale one cannot be masked as a stale row is, so the module's
@@ -32,7 +36,7 @@ never looks inside.  There are three kinds of layer:
 A module may also state the ladder of lengths its prompts are padded to
 (``prefill_buckets(cache_len)``); without one the engine pads to the
 next power of two (:func:`_prefill_buckets`).
-Three layouts exist today:
+Four layouts exist today:
 
 * ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
   Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
@@ -44,6 +48,11 @@ Three layouts exist today:
   its K and V rows, kept flat as ``[S, cache_len * Hkv, Dh]``, a Gated
   DeltaNet layer's its recurrent state ``[S, Hv, Dk, Dv]`` float32 and
   its convolution's last inputs.
+* ``models/bailing_hybrid.py``'s model (its own serving module): one
+  array a layer in each of ``ck`` and ``cv``; a latent-attention layer's
+  ``ck`` is its rows ``[S, cache_len, row]`` and its ``cv`` is empty, a
+  Kimi Delta Attention layer's are its recurrent state ``[S, H, D, D]``
+  float32 and its convolution's last inputs.
 * ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
   stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
   them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU
@@ -112,7 +121,8 @@ _PREFILL_PROGRAMS = obs_metrics.gauge(
 _CACHE_BYTES = obs_metrics.gauge(
     "serve_cache_bytes", "bytes of the engine's cache, by the kind of "
     "layer that holds them (full = cache_len K/V rows a slot, window = a "
-    "ring of rows, state = no rows but a recurrent state of a fixed size)")
+    "ring of rows, latent = cache_len compressed rows a slot that every "
+    "head shares, state = no rows but a recurrent state of a fixed size)")
 _STATE_BYTES = obs_metrics.counter(
     "serve_state_bytes_total", "bytes of recurrent state the decode steps "
     "moved, summed over steps: state layers x bytes a slot x 2 (a state "
@@ -121,8 +131,8 @@ _STATE_BYTES = obs_metrics.counter(
 _ROWS_READ = obs_metrics.counter(
     "serve_cache_rows_read_total", "cache rows the busy slots' queries "
     "attended, summed over decode steps and layers, by kind of layer (a "
-    "query at position p reads p + 1 rows of a full layer and min(p + 1, "
-    "rows) of a window layer's ring)")
+    "query at position p reads p + 1 rows of a full or a latent layer and "
+    "min(p + 1, rows) of a window layer's ring)")
 _ROWS_FETCHED = obs_metrics.counter(
     "serve_cache_rows_fetched_total", "cache rows the decode steps' "
     "attention fetched, summed over steps and layers, by kind of layer: "
@@ -591,7 +601,8 @@ class DecodeEngine:
         self._state_bytes_slot = 2 * by_kind.get("state", 0) // self.slots
         #: Layers whose cache is not ``cache_len`` rows by position.
         self.layers_without_rows_by_position = sum(
-            n for kind, (n, _) in kinds.items() if kind != "full")
+            n for kind, (n, _) in kinds.items()
+            if kind in _NO_ROWS_BY_POSITION)
         # Host-owned scalars-per-slot, uploaded per call (tiny): the
         # returned next-token array is the only per-step device output
         # besides the aliased caches.

@@ -313,3 +313,88 @@ def test_qwen3_next_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     assert ("splash" in compiled.as_text()) == (
         bucket > attention_op.ATTN_BLOCK)
     assert bucket in model.prefill_buckets(4096)
+
+
+# ---- the bailing_hybrid share at the benchmark cell's own sizes (PR 37) -----
+
+def _ling3_programs(topo, slots=256, cache_len=8192):
+    """The model of benchmarks/configs/ling3_flash_ep8.json with shapes
+    for its parameters and for a 256-slot, 8,192-row engine's cache —
+    latent rows of one layer, recurrent and convolution states of six —
+    all on the described chip."""
+    import os
+    from distributedtensorflowexample_tpu.models import (
+        build_model_from_config)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "ling3_flash_ep8.json"))
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ck, cv = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, cache_len)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+    return model, params, ck, cv, i32
+
+
+def test_ling3_decode_step_compiles_for_v5e_in_place(topo, uncached,
+                                                     monkeypatch):
+    """The cell's decode program built for a TPU: the latent kernel in
+    the one MLA layer over rows of 640 (one shared row a position, key
+    and value), the recurrence's kernel — its per-channel body — in the
+    six KDA layers, the experts' grouped products over blocks sized to
+    the pairs this share holds, the latent rows AND the states aliased
+    onto their inputs (6.0 GB, updated in place), no copy of a state- or
+    cache-sized array, and small temporaries beside 11.8 GB of weights
+    and cache."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _ling3_programs(topo)
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(256), i32(256)).compile()
+    text = compiled.as_text()
+    kernel = lambda name: [
+        line for line in text.splitlines()
+        if re.search(rf"%{name}\S* = .* custom-call\(", line)]
+    assert len(kernel("latent_decode_attention")) == 1
+    assert len(kernel("gated_delta_step")) == 6     # one a KDA layer
+    assert not kernel("ragged_decode_attention")
+    # 256 slots x 8 picks = 2,048 pairs, ~256 of them on the 64 held
+    # experts: blocks of 512 sorted rows (ops/moe.block_rows).
+    products = _grouped_products(text)
+    assert sorted(set(products)) == [(512, 768), (512, 2560)]
+    assert len(products) == 3 * 6
+    mem = compiled.memory_analysis()
+    state = 32 * 128 * 128 * 4 + 3 * 3 * 4096 * 2
+    assert mem.alias_size_in_bytes == 256 * (8192 * 640 * 2 + 6 * state)
+    assert 11.7e9 < mem.argument_size_in_bytes < 11.9e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert not re.search(r"f32\[256,32,128,128\]\S* copy\(", text)
+    assert not re.search(r"bf16\[256,8192,640\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("batch, bucket", [(2, 256), (1, 1024), (2, 4096)])
+def test_ling3_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
+                                                 bucket, monkeypatch):
+    """The ladder's first bucket, one tile (the einsum chain's last) and
+    the fullest program the cell warms: the chunked scan with its decays
+    in blocks of 16 and its triangular solve compile, latent attention
+    past one tile is the TPU's kernel at query/key heads of 192 padded to
+    256 and value heads of 128, and weights, cache and activations fit
+    the chip."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _ling3_programs(topo)
+    compiled = jax.jit(lambda *args: eng._prefill_bucketed.__wrapped__(
+        model, *args), donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(batch, bucket), i32(batch), i32(batch)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert ("splash" in compiled.as_text()) == (
+        bucket > attention_op.ATTN_BLOCK)
+    assert bucket in model.prefill_buckets(8192)
