@@ -26,29 +26,48 @@ experts first; pairs on absent experts and pairs of dead tokens (padding,
 parked slots) sort last, into no group.  The sorted rows are walked in
 blocks, only as many blocks as the held pairs fill (a loop whose trip
 count the routing decides): each block gathers its tokens, goes through
-three grouped products (``jax.lax.ragged_dot``, which XLA:TPU lowers to
-a grouped-matmul kernel that reads only the experts a block has rows for
-but multiplies every row it is handed, in row tiles it chooses from the
-block's size — which is why it is handed held pairs only, and no more
-rows than they are likely to need), and adds its weighted rows onto
-their tokens.  No pair is ever dropped: the walk is as long as the held
-pairs need, so any imbalance — every token on one expert — fits, in more
-trips.
+three grouped products (:func:`grouped_product`: each expert's rows times
+that expert's matrix, bfloat16 operands, float32 accumulation, the result
+in the operands' type), and adds its weighted rows onto their tokens.  No
+pair is ever dropped: the walk is as long as the held pairs need, so any
+imbalance — every token on one expert — fits, in more trips.
 
-**How many rows a block has** (:func:`block_rows`) follows from what a
-call observes — its pairs, the experts held and the experts the router
+**Which kernel runs a grouped product** (:func:`product_tiling`) follows
+from what the call observes — the backend, the block's rows, the expert
+matrix's shape and type — as ``ops/attention.py`` chooses its paths, each
+product for itself; ``moe_grouped_products_total{kernel}`` counts the
+products traced by the kernel taken.  Where the program is built for a
+TPU, the block is whole row tiles and an expert's bfloat16 matrix fits the
+kernel's VMEM twice over (double-buffered, beside a row tile of the left
+operand and of the output), the product is JAX's ``megablox.gmm`` in tiles
+this module states: :data:`ROW_TILE` rows by a whole expert.  The kernel
+visits only the row tiles that hold a group's rows, reads each touched
+expert once and multiplies 128 rows against it, so its time is the touched
+experts' bytes at 85–90% of the HBM's pace whatever a token step's block:
+0.32 ms for 56 experts of ``[2560, 768]`` (221 MB) in a block of 512 or of
+640 rows, and 0.40 for all 64 in a full block of 2,048 (PERF.md section 6,
+PR 38).  Everywhere else — an expert of ``[3072, 3072]`` (18.9 MB), a
+block that is no whole row tiles, the CPU — it is ``jax.lax.ragged_dot``,
+which XLA:TPU lowers to a kernel of its own that picks its row tile from
+the block's size: a block that is a multiple of 512 rows gets a tile of
+512 and the same product takes 0.87–1.0 ms, one of 640 or 384 rows
+0.54–0.57.  Both read only the experts a block has rows for; rows past the
+held pairs belong to no group, ``gmm`` leaves them unwritten, and the walk
+selects them away.
+
+**How many rows a block has** (:func:`block_rows`) follows from the same
+observation — a call's pairs, the experts held and the experts the router
 knows: twice the pairs that even routing puts on held experts, in whole
 tiles of :data:`ROW_TILE`, at most :data:`BLOCK_ROWS`.  A call whose
 pairs all fit that block runs it once, with no loop: a token step of a
 few dozen slots (32 slots x 4 picks over 32 of 256 experts: 128 rows).
-Every other call loops: a token step of many slots (256 x 10 picks over
-64 of 512 experts: 2,560 pairs, ~320 of them held, blocks of 640 rows,
-one trip unless the routing is skewed), and prefill (thousands of held
-pairs, a few blocks of ``BLOCK_ROWS``; a short bucket gets the block
-its own pairs call for).  On a TPU v5e a product over 64 experts of
-``[2048, 512]`` with ~5 rows an expert takes 0.39 ms in a block of 2,048
-rows and 0.21 ms in one of 640, where reading the experts once takes
-0.16 (PERF.md section 6, PR 35).
+Every other call loops: a token step of many slots (256 x 8 picks over 64
+of 512 experts: 2,048 pairs, ~256 of them held, blocks of 512 rows, one
+trip unless the routing is skewed), and prefill (thousands of held pairs,
+a few blocks of ``BLOCK_ROWS``; a short bucket gets the block its own
+pairs call for).  The headroom of twice is what keeps a token step to one
+trip, and with the row tile the code's own it costs next to nothing: the
+kernel skips the tiles no pair reaches.
 """
 
 from __future__ import annotations
@@ -56,19 +75,38 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+
+_PRODUCTS = obs_metrics.counter(
+    "moe_grouped_products_total",
+    "grouped products traced (three an expert layer of a program), by "
+    "the kernel taken: gmm (megablox.gmm in the tiles ops/moe.py chose: a "
+    "row tile by a whole expert) | ragged_dot (jax.lax.ragged_dot)")
+
 #: The most sorted (token, expert) rows that go through the grouped
 #: products at a time: the cap of :func:`block_rows`, reached by the
 #: longer prefill buckets (from 1,024 positions at top-10 over 64 of 512
-#: experts, from 2,048 at top-4 over 32 of 256).  At d = f = 3072 such
-#: a block's gathered rows, its two hidden arrays and its output are
-#: 2048 x 4 x 3072 x 2 B = 50 MB; it spans the few experts its rows
-#: belong to, so over a walk each touched expert's weights are read
-#: about once.
+#: experts, from 2,048 at top-4 over 32 of 256).  What bounds it is the
+#: walk's own arrays — a block's gathered rows, its two hidden arrays and
+#: its output, four ``[rows, width]`` arrays: 21 to 50 MB at the widths
+#: served — and not the products' tile: a block spans the few experts its
+#: rows belong to, so over a walk each touched expert's weights are read
+#: about once, by either kernel.
 BLOCK_ROWS = 2048
 
-#: A block is a whole number of these rows (a lane tile; the grouped
-#: products' kernels walk rows in multiples of it).
+#: A block is a whole number of these rows, and they are the row tile of
+#: the grouped products where this module chooses it (``megablox.gmm``'s
+#: ``tm``): one MXU pass of rows a visit, so an expert with five rows
+#: costs 128 rows of products and not 512.  On the chip, tiles of 64 and
+#: of 256 rows read 3% slower at a token step's blocks and 256 rows 7%
+#: slower on a full block (PERF.md section 6, PR 38).
 ROW_TILE = 128
+
+#: VMEM the tiles of a grouped product may fill: a TPU v5e's scoped
+#: default, under which Mosaic compiles a kernel that states no limit of
+#: its own, as ``megablox.gmm`` does.  The one chip this repo is built
+#: and measured on; another generation's default belongs here beside it.
+V5E_SCOPED_VMEM = 16 * 2 ** 20
 
 #: What :func:`expert_ffn` counts, in this order, as one int32 vector.
 STATS = ("pairs_held", "pairs_absent", "experts_touched", "rows_walked")
@@ -118,6 +156,42 @@ def _swiglu(g, u, dtype):
             * u.astype(jnp.float32)).astype(dtype)
 
 
+def product_tiling(rows: int, k: int, n: int, dtype) -> tuple | None:
+    """The tiles ``(rows, k, n)`` in which ``megablox.gmm`` runs a
+    grouped product of a ``[rows, k]`` block with experts of ``[k, n]``,
+    or None where the product is ``jax.lax.ragged_dot``'s.  One rule:
+    built for a TPU, a bfloat16 block of whole row tiles, and
+    :data:`ROW_TILE` rows by a whole expert fit :data:`V5E_SCOPED_VMEM`
+    as the kernel holds them — the matrix and the row tiles of the left
+    operand and of the output double-buffered, beside the float32
+    accumulator and product."""
+    if (jax.default_backend() != "tpu" or rows % ROW_TILE
+            or jnp.dtype(dtype) != jnp.bfloat16):
+        return None
+    tiles = 2 * 2 * (k * n + ROW_TILE * (k + n)) + 2 * 4 * ROW_TILE * n
+    return (ROW_TILE, k, n) if tiles <= V5E_SCOPED_VMEM else None
+
+
+def grouped_product(x, w, sizes):
+    """``x [rows, k]`` sorted by group, ``w [E, k, n]``, ``sizes [E]``
+    int32 rows a group -> ``[rows, n]`` in ``x``'s type: group ``e``'s
+    rows times ``w[e]``, accumulated in float32, by the kernel
+    :func:`product_tiling` names for these shapes (the Pallas interpreter
+    on the CPU, as this repo's own kernels).  Rows past the groups hold
+    nothing that may be read: the tiled kernel never writes them."""
+    tiling = product_tiling(x.shape[0], *w.shape[1:], x.dtype)
+    _PRODUCTS.labels(kernel="ragged_dot" if tiling is None else "gmm").inc()
+    if tiling is None:
+        return jax.lax.ragged_dot(x, w, sizes)
+    # Imported where the kernel is taken, not with the model:
+    # jax.experimental.pallas costs every CPU run a second or two.
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    from distributedtensorflowexample_tpu.ops.pallas.tiling import (
+        resolve_interpret)
+    return gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling,
+               interpret=resolve_interpret(None))
+
+
 def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int,
                experts_known: int, live=None):
     """The held experts' part of the layer: ``m [N, d]``, ``sel``/``w``
@@ -162,12 +236,12 @@ def expert_ffn(m, sel, w, gate, up, down, *, first_expert: int,
                     - jnp.clip(ends - sizes - first, 0, rows))
             token = at // k
             x = m[token]
-            g = jax.lax.ragged_dot(x, gate, part)
-            u = jax.lax.ragged_dot(x, up, part)
-            o = jax.lax.ragged_dot(_swiglu(g, u, m.dtype), down, part)
+            g = grouped_product(x, gate, part)
+            u = grouped_product(x, up, part)
+            o = grouped_product(_swiglu(g, u, m.dtype), down, part)
             # Rows past the held pairs belong to no group and hold
-            # nothing that may be read: selected away, not multiplied
-            # by zero.
+            # nothing that may be read (the tiled kernel leaves them
+            # unwritten): selected away, not multiplied by zero.
             mine = (first + jnp.arange(rows) < held)[:, None]
             o = jnp.where(mine, o.astype(jnp.float32) * w[at][:, None], 0.0)
             return y.at[token].add(o)

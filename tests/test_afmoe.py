@@ -502,6 +502,39 @@ def test_the_engines_counters_follow_the_programs_counts(params, sequences):
     assert engine.cache_bytes == 3 * (64 + 3 * 8) * row
 
 
+@pytest.mark.parametrize("tiled, slots", [(False, 5), (True, 7)])
+def test_the_products_an_engine_traces_are_counted_by_kernel(
+        params, sequences, monkeypatch, tiled, slots):
+    """``moe_grouped_products_total{kernel}``: every product of programs
+    built for the CPU is ``ragged_dot``'s; where the chooser answers with
+    tiles (here: a block's rows by a whole expert, interpreted) every
+    one is the tiled kernel's — three an expert layer of each program
+    traced, and the counts the programs return do not change.  Engines of
+    their own sizes, so that each traces its programs under the chooser
+    it is given."""
+    if tiled:
+        monkeypatch.setattr(moe, "product_tiling",
+                            lambda rows, k, n, dtype: (rows, k, n))
+    names = ['moe_grouped_products_total{kernel="ragged_dot"}',
+             'moe_grouped_products_total{kernel="gmm"}',
+             "moe_rows_walked_total"]
+    before = [_counter(n) for n in names]
+
+    def serve(slots):
+        engine = DecodeEngine(_model(), params, slots=slots, cache_len=48)
+        engine.prefill_many([(0, sequences[0, :5], 1),
+                             (2, sequences[1, :19], 1)])
+        return engine.decode(busy=[0, 2])[[0, 2]].tolist()
+
+    toks = serve(slots)
+    ragged, gmm, walked = (_counter(n) - b for n, b in zip(names, before))
+    # Two prefill programs and the token step, three expert layers each.
+    assert (gmm, ragged) == ((27, 0) if tiled else (0, 27))
+    assert walked == (8 + 32 + slots) * 2 * 3
+    monkeypatch.undo()
+    assert toks == serve(3)             # either kernel serves the same
+
+
 # ---- what refuses, and what holds -----------------------------------------
 
 def _engine(params, **kw):

@@ -101,11 +101,38 @@ def test_sync_step_over_four_chips_runs_the_kernels_per_shard(
     assert not re.search(r"\[\d+,12,1024,1024\]", text)
 
 
-def _grouped_products(text: str) -> list:
+def _grouped_products(text: str, kernel: str = "ragged-dot-none") -> list:
     """``(rows, columns)`` of every grouped product's result in a
-    compiled program's text (XLA:TPU's ``ragged_dot`` kernel)."""
+    compiled program's text: by XLA:TPU's ``ragged_dot`` kernel, or
+    (``kernel="gmm"``) by ``megablox.gmm`` in the tiles ``ops/moe.py``
+    chose."""
     return [(int(r), int(c)) for r, c in re.findall(
-        r"%ragged-dot-none\S* = bf16\[(\d+),(\d+)\]", text)]
+        rf"%{kernel}\S* = bf16\[(\d+),(\d+)\]\S* custom-call\(",
+        text)]
+
+
+@pytest.mark.parametrize("rows, k, n", [
+    (512, 2560, 768),       # ling3_flash_ep8, gate and up
+    (2048, 768, 2560),      # its down, a full prefill block
+    (640, 2048, 512),       # qwen3_next_ep8, gate and up
+    (2048, 512, 2048),      # its down, a full prefill block
+    (512, 2560, 1280),      # the largest the rule lets in: 16.4 of 16.8 MB
+])
+def test_the_tiles_the_walk_chooses_compile_for_v5e(topo, uncached,
+                                                    monkeypatch, rows, k, n):
+    """``ops/moe.product_tiling``'s answer is one Mosaic accepts inside
+    the VMEM the kernel compiles under, at the cells' shapes and at the
+    edge of the rule: one kernel, no copy of the experts."""
+    from distributedtensorflowexample_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.product_tiling(rows, k, n, jnp.bfloat16) == (128, k, n)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, t: jax.ShapeDtypeStruct(shape, t, sharding=one)
+    compiled = jax.jit(moe.grouped_product).lower(
+        sds((rows, k), jnp.bfloat16), sds((64, k, n), jnp.bfloat16),
+        sds((64,), jnp.int32)).compile()
+    assert _grouped_products(compiled.as_text(), "gmm") == [(rows, n)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e6
 
 
 # ---- the afmoe share at the benchmark cell's own sizes ---------------------
@@ -147,11 +174,14 @@ def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(topo,
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
     assert mem.temp_size_in_bytes < 0.5e9
     assert compiled.as_text().count('op_name="ragged-dot-none"') >= 12
+    assert not _grouped_products(compiled.as_text(), "gmm")
 
 
-@pytest.mark.parametrize("batch, bucket", [(1, 256), (2, 16384), (1, 6144)])
+@pytest.mark.parametrize("batch, bucket, block", [
+    (1, 256, 256), (2, 16384, 2048), (1, 6144, 2048)])
 def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
-                                                   bucket, monkeypatch):
+                                                   bucket, block,
+                                                   monkeypatch):
     """A prefill program below the cell's ladder, the fullest one, and a
     bucket of the ladder that is no power of two (six tiles, a window
     and a half): no ``[H, T, T]`` scores (a 16,384-token prompt's would
@@ -159,7 +189,9 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     and the program's activations together inside the chip's 16.9 GB.
     Past one tile the attention of every layer is the TPU's kernel, not
     the tiled walk's loop (the backend is the CPU's here, so the test
-    says "built for a TPU" itself)."""
+    says "built for a TPU" itself).  The grouped products stay
+    ``ragged_dot``'s over the walk's blocks — an expert of 18.9 MB fits
+    no VMEM tile — and no kernel of ``ops/moe.py``'s choosing appears."""
     from distributedtensorflowexample_tpu.ops import attention as attention_op
     from distributedtensorflowexample_tpu.serving import engine as eng
     monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
@@ -170,9 +202,11 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
     assert mem.temp_size_in_bytes < 3.5e9
-    assert ("splash" in compiled.as_text()) == (
-        bucket > attention_op.ATTN_BLOCK)
+    text = compiled.as_text()
+    assert ("splash" in text) == (bucket > attention_op.ATTN_BLOCK)
     assert (bucket in model.prefill_buckets(16384)) == (bucket > 256)
+    assert _grouped_products(text) == [(block, 3072)] * 12
+    assert not _grouped_products(text, "gmm")
 
 
 # ---- the token step's ragged attention (PR 29) ------------------------------
@@ -221,6 +255,7 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
     # 32 slots x 4 picks are one block of 128 sorted rows, no loop: the
     # walk's rule (ops/moe.block_rows) leaves this step as it was.
     assert _grouped_products(text) == [(128, 3072)] * 12
+    assert not _grouped_products(text, "gmm")
     assert "moe.experts/while" not in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
@@ -258,7 +293,8 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
     """The cell's decode program built for a TPU: the ragged kernel in
     both attention layers over the flat rows of two K/V heads, the
     recurrence's kernel in the six Gated DeltaNet layers, the experts'
-    grouped products over blocks sized to the pairs this share holds,
+    grouped products by ``megablox.gmm`` over blocks sized to the pairs
+    this share holds,
     every layer's rows AND states aliased onto their inputs (7.6 GB: the
     recurrent state is updated in place), no copy of a state- or
     cache-sized array, and small temporaries beside 11.5 GB of weights
@@ -280,10 +316,13 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
     assert len(steps) == 6              # one a Gated DeltaNet layer
     # 256 slots x 10 picks = 2,560 pairs, ~320 of them on the 64 held
     # experts: the walk hands the grouped products blocks of 640 sorted
-    # rows (ops/moe.block_rows), not prefill's 2,048.
-    products = _grouped_products(text)
+    # rows (ops/moe.block_rows), not prefill's 2,048 — and an expert of
+    # 2 MB is one VMEM tile, so the kernel is the one whose tiles
+    # ops/moe.product_tiling chose, three a layer.
+    products = _grouped_products(text, "gmm")
     assert sorted(set(products)) == [(640, 512), (640, 2048)]
     assert len(products) == 3 * 8
+    assert not _grouped_products(text)
     mem = compiled.memory_analysis()
     state = 32 * 128 * 128 * 4 + 3 * 8192 * 2
     assert mem.alias_size_in_bytes == 256 * (
@@ -344,8 +383,9 @@ def test_ling3_decode_step_compiles_for_v5e_in_place(topo, uncached,
     """The cell's decode program built for a TPU: the latent kernel in
     the one MLA layer over rows of 640 (one shared row a position, key
     and value), the recurrence's kernel — its per-channel body — in the
-    six KDA layers, the experts' grouped products over blocks sized to
-    the pairs this share holds, the latent rows AND the states aliased
+    six KDA layers, the experts' grouped products by ``megablox.gmm``
+    over blocks sized to the pairs this share holds, the latent rows AND
+    the states aliased
     onto their inputs (6.0 GB, updated in place), no copy of a state- or
     cache-sized array, and small temporaries beside 11.8 GB of weights
     and cache."""
@@ -364,10 +404,13 @@ def test_ling3_decode_step_compiles_for_v5e_in_place(topo, uncached,
     assert len(kernel("gated_delta_step")) == 6     # one a KDA layer
     assert not kernel("ragged_decode_attention")
     # 256 slots x 8 picks = 2,048 pairs, ~256 of them on the 64 held
-    # experts: blocks of 512 sorted rows (ops/moe.block_rows).
-    products = _grouped_products(text)
+    # experts: blocks of 512 sorted rows (ops/moe.block_rows), and an
+    # expert of 3.9 MB fits VMEM twice: the kernel whose tiles
+    # ops/moe.product_tiling chose, three a layer, not ragged_dot's.
+    products = _grouped_products(text, "gmm")
     assert sorted(set(products)) == [(512, 768), (512, 2560)]
     assert len(products) == 3 * 6
+    assert not _grouped_products(text)
     mem = compiled.memory_analysis()
     state = 32 * 128 * 128 * 4 + 3 * 3 * 4096 * 2
     assert mem.alias_size_in_bytes == 256 * (8192 * 640 * 2 + 6 * state)
