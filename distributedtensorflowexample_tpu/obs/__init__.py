@@ -62,4 +62,5 @@ from distributedtensorflowexample_tpu.obs.metrics import (  # noqa: F401
 from distributedtensorflowexample_tpu.obs.recorder import (  # noqa: F401
     FlightRecorder, dump_global, flight_path, install, maybe_install)
 from distributedtensorflowexample_tpu.obs.trace import (  # noqa: F401
-    add_sink, event, hot_span, remove_sink, span, tape, tape_dropped)
+    add_sink, event, hot_span, remove_sink, span, tape, tape_dropped,
+    watch_gc)

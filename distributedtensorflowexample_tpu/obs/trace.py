@@ -45,12 +45,18 @@ is looked up through ``sys.modules`` — importing obs still never
 imports it, and a process that has not imported jax has no device to
 annotate.  Always on: there is no switch.  The guard is in
 tests/test_obs.py (under 5 us a span; it measures about 1).
+
+:func:`watch_gc` puts Python's collector on the same tape: a collection
+of :data:`GC_SPAN_MIN_S` or more is a ``host.gc`` span under whichever
+span it struck, and every collection adds its seconds to
+``host_gc_seconds_total{generation}``.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -71,6 +77,16 @@ _tape: collections.deque = collections.deque(maxlen=TAPE_LEN)
 _seq = itertools.count()    # each entry's number: next() is one C call,
 #                             so threads never hand out one number twice
 _annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+#: A collection shorter than this adds to the counter only: the ring is
+#: for the busy periods, and a young generation is collected in
+#: microseconds, hundreds of times a second.
+GC_SPAN_MIN_S = 1e-3
+_GC_SECONDS = _metrics.counter(
+    "host_gc_seconds_total",
+    "seconds Python's collector ran, by the generation collected")
+_gc_by_generation = None    # the three series, bound by watch_gc()
+_gc_t0 = 0.0
 
 
 def add_sink(sink) -> None:
@@ -163,6 +179,31 @@ class hot_span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry.  Collections do not nest and run under
+    the GIL, on the thread that tripped the threshold: one start stamp
+    serves, and that thread's innermost open span is the parent."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = _metrics._now()
+        return
+    t1 = _metrics._now()
+    _gc_by_generation[info["generation"]].inc(t1 - _gc_t0)
+    if t1 - _gc_t0 >= GC_SPAN_MIN_S:
+        stack = _stack()
+        record("host.gc", _gc_t0, t1, stack[-1] if stack else None)
+
+
+def watch_gc() -> None:
+    """Put Python's collector on the tape and in the registry, from now
+    on; a second call changes nothing."""
+    global _gc_by_generation
+    if _gc_by_generation is None:
+        _gc_by_generation = tuple(_GC_SECONDS.labels(generation=g)
+                                  for g in range(3))
+        gc.callbacks.append(_on_gc)
 
 
 def _context() -> dict:

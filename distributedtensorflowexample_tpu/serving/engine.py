@@ -126,8 +126,8 @@ _CACHE_BYTES = obs_metrics.gauge(
 _STATE_BYTES = obs_metrics.counter(
     "serve_state_bytes_total", "bytes of recurrent state the decode steps "
     "moved, summed over steps: state layers x bytes a slot x 2 (a state "
-    "is read and written whole), over the busy slots and over all of "
-    "them (an idle slot's is moved too: the program has one shape)")
+    "is read and written whole), over all the slots (an idle slot's is "
+    "moved too: the program has one shape)")
 _ROWS_READ = obs_metrics.counter(
     "serve_cache_rows_read_total", "cache rows the busy slots' queries "
     "attended, summed over decode steps and layers, by kind of layer (a "
@@ -712,28 +712,40 @@ class DecodeEngine:
         live).  Returns the next token per slot and advances the BUSY
         slots' frontiers (``busy=None`` advances all): an idle slot's
         parked frontier must not drift toward the cache/positional-
-        table edge one row per step of everyone else's work."""
+        table edge one row per step of everyone else's work.
+
+        Three spans, end to start: ``engine.decode.dispatch`` (the
+        jitted call until it returns; the two host vectors' upload is in
+        it), ``engine.decode.readback`` (its head
+        ``engine.decode.wait`` is the device's step and the runtime's
+        latency and nothing else; the rest is the copy and the counts)
+        and ``engine.decode.account`` (the counters and the numpy state:
+        host time with the chip idle)."""
         with hot_span("engine.decode.dispatch"):
             toks, self._ck, self._cv = _decode_step(
                 self.smodel, self.params, self._ck, self._cv,
                 self.last_tokens, self.positions)
         with hot_span("engine.decode.readback"):
+            # As np.asarray below would: the copy queues behind the step,
+            # so waiting first costs no second round trip to the device.
+            toks.copy_to_host_async()
+            with hot_span("engine.decode.wait"):
+                toks.block_until_ready()
             out = self._count_pairs(np.asarray(toks), self.slots,
                                     decode=True)
-        advance = (np.ones(self.slots, bool) if busy is None
-                   else np.zeros(self.slots, bool))
-        if busy is not None:
-            advance[list(busy)] = True
-        self._count_rows_read(advance)
-        if self._state_bytes_slot:
-            _STATE_BYTES.labels(whose="busy").inc(
-                self._state_bytes_slot * int(advance.sum()))
-            _STATE_BYTES.labels(whose="all").inc(
-                self._state_bytes_slot * self.slots)
-        self.last_tokens = np.where(advance, out, self.last_tokens) \
-            .astype(np.int32)
-        self.positions = self.positions + advance.astype(np.int32)
-        self.decode_steps += 1
+        with hot_span("engine.decode.account"):
+            advance = (np.ones(self.slots, bool) if busy is None
+                       else np.zeros(self.slots, bool))
+            if busy is not None:
+                advance[list(busy)] = True
+            self._count_rows_read(advance)
+            if self._state_bytes_slot:
+                _STATE_BYTES.labels(whose="all").inc(
+                    self._state_bytes_slot * self.slots)
+            self.last_tokens = np.where(advance, out, self.last_tokens) \
+                .astype(np.int32)
+            self.positions = self.positions + advance.astype(np.int32)
+            self.decode_steps += 1
         return out
 
     def _count_pairs(self, out: np.ndarray, n: int,

@@ -36,7 +36,9 @@ probes), the engine's ``engine.prefill.*`` and ``engine.decode.*``
 spans, and ``serve.retire`` (token append, retirement, gauges); what is
 left — the per-request bookkeeping after a prefill, the step-time EWMA,
 ``on_step`` — is the step's self time.  An idle poll (nothing queued,
-nothing live) leaves no span: the ring is for the busy periods.  Per
+nothing live) leaves no span: the ring is for the busy periods.  A
+pause of Python's collector is a ``host.gc`` span inside whichever of
+these it struck (``watch_gc``).  Per
 request three events
 partition its life and carry its ``rid``: ``serve_queue`` (``submit_t``
 → ``prefill_t``), ``serve_prefill`` (``prefill_t`` → ``first_token_t``)
@@ -274,6 +276,9 @@ class ContinuousBatcher:
         self.completed: list = []       # finished Requests (tape)
         self.rejected: list = []
         self.admitted_total = 0
+        # A pause of Python's collector lands on the tape as a
+        # ``host.gc`` span inside whichever boundary span it struck.
+        obs_trace.watch_gc()
 
     def set_slo_ms(self, slo_ms: float) -> float:
         """The remediation seam (resilience/remediate.py's slo_tighten

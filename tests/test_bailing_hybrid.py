@@ -582,8 +582,7 @@ def test_the_engines_counters_follow_a_hand_count(params, sequences):
     ``serve_state_bytes_total`` is state layers x bytes a slot x 2 a
     decode step; the recurrence's and latent attention's two forms are
     counted where they are traced."""
-    names = ['serve_state_bytes_total{whose="busy"}',
-             'serve_state_bytes_total{whose="all"}',
+    names = ['serve_state_bytes_total{whose="all"}',
              'serve_cache_rows_read_total{kind="latent"}',
              'serve_cache_rows_fetched_total{kind="latent"}',
              'serve_cache_rows_read_total{kind="state"}',
@@ -599,12 +598,11 @@ def test_the_engines_counters_follow_a_hand_count(params, sequences):
     engine.prefill_many([(0, sequences[0, :5], 1), (2, sequences[1, :19], 1)])
     engine.decode(busy=[0, 2])          # positions 5 and 19
     engine.decode(busy=[2])             # position 20; slot 0 still live
-    (busy, every, read, fetched, none, held, absent, slots, chunked,
+    (every, read, fetched, none, held, absent, slots, chunked,
      recurrent, expanded, absorbed) = (
         _counter(n) - b for n, b in zip(names, before))
     # a slot's state in one layer: S [4, 8, 8] f32, conv [3, 96] f32 here
     state = 4 * 4 * 8 * 8 + 4 * 3 * (3 * 4 * 8)
-    assert busy == (2 + 1) * 6 * state * 2
     assert every == 2 * 3 * 6 * state * 2
     assert read == (6 + 20) + 21 and none == 0      # one latent layer
     assert fetched == 2 * 3 * 96        # the CPU's chain reads every row

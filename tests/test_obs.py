@@ -13,6 +13,7 @@ tools/obs_report.py renders the lot without error.
 Single-device, no collectives.
 """
 
+import gc
 import glob
 import json
 import os
@@ -187,13 +188,17 @@ def test_span_attrs_writable_and_exception_safe(sink):
 @pytest.fixture()
 def tape(monkeypatch):
     """An empty tape of its own for one test (the module's ring is
-    process-wide and other tests write to it)."""
+    process-wide and other tests write to it).  The collector rests
+    meanwhile: once something in this process has called ``watch_gc()``
+    a collection reads the clock these tests pin and count."""
     import collections
     import itertools
     ring = collections.deque(maxlen=8)
     monkeypatch.setattr(obs_trace, "_tape", ring)
     monkeypatch.setattr(obs_trace, "_seq", itertools.count())
-    return ring
+    gc.disable()
+    yield ring
+    gc.enable()
 
 
 def _ticking_clock(monkeypatch, step=1.0):
@@ -300,6 +305,35 @@ def test_hot_span_exception_safe_and_annotates_when_jax_is_loaded(tape):
     assert obs_trace._trace_annotation() is jax.profiler.TraceAnnotation
 
 
+def test_watch_gc_lands_a_long_collection_under_the_open_span(
+        tape, monkeypatch):
+    obs_trace.watch_gc()
+    obs_trace.watch_gc()
+    assert gc.callbacks.count(obs_trace._on_gc) == 1
+    full = obs_trace._GC_SECONDS.labels(generation=2)
+    before = full.value
+    _ticking_clock(monkeypatch)         # each read a second later
+    with obs_trace.hot_span("outer"):               # opens at 1
+        gc.collect()                                # 2 .. 3
+    assert obs_trace.tape() == [("host.gc", 2.0, 3.0, "outer", None),
+                                ("outer", 1.0, 4.0, None, None)]
+    assert full.value - before == 1.0
+    assert obs_trace._stack() == []
+
+
+def test_watch_gc_keeps_a_short_collection_off_the_tape(tape, monkeypatch):
+    obs_trace.watch_gc()
+    young = obs_trace._GC_SECONDS.labels(generation=0)
+    before = young.value
+    _ticking_clock(monkeypatch, step=obs_trace.GC_SPAN_MIN_S / 2)
+    with obs_trace.hot_span("outer"):
+        gc.collect(0)
+    assert [e[0] for e in obs_trace.tape()] == ["outer"]
+    # ... and the counter has it all the same
+    assert young.value - before == pytest.approx(
+        obs_trace.GC_SPAN_MIN_S / 2)
+
+
 def test_hot_span_microbench_guard():
     """Tentpole promise: a per-boundary span (the tape record plus the
     profiler annotation, jax loaded, no profiler session) is about a
@@ -321,6 +355,7 @@ def test_importing_obs_pulls_no_jax():
     code = textwrap.dedent("""
         import sys
         import distributedtensorflowexample_tpu.obs as obs
+        obs.watch_gc()
         with obs.hot_span("x"):
             pass
         assert obs.tape()[-1][0] == "x"

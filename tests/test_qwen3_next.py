@@ -440,11 +440,10 @@ def test_the_sigmoid_routers_program_did_not_change():
 
 def test_the_engines_counters_follow_a_hand_count(params, sequences):
     """``serve_state_bytes_total`` is state layers x bytes a slot x 2 a
-    decode step, over the busy slots and over all; ``serve_cache_bytes``
+    decode step, over every slot; ``serve_cache_bytes``
     is the module's own count by kind; rows are read in attention layers
     only; the scan's two forms are counted where they are traced."""
-    names = ['serve_state_bytes_total{whose="busy"}',
-             'serve_state_bytes_total{whose="all"}',
+    names = ['serve_state_bytes_total{whose="all"}',
              'serve_cache_rows_read_total{kind="full"}',
              'serve_cache_rows_read_total{kind="state"}',
              'moe_pairs_total{where="held"}',
@@ -457,11 +456,10 @@ def test_the_engines_counters_follow_a_hand_count(params, sequences):
     engine.prefill_many([(0, sequences[0, :5], 1), (2, sequences[1, :19], 1)])
     engine.decode(busy=[0, 2])          # positions 5 and 19
     engine.decode(busy=[2])             # position 20; slot 0 still live
-    busy, every, full, none, held, absent, slots, chunked, recurrent = (
+    every, full, none, held, absent, slots, chunked, recurrent = (
         _counter(n) - b for n, b in zip(names, before))
     # a slot's state in one layer: S [4, 8, 8] f32, conv [3, 64] f32 here
     state = 4 * 4 * 8 * 8 + 4 * 3 * (2 * 2 * 8 + 4 * 8)
-    assert busy == (2 + 1) * 6 * state * 2
     assert every == 2 * 3 * 6 * state * 2
     assert full == 2 * ((6 + 20) + 21) and none == 0   # two attention layers
     # prefill: 24 prompt tokens; two steps of two live slots; top 3; 8 layers

@@ -17,6 +17,7 @@ in tests/test_scheduler.py next to the other control-plane drills.
 
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -762,6 +763,13 @@ def test_percentiles_and_drive_file(tmp_path):
 
 # ---- the program's own spans and counters ---------------------------------
 
+def _tape_since(t: float) -> list:
+    """The tape's entries that closed after ``t`` (by the stamp, not by
+    the ring's length: a full ring keeps its length), less the
+    collector's: it strikes where it will."""
+    return [e for e in obs_trace.tape() if e[2] >= t and e[0] != "host.gc"]
+
+
 @pytest.fixture(scope="module")
 def traced_run(lm_state):
     """A small greedy run driven by ``batcher.step()`` on an engine of
@@ -779,7 +787,7 @@ def traced_run(lm_state):
             'serve_prefill_positions_total{kind="%s"}' % k, 0)
             for k in ("prompt", "pad")}
 
-    before, n_before = counters(), len(obs_trace.tape())
+    before, t_before = counters(), time.monotonic()
     lengths = [3, 5, 8, 9, 2]
     # Boundary 1 admits 3, 5 and 8 tokens: all bucket 8, one [3, 8]
     # block.  The others wait for a slot: 9 tokens (bucket 16) and 2
@@ -794,7 +802,7 @@ def traced_run(lm_state):
         == obs_trace.TAPE_LEN
     after = counters()
     return {"reqs": reqs, "engine": engine, "lengths": lengths,
-            "tape": obs_trace.tape()[n_before:],
+            "tape": _tape_since(t_before),
             "moved": {k: after[k] - before[k] for k in after}}
 
 
@@ -836,16 +844,70 @@ def test_every_engine_span_lies_inside_a_serve_step(traced_run):
     assert steps and {e[0] for e in inner} == {
         "serve.admit", "serve.retire", "engine.prefill.pack",
         "engine.prefill.dispatch", "engine.prefill.readback",
-        "engine.decode.dispatch", "engine.decode.readback"}
+        "engine.decode.dispatch", "engine.decode.readback",
+        "engine.decode.wait", "engine.decode.account"}
     for e in inner:
-        assert e[3] == "serve.step", e
+        assert e[3] == ("engine.decode.readback"
+                        if e[0] == "engine.decode.wait" else "serve.step"), e
         assert any(s[1] <= e[1] and e[2] <= s[2] for s in steps), e
-    # one decode dispatch and read-back, one admit and one retire a step
+    # one decode dispatch, read-back (with its wait) and account, one
+    # admit and one retire a step
     for name in ("engine.decode.dispatch", "engine.decode.readback",
+                 "engine.decode.wait", "engine.decode.account",
                  "serve.admit", "serve.retire"):
         assert sum(e[0] == name for e in tape) == len(steps)
     # steps do not overlap, and the tape closes them in order
     assert all(a[2] <= b[1] for a, b in zip(steps, steps[1:]))
+
+
+#: What of a decode-only ``serve.step`` its named pieces may leave
+#: uncovered: ``_busy()``, the step-time EWMA, a counter and six spans'
+#: own stamps are tens of microseconds; the room is for a loaded host.
+STEP_HOLE_S = 1e-3
+
+
+def test_a_decode_only_step_holds_its_pieces_in_order(traced_run):
+    tape = traced_run["tape"]
+    holes = []
+    for step in (e for e in tape if e[0] == "serve.step"):
+        held = sorted((e for e in tape if e is not step and e[4] is None
+                       and step[1] <= e[1] and e[2] <= step[2]),
+                      key=lambda e: (e[1], -e[2]))
+        if any(e[0].startswith("engine.prefill.") for e in held):
+            continue
+        assert [e[0] for e in held] == [
+            "serve.admit", "engine.decode.dispatch",
+            "engine.decode.readback", "engine.decode.wait",
+            "engine.decode.account", "serve.retire"]
+        admit, dispatch, readback, wait, account, retire = held
+        # each ends before the next begins; the wait alone is nested,
+        # at the read-back's head
+        for a, b in ((admit, dispatch), (dispatch, readback),
+                     (readback, account), (account, retire)):
+            assert a[2] <= b[1], (a, b)
+        assert wait[3] == "engine.decode.readback" \
+            and readback[1] <= wait[1] and wait[2] <= readback[2]
+        assert wait[1] - readback[1] < wait[2] - wait[1] + 1e-4
+        assert account[3] == "serve.step"
+        holes.append((step[2] - step[1]) - sum(
+            e[2] - e[1] for e in held if e is not wait))
+    assert len(holes) >= 3
+    assert sorted(holes)[len(holes) // 2] < STEP_HOLE_S, holes
+
+
+def test_the_spans_change_no_token(traced_run, lm_state):
+    model, state = lm_state
+    for req, n in zip(traced_run["reqs"], traced_run["lengths"]):
+        assert req.tokens == _greedy_reference(
+            model, state.params, list(range(1, n + 1)), len(req.tokens),
+            got=req.tokens), req.rid
+
+
+def test_the_batcher_watches_the_collector_once(engine):
+    import gc
+    for _ in range(2):
+        ContinuousBatcher(engine, RequestQueue(engine.vocab), slo_ms=0.0)
+    assert gc.callbacks.count(obs_trace._on_gc) == 1
 
 
 def test_an_idle_poll_leaves_no_span_on_the_tape(engine):
@@ -854,13 +916,13 @@ def test_an_idle_poll_leaves_no_span_on_the_tape(engine):
     rejected a request is work, and stays on it."""
     q = RequestQueue(engine.vocab)
     batcher = ContinuousBatcher(engine, q, slo_ms=0.0)
-    n_before = len(obs_trace.tape())
+    t_before = time.monotonic()
     for _ in range(3):
         assert batcher.step() == 0
-    assert obs_trace.tape()[n_before:] == []
+    assert _tape_since(t_before) == []
     q.submit(list(range(1, CACHE + 8)), 4, rid="too-long")
     assert batcher.step() == 0 and batcher.rejected[-1].rid == "too-long"
-    assert [e[0] for e in obs_trace.tape()[n_before:]] == [
+    assert [e[0] for e in _tape_since(t_before)] == [
         "serve.admit", "serve.step"]
 
 
