@@ -161,7 +161,12 @@ _MOE_SLOTS = obs_metrics.counter(
 #: nothing cache-shaped" claim; no collective may appear (decode is a
 #: single-device program today — an exact 0 budget makes ANY collective
 #: a finding); no float wider than f32 anywhere (the f32
-#: softmax/logits ceiling the training models hold).
+#: softmax/logits ceiling the training models hold).  The program it is
+#: checked on is ``_decode_step_fn(module, params, ck, cv, tok, pos,
+#: host) -> (tokens with the model's counts, tok', pos', ck', cv')``:
+#: the two caches are what is donated and what the alias clauses are
+#: about; the two ``[S]`` int32 vectors that stay on the device between
+#: steps are not (a step in flight still owns the ones it was given).
 DECODE_HLO_CONTRACT = {
     "mode": "serve_decode",
     "require_alias": True,
@@ -476,11 +481,21 @@ def _with_stats(toks, rest: tuple):
     return jnp.concatenate([toks.ravel(), *rest]) if rest else toks
 
 
-def _decode_step_fn(smodel, params, ck, cv, tok, pos):
+def _decode_step_fn(smodel, params, ck, cv, tok, pos, host):
+    # tok, pos [S]: what the step before this one left on the device.
+    # host int32 [4, S], the one upload a step: which slots advance,
+    # which the host has written since the last step, and the host's
+    # token and position for those (a prefill's, a parked slot's).  The
+    # model's arithmetic on the cache and the logits is what it was when
+    # the host kept both vectors.
+    advance, written, tok_host, pos_host = host
+    tok = jnp.where(written > 0, tok_host, tok)
+    pos = jnp.where(written > 0, pos_host, pos)
     logits, ck, cv, *stats = smodel.apply({"params": params}, tok, pos, ck,
                                           cv, method="decode")
     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return _with_stats(toks, stats), ck, cv
+    return (_with_stats(toks, stats), jnp.where(advance > 0, toks, tok),
+            pos + advance, ck, cv)
 
 
 _decode_step = jax.jit(_decode_step_fn, static_argnums=0,
@@ -538,7 +553,14 @@ class DecodeEngine:
 
     Donation discipline: both programs donate the cache buffers, so
     after every call the PREVIOUS cache handles are dead — the engine
-    always rebinds, and no caller ever holds a cache reference."""
+    always rebinds, and no caller ever holds a cache reference.
+
+    The greedy step's token and position vectors stay on the device
+    too, one step's outputs being the next one's inputs, so that
+    :meth:`decode` can hand the device a step before it has read the
+    one before it; ``decode_logits``, ``verify_step`` and ``extend``
+    keep the synchronous form they have, because there the host
+    produces or owns the tokens and there is nothing to read late."""
 
     def __init__(self, model, params, *,
                  slots: int = DEFAULT_SLOTS, cache_len: int = 128,
@@ -603,11 +625,24 @@ class DecodeEngine:
         self.layers_without_rows_by_position = sum(
             n for kind, (n, _) in kinds.items()
             if kind in _NO_ROWS_BY_POSITION)
-        # Host-owned scalars-per-slot, uploaded per call (tiny): the
-        # returned next-token array is the only per-step device output
-        # besides the aliased caches.
+        # Each slot's last token and position live ON THE DEVICE, a
+        # greedy step's outputs being the next one's inputs (``_tok``,
+        # ``_pos``), so that a step can be handed over before the one
+        # before it is read (:meth:`decode`).  The host keeps mirrors:
+        # ``positions`` is exact for every step dispatched (it advances
+        # by the slots that advance, known without the device);
+        # ``last_tokens`` is one step behind while a step is in flight.
+        # What the host writes into a slot (a prefill's first token and
+        # length, :meth:`set_slot`) goes into the mirrors and is marked
+        # in ``_written``; the next greedy step takes the marked slots
+        # from its one small upload in place of the device's.
         self.positions = np.zeros((self.slots,), np.int32)
         self.last_tokens = np.zeros((self.slots,), np.int32)
+        self._tok = self._pos = jnp.zeros((self.slots,), jnp.int32)
+        self._written = np.ones((self.slots,), bool)
+        #: The greedy step handed to the device and not read yet: (its
+        #: tokens on the device, the slots whose ``last_tokens`` it sets).
+        self._flying: tuple | None = None
         self.decode_steps = 0
         self.prefills = 0
         # Which (bucket, batch) prefill shapes have compiled: the first
@@ -691,8 +726,7 @@ class DecodeEngine:
                 toks = self._count_pairs(np.asarray(toks), B)
                 last = np.asarray(last)
             for i, (slot, prompt) in enumerate(group):
-                self.positions[slot] = len(prompt)
-                self.last_tokens[slot] = int(toks[i])
+                self.set_slot(slot, toks[i], len(prompt))
                 out[slot] = (int(toks[i]), last[i])
             self.prefills += B
             real = int(lengths.sum())
@@ -705,7 +739,7 @@ class DecodeEngine:
         self.last_prefill_was_cold = cold
         return out
 
-    def decode(self, busy=None) -> np.ndarray:
+    def decode(self, busy=None, then=None) -> np.ndarray:
         """One decode step over ALL slots (idle slots compute too — the
         program has one static shape; their outputs are ignored and
         their stale rows are overwritten the next time the slot is
@@ -714,38 +748,80 @@ class DecodeEngine:
         parked frontier must not drift toward the cache/positional-
         table edge one row per step of everyone else's work.
 
-        Three spans, end to start: ``engine.decode.dispatch`` (the
-        jitted call until it returns; the two host vectors' upload is in
-        it), ``engine.decode.readback`` (its head
-        ``engine.decode.wait`` is the device's step and the runtime's
-        latency and nothing else; the rest is the copy and the counts)
-        and ``engine.decode.account`` (the counters and the numpy state:
-        host time with the chip idle)."""
+        ONE call, which may read a step one call late.  ``then`` names
+        the slots of the step AFTER the one this call returns: it is
+        handed to the device before this call reads anything, its inputs
+        being this step's outputs on the device, and the next call
+        returns it (``busy`` is then not looked at: that step is in
+        flight already).  So the device goes from one step into the
+        next while the host reads, accounts and retires.  Without
+        ``then`` a call leaves nothing in flight, and one that found
+        nothing in flight is the synchronous step it always was.
+        :meth:`settle` reads a step left in flight without another.
+
+        The spans, by name: ``engine.decode.dispatch`` (a jitted call
+        until it returns, the one small upload in it),
+        ``engine.decode.account`` after each dispatch (the counters and
+        the numpy state, the device at work meanwhile) and
+        ``engine.decode.readback``, whose head ``engine.decode.wait`` is
+        the rest of the device's step and the runtime's latency and
+        nothing else; the rest of it is the copy and the counts."""
+        if self._flying is None:
+            self._dispatch(busy)
+        step, self._flying = self._flying, None
+        if then is not None:
+            self._dispatch(then)
+        return self._read(step)
+
+    def settle(self):
+        """Read the step in flight, if there is one: its tokens, else
+        None.  Nothing is in flight after it."""
+        if self._flying is None:
+            return None
+        step, self._flying = self._flying, None
+        return self._read(step)
+
+    def _advancing(self, busy) -> np.ndarray:
+        """``busy`` as a mask over the slots (None: every slot)."""
+        if busy is None:
+            return np.ones(self.slots, bool)
+        advance = np.zeros(self.slots, bool)
+        advance[list(busy)] = True
+        return advance
+
+    def _dispatch(self, busy) -> None:
+        advance = self._advancing(busy)
         with hot_span("engine.decode.dispatch"):
-            toks, self._ck, self._cv = _decode_step(
-                self.smodel, self.params, self._ck, self._cv,
-                self.last_tokens, self.positions)
-        with hot_span("engine.decode.readback"):
-            # As np.asarray below would: the copy queues behind the step,
-            # so waiting first costs no second round trip to the device.
+            toks, self._tok, self._pos, self._ck, self._cv = _decode_step(
+                self.smodel, self.params, self._ck, self._cv, self._tok,
+                self._pos, self._host_vectors(advance))
+            # Started here, not at the read: the copy then queues behind
+            # the step, and a later start costs a round trip to the device.
             toks.copy_to_host_async()
-            with hot_span("engine.decode.wait"):
-                toks.block_until_ready()
-            out = self._count_pairs(np.asarray(toks), self.slots,
-                                    decode=True)
         with hot_span("engine.decode.account"):
-            advance = (np.ones(self.slots, bool) if busy is None
-                       else np.zeros(self.slots, bool))
-            if busy is not None:
-                advance[list(busy)] = True
+            self._written[:] = False
             self._count_rows_read(advance)
             if self._state_bytes_slot:
                 _STATE_BYTES.labels(whose="all").inc(
                     self._state_bytes_slot * self.slots)
-            self.last_tokens = np.where(advance, out, self.last_tokens) \
-                .astype(np.int32)
             self.positions = self.positions + advance.astype(np.int32)
             self.decode_steps += 1
+        self._flying = (toks, advance)
+
+    def _host_vectors(self, advance: np.ndarray) -> np.ndarray:
+        """The greedy program's one upload (``_decode_step_fn``)."""
+        return np.stack([advance, self._written, self.last_tokens,
+                         self.positions]).astype(np.int32, copy=False)
+
+    def _read(self, step: tuple) -> np.ndarray:
+        toks, advance = step
+        with hot_span("engine.decode.readback"):
+            with hot_span("engine.decode.wait"):
+                toks.block_until_ready()
+            out = self._count_pairs(np.asarray(toks), self.slots,
+                                    decode=True)
+            self.last_tokens = np.where(advance, out, self.last_tokens) \
+                .astype(np.int32)
         return out
 
     def _count_pairs(self, out: np.ndarray, n: int,
@@ -779,17 +855,18 @@ class DecodeEngine:
         slot's next token: it must ``set_slot(slot, token,
         positions[slot])`` before the next step (greedy's fused-argmax
         program, and its HLO contract, are untouched by this seam)."""
+        self.settle()
         with hot_span("engine.decode.dispatch"):
             logits, self._ck, self._cv = _decode_logits_step(
                 self.smodel, self.params, self._ck, self._cv,
                 self.last_tokens, self.positions)
         with hot_span("engine.decode.readback"):
             out = np.asarray(logits)
-        advance = (np.ones(self.slots, bool) if busy is None
-                   else np.zeros(self.slots, bool))
-        if busy is not None:
-            advance[list(busy)] = True
-        self.positions = self.positions + advance.astype(np.int32)
+        # The host owns both vectors on this path: a greedy step after
+        # it takes every slot from the host's.
+        self._written[:] = True
+        self.positions = self.positions + self._advancing(busy).astype(
+            np.int32)
         self.decode_steps += 1
         return out
 
@@ -854,9 +931,14 @@ class DecodeEngine:
 
     def set_slot(self, slot: int, last_token: int, position: int) -> None:
         """Host bookkeeping hook (the batcher parks retired slots at
-        position 0 so their frontier never walks off the cache end)."""
+        position 0 so their frontier never walks off the cache end).
+        The next greedy step takes the slot from here and not from the
+        device; a step in flight no longer sets its ``last_tokens``."""
         self.last_tokens[slot] = int(last_token)
         self.positions[slot] = int(position)
+        self._written[slot] = True
+        if self._flying is not None:
+            self._flying[1][slot] = False
 
     # --- the contract surface --------------------------------------------
     def decode_hlo(self) -> str:
@@ -864,7 +946,12 @@ class DecodeEngine:
         front checks :data:`DECODE_HLO_CONTRACT` against.  Compiled
         from the UNDONATED argument values via a separate lowering (the
         live step's buffers must not be consumed by a lint pass)."""
-        lowered = _decode_step.lower(
-            self.smodel, self.params, self._ck, self._cv,
-            self.last_tokens, self.positions)
-        return lowered.compile().as_text()
+        return _decode_step.lower(
+            self.smodel, *self.decode_args()).compile().as_text()
+
+    def decode_args(self) -> tuple:
+        """What the greedy decode program takes after the module, every
+        slot advancing: for whoever lowers it again (this contract, the
+        tests that read its text)."""
+        return (self.params, self._ck, self._cv, self._tok, self._pos,
+                self._host_vectors(np.ones(self.slots, bool)))

@@ -12,6 +12,13 @@ a mid-decode admission cannot perturb the requests already in flight —
 tests/test_serving.py pins that a request admitted mid-decode produces
 bitwise the tokens it produces solo.
 
+The greedy path reads a step's tokens one boundary late while every slot
+is busy: the next step is handed to the device first, its inputs the
+last step's outputs there (``_greedy_boundary``), so the device does not
+wait for the host's read-back, retirement and admission.  The lag is 0
+or 1 by what the slots say and by nothing else; each boundary still
+reads exactly one step.
+
 Admission is SLO-aware (``SERVE_SLO_MS``, 0 = off): a queued request is
 priced at admission time — wait so far + a prefill estimate + max_new x
 the decode-step EWMA — and one that can no longer finish inside the SLO
@@ -52,6 +59,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import inspect
 import os
 import threading
 import time
@@ -71,7 +79,12 @@ _REQUESTS = obs_metrics.counter(
 _TOKENS = obs_metrics.counter(
     "serve_tokens_total", "tokens generated (completed requests only)")
 _STEPS = obs_metrics.counter(
-    "serve_decode_steps_total", "compiled decode steps executed")
+    "serve_decode_steps_total", "compiled decode steps executed, by when "
+    "their tokens were read: same_step (handed to the device and read at "
+    "one boundary) or late (handed over at the boundary before, read "
+    "after the step behind it was handed over)")
+_STEPS_SAME = _STEPS.labels(readback="same_step")
+_STEPS_LATE = _STEPS.labels(readback="late")
 _PREFILLS = obs_metrics.counter(
     "serve_prefills_total", "bucketed prefill calls, by bucket")
 _QUEUE_DEPTH = obs_metrics.gauge(
@@ -232,6 +245,9 @@ class RequestQueue:
 @dataclasses.dataclass
 class _Slot:
     req: Request | None = None
+    #: Tokens ``req`` has or will have once every step handed to the
+    #: device is read (the greedy path's count: ``_issue``).
+    issued: int = 0
 
 
 class ContinuousBatcher:
@@ -276,6 +292,16 @@ class ContinuousBatcher:
         self.completed: list = []       # finished Requests (tape)
         self.rejected: list = []
         self.admitted_total = 0
+        # The greedy path may hand the device its next step before it
+        # reads the last one's tokens (``_greedy_boundary``): only where
+        # the engine has that seam and nothing on the host produces or
+        # owns a step's tokens (a sampler, a draft, a prefix cache's
+        # suffix extension).
+        self._may_run_ahead = (
+            "then" in inspect.signature(engine.decode).parameters
+            and spec is None and sampler is None and prefix_cache is None)
+        #: The greedy step in flight: [(slot, its request)], or None.
+        self._flying: list | None = None
         # A pause of Python's collector lands on the tape as a
         # ``host.gc`` span inside whichever boundary span it struck.
         obs_trace.watch_gc()
@@ -411,7 +437,7 @@ class ContinuousBatcher:
                             t0_s=req.prefill_t, rid=req.rid, slot=slot,
                             outcome=outcome, batch=len(todo))
             req.tokens.append(int(first))
-            self._slots[slot].req = req
+            self._slots[slot] = _Slot(req, issued=1)
             self.admitted_total += 1
             if self.spec is not None:
                 self.spec.on_admit(slot, req.prompt, req.max_new)
@@ -422,11 +448,19 @@ class ContinuousBatcher:
         req = self._slots[slot].req
         if req is None:
             return True
-        full = len(req.tokens) >= req.max_new
-        eos = self.eos_id is not None and req.tokens \
-            and req.tokens[-1] == self.eos_id
-        if not (full or eos):
+        if not self._ended(req):
             return False
+        self._finish(req, slot, now)
+        self._release(slot)
+        return True
+
+    def _ended(self, req: Request) -> bool:
+        return len(req.tokens) >= req.max_new or (
+            self.eos_id is not None and bool(req.tokens)
+            and req.tokens[-1] == self.eos_id)
+
+    def _finish(self, req: Request, slot: int, now: float) -> None:
+        """The request's half of a retirement: it has its last token."""
         req.finish("ok", now)
         _REQUESTS.labels(outcome="ok").inc()
         _TOKENS.inc(len(req.tokens))
@@ -436,19 +470,20 @@ class ContinuousBatcher:
                         slot=slot, tokens=len(req.tokens),
                         outcome=req.outcome)
         self.completed.append(req)
-        self._slots[slot].req = None
-        # Park the freed slot's frontier at 0: idle slots still compute
-        # every step, and an unbounded frontier would walk past the
-        # positional table for nothing.
-        self.engine.set_slot(slot, 0, 0)
-        if self.spec is not None:
-            self.spec.park(slot)
         if len(self.completed) % 32 == 0 or len(self.completed) < 8:
             tape = sorted(r.latency_s
                           for r in self.completed[-GAUGE_WINDOW:])
             _P50.set(round(percentile(tape, 0.50) * 1000.0, 3))
             _P99.set(round(percentile(tape, 0.99) * 1000.0, 3))
-        return True
+
+    def _release(self, slot: int) -> None:
+        """The slot's half: open for admission, and parked.  Idle slots
+        still compute every step, and an unbounded frontier would walk
+        past the positional table for nothing."""
+        self._slots[slot].req = None
+        self.engine.set_slot(slot, 0, 0)
+        if self.spec is not None:
+            self.spec.park(slot)
 
     # --- the loop ---------------------------------------------------------
     def _busy(self) -> list:
@@ -475,20 +510,20 @@ class ContinuousBatcher:
         its request's RNG lane), or the default greedy fused-argmax
         step.  Retires whatever finished.  Returns live slots decoded."""
         busy = self._busy()
-        if not busy:
+        if not busy and self._flying is None:
             return 0
+        if self.spec is None and self.sampler is None:
+            return self._greedy_boundary(busy)
         t0 = time.monotonic()
         if self.spec is not None:
             remaining = {
                 s: self._slots[s].req.max_new - len(self._slots[s].req.tokens)
                 for s in busy}
             emitted = self.spec.round(busy, remaining)
-        elif self.sampler is not None:
-            logits = self.engine.decode_logits(busy=busy)
         else:
-            toks = self.engine.decode(busy=busy)
+            logits = self.engine.decode_logits(busy=busy)
         self._note_step_time(time.monotonic() - t0)
-        _STEPS.inc()
+        _STEPS_SAME.inc()
         with obs_trace.hot_span("serve.retire"):
             now = time.monotonic()
             for slot in busy:
@@ -501,17 +536,89 @@ class ContinuousBatcher:
                         # have produced (the oracle contract).
                         new = new[:new.index(self.eos_id) + 1]
                     req.tokens.extend(new)
-                elif self.sampler is not None:
+                else:
                     tok = self.sampler.sample(req.rid, len(req.tokens),
                                               logits[slot])
                     self.engine.set_slot(slot, tok,
                                          int(self.engine.positions[slot]))
                     req.tokens.append(tok)
-                else:
-                    req.tokens.append(int(toks[slot]))
                 self._maybe_retire(slot, now)
             _SLOTS_BUSY.set(self.engine.slots - len(self._free_slots()))
         return len(busy)
+
+    def _issue(self, busy: list) -> tuple:
+        """The bookkeeping of handing the device one greedy step over
+        ``busy``: each slot's request is counted one more token, and a
+        request whose last token this step makes gives its slot up NOW —
+        the slot is open for admission at the next boundary, before the
+        token has been read, and the request waits in the step's list
+        for it.  Returns ([(slot, request)], the slots given up: to be
+        parked once the engine has the step)."""
+        step, given_up = [], []
+        for s in busy:
+            slot = self._slots[s]
+            step.append((s, slot.req))
+            slot.issued += 1
+            if slot.issued >= slot.req.max_new:
+                slot.req = None
+                given_up.append(s)
+        return step, given_up
+
+    def _greedy_boundary(self, busy: list) -> int:
+        """The greedy boundary: ONE algorithm whose read-back lags the
+        dispatch by 0 or 1 step, by what the slots say.  The step this
+        boundary reads is the one in flight, or is handed over now; the
+        step after it is handed over too, BEFORE the read, while no slot
+        is free — a queued request could not be admitted before that
+        step anyway, and the device goes from one step into the next
+        while the host reads and retires.  With a free slot nothing is
+        handed over ahead: a request arriving mid-step is prefilled at
+        the next boundary and not one step later.  Every boundary reads
+        exactly one step, so a request gets at most one token a
+        boundary whichever way the lag changes.
+
+        A request that ends by count was known to at its last step's
+        dispatch (``_issue``), so its slot is parked or admitted into on
+        time and no slot idles.  One that ends at an EOS is known only
+        when that token is read, with the next step already in flight:
+        that step's token for it is dropped, never delivered (greedy
+        stops AT eos), and the slot is admitted into one boundary later
+        than the synchronous order would."""
+        given_up: list = []
+        step, late = self._flying, self._flying is not None
+        if step is None:
+            step, given_up = self._issue(busy)
+        then = None
+        if self._may_run_ahead and not self._free_slots():
+            # no slot free: ``busy`` is every slot, now as before
+            then, more = self._issue(busy)
+            given_up += more
+        self._flying = then
+        t0 = time.monotonic()
+        slots = [s for s, _ in step]
+        if then is None:
+            toks = self.engine.decode(busy=slots)
+        else:
+            toks = self.engine.decode(busy=slots,
+                                      then=[s for s, _ in then])
+        self._note_step_time(time.monotonic() - t0)
+        (_STEPS_LATE if late else _STEPS_SAME).inc()
+        with obs_trace.hot_span("serve.retire"):
+            now = time.monotonic()
+            for slot in given_up:
+                self._release(slot)
+            n = 0
+            for slot, req in step:
+                if req.done.is_set():
+                    continue        # it ended at an EOS one step ago
+                n += 1
+                req.tokens.append(int(toks[slot]))
+                if self._ended(req):
+                    self._finish(req, slot, now)
+                    if self._slots[slot].req is req:    # ended at an EOS
+                        self._release(slot)
+            _SLOTS_BUSY.set(self.engine.slots - len(self._free_slots()))
+        return n
 
     def step(self) -> int:
         """One boundary: admit into open slots, one decode boundary
@@ -560,7 +667,7 @@ class ContinuousBatcher:
         # batch keeps drafting+verifying mid-drain (its tokens are
         # greedy's tokens either way), a sampled batch keeps its RNG
         # lanes.
-        while self._busy():
+        while self._busy() or self._flying is not None:
             with obs_trace.hot_span("serve.step"):
                 self._decode_once()
         _SLOTS_BUSY.set(0)

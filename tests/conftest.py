@@ -60,3 +60,56 @@ def small_synthetic(monkeypatch):
     monkeypatch.setattr(mnist, "_SYNTH_SIZES", {"train": 2048, "test": 512})
     monkeypatch.setattr(cifar10, "_SYNTH_SIZES",
                         {"train": 2048, "test": 512})
+
+
+@pytest.fixture()
+def serve_backlog():
+    """``serve(engine, plan, run_ahead, eos_id=None, arrivals=None)``:
+    every (prompt, max_new) of ``plan`` queued behind a fresh greedy
+    ContinuousBatcher — at once, or request i before boundary
+    ``arrivals[i]`` — stepped until all are answered, then drained.
+    ``run_ahead=False`` holds the batcher to a read-back at every step
+    (the synchronous order, the oracle of the late read-back's tests).
+    Returns the requests, one row a boundary and what the registry's
+    counters moved by."""
+    import types
+
+    from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
+    from distributedtensorflowexample_tpu.serving.queue import (
+        ContinuousBatcher, RequestQueue)
+
+    def counters() -> dict:
+        return dict(obs_metrics.registry().snapshot()["counters"])
+
+    def serve(engine, plan, run_ahead: bool, eos_id=None, arrivals=None):
+        queue = RequestQueue(engine.vocab)
+        batcher = ContinuousBatcher(engine, queue, slo_ms=0.0, eos_id=eos_id)
+        assert batcher._may_run_ahead
+        batcher._may_run_ahead = run_ahead
+        before = counters()
+        due = [0] * len(plan) if arrivals is None else list(arrivals)
+        reqs: list = []
+        rows: list = []
+        while len(reqs) < len(plan) or not all(
+                r.done.is_set() for r in reqs):
+            while len(reqs) < len(plan) and due[len(reqs)] <= len(rows):
+                prompt, max_new = plan[len(reqs)]
+                reqs.append(queue.submit(prompt, max_new,
+                                         rid=f"b{len(reqs)}"))
+            had = [len(r.tokens) for r in reqs]
+            n = batcher.step()
+            rows.append(types.SimpleNamespace(
+                n=n, positions=engine.positions.copy(),
+                in_flight=batcher._flying is not None,
+                had=had, got=[len(r.tokens) - h for r, h in zip(reqs, had)],
+                done=[r.done.is_set() for r in reqs],
+                owners=[(s.req, s.issued) for s in batcher._slots]))
+            assert len(rows) < 10_000
+        batcher.drain()
+        after = counters()
+        moved = {k: after[k] - before.get(k, 0) for k in after
+                 if after[k] != before.get(k, 0)}
+        return types.SimpleNamespace(reqs=reqs, rows=rows, moved=moved,
+                                     batcher=batcher)
+
+    return serve

@@ -437,6 +437,34 @@ def test_batcher_serves_the_references_tokens(params):
         assert gaps["widest_over_all"] < 1e-4 and gaps["tokens"] == r.max_new
 
 
+def test_a_late_readback_serves_the_synchronous_orders_tokens_and_counts(
+        params, serve_backlog):
+    """The same seven requests on three slots, the next step handed to
+    the device before the last one's tokens are read (the counts ride
+    behind the tokens in the one array read late) and then a read-back
+    at every step: request for request the same tokens, boundary for
+    boundary the same ``step()``, and the host's and the model's
+    counters total the same over the run."""
+    engine = DecodeEngine(_model(), params, slots=3, cache_len=128)
+    rng = np.random.default_rng(11)
+    plan = [(rng.integers(0, 97, n).astype(np.int32), new)
+            for n, new in [(5, 30), (21, 25), (9, 12), (70, 20), (3, 40), (14, 9), (27, 18)]]
+    late = serve_backlog(engine, plan, run_ahead=True)
+    sync = serve_backlog(engine, plan, run_ahead=False)
+    assert [r.tokens for r in late.reqs] == [r.tokens for r in sync.reqs]
+    assert [row.n for row in late.rows] == [row.n for row in sync.rows]
+    counted = ("moe_pairs_total", "moe_rows_walked_total",
+               "moe_experts_touched_total", "moe_expert_slots_total",
+               "serve_cache_rows_read_total", "serve_state_bytes_total",
+               "serve_prefill_positions_total", "serve_tokens_total")
+    pick = lambda moved: {k: v for k, v in moved.items()
+                          if k.startswith(counted)}
+    assert pick(late.moved) == pick(sync.moved) and len(pick(sync.moved)) > 4
+    steps = 'serve_decode_steps_total{readback="%s"}'
+    assert late.moved[steps % "late"] > late.moved[steps % "same_step"]
+    assert steps % "late" not in sync.moved
+
+
 # ---- the expert layer ------------------------------------------------------
 
 def _layer_inputs(n=50, seed=2):
@@ -668,7 +696,7 @@ def test_the_decode_program_honours_the_hlo_contract(params):
                                                               6)]
     lower = lambda f, *a: f.lower(engine.smodel, engine.params, engine._ck,
                                   engine._cv, *a).as_text(debug_info=True)
-    text = lower(eng._decode_step, engine.last_tokens, engine.positions)
+    text = lower(eng._decode_step, *engine.decode_args()[3:])
     for scope in ("kda.proj", "kda.conv", "kda.step", "kda.out", "mla.q",
                   "mla.kv", "mla.attend", "moe.route", "moe.experts",
                   "moe.shared", "cache_update", "head"):

@@ -390,8 +390,7 @@ def test_decode_hlo_contract_holds_and_catches_violations(engine):
     # Teeth: the SAME step compiled WITHOUT donation must fail the
     # aliasing clause — the contract distinguishes the schedules.
     undonated = jax.jit(engine._decode_fn).lower(
-        engine.params, engine._ck, engine._cv, engine.last_tokens,
-        engine.positions).compile().as_text()
+        *engine.decode_args()).compile().as_text()
     findings = check_contract(undonated, DECODE_HLO_CONTRACT)
     assert any(f.rule == "hlo-donation" for f in findings)
 
@@ -875,20 +874,26 @@ def test_a_decode_only_step_holds_its_pieces_in_order(traced_run):
                       key=lambda e: (e[1], -e[2]))
         if any(e[0].startswith("engine.prefill.") for e in held):
             continue
+        # a step handed over (0, 1 or 2 of them: the one in flight needs
+        # none, the first to run ahead brings its successor) is accounted
+        # while the device works, then ONE step is read
+        handed = [e for e in held if e[0] == "engine.decode.dispatch"]
         assert [e[0] for e in held] == [
-            "serve.admit", "engine.decode.dispatch",
-            "engine.decode.readback", "engine.decode.wait",
-            "engine.decode.account", "serve.retire"]
-        admit, dispatch, readback, wait, account, retire = held
+            "serve.admit",
+            *["engine.decode.dispatch", "engine.decode.account"]
+            * len(handed),
+            "engine.decode.readback", "engine.decode.wait", "serve.retire"]
+        assert len(handed) <= 2
+        admit, *_, readback, wait, retire = held
         # each ends before the next begins; the wait alone is nested,
         # at the read-back's head
-        for a, b in ((admit, dispatch), (dispatch, readback),
-                     (readback, account), (account, retire)):
+        flat = [e for e in held if e is not wait]
+        for a, b in zip(flat, flat[1:]):
             assert a[2] <= b[1], (a, b)
         assert wait[3] == "engine.decode.readback" \
             and readback[1] <= wait[1] and wait[2] <= readback[2]
         assert wait[1] - readback[1] < wait[2] - wait[1] + 1e-4
-        assert account[3] == "serve.step"
+        assert all(e[3] == "serve.step" for e in flat)
         holes.append((step[2] - step[1]) - sum(
             e[2] - e[1] for e in held if e is not wait))
     assert len(holes) >= 3
@@ -973,12 +978,192 @@ def test_decode_step_carries_the_named_scopes(engine):
     from distributedtensorflowexample_tpu.serving.engine import (
         _decode_step)
     text = _decode_step.lower(
-        engine.smodel, engine.params, engine._ck, engine._cv,
-        engine.last_tokens, engine.positions).as_text(debug_info=True)
+        engine.smodel, *engine.decode_args()).as_text(debug_info=True)
     for scope in ("attn", "head", "cache_update"):
         assert f"/{scope}/" in text, scope
     assert "block0.verify/attn/" in text
     assert "block0.verify/cache_update/" in text
+
+
+# ---- the late read-back (PR 40) ---------------------------------------------
+
+def _backlog_plan(seed: int, count: int, new_from: int, vocab: int) -> list:
+    rng = np.random.default_rng([seed, 40])
+    return [(rng.integers(1, vocab, size=int(rng.integers(2, 9))).tolist(),
+             int(rng.integers(new_from, 9))) for _ in range(count)]
+
+
+def _steps_by_readback() -> dict:
+    got = obs_metrics.registry().snapshot()["counters"]
+    return {k: got.get(f'serve_decode_steps_total{{readback="{k}"}}', 0)
+            for k in ("late", "same_step")}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_late_readback_by_count_is_the_synchronous_order_step_for_step(
+        engine, serve_backlog, seed):
+    """Nine requests behind three slots, every one ending by ``max_new``
+    (known when its last step is handed over): the streams, ``step()``'s
+    return at every boundary and the number of boundaries are the
+    synchronous order's, every boundary but the first and those around a
+    free slot read their step late, and ``engine.positions`` is exact
+    for every step handed over."""
+    plan = _backlog_plan(seed, 9, 2, engine.vocab)
+    sync = serve_backlog(engine, plan, run_ahead=False)
+    before = _steps_by_readback()
+    late = serve_backlog(engine, plan, run_ahead=True)
+    moved = {k: v - before[k] for k, v in _steps_by_readback().items()}
+    assert [r.tokens for r in late.reqs] == [r.tokens for r in sync.reqs]
+    assert [len(r.tokens) for r in late.reqs] == [m for _, m in plan]
+    assert [row.n for row in late.rows] == [row.n for row in sync.rows]
+    assert not any(row.in_flight for row in sync.rows)
+    assert sync.moved.get(
+        'serve_decode_steps_total{readback="late"}', 0) == 0
+    assert moved["late"] >= len(late.rows) // 2
+    assert moved["late"] + moved["same_step"] == len(late.rows)
+    for run in (sync, late):
+        lengths = {id(r): len(p) for r, (p, _) in zip(run.reqs, plan)}
+        for row in run.rows:
+            # a slot's position: its request's prompt and every token it
+            # was issued but the newest, which no step has been fed yet
+            assert row.positions.tolist() == [
+                0 if req is None else lengths[id(req)] + issued - 1
+                for req, issued in row.owners]
+            # at most one token a boundary to a request that had any; a
+            # request admitted at this boundary may add its first
+            assert all(g <= (1 if h else 2)
+                       for g, h in zip(row.got, row.had))
+    assert not engine.positions.any()
+    assert engine.settle() is None
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_late_readback_stops_at_an_eos_and_delivers_nothing_after_it(
+        engine, serve_backlog, seed):
+    """Requests that end at an EOS (known only when the token is read,
+    with the next step in flight) mixed with ones that end by count or
+    on their prefill's token: request for request the synchronous
+    order's streams, the EOS last where there is one."""
+    plan = _backlog_plan(seed, 10, 3, engine.vocab)
+    plan.insert(4, ([7, 8, 9], 1))
+    free = serve_backlog(engine, plan, run_ahead=False)
+    # a token that some stream holds before its end
+    inner = [t for r in free.reqs for t in r.tokens[1:-1]]
+    eos = max(set(inner), key=inner.count)
+    sync = serve_backlog(engine, plan, run_ahead=False, eos_id=eos)
+    late = serve_backlog(engine, plan, run_ahead=True, eos_id=eos)
+    assert [r.tokens for r in late.reqs] == [r.tokens for r in sync.reqs]
+    cut = [r for r, (_, m) in zip(late.reqs, plan) if len(r.tokens) < m]
+    assert cut and all(r.tokens[-1] == eos for r in cut)
+    assert all(eos not in r.tokens[:-1] for r in late.reqs)
+    # one of them at least met its EOS with the next step in flight
+    ended = {i: next(b for b, row in enumerate(late.rows) if row.done[i])
+             for i, r in enumerate(late.reqs)
+             if any(r is c for c in cut)}
+    assert any(late.rows[b].in_flight for b in ended.values())
+    for row in late.rows:
+        assert all(g <= (1 if h else 2) for g, h in zip(row.got, row.had))
+    assert not engine.positions.any()
+    assert engine.settle() is None
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_late_readback_with_arrivals_between_boundaries(
+        engine, serve_backlog, seed):
+    """Requests that arrive while others decode, none to three a
+    boundary, so the lag goes from 0 to 1 and back as slots fill and
+    free; odd seeds end some at an EOS: request for request the
+    synchronous order's streams, at most one decode token a boundary,
+    nothing left in flight."""
+    rng = np.random.default_rng([seed, 41])
+    plan = _backlog_plan(seed + 10, 14, 1, engine.vocab)
+    arrivals = np.cumsum(rng.integers(0, 4, size=len(plan))).tolist()
+    eos = None
+    if seed % 2:
+        free = serve_backlog(engine, plan, run_ahead=False,
+                             arrivals=arrivals)
+        inner = [t for r in free.reqs for t in r.tokens[1:-1]]
+        eos = max(set(inner), key=inner.count)
+    sync = serve_backlog(engine, plan, run_ahead=False, eos_id=eos,
+                         arrivals=arrivals)
+    late = serve_backlog(engine, plan, run_ahead=True, eos_id=eos,
+                         arrivals=arrivals)
+    assert [r.tokens for r in late.reqs] == [r.tokens for r in sync.reqs]
+    lags = [row.in_flight for row in late.rows]
+    assert True in lags and False in lags[:-1]
+    for row in late.rows:
+        assert all(g <= (1 if h else 2) for g, h in zip(row.got, row.had))
+    assert not engine.positions.any() and engine.settle() is None
+
+
+def test_drain_reads_the_step_in_flight(engine):
+    queue = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, queue, slo_ms=0.0)
+    reqs = [queue.submit([1 + i, 2, 3], 6, rid=f"d{i}") for i in range(5)]
+    for _ in range(2):
+        batcher.step()
+    assert batcher._flying is not None      # three slots, two queued
+    batcher.drain()
+    assert batcher._flying is None and engine.settle() is None
+    assert [r.outcome for r in reqs] == ["ok"] * 3 + ["drained"] * 2
+    assert all(len(r.tokens) == 6 for r in reqs[:3])
+    assert not engine.positions.any()
+
+
+@pytest.mark.parametrize("queued, reads", [
+    (2, ["same_step"]),                         # a slot stays free
+    (3, ["same_step", "late", "late", "late", "late"]),
+    (5, ["same_step", "late", "late", "late", "late"])])
+def test_a_step_is_handed_over_ahead_only_while_no_slot_is_free(
+        engine, queued, reads):
+    """By the slots, not by a flag: with a free slot and an empty queue
+    every step is read at the boundary that handed it over (a request
+    arriving mid-step is prefilled at the next boundary); with every
+    slot busy every boundary after the first reads its step late."""
+    queue = RequestQueue(engine.vocab)
+    batcher = ContinuousBatcher(engine, queue, slo_ms=0.0)
+    for i in range(queued):
+        queue.submit([5 + i, 6], 7, rid=f"q{i}")
+    seen = []
+    for _ in range(5):
+        before = _steps_by_readback()
+        assert batcher.step() == min(queued, engine.slots)
+        seen += [k for k, v in _steps_by_readback().items()
+                 if v > before[k]]
+    assert seen == reads * (5 // len(reads))
+    assert (batcher._flying is not None) == (queued >= engine.slots)
+    batcher.drain()
+
+
+@pytest.mark.parametrize("what", ["sampler", "spec", "prefix_cache",
+                                  "sharded"])
+def test_whoever_owns_a_steps_tokens_reads_it_at_its_own_boundary(
+        request, engine, what):
+    """A sampler draws each token from the step's logits, a draft's
+    verify owns the window, a prefix cache extends suffixes through the
+    verify program and the sharded engine has no seam: every slot busy,
+    and still no step is read late."""
+    kw, eng = {}, engine
+    if what == "sampler":
+        kw["sampler"] = Sampler(temperature=0.8, top_k=8, seed=3)
+    elif what == "spec":
+        kw["spec"] = SpecDecoder(
+            engine, request.getfixturevalue("draft_engine"), k=2)
+    elif what == "prefix_cache":
+        kw["prefix_cache"] = PrefixCache(engine)
+    else:
+        eng = request.getfixturevalue("sharded_engine")
+    queue = RequestQueue(eng.vocab)
+    batcher = ContinuousBatcher(eng, queue, slo_ms=0.0, **kw)
+    reqs = [queue.submit([3 + i, 4, 5], 5, rid=f"o{i}")
+            for i in range(eng.slots + 2)]
+    before = _steps_by_readback()
+    while not all(r.done.is_set() for r in reqs):
+        assert batcher.step() > 0
+        assert batcher._flying is None
+    moved = {k: v - before[k] for k, v in _steps_by_readback().items()}
+    assert moved["late"] == 0 and moved["same_step"] > 0
+    assert all(len(r.tokens) == 5 for r in reqs)
 
 
 def test_obs_never_imports_serving():

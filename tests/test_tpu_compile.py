@@ -168,7 +168,7 @@ def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(topo,
     from distributedtensorflowexample_tpu.serving import engine as eng
     model, params, ck, cv, i32 = _trinity_programs(topo)
     compiled = eng._decode_step.lower(model, params, ck, cv, i32(32),
-                                      i32(32)).compile()
+                                      i32(32), i32(4, 32)).compile()
     mem = compiled.memory_analysis()
     assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
@@ -244,7 +244,7 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
     # the op was told: a new function, so the test above leaves no trace.
     compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
                        donate_argnums=(1, 2)).lower(
-        params, ck, cv, i32(32), i32(32)).compile()
+        params, ck, cv, i32(32), i32(32), i32(4, 32)).compile()
     text = compiled.as_text()
     # (XLA:TPU's own ragged_dot is a tpu_custom_call too: count ours.)
     kernels = [line for line in text.splitlines()
@@ -262,6 +262,18 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
     assert mem.temp_size_in_bytes < 0.5e9
     assert not re.search(r"bf16\[32,(16384|4096|131072|32768),[^\]]*\]\S* "
                          r"copy\(", text)
+    _vectors_stay_on_the_chip(compiled, 32)
+
+
+def _vectors_stay_on_the_chip(compiled, slots: int) -> None:
+    """The greedy program's outputs: the tokens with the model's four
+    counts behind them (the host's one read-back), then each slot's next
+    token and position, left on the device as the next step's inputs,
+    then the caches."""
+    toks, tok, pos = compiled.out_info[:3]
+    assert (toks.shape, tok.shape, pos.shape) == (
+        (slots + 4,), (slots,), (slots,))
+    assert toks.dtype == tok.dtype == pos.dtype == jnp.int32
 
 
 # ---- the qwen3_next share at the benchmark cell's own sizes (PR 34) ---------
@@ -305,7 +317,7 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
     model, params, ck, cv, i32 = _qwen3_next_programs(topo)
     compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
                        donate_argnums=(1, 2)).lower(
-        params, ck, cv, i32(256), i32(256)).compile()
+        params, ck, cv, i32(256), i32(256), i32(4, 256)).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if re.search(r"%ragged_decode_attention\S* = \S+ custom-call\(",
@@ -331,6 +343,7 @@ def test_qwen3_next_decode_step_compiles_for_v5e_in_place(topo, uncached,
     assert mem.temp_size_in_bytes < 1.0e9
     assert not re.search(r"f32\[256,32,128,128\]\S* copy\(", text)
     assert not re.search(r"bf16\[256,8192,256\]\S* copy\(", text)
+    _vectors_stay_on_the_chip(compiled, 256)
 
 
 @pytest.mark.parametrize("batch, bucket", [(2, 256), (1, 1024), (2, 4096)])
@@ -395,7 +408,7 @@ def test_ling3_decode_step_compiles_for_v5e_in_place(topo, uncached,
     model, params, ck, cv, i32 = _ling3_programs(topo)
     compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
                        donate_argnums=(1, 2)).lower(
-        params, ck, cv, i32(256), i32(256)).compile()
+        params, ck, cv, i32(256), i32(256), i32(4, 256)).compile()
     text = compiled.as_text()
     kernel = lambda name: [
         line for line in text.splitlines()
@@ -418,6 +431,7 @@ def test_ling3_decode_step_compiles_for_v5e_in_place(topo, uncached,
     assert mem.temp_size_in_bytes < 1.0e9
     assert not re.search(r"f32\[256,32,128,128\]\S* copy\(", text)
     assert not re.search(r"bf16\[256,8192,640\]\S* copy\(", text)
+    _vectors_stay_on_the_chip(compiled, 256)
 
 
 @pytest.mark.parametrize("batch, bucket", [(2, 256), (1, 1024), (2, 4096)])
