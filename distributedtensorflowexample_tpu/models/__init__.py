@@ -59,10 +59,14 @@ def build_model_from_config(config, **kw):
         from distributedtensorflowexample_tpu.models.bailing_hybrid import (
             build_bailing_hybrid)
         return build_bailing_hybrid(config, **kw)
+    if kind == "granitemoehybrid":
+        from distributedtensorflowexample_tpu.models.granitemoehybrid import (
+            build_granitemoehybrid)
+        return build_granitemoehybrid(config, **kw)
     raise ValueError(
         f"no model is built from a configuration of model_type {kind!r} "
-        f"(have: afmoe, bailing_hybrid, qwen3_next; the GPT-2 ladder is "
-        f"built by size, LM_SIZES)")
+        f"(have: afmoe, bailing_hybrid, granitemoehybrid, qwen3_next; the "
+        f"GPT-2 ladder is built by size, LM_SIZES)")
 
 
 __all__ = ["SoftmaxRegression", "MnistCNN", "ResNet20", "ResNetCIFAR",

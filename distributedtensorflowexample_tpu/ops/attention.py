@@ -163,12 +163,13 @@ def _visible(q_pos, k_pos, window):
     return ok
 
 
-def _scores(q, k, ok):
+def _scores(q, k, ok, scale=None):
     """One tile of scores: ``q [B, Tq, Hkv, G, Dh]``, ``k [B, Tk, Hkv,
-    Dh]``, ``ok [Tq, Tk]`` -> float32 ``[B, Hkv, G, Tq, Tk]``, masked."""
+    Dh]``, ``ok [Tq, Tk]`` -> float32 ``[B, Hkv, G, Tq, Tk]``, masked;
+    times ``scale`` (None: ``Dh ** -0.5``)."""
     s = jnp.einsum("bthgd,bshd->bhgts", q, k,
                    preferred_element_type=jnp.float32)
-    return jnp.where(ok, s * q.shape[-1] ** -0.5, _MASKED)
+    return jnp.where(ok, s * (scale or q.shape[-1] ** -0.5), _MASKED)
 
 
 def takes_splash(q_shape: tuple, block: int, v_dim: int | None = None) -> bool:
@@ -200,7 +201,8 @@ def tile_ladder(cache_len: int, tile: int = ATTN_BLOCK):
 
 def splash_grouped_attention(q, k, v, *, window: int = 0,
                              block: int = ATTN_BLOCK,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             scale: float | None = None):
     """:func:`grouped_attention` by JAX's own TPU kernel (splash
     attention: an online softmax over key blocks held in VMEM, blocks
     the causal or window mask empties never visited), one call per
@@ -215,7 +217,7 @@ def splash_grouped_attention(q, k, v, *, window: int = 0,
     B, T, Hq, Dh = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
     G = Hq // Hkv
-    q = (q * Dh ** -0.5).astype(q.dtype)
+    q = (q * (scale or Dh ** -0.5)).astype(q.dtype)
     if Dh % 128:
         q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, -Dh % 128),))
                 for a in (q, k))
@@ -233,14 +235,16 @@ def splash_grouped_attention(q, k, v, *, window: int = 0,
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, T, Hq, Dv)
 
 
-def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
+def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK,
+                      scale: float | None = None):
     """Causal softmax attention with fewer key/value heads than query
     heads: ``q [B, T, Hq, Dh]``, ``k`` ``[B, T, Hkv, Dh]``, ``v`` ``[B,
     T, Hkv, Dv]`` (``Dv`` is ``Dh`` in most models; latent attention's
     expanded keys are wider than its values), query head i reading
     key/value head ``i // (Hq // Hkv)``; with ``window`` a query sees
-    only the last ``window`` positions, itself included.  Returns ``[B,
-    T, Hq, Dv]``.
+    only the last ``window`` positions, itself included; the scores are
+    times ``scale`` (None: ``Dh ** -0.5``; a model states its own where
+    its attention has a multiplier).  Returns ``[B, T, Hq, Dv]``.
 
     One path that adapts to what the call can observe, as
     :func:`causal_attention` does: a sequence of at most ``block``
@@ -257,11 +261,12 @@ def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
     B, T, Hq, Dh = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
     if takes_splash(q.shape, block, Dv):
-        return splash_grouped_attention(q, k, v, window=window, block=block)
+        return splash_grouped_attention(q, k, v, window=window, block=block,
+                                        scale=scale)
     q = q.reshape(B, T, Hkv, Hq // Hkv, Dh)
     if T <= block:
         pos = jnp.arange(T)
-        s = _scores(q, k, _visible(pos, pos, window))
+        s = _scores(q, k, _visible(pos, pos, window), scale)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return jnp.einsum("bhgts,bshd->bthgd", p, v).reshape(B, T, Hq, Dv)
 
@@ -281,7 +286,7 @@ def grouped_attention(q, k, v, *, window: int = 0, block: int = ATTN_BLOCK):
         def key_tile(j, carry):
             m, l, acc = carry
             ok = _visible(q_pos, j * block + jnp.arange(block), window)
-            s = _scores(qi, rows(k, j), ok)
+            s = _scores(qi, rows(k, j), ok, scale)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
@@ -325,26 +330,28 @@ def decode_fetch_block(rows: int, n_kv_heads: int, head_dim: int,
     return _ragged().fetch_block(rows, n_kv_heads, head_dim, flat)
 
 
-def einsum_decode_attention(q, ck, cv, lengths):
+def einsum_decode_attention(q, ck, cv, lengths, scale=None):
     """The reference chain, the code ``AfmoeBlock.step`` carried inline
-    until PR 29: float32 scores over every row the layer holds, the rows
-    from ``lengths`` on masked, float32 softmax, the weighted sum in the
-    cache's type."""
+    until PR 29: float32 scores (times ``scale``; None: ``Dh ** -0.5``)
+    over every row the layer holds, the rows from ``lengths`` on masked,
+    float32 softmax, the weighted sum in the cache's type."""
     ok = jnp.arange(ck.shape[1]) < lengths[..., None]           # [S,K,R]
     s = jnp.einsum("skhgd,srhd->shgkr", q, ck,
                    preferred_element_type=jnp.float32)
-    s = jnp.where(ok[:, None, None], s * q.shape[-1] ** -0.5, _MASKED)
+    s = jnp.where(ok[:, None, None], s * (scale or q.shape[-1] ** -0.5),
+                  _MASKED)
     p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
     return jnp.einsum("shgkr,srhd->skhgd", p, cv)
 
 
-def decode_attention(q, ck, cv, lengths):
+def decode_attention(q, ck, cv, lengths, scale=None):
     """The token step's attention: ``q [S, K, Hkv, G, Dh]`` (a K-token
     window a slot, plain decode is K == 1; ``G`` query heads a key/value
     head), ``ck``/``cv`` ``[S, R, Hkv, Dh]`` as the engine holds them —
     or flat, ``[S, R * Hkv, Dh]``, as a model keeps them whose K/V heads
     are fewer than a tile's sublanes —, ``lengths [S, K]`` the count of
-    LEADING rows each query sees (``1..R``).  Returns ``[S, K, Hkv, G,
+    LEADING rows each query sees (``1..R``), ``scale`` what the scores
+    are multiplied by (None: ``Dh ** -0.5``).  Returns ``[S, K, Hkv, G,
     Dh]``.
 
     One function, two regimes chosen from what the call can observe: for
@@ -361,10 +368,10 @@ def decode_attention(q, ck, cv, lengths):
         _DECODE.labels(impl="einsum").inc()
         if flat:
             ck, cv = (c.reshape(S, R, Hkv, Dh) for c in (ck, cv))
-        return einsum_decode_attention(q, ck, cv, lengths)
+        return einsum_decode_attention(q, ck, cv, lengths, scale)
     _DECODE.labels(impl="ragged").inc()
     return _ragged().ragged_decode_attention(
-        q[:, 0], ck, cv, lengths[:, 0])[:, None]
+        q[:, 0], ck, cv, lengths[:, 0], scale=scale)[:, None]
 
 
 # --- latent attention: one shared row a position ----------------------------

@@ -212,18 +212,20 @@ def chunked_sequence(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
     return jnp.swapaxes(o, 1, 2)[:, :T], state
 
 
-def causal_conv_sequence(x, kernel, lengths=None):
+def causal_conv_sequence(x, kernel, lengths=None, bias=None):
     """Depthwise causal convolution over a sequence that starts from
     nothing: ``x [B, T, C]``, ``kernel [K, C]`` (``y_t = sum_j kernel[j]
-    x_{t - K + 1 + j}``, inputs before position 0 zero), ``lengths [B]``
-    each row's live length (None: ``T``).  Returns ``(y [B, T, C] float32,
-    state [B, K - 1, C]``: the last ``K - 1`` inputs up to each row's
-    length, in ``x``'s type)."""
+    x_{t - K + 1 + j}``, inputs before position 0 zero; plus ``bias [C]``
+    where the layer has one), ``lengths [B]`` each row's live length
+    (None: ``T``).  Returns ``(y [B, T, C] float32, state [B, K - 1, C]``:
+    the last ``K - 1`` inputs up to each row's length, in ``x``'s type)."""
     B, T, _ = x.shape
     K = kernel.shape[0]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     w = kernel.astype(F32)
     y = sum(w[j] * xp[:, j:j + T].astype(F32) for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(F32)
     if lengths is None:
         return y, xp[:, T:]
     # Input t sits at row t + K - 1 of xp: the K - 1 before `length`.
@@ -231,12 +233,14 @@ def causal_conv_sequence(x, kernel, lengths=None):
     return y, jnp.take_along_axis(xp, at[:, :, None], axis=1)
 
 
-def causal_conv_step(x, kernel, state, live=None):
+def causal_conv_step(x, kernel, state, live=None, bias=None):
     """One token a slot: ``x [S, C]``, ``state [S, K - 1, C]`` the inputs
-    before it.  Returns ``(y [S, C] float32, state')``; a slot that is
-    not live keeps its state."""
+    before it, ``bias [C]`` where the layer has one.  Returns ``(y [S, C]
+    float32, state')``; a slot that is not live keeps its state."""
     window = jnp.concatenate([state, x[:, None].astype(state.dtype)], axis=1)
     y = jnp.sum(kernel.astype(F32)[None] * window.astype(F32), axis=1)
+    if bias is not None:
+        y = y + bias.astype(F32)
     new = window[:, 1:]
     if live is not None:
         new = jnp.where(live[:, None, None], new, state)

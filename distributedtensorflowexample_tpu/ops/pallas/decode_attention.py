@@ -114,13 +114,15 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def ragged_decode_attention(q, ck, cv, lengths, *, block: int | None = None,
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def ragged_decode_attention(q, ck, cv, lengths, *, scale: float | None = None,
+                            block: int | None = None,
                             interpret: bool | None = None):
-    """``softmax(q k^T / sqrt(Dh)) v`` per slot over the slot's first
-    ``lengths[s]`` cache rows: ``q [S, Hkv, G, Dh]``, ``ck``/``cv`` ``[S,
-    R, Hkv, Dh]`` (or flat, ``[S, R * Hkv, Dh]``), ``lengths [S]`` int32
-    in ``1..R``.  Returns ``[S, Hkv, G, Dh]`` in the cache's type.
+    """``softmax(scale q k^T) v`` (``scale`` None: ``1 / sqrt(Dh)``) per
+    slot over the slot's first ``lengths[s]`` cache rows: ``q [S, Hkv, G,
+    Dh]``, ``ck``/``cv`` ``[S, R, Hkv, Dh]`` (or flat, ``[S, R * Hkv,
+    Dh]``), ``lengths [S]`` int32 in ``1..R``.  Returns ``[S, Hkv, G, Dh]``
+    in the cache's type.
     Jitted here, so the layers of one program that share a shape share
     one trace."""
     S, Hkv, G, Dh = q.shape
@@ -139,7 +141,7 @@ def ragged_decode_attention(q, ck, cv, lengths, *, block: int | None = None,
     q_spec = pl.BlockSpec((None, Hq, Dh), lambda s, j, lens: (s, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, n_kv_heads=Hkv, group=G,
-                          scale=Dh ** -0.5),
+                          scale=scale or Dh ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S, R // block),
             in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,

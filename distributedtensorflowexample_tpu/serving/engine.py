@@ -36,7 +36,7 @@ never looks inside.  There are four kinds of layer:
 A module may also state the ladder of lengths its prompts are padded to
 (``prefill_buckets(cache_len)``); without one the engine pads to the
 next power of two (:func:`_prefill_buckets`).
-Four layouts exist today:
+Five layouts exist today:
 
 * ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
   Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
@@ -53,6 +53,12 @@ Four layouts exist today:
   ``ck`` is its rows ``[S, cache_len, row]`` and its ``cv`` is empty, a
   Kimi Delta Attention layer's are its recurrent state ``[S, H, D, D]``
   float32 and its convolution's last inputs.
+* ``models/granitemoehybrid.py``'s model (its own serving module): one
+  array a layer in each of ``ck`` and ``cv``; the attention layer's are
+  its K and V rows ``[S, cache_len, Hkv, Dh]`` (eight K/V heads: whole
+  tiles as they are), a Mamba-2 layer's its recurrent state ``[S, H, P,
+  N]`` float32 and its convolution's last inputs — the ``state`` kind
+  again, under a third recurrence; nothing here changed for it.
 * ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
   stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
   them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU

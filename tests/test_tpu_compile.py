@@ -455,3 +455,90 @@ def test_ling3_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     assert ("splash" in compiled.as_text()) == (
         bucket > attention_op.ATTN_BLOCK)
     assert bucket in model.prefill_buckets(8192)
+
+
+# ---- the granite-4.0-h share at the benchmark cell's own sizes (PR 41) ------
+
+def _granite4_programs(topo, slots=192, cache_len=2048):
+    """The model of benchmarks/configs/granite4_h_small_ep8.json with
+    shapes for its parameters and for a 192-slot, 2,048-row engine's cache
+    — K/V rows of one layer, recurrent and convolution states of nine —
+    all on the described chip."""
+    import os
+    from distributedtensorflowexample_tpu.models import (
+        build_model_from_config)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "granite4_h_small_ep8.json"))
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ck, cv = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, cache_len)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+    return model, params, ck, cv, i32
+
+
+def test_granite4_decode_step_compiles_for_v5e_in_place(topo, uncached,
+                                                        monkeypatch):
+    """The cell's decode program built for a TPU: no kernel of this
+    repo's in the nine Mamba-2 layers (XLA's one fused pass over the state
+    was faster than the kernel written for it, PERF.md section 6, PR 41),
+    the ragged kernel in the one attention
+    layer (8 K/V heads of 128: whole tiles, no flat view), the experts'
+    gate and up products by ``megablox.gmm`` and the down product, whose
+    expert does not fit VMEM twice, by ``ragged_dot``; the K/V rows AND
+    the states aliased onto their inputs (8.9 GB, updated in place), no
+    copy of a state- or cache-sized array, and small temporaries beside
+    13 GB of weights and cache."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _granite4_programs(topo)
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(192), i32(192), i32(4, 192)).compile()
+    text = compiled.as_text()
+    kernel = lambda name: [
+        line for line in text.splitlines()
+        if re.search(rf"%{name}\S* = .* custom-call\(", line)]
+    assert len(kernel("ragged_decode_attention")) == 1
+    assert not kernel("gated_delta_step")
+    # 192 slots x 10 picks = 1,920 pairs, ~240 of them on the 9 held
+    # experts: blocks of 512 sorted rows (ops/moe.block_rows); the gate
+    # and up products [4096, 768] fit VMEM twice over and take gmm, the
+    # down product [768, 4096] does not (ops/moe.product_tiling's one
+    # rule) and stays on ragged_dot, in each of ten layers.
+    assert _grouped_products(text, "gmm") == [(512, 768)] * 20
+    assert _grouped_products(text) == [(512, 4096)] * 10
+    mem = compiled.memory_analysis()
+    state = 128 * 64 * 128 * 4 + 3 * 8448 * 2
+    assert mem.alias_size_in_bytes == 192 * (2048 * 4096 + 9 * state)
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.2e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert not re.search(r"f32\[192,128,64,128\]\S* copy\(", text)
+    assert not re.search(r"bf16\[192,2048,8,128\]\S* copy\(", text)
+    _vectors_stay_on_the_chip(compiled, 192)
+
+
+@pytest.mark.parametrize("batch, bucket", [(2, 256), (1, 512), (2, 1024)])
+def test_granite4_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
+                                                    bucket, monkeypatch):
+    """The ladder's three buckets the cell warms, the fullest last: the
+    chunked scan compiles (no solve: a plain recurrence over the chunks),
+    attention up to one tile is the einsum chain, and weights, cache and
+    activations fit the chip."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _granite4_programs(topo)
+    compiled = jax.jit(lambda *args: eng._prefill_bucketed.__wrapped__(
+        model, *args), donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(batch, bucket), i32(batch), i32(batch)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    assert "splash" not in text and "triangular" not in text.lower()
+    assert bucket in model.prefill_buckets(2048)
