@@ -42,6 +42,10 @@ from distributedtensorflowexample_tpu.models import build_model
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.resilience.snapshot import (
     SnapshotStore)
+# A second name for queue.py's definition: queue.py must not import
+# this module (tests/test_import_graph.py).
+from distributedtensorflowexample_tpu.serving.queue import (  # noqa: F401
+    as_prompt)
 from distributedtensorflowexample_tpu.training.state import TrainState
 
 _LAYOUTS = ("tree", "bucket_rows", "zero3_rows")
@@ -443,25 +447,3 @@ class Canary:
             "baseline_p99_ms": (None if p99b is None
                                 else round(p99b * 1000, 3)),
             "canary_failures": self._bad}
-
-
-def as_prompt(tokens, vocab: int) -> np.ndarray:
-    """Validate a request's prompt tokens on the HOST, before anything
-    reaches the device: out-of-vocab ids are refused by name — the
-    training-side OOV NaN-poison guards corruption mid-run, but a live
-    batch must never be poisoned by one bad request (the refusal is the
-    serving analog: loud, per-request, batch untouched)."""
-    arr = np.asarray(tokens)
-    if arr.ndim != 1 or arr.size == 0:
-        raise ValueError(f"prompt must be a non-empty 1-D token list, "
-                         f"got shape {arr.shape}")
-    if not np.issubdtype(arr.dtype, np.integer):
-        raise ValueError(f"prompt tokens must be integers, got dtype "
-                         f"{arr.dtype}")
-    if int(arr.min()) < 0 or int(arr.max()) >= vocab:
-        raise ModeRefusal(
-            f"request carries out-of-vocab token id(s) (valid range "
-            f"[0, {vocab})) — refused at admission; the --size model's "
-            f"vocabulary is fixed at training time and an OOV gather "
-            f"would silently clamp into a wrong embedding row")
-    return arr.astype(np.int32)

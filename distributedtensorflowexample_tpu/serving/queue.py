@@ -70,7 +70,6 @@ from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.obs import trace as obs_trace
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.serving.engine import DecodeEngine
-from distributedtensorflowexample_tpu.serving.promote import as_prompt
 
 _REQUESTS = obs_metrics.counter(
     "serve_requests_total", "serving requests by outcome "
@@ -98,6 +97,28 @@ _P50 = obs_metrics.gauge(
     "serve_latency_p50_ms", "rolling p50 of completed-request latency")
 _P99 = obs_metrics.gauge(
     "serve_latency_p99_ms", "rolling p99 of completed-request latency")
+
+
+def as_prompt(tokens, vocab: int) -> np.ndarray:
+    """Validate a request's prompt tokens on the HOST, before anything
+    reaches the device: out-of-vocab ids are refused by name — the
+    training-side OOV NaN-poison guards corruption mid-run, but a live
+    batch must never be poisoned by one bad request (the refusal is the
+    serving analog: loud, per-request, batch untouched)."""
+    arr = np.asarray(tokens)
+    if arr.ndim != 1 or arr.size == 0:
+        raise ValueError(f"prompt must be a non-empty 1-D token list, "
+                         f"got shape {arr.shape}")
+    if not np.issubdtype(arr.dtype, np.integer):
+        raise ValueError(f"prompt tokens must be integers, got dtype "
+                         f"{arr.dtype}")
+    if int(arr.min()) < 0 or int(arr.max()) >= vocab:
+        raise ModeRefusal(
+            f"request carries out-of-vocab token id(s) (valid range "
+            f"[0, {vocab})) — refused at admission; the --size model's "
+            f"vocabulary is fixed at training time and an OOV gather "
+            f"would silently clamp into a wrong embedding row")
+    return arr.astype(np.int32)
 
 
 def serve_slo_ms_default() -> float:

@@ -5,6 +5,12 @@ Semantics preserved from the reference: periodic saves, keep-N rotation,
 auto-restore-from-latest on startup, chief-only effective writes (Orbax is
 multi-host aware — every process must call save, primary writes).  Gained:
 async saves (training does not stall on serialization).
+
+``orbax.checkpoint`` is imported where a manager is built, not with this
+module: it pulls tensorstore, grpc and ``google.cloud.logging`` with it
+(11–13 s of a fresh interpreter on a chip machine, PERF.md §6 PR 42), and
+``saveable_state_dict`` — all that resilience/ takes from here — needs
+none of it.
 """
 
 from __future__ import annotations
@@ -14,7 +20,6 @@ import os
 from typing import Any
 
 import jax
-import orbax.checkpoint as ocp
 
 from distributedtensorflowexample_tpu.training.state import TrainState
 
@@ -34,12 +39,20 @@ _saveable = saveable_state_dict
 
 
 class CheckpointManager:
+    """Building one is what imports ``orbax.checkpoint``.  A run that
+    checkpoints (``cfg.checkpoint_every > 0 or cfg.resume``) builds its
+    manager during set-up (engine/engine.py), so it pays the import
+    there and never in the middle of the loop; a run that opens no
+    checkpoint never pays it."""
+
     def __init__(self, directory: str, max_to_keep: int = 3,
                  async_save: bool = True, run_metadata: dict | None = None):
         """``run_metadata``: small JSON-able facts about the writing run
         (e.g. ``sync_mode``) persisted next to the checkpoints so a later
         run can refuse a structurally-incompatible restore with a clear
         error instead of a shape mismatch deep inside Orbax."""
+        import orbax.checkpoint as ocp
+        self._ocp = ocp
         self._dir = os.path.abspath(directory)
         os.makedirs(self._dir, exist_ok=True)
         self._mgr = ocp.CheckpointManager(
@@ -54,9 +67,9 @@ class CheckpointManager:
         if step in self._mgr.all_steps():
             return False  # periodic save already covered this step
         self._write_run_metadata()
-        return self._mgr.save(step,
-                              args=ocp.args.StandardSave(_saveable(state)),
-                              force=force)
+        return self._mgr.save(
+            step, args=self._ocp.args.StandardSave(_saveable(state)),
+            force=force)
 
     def _write_run_metadata(self) -> None:
         """Keep the metadata describing the CURRENT writer: a reused
@@ -91,8 +104,8 @@ class CheckpointManager:
         if step is None:
             return state
         template = jax.tree.map(lambda x: x, _saveable(state))
-        restored = self._mgr.restore(step,
-                                     args=ocp.args.StandardRestore(template))
+        restored = self._mgr.restore(
+            step, args=self._ocp.args.StandardRestore(template))
         return state.replace(**restored)
 
     def wait(self) -> None:
