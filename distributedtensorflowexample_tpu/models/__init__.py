@@ -63,9 +63,14 @@ def build_model_from_config(config, **kw):
         from distributedtensorflowexample_tpu.models.granitemoehybrid import (
             build_granitemoehybrid)
         return build_granitemoehybrid(config, **kw)
+    if kind == "kimi_k2":
+        from distributedtensorflowexample_tpu.models.kimi_k2 import (
+            build_kimi_k2)
+        return build_kimi_k2(config, **kw)
     raise ValueError(
         f"no model is built from a configuration of model_type {kind!r} "
-        f"(have: afmoe, bailing_hybrid, granitemoehybrid, qwen3_next; the "
+        f"(have: afmoe, bailing_hybrid, granitemoehybrid, kimi_k2, "
+        f"qwen3_next; the "
         f"GPT-2 ladder is built by size, LM_SIZES)")
 
 

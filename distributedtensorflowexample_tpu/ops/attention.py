@@ -412,10 +412,12 @@ def latent_decode_attention(q, rows, lengths, *, v_dim: int, scale: float):
                       rows[..., :v_dim])
 
 
-def latent_expanded_attention(q, k, v, *, block: int = ATTN_BLOCK):
+def latent_expanded_attention(q, k, v, *, block: int = ATTN_BLOCK,
+                              scale: float | None = None):
     """A whole sequence of a latent-attention layer, expanded form: the
     model has made per-head keys ``k [B, T, H, Dh]`` and values ``v [B,
     T, H, Dv]`` from the rows; :func:`grouped_attention` with the call
-    counted."""
+    counted (``scale`` None: ``Dh ** -0.5``; a model whose positions are
+    stretched states its own)."""
     _LATENT.labels(impl="expanded").inc()
-    return grouped_attention(q, k, v, block=block)
+    return grouped_attention(q, k, v, block=block, scale=scale)

@@ -36,7 +36,7 @@ never looks inside.  There are four kinds of layer:
 A module may also state the ladder of lengths its prompts are padded to
 (``prefill_buckets(cache_len)``); without one the engine pads to the
 next power of two (:func:`_prefill_buckets`).
-Five layouts exist today:
+Six layouts exist today:
 
 * ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
   Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
@@ -59,6 +59,11 @@ Five layouts exist today:
   tiles as they are), a Mamba-2 layer's its recurrent state ``[S, H, P,
   N]`` float32 and its convolution's last inputs — the ``state`` kind
   again, under a third recurrence; nothing here changed for it.
+* ``models/kimi_k2.py``'s model (its own serving module): every layer
+  ``latent`` and no other kind — ``ck`` holds the rows ``[S, cache_len,
+  row]`` of each layer, ``cv`` only empty arrays; the bookkeeping is the
+  ``latent`` kind's as it was (``cache_slot_bytes``, rows read and
+  fetched), and what moves the stacked K/V pair refuses it by name.
 * ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
   stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
   them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU
@@ -417,8 +422,8 @@ def serving_lm_for(model) -> ServingLM:
                      parent=None)    # a module of its own, not a child
 
 
-#: Why a kind of layer whose cache is not ``cache_len`` rows by position
-#: cannot be served by what reads, writes or rolls back such rows:
+#: Why a kind of layer whose cache is not ``cache_len`` K/V rows by
+#: position cannot be served by what reads, writes or rolls back such rows:
 #: (what the layers are called, what they keep, what that breaks).
 _NO_ROWS_BY_POSITION = {
     "window": (
@@ -431,14 +436,20 @@ _NO_ROWS_BY_POSITION = {
         "keep no cache rows but a state that every token rewrites whole",
         "a state that has taken a token can neither be cut at a position "
         "nor give the token back"),
+    "latent": (
+        "latent-attention layers",
+        "keep one compressed row a position that every head shares as key "
+        "and as value, and no V array",
+        "what that moves is the stacked K/V pair of every head, which such "
+        "a layer never holds"),
 }
 
 
 def refuse_cache_without_rows_by_position(model, what: str) -> None:
     """``what`` needs every layer's cache to be the same ``cache_len``
-    rows, addressed by position; a model with window layers keeps rings,
-    one with state layers keeps no rows at all, and either is refused by
-    name."""
+    K/V rows, addressed by position; a model with window layers keeps
+    rings, one with state layers keeps no rows at all, one with latent
+    layers no K/V pair, and each is refused by name."""
     rows = model.serving_module().cache_rows(1)
     for kind, (called, keep, breaks) in _NO_ROWS_BY_POSITION.items():
         n = sum(k == kind for k, _ in rows)
@@ -627,7 +638,7 @@ class DecodeEngine:
             _CACHE_BYTES.labels(kind=kind).set(held)
         # What one slot's states cost a decode step: read and written.
         self._state_bytes_slot = 2 * by_kind.get("state", 0) // self.slots
-        #: Layers whose cache is not ``cache_len`` rows by position.
+        #: Layers whose cache is not ``cache_len`` K/V rows by position.
         self.layers_without_rows_by_position = sum(
             n for kind, (n, _) in kinds.items()
             if kind in _NO_ROWS_BY_POSITION)

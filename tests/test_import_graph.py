@@ -126,6 +126,27 @@ assert not loaded("orbax")      # promotion reads snapshots, not Orbax
 """)
 
 
+@pytest.mark.parametrize("module, config", [
+    ("kimi_k2", "kimi_k2_5_ep32"), ("bailing_hybrid", "ling3_flash_ep8")])
+def test_an_architectures_module_loads_when_a_configuration_asks(module,
+                                                                  config):
+    """``models`` and the serving path load no architecture's module (its
+    imports, counters and kernels' wrappers would be set-up seconds of
+    every cell that does not run it); ``build_model_from_config`` loads
+    the one a configuration names, and no other."""
+    others = sorted({"afmoe", "bailing_hybrid", "granitemoehybrid",
+                     "kimi_k2", "qwen3_next"} - {module})
+    fresh_python(SERVE_IMPORTS + f"""
+import {PKG}.models as models
+blocks = tuple("{PKG}.models." + m for m in {[module] + others!r})
+assert not loaded(*blocks), loaded(*blocks)
+model = models.build_model_from_config("benchmarks/configs/{config}.json")
+assert blocks[0] in sys.modules
+assert not loaded(*blocks[1:]), loaded(*blocks[1:])
+assert model.serving_module() is model
+""")
+
+
 def test_as_prompt_has_one_definition_and_two_names():
     fresh_python(f"""
 from {PKG}.serving import queue

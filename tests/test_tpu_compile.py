@@ -542,3 +542,84 @@ def test_granite4_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     text = compiled.as_text()
     assert "splash" not in text and "triangular" not in text.lower()
     assert bucket in model.prefill_buckets(2048)
+
+
+# ---- the kimi_k2 share at the benchmark cell's own sizes (PR 43) ------------
+
+def _kimi_k2_programs(topo, slots=80, cache_len=10240):
+    """The model of benchmarks/configs/kimi_k2_5_ep32.json with shapes for
+    its parameters and for an 80-slot, 10,240-row engine's cache — latent
+    rows in all five layers and nothing else — all on the described
+    chip."""
+    import os
+    from distributedtensorflowexample_tpu.models import (
+        build_model_from_config)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model_from_config(os.path.join(
+        root, "benchmarks", "configs", "kimi_k2_5_ep32.json"))
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ck, cv = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, cache_len)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+    return model, params, ck, cv, i32
+
+
+def test_kimi_k2_decode_step_compiles_for_v5e_in_place(topo, uncached,
+                                                       monkeypatch):
+    """The cell's decode program built for a TPU: the latent kernel in
+    each of the five layers over rows of 640 at 64 heads, the experts'
+    grouped products on ``ragged_dot`` (an expert of [7168, 2048] is 58.7
+    MB of tiles against 16 MB of scoped VMEM: ``ops/moe.product_tiling``'s
+    one rule) over blocks of 128 sorted rows, the rows of every layer
+    aliased onto their inputs (5.24 GB, updated in place), no copy of a
+    cache-sized array, and small temporaries beside 12.2 GB of weights
+    and rows."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _kimi_k2_programs(topo)
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(80), i32(80), i32(4, 80)).compile()
+    text = compiled.as_text()
+    kernel = lambda name: [
+        line for line in text.splitlines()
+        if re.search(rf"%{name}\S* = .* custom-call\(", line)]
+    assert len(kernel("latent_decode_attention")) == 5
+    assert not kernel("ragged_decode_attention")
+    # 80 slots x 8 picks = 640 pairs, ~20 of them on the 12 held experts:
+    # one block of 128 sorted rows (ops/moe.block_rows), three products a
+    # layer in each of four expert layers.
+    assert not _grouped_products(text, "gmm")
+    assert sorted(_grouped_products(text)) == (
+        [(128, 2048)] * 8 + [(128, 7168)] * 4)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 80 * 5 * 10240 * 640 * 2
+    assert 12.1e9 < mem.argument_size_in_bytes < 12.4e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert not re.search(r"bf16\[80,10240,640\]\S* copy\(", text)
+    _vectors_stay_on_the_chip(compiled, 80)
+
+
+@pytest.mark.parametrize("batch, bucket", [(1, 3072), (2, 5120)])
+def test_kimi_k2_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
+                                                   bucket, monkeypatch):
+    """The first and the fullest program the cell warms: latent attention
+    past one tile of 512 is the TPU's kernel at 64 query/key heads of 192
+    padded to 256 and value heads of 128, in each of five layers, and
+    weights, rows and activations fit the chip."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    model, params, ck, cv, i32 = _kimi_k2_programs(topo)
+    compiled = jax.jit(lambda *args: eng._prefill_bucketed.__wrapped__(
+        model, *args), donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(batch, bucket), i32(batch), i32(batch)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert "splash" in compiled.as_text()
+    assert bucket in model.prefill_buckets(10240)
