@@ -37,23 +37,34 @@ from what the call observes — the backend, the block's rows, the expert
 matrix's shape and type — as ``ops/attention.py`` chooses its paths, each
 product for itself; ``moe_grouped_products_total{kernel}`` counts the
 products traced by the kernel taken.  Where the program is built for a
-TPU, the block is whole row tiles and an expert's bfloat16 matrix fits the
-kernel's VMEM twice over (double-buffered, beside a row tile of the left
-operand and of the output), the product is JAX's ``megablox.gmm`` in tiles
-this module states: :data:`ROW_TILE` rows by a whole expert.  The kernel
-visits only the row tiles that hold a group's rows, reads each touched
-expert once and multiplies 128 rows against it, so its time is the touched
-experts' bytes at 85–90% of the HBM's pace whatever a token step's block:
-0.32 ms for 56 experts of ``[2560, 768]`` (221 MB) in a block of 512 or of
-640 rows, and 0.40 for all 64 in a full block of 2,048 (PERF.md section 6,
-PR 38).  Everywhere else — an expert of ``[3072, 3072]`` (18.9 MB), a
-block that is no whole row tiles, the CPU — it is ``jax.lax.ragged_dot``,
-which XLA:TPU lowers to a kernel of its own that picks its row tile from
-the block's size: a block that is a multiple of 512 rows gets a tile of
-512 and the same product takes 0.87–1.0 ms, one of 640 or 384 rows
-0.54–0.57.  Both read only the experts a block has rows for; rows past the
-held pairs belong to no group, ``gmm`` leaves them unwritten, and the walk
-selects them away.
+TPU and the block is whole row tiles of bfloat16, the product is JAX's
+``megablox.gmm`` in tiles this module states: :data:`ROW_TILE` rows by a
+whole expert or by the widest whole-lane split of it that fits — the
+fewest equal tiles of columns, each whole lane tiles, that the kernel's
+VMEM holds twice over (double-buffered, beside a row tile of the left
+operand and of the output), at most :data:`MOST_TILES` of them.  An
+expert of ``[2560, 768]`` (3.9 MB) goes whole; one of ``[3072, 3072]``
+(18.9 MB) in three tiles of 1,024 columns, ``[768, 4096]`` in two; one of
+``[7168, 2048]`` (29.4 MB) would need eight and stays on ``ragged_dot``
+(what the kernel costs set-up, not the device, draws that line: see
+:data:`MOST_TILES`).  K is never split: a column's sum stays one float32
+sum, so the tiles change no bit of the result.  The kernel's grid
+runs the column tiles outermost and inside them visits only the row tiles
+that hold a group's rows, so each touched expert is still read once and
+128 rows are multiplied against it (the block's rows are read once a
+column tile: a few hundred KB); its time is the touched experts' bytes at
+85–90% of the HBM's pace whatever a token step's block: 0.32 ms for 56
+experts of ``[2560, 768]`` (221 MB) in a block of 512 or of 640 rows, and
+0.40 for all 64 in a full block of 2,048 (PERF.md section 6, PR 38; the
+split tiles' readings: section 6, PR 44).  Everywhere else — a block that
+is no whole row tiles, an expert that fits in no such split, the CPU — it
+is
+``jax.lax.ragged_dot``, which XLA:TPU lowers to a kernel of its own that
+picks its row tile from the block's size: a block that is a multiple of
+512 rows gets a tile of 512 and the same product takes 0.87–1.0 ms, one of
+640 or 384 rows 0.54–0.57.  Both read only the experts a block has rows
+for; rows past the held pairs belong to no group, ``gmm`` leaves them
+unwritten, and the walk selects them away.
 
 **How many rows a block has** (:func:`block_rows`) follows from the same
 observation — a call's pairs, the experts held and the experts the router
@@ -81,7 +92,8 @@ _PRODUCTS = obs_metrics.counter(
     "moe_grouped_products_total",
     "grouped products traced (three an expert layer of a program), by "
     "the kernel taken: gmm (megablox.gmm in the tiles ops/moe.py chose: a "
-    "row tile by a whole expert) | ragged_dot (jax.lax.ragged_dot)")
+    "row tile by a whole expert or by the widest whole-lane split of it "
+    "that fits) | ragged_dot (jax.lax.ragged_dot)")
 
 #: The most sorted (token, expert) rows that go through the grouped
 #: products at a time: the cap of :func:`block_rows`, reached by the
@@ -101,6 +113,25 @@ BLOCK_ROWS = 2048
 #: of 256 rows read 3% slower at a token step's blocks and 256 rows 7%
 #: slower on a full block (PERF.md section 6, PR 38).
 ROW_TILE = 128
+
+#: Columns of one lane tile: a split of an expert's columns is a whole
+#: number of these (``megablox.gmm``'s ``tn``).  ``ops/pallas/tiling``'s
+#: constant, stated again: importing that package imports Pallas, which
+#: this module leaves to the call that takes the kernel.
+LANES = 128
+
+#: The most tiles an expert's columns are split into; an expert that
+#: needs more stays on ``ragged_dot``.  Not the device's limit — on the
+#: chip the kernel wins at seven and eight tiles too (Kimi-K2.5's
+#: ``[2048, 7168]`` and ``[7168, 2048]``: 0.36 and 0.38 ms a token step's
+#: product against 0.49) — but set-up's: every (product shape, block size)
+#: the kernel is traced for and every program that carries it cost the
+#: chip's host 0.2–0.4 s, and such experts bring two shapes a layer
+#: (gate/up and down are no longer the same ``[k, n]``).  It stands
+#: between the widest split whose cell afforded that (three tiles: warm
+#: ``setup_s`` +9%, bound 10%) and the narrowest whose cell did not
+#: (seven and eight: +13 to +15%); PERF.md section 6, PR 44.
+MOST_TILES = 4
 
 #: VMEM the tiles of a grouped product may fill: a TPU v5e's scoped
 #: default, under which Mosaic compiles a kernel that states no limit of
@@ -161,15 +192,25 @@ def product_tiling(rows: int, k: int, n: int, dtype) -> tuple | None:
     grouped product of a ``[rows, k]`` block with experts of ``[k, n]``,
     or None where the product is ``jax.lax.ragged_dot``'s.  One rule:
     built for a TPU, a bfloat16 block of whole row tiles, and
-    :data:`ROW_TILE` rows by a whole expert fit :data:`V5E_SCOPED_VMEM`
-    as the kernel holds them — the matrix and the row tiles of the left
-    operand and of the output double-buffered, beside the float32
-    accumulator and product."""
+    :data:`ROW_TILE` rows by a whole expert — or by the widest split of
+    it into at most :data:`MOST_TILES` equal whole lane tiles of columns
+    — fit :data:`V5E_SCOPED_VMEM` as the kernel holds them: the matrix's
+    tile and the row tiles of the left operand and of the output
+    double-buffered, beside the float32 accumulator and product.  K is
+    never split: that costs the bit-equality with ``ragged_dot`` and
+    time (PERF.md section 6, PR 38)."""
     if (jax.default_backend() != "tpu" or rows % ROW_TILE
             or jnp.dtype(dtype) != jnp.bfloat16):
         return None
-    tiles = 2 * 2 * (k * n + ROW_TILE * (k + n)) + 2 * 4 * ROW_TILE * n
-    return (ROW_TILE, k, n) if tiles <= V5E_SCOPED_VMEM else None
+    for split in range(1, MOST_TILES + 1):
+        tn = n // split
+        if n % split or tn % LANES:
+            continue
+        tiles = (2 * 2 * (k * tn + ROW_TILE * (k + tn))
+                 + 2 * 4 * ROW_TILE * tn)
+        if tiles <= V5E_SCOPED_VMEM:
+            return (ROW_TILE, k, tn)
+    return None
 
 
 def grouped_product(x, w, sizes):

@@ -530,19 +530,20 @@ def test_the_engines_counters_follow_the_programs_counts(params, sequences):
     assert engine.cache_bytes == 3 * (64 + 3 * 8) * row
 
 
-@pytest.mark.parametrize("tiled, slots", [(False, 5), (True, 7)])
+@pytest.mark.parametrize("tiled, slots", [(0, 5), (1, 7), (2, 6)])
 def test_the_products_an_engine_traces_are_counted_by_kernel(
         params, sequences, monkeypatch, tiled, slots):
     """``moe_grouped_products_total{kernel}``: every product of programs
     built for the CPU is ``ragged_dot``'s; where the chooser answers with
-    tiles (here: a block's rows by a whole expert, interpreted) every
-    one is the tiled kernel's — three an expert layer of each program
-    traced, and the counts the programs return do not change.  Engines of
-    their own sizes, so that each traces its programs under the chooser
-    it is given."""
+    tiles (here: a block's rows by a whole expert, or by half of its
+    columns — ``tiled`` tiles an expert — interpreted) every one is the
+    tiled kernel's — three an expert layer of each program traced, and
+    the counts the programs return do not change.  Engines of their own
+    sizes, so that each traces its programs under the chooser it is
+    given."""
     if tiled:
         monkeypatch.setattr(moe, "product_tiling",
-                            lambda rows, k, n, dtype: (rows, k, n))
+                            lambda rows, k, n, dtype: (rows, k, n // tiled))
     names = ['moe_grouped_products_total{kernel="ragged_dot"}',
              'moe_grouped_products_total{kernel="gmm"}',
              "moe_rows_walked_total"]

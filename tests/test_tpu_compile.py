@@ -111,21 +111,30 @@ def _grouped_products(text: str, kernel: str = "ragged-dot-none") -> list:
         text)]
 
 
-@pytest.mark.parametrize("rows, k, n", [
-    (512, 2560, 768),       # ling3_flash_ep8, gate and up
-    (2048, 768, 2560),      # its down, a full prefill block
-    (640, 2048, 512),       # qwen3_next_ep8, gate and up
-    (2048, 512, 2048),      # its down, a full prefill block
-    (512, 2560, 1280),      # the largest the rule lets in: 16.4 of 16.8 MB
+@pytest.mark.parametrize("rows, k, n, tn", [
+    (512, 2560, 768, 768),      # ling3_flash_ep8, gate and up
+    (2048, 768, 2560, 2560),    # its down, a full prefill block
+    (640, 2048, 512, 512),      # qwen3_next_ep8, gate and up
+    (2048, 512, 2048, 2048),    # its down, a full prefill block
+    (512, 2560, 1280, 1280),    # the largest whole expert: 16.4 of 16.8 MB
+    (512, 2560, 1536, 768),     # the next: its columns in two tiles
+    (128, 3072, 3072, 1024),    # trinity_large_ep8's token step: in three,
+    (2048, 3072, 3072, 1024),   # and a full prefill block (15.7 of 16.8 MB)
+    (512, 2560, 4096, 1024),    # the most tiles the rule splits into: four
+    (512, 768, 4096, 2048),     # granite4_h_small_ep8's down: in two
+    (512, 4096, 768, 768),      # its gate and up, whole
 ])
 def test_the_tiles_the_walk_chooses_compile_for_v5e(topo, uncached,
-                                                    monkeypatch, rows, k, n):
+                                                    monkeypatch, rows, k, n,
+                                                    tn):
     """``ops/moe.product_tiling``'s answer is one Mosaic accepts inside
     the VMEM the kernel compiles under, at the cells' shapes and at the
-    edge of the rule: one kernel, no copy of the experts."""
+    edges of the rule — a whole expert, or its columns in the fewest
+    equal whole-lane tiles that fit, four at most: one kernel, no copy
+    of the experts."""
     from distributedtensorflowexample_tpu.ops import moe
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert moe.product_tiling(rows, k, n, jnp.bfloat16) == (128, k, n)
+    assert moe.product_tiling(rows, k, n, jnp.bfloat16) == (128, k, tn)
     one = SingleDeviceSharding(topo.devices[0])
     sds = lambda shape, t: jax.ShapeDtypeStruct(shape, t, sharding=one)
     compiled = jax.jit(moe.grouped_product).lower(
@@ -158,23 +167,28 @@ def _trinity_programs(topo, slots=32, cache_len=16384):
     return model, params, ck, cv, i32
 
 
-def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(topo,
-                                                                 uncached):
-    """The decode program of the cell (32 slots, 16,384 rows): the
-    grouped products are the TPU's own kernel (three a layer, four
-    expert layers), every layer's cache is aliased onto its input, and
-    the program's temporaries are small beside 12.9 GB of weights and
-    cache."""
+def test_trinity_decode_step_compiles_for_v5e_beside_a_full_chip(
+        topo, uncached, monkeypatch):
+    """The decode program of the cell (32 slots, 16,384 rows) as the
+    chip builds it: the grouped products are ``megablox.gmm``'s in the
+    tiles ``ops/moe.product_tiling`` chose (three a layer, four expert
+    layers; an expert's columns in three tiles of 1,024), every layer's
+    cache is aliased onto its input, and the program's temporaries are
+    small beside 12.9 GB of weights and cache."""
     from distributedtensorflowexample_tpu.serving import engine as eng
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, params, ck, cv, i32 = _trinity_programs(topo)
-    compiled = eng._decode_step.lower(model, params, ck, cv, i32(32),
-                                      i32(32), i32(4, 32)).compile()
+    # A new function: jax caches a trace by its arguments, not by the
+    # backend the ops were told.
+    compiled = jax.jit(lambda *args: eng._decode_step_fn(model, *args),
+                       donate_argnums=(1, 2)).lower(
+        params, ck, cv, i32(32), i32(32), i32(4, 32)).compile()
     mem = compiled.memory_analysis()
     assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
     assert mem.temp_size_in_bytes < 0.5e9
-    assert compiled.as_text().count('op_name="ragged-dot-none"') >= 12
-    assert not _grouped_products(compiled.as_text(), "gmm")
+    assert _grouped_products(compiled.as_text(), "gmm") == [(128, 3072)] * 12
+    assert not _grouped_products(compiled.as_text())
 
 
 @pytest.mark.parametrize("batch, bucket, block", [
@@ -189,9 +203,10 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     and the program's activations together inside the chip's 16.9 GB.
     Past one tile the attention of every layer is the TPU's kernel, not
     the tiled walk's loop (the backend is the CPU's here, so the test
-    says "built for a TPU" itself).  The grouped products stay
-    ``ragged_dot``'s over the walk's blocks — an expert of 18.9 MB fits
-    no VMEM tile — and no kernel of ``ops/moe.py``'s choosing appears."""
+    says "built for a TPU" itself).  The grouped products over the walk's
+    blocks are ``megablox.gmm``'s — an expert of 18.9 MB fits no VMEM
+    tile, a third of its columns does (``ops/moe.product_tiling``) — and
+    none is left to ``ragged_dot``."""
     from distributedtensorflowexample_tpu.ops import attention as attention_op
     from distributedtensorflowexample_tpu.serving import engine as eng
     monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
@@ -205,8 +220,8 @@ def test_trinity_prefill_compiles_for_v5e_and_fits(topo, uncached, batch,
     text = compiled.as_text()
     assert ("splash" in text) == (bucket > attention_op.ATTN_BLOCK)
     assert (bucket in model.prefill_buckets(16384)) == (bucket > 256)
-    assert _grouped_products(text) == [(block, 3072)] * 12
-    assert not _grouped_products(text, "gmm")
+    assert _grouped_products(text, "gmm") == [(block, 3072)] * 12
+    assert not _grouped_products(text)
 
 
 # ---- the token step's ragged attention (PR 29) ------------------------------
@@ -252,10 +267,10 @@ def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
                             line)]
     assert len(kernels) == 5
     assert all('custom_call_target="tpu_custom_call"' in k for k in kernels)
-    # 32 slots x 4 picks are one block of 128 sorted rows, no loop: the
-    # walk's rule (ops/moe.block_rows) leaves this step as it was.
-    assert _grouped_products(text) == [(128, 3072)] * 12
-    assert not _grouped_products(text, "gmm")
+    # 32 slots x 4 picks are one block of 128 sorted rows, no loop
+    # (ops/moe.block_rows), each product the tiled kernel's.
+    assert _grouped_products(text, "gmm") == [(128, 3072)] * 12
+    assert not _grouped_products(text)
     assert "moe.experts/while" not in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * 32 * (16384 + 4 * 4096) * 2048
@@ -488,8 +503,9 @@ def test_granite4_decode_step_compiles_for_v5e_in_place(topo, uncached,
     was faster than the kernel written for it, PERF.md section 6, PR 41),
     the ragged kernel in the one attention
     layer (8 K/V heads of 128: whole tiles, no flat view), the experts'
-    gate and up products by ``megablox.gmm`` and the down product, whose
-    expert does not fit VMEM twice, by ``ragged_dot``; the K/V rows AND
+    three products by ``megablox.gmm`` — gate and up by whole experts,
+    the down product, whose expert does not fit VMEM twice, with its
+    columns in two tiles; the K/V rows AND
     the states aliased onto their inputs (8.9 GB, updated in place), no
     copy of a state- or cache-sized array, and small temporaries beside
     13 GB of weights and cache."""
@@ -508,11 +524,12 @@ def test_granite4_decode_step_compiles_for_v5e_in_place(topo, uncached,
     assert not kernel("gated_delta_step")
     # 192 slots x 10 picks = 1,920 pairs, ~240 of them on the 9 held
     # experts: blocks of 512 sorted rows (ops/moe.block_rows); the gate
-    # and up products [4096, 768] fit VMEM twice over and take gmm, the
-    # down product [768, 4096] does not (ops/moe.product_tiling's one
-    # rule) and stays on ragged_dot, in each of ten layers.
-    assert _grouped_products(text, "gmm") == [(512, 768)] * 20
-    assert _grouped_products(text) == [(512, 4096)] * 10
+    # and up products [4096, 768] fit VMEM twice over, the down product
+    # [768, 4096] does in halves (ops/moe.product_tiling's one rule):
+    # thirty products by gmm in ten layers, none by ragged_dot.
+    assert sorted(_grouped_products(text, "gmm")) == (
+        [(512, 768)] * 20 + [(512, 4096)] * 10)
+    assert not _grouped_products(text)
     mem = compiled.memory_analysis()
     state = 128 * 64 * 128 * 4 + 3 * 8448 * 2
     assert mem.alias_size_in_bytes == 192 * (2048 * 4096 + 9 * state)
@@ -573,8 +590,10 @@ def test_kimi_k2_decode_step_compiles_for_v5e_in_place(topo, uncached,
     """The cell's decode program built for a TPU: the latent kernel in
     each of the five layers over rows of 640 at 64 heads, the experts'
     grouped products on ``ragged_dot`` (an expert of [7168, 2048] is 58.7
-    MB of tiles against 16 MB of scoped VMEM: ``ops/moe.product_tiling``'s
-    one rule) over blocks of 128 sorted rows, the rows of every layer
+    MB of tiles against 16 MB of scoped VMEM and fits in eight tiles of
+    columns, the down matrix in seven: more than
+    ``ops/moe.product_tiling`` splits into) over blocks of 128 sorted
+    rows, the rows of every layer
     aliased onto their inputs (5.24 GB, updated in place), no copy of a
     cache-sized array, and small temporaries beside 12.2 GB of weights
     and rows."""
