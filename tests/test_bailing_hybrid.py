@@ -249,7 +249,7 @@ def test_the_latent_kernel_reads_one_shared_row(monkeypatch):
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     q, rows = f(S, H, D) * 0.3, f(S, R, D)
     lengths = jnp.asarray([1, 100, 256], jnp.int32)
-    assert ragged.latent_fetch_block(8192, 640, 512) == 512
+    assert ragged.latent_fetch_block(8192, 640, 512) == 128
     assert ragged.latent_fetch_block(8192, 576, 500) == 0
     assert attention_op.latent_fetch_block(8192, 640, 512) == 0     # the CPU
     want = attention_op.latent_decode_attention(

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmarks.reference import kimi_k2 as ref
 from distributedtensorflowexample_tpu.models import build_model_from_config
@@ -22,6 +23,8 @@ from distributedtensorflowexample_tpu.models import kimi_k2
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.ops import attention as attention_op
 from distributedtensorflowexample_tpu.ops import moe
+from distributedtensorflowexample_tpu.ops.pallas import (
+    decode_attention as latent_kernel)
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
 from distributedtensorflowexample_tpu.serving.engine import (
     DECODE_HLO_CONTRACT, DecodeEngine)
@@ -316,12 +319,56 @@ def test_the_latent_kernel_takes_64_heads():
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     q, rows = f(S, H, D) * 0.3, f(S, R, D)
     lengths = jnp.asarray([77, 256], jnp.int32)
-    assert ragged.latent_fetch_block(10240, 640, 512) == 512
+    assert ragged.latent_fetch_block(10240, 640, 512) == 128
     want = attention_op.latent_decode_attention(
         q, rows, lengths, v_dim=V, scale=0.144680)
     got = ragged.latent_decode_attention(
         q, rows, lengths, v_dim=V, scale=0.144680, block=128, interpret=True)
     assert got.shape == (S, H, V)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
+# The two interpreters the walk is held under: Pallas's own, whose copies
+# land as they are started, and the TPU's, whose copies land when they
+# are WAITED for into buffers that start as NaN — a block read before
+# its wait, or a wait on a copy never started, shows.
+@pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
+                         ids=["pallas", "tpu"])
+@pytest.mark.parametrize("block", latent_kernel.LATENT_BLOCKS)
+@pytest.mark.parametrize("name, lengths", [
+    ("one_row", lambda b, R: [1, 1, 1]),
+    ("exactly_one_block", lambda b, R: [b, b, b]),
+    ("one_row_over_a_block", lambda b, R: [b + 1, b + 1, b + 1]),
+    ("every_row", lambda b, R: [R, R, R]),
+    # the fetch ahead across slots: a slot's only block is started by
+    # the slot before it and starts the next slot's first itself
+    ("one_row_between_full_slots", lambda b, R: [R, 1, R]),
+    ("very_different_slots", lambda b, R: [1, R, b + 1, b, 2, R - 1]),
+])
+def test_the_latent_kernel_walks_live_blocks_only(name, lengths, block,
+                                                  interpret):
+    """One grid step a slot, the slot's ``ceil(length / block)`` blocks
+    fetched two buffers deep in granules of 128 rows, at every block of
+    ``LATENT_BLOCKS``: the einsum chain's attention with the dead rows of
+    the last live granule large and NaN in every granule past it."""
+    R, H, D, V = 2 * max(latent_kernel.LATENT_BLOCKS), 8, 256, 128
+    lengths = lengths(block, R)
+    rng = np.random.default_rng(5)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q, rows = f(len(lengths), H, D) * 0.3, f(len(lengths), R, D)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    assert latent_kernel.pick_block(
+        R, block, latent_kernel.LATENT_BLOCKS) == block
+    want = attention_op.latent_decode_attention(
+        q, rows, lengths, v_dim=V, scale=0.125)
+    at = jnp.arange(R)[None]
+    fetched = -(-lengths // latent_kernel.GRANULE) * latent_kernel.GRANULE
+    poisoned = jnp.where((at < lengths[:, None])[..., None], rows,
+                         jnp.where((at < fetched[:, None])[..., None],
+                                   1e4, jnp.nan))
+    got = latent_kernel.latent_decode_attention(
+        q, poisoned, lengths, v_dim=V, scale=0.125, block=block,
+        interpret=interpret)
     assert np.abs(np.asarray(got - want)).max() < 1e-5
 
 

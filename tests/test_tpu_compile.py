@@ -246,6 +246,51 @@ def test_decode_attention_compiles_to_one_kernel_for_v5e(topo, uncached,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+@pytest.mark.parametrize("slots, heads, rows", [
+    (80, 64, 10240),            # kimi_k2_5_ep32.serve_reasoning_backlog
+    (256, 32, 8192),            # ling3_flash_ep8.serve_longform_backlog
+])
+def test_latent_decode_attention_compiles_to_one_kernel_for_v5e(
+        topo, uncached, monkeypatch, slots, heads, rows):
+    """The latent op at the two cells' shapes: one Mosaic call a layer
+    with one grid step a slot, the rows left in HBM for the kernel's own
+    copies — a slot's 10 to 13 MB of them held whole and twice would not
+    fit the 16 MiB of scoped VMEM; the kernel holds two blocks of 1,024
+    rows, 2.6 MB — and no temporary, so no relaid copy of a 1 to 2.7 GB
+    operand."""
+    from distributedtensorflowexample_tpu.ops import attention as attention_op
+    from distributedtensorflowexample_tpu.ops.pallas import (
+        decode_attention as ragged)
+    monkeypatch.setattr(attention_op.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    op = lambda q, c, n: attention_op.latent_decode_attention(
+        q, c, n, v_dim=512, scale=0.1447)
+    args = (sds((slots, heads, 640), jnp.bfloat16),
+            sds((slots, rows, 640), jnp.bfloat16), sds((slots,), jnp.int32))
+    calls = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.append(eqn.params["grid_mapping"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(op)(*args).jaxpr)
+    (call,) = calls
+    block = ragged.pick_block(rows, blocks=ragged.LATENT_BLOCKS)
+    assert ragged.latent_fetch_block(rows, 640, 512) == ragged.GRANULE
+    assert call.grid == (slots,)
+    assert "any" in str(call.block_mappings[1].transformed_block_aval)
+    assert str(call.scratch_avals[0]) == (
+        f"Ref<vmem>{{bfloat16[2,{block},640]}}")
+    compiled = jax.jit(op).lower(*args).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
 def test_trinity_decode_step_takes_the_ragged_kernel_in_every_layer(
         topo, uncached, monkeypatch):
     """The cell's decode program built for a TPU: five ragged kernels
