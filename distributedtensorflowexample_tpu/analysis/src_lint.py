@@ -694,6 +694,9 @@ WIRING_ALLOWLIST = {
     "distributedtensorflowexample_tpu/ops/pallas/sgd.py":
         "fused-optimizer kernel launch idiom — per-device pallas "
         "dispatch under shard_map, not trainer wiring",
+    "distributedtensorflowexample_tpu/ops/attention.py":
+        "the training attention's Pallas kernel is wrapped per device "
+        "under shard_map where it is chosen (PR 25), not trainer wiring",
     "distributedtensorflowexample_tpu/serving/sharded.py":
         "sharded decode programs declare their own HLO contracts "
         "(DESIGN.md §25) — serving's analogue of parallel/",

@@ -14,6 +14,13 @@ import argparse
 import dataclasses
 from typing import Sequence
 
+#: The ``model_type``s a published configuration may name: the branches of
+#: ``models.build_model_from_config`` (each a subclass of ``models/
+#: served_lm.py: ServedLM`` in a module of its name), kept here, free of
+#: jax, for ``tools/serve_lm.py --model_config``'s help.
+CONFIG_MODEL_TYPES = ("afmoe", "bailing_hybrid", "granitemoehybrid",
+                      "kimi_k2", "qwen3_next")
+
 
 @dataclasses.dataclass
 class RunConfig:

@@ -1,3 +1,4 @@
+from distributedtensorflowexample_tpu.config import CONFIG_MODEL_TYPES
 from distributedtensorflowexample_tpu.models.softmax import SoftmaxRegression
 from distributedtensorflowexample_tpu.models.mnist_cnn import MnistCNN
 from distributedtensorflowexample_tpu.models.resnet import ResNet20, ResNetCIFAR
@@ -69,9 +70,8 @@ def build_model_from_config(config, **kw):
         return build_kimi_k2(config, **kw)
     raise ValueError(
         f"no model is built from a configuration of model_type {kind!r} "
-        f"(have: afmoe, bailing_hybrid, granitemoehybrid, kimi_k2, "
-        f"qwen3_next; the "
-        f"GPT-2 ladder is built by size, LM_SIZES)")
+        f"(have: {', '.join(CONFIG_MODEL_TYPES)}; the GPT-2 ladder is built "
+        f"by size, LM_SIZES)")
 
 
 __all__ = ["SoftmaxRegression", "MnistCNN", "ResNet20", "ResNetCIFAR",

@@ -19,16 +19,14 @@ no bias anywhere)::
 with ``ffn(m; G, U, D) = (silu(m G) * (m U)) D``; the embedding is
 scaled by ``sqrt(d)``, the head is untied, logits are float32.
 
-**One definition of a block** (:class:`AfmoeBlock`), with the methods
-serving needs beside the training-shape forward: ``sequence`` (a whole
-sequence: the forward, and prefill, which also keeps the K/V it made)
-and ``step`` (a K-token window per slot against that slot's cache rows;
-plain decode is K == 1).  There is no serving twin: :class:`AfmoeLM`
-IS its own serving module (``serving_module()`` returns it), and states
-each layer's cache rows itself (``cache_rows``): a full-attention layer
-holds ``cache_len`` rows a slot, a window layer ``min(window,
-cache_len)`` rows as a ring, a position's row being ``position mod
-rows``.
+**One definition of a block** (:class:`AfmoeBlock`) with the two methods
+the shell of ``served_lm.py`` walks: ``sequence`` (a whole sequence: the
+forward, and prefill, which also keeps the K/V it made) and ``step`` (a
+K-token window per slot against that slot's cache rows; plain decode is
+K == 1).  There is no serving twin: :class:`AfmoeLM` is that shell and
+states each layer's cache rows: a full-attention layer holds
+``cache_len`` rows a slot, a window layer ``min(window, cache_len)`` rows
+as a ring, a position's row being ``position mod rows``.
 
 **The expert layer holds a share** (``ops/moe.py``): ``experts_held``
 experts from ``first_expert`` on, of the ``n_routed`` the router scores.
@@ -47,6 +45,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.models.served_lm import (
+    CacheLayer, ServedLM, gated_params, rms_norm)
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
     ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention)
@@ -79,12 +79,6 @@ class AfmoeDims:
     max_len: int
     embed_scale: float          # sqrt(d_model) under muP, else 1
     init_std: float = 0.02
-
-
-def _rms(x, g, eps):
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * g.astype(jnp.float32)).astype(x.dtype)
 
 
 def _rope(x, positions, theta):
@@ -128,31 +122,28 @@ class AfmoeBlock(nn.Module):
         self.wg = P("wg", w, (d, qd), pd)
         self.wo = P("wo", w, (qd, d), pd)
         if not self.experts:
-            self.ffn = tuple(P(f"ffn_{n}", w, s, pd) for n, s in (
-                ("gate", (d, c.d_ff)), ("up", (d, c.d_ff)),
-                ("down", (c.d_ff, d))))
+            self.ffn = gated_params(P, "ffn", w, pd, d, c.d_ff)
             return
         f, E, fs = c.d_expert, c.experts_held, c.n_shared * c.d_expert
         self.router = P("router", w, (d, c.n_routed), pd)
         self.router_bias = P("router_bias", nn.initializers.normal(0.01),
                              (c.n_routed,), jnp.float32)
-        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
-            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
-        self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
-            ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+        self.shared = gated_params(P, "shared", w, pd, d, fs)
+        self.held = gated_params(P, "experts", w, pd, d, f, E)
 
     # --- attention ---------------------------------------------------------
     def _qkvu(self, h, positions):
         """h [..., T, d], positions [..., T] -> q [..., T, Hq, Dh], k and
         v [..., T, Hkv, Dh], the gate's input u [..., T, Hq Dh]."""
         c, dt = self.dims, self.dtype
-        a = _rms(h, self.norm_in, c.eps)
+        a = rms_norm(h, self.norm_in, c.eps)
         heads = lambda x, n: x.reshape(*x.shape[:-1], n, c.head_dim)
         q = heads(jnp.dot(a, self.wq.astype(dt)), c.n_heads)
         k = heads(jnp.dot(a, self.wk.astype(dt)), c.n_kv_heads)
         v = heads(jnp.dot(a, self.wv.astype(dt)), c.n_kv_heads)
         u = jnp.dot(a, self.wg.astype(dt))
-        q, k = _rms(q, self.norm_q, c.eps), _rms(k, self.norm_k, c.eps)
+        q = rms_norm(q, self.norm_q, c.eps)
+        k = rms_norm(k, self.norm_k, c.eps)
         if self.window:
             q = _rope(q, positions, c.rope_theta)
             k = _rope(k, positions, c.rope_theta)
@@ -162,13 +153,13 @@ class AfmoeBlock(nn.Module):
         o = o.reshape(u.shape) * jax.nn.sigmoid(
             u.astype(jnp.float32)).astype(o.dtype)
         o = jnp.dot(o, self.wo.astype(self.dtype))
-        return h + _rms(o, self.norm_post_attn, self.dims.eps)
+        return h + rms_norm(o, self.norm_post_attn, self.dims.eps)
 
     # --- feed-forward --------------------------------------------------------
     def _ffn(self, h, live):
         """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
-        m = _rms(h, self.norm_pre_mlp, c.eps).reshape(-1, c.d_model)
+        m = rms_norm(h, self.norm_pre_mlp, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
         if not self.experts:
             f = moe.gated_ffn(m, *cast(self.ffn))
@@ -183,23 +174,21 @@ class AfmoeBlock(nn.Module):
                 live=None if live is None else live.reshape(-1))
             with jax.named_scope("moe.shared"):
                 f = f + moe.gated_ffn(m, *cast(self.shared))
-        return h + _rms(f.reshape(h.shape), self.norm_post_mlp, c.eps), stats
+        f = rms_norm(f.reshape(h.shape), self.norm_post_mlp, c.eps)
+        return h + f, stats
 
     # --- the two shapes of work --------------------------------------------
     def sequence(self, x, live=None):
         """A whole sequence: x [B, T, d], live [B, T] (false on padding)
-        -> (x', k [B, T, Hkv, Dh], v, stats).  The training-shape forward
-        and prefill are this one method."""
+        -> (x', (k [B, T, Hkv, Dh], v), stats).  The training-shape
+        forward and prefill are this one method."""
         T = x.shape[1]
         q, k, v, u = self._qkvu(x, jnp.arange(T)[None])
         with jax.named_scope("attn.window" if self.window else "attn.full"):
             o = grouped_attention(q, k, v, window=self.window,
                                   block=self.attn_block)
         x, stats = self._ffn(self._attn_out(x, o, u), live)
-        return x, k, v, stats
-
-    def __call__(self, x):
-        return self.sequence(x)[0]
+        return x, (k, v), stats
 
     def step(self, x, ck, cv, pos):
         """A K-token window per slot: x [S, K, d], this layer's cache
@@ -249,65 +238,44 @@ class AfmoeBlock(nn.Module):
         return x, ck, cv, stats
 
 
-class AfmoeLM(nn.Module):
-    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
-    programs ``DecodeEngine`` asks a model for."""
+class AfmoeLM(ServedLM):
+    """The shell (``served_lm.py``) over :class:`AfmoeBlock`: an embedding
+    scaled under muP, window and full layers, its own ladder of prompt
+    lengths, and a K-token ``verify``."""
     dims: AfmoeDims
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-    attn_block: int = ATTN_BLOCK
 
-    # What DecodeEngine reads of any model.
-    vocab_size = property(lambda self: self.dims.vocab_size)
-    max_len = property(lambda self: self.dims.max_len)
-    n_layers = property(lambda self: len(self.dims.layer_types))
-    #: Positions one prefill program takes at most (DecodeEngine splits a
-    #: larger group): two prompts of the longest bucket the benchmark's
-    #: cell uses, 3.2 GB of activations at the published widths.
+    #: Two prompts of the longest bucket the benchmark's cell uses, 3.2 GB
+    #: of activations at the published widths.
     prefill_positions_max = 32768
-    #: Held experts x expert layers: what one step can touch at most.
     expert_slots = property(lambda self: self.dims.experts_held * (
         len(self.dims.layer_types) - self.dims.n_dense_layers))
 
-    def setup(self):
-        c, pd = self.dims, self.param_dtype
-        w = nn.initializers.normal(c.init_std)
-        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
-        self.blocks = [AfmoeBlock(
-            c, c.window if kind == WINDOW else 0,
-            i >= c.n_dense_layers, self.dtype, pd, self.attn_block,
-            name=f"block{i}") for i, kind in enumerate(c.layer_types)]
-        self.norm_f = self.param("norm_f", nn.initializers.ones,
-                                 (c.d_model,), pd)
-        self.head = self.param("head", w, (c.d_model, c.vocab_size), pd)
+    def make_block(self, i):
+        c = self.dims
+        return AfmoeBlock(
+            c, c.window if c.layer_types[i] == WINDOW else 0,
+            i >= c.n_dense_layers, self.dtype, self.param_dtype,
+            self.attn_block, name=f"block{i}")
+
+    def cache_layers(self, cache_len: int) -> tuple:
+        """A full-attention layer holds ``cache_len`` rows ``[Hkv, Dh]`` a
+        slot, a window layer ``min(window, cache_len)`` as a ring."""
+        c = self.dims
+
+        def layer(kind, rows):
+            kv = ((rows, c.n_kv_heads, c.head_dim), self.dtype)
+            return CacheLayer(kind, rows, kv, kv)
+        return tuple(layer("window", min(c.window, cache_len))
+                     if kind == WINDOW else layer("full", cache_len)
+                     for kind in c.layer_types)
 
     def _embed(self, tokens):
         x = self.embed.astype(self.dtype)[tokens]
         return x * jnp.asarray(self.dims.embed_scale, self.dtype)
 
-    def _logits(self, x):
-        with jax.named_scope("head"):
-            x = _rms(x, self.norm_f, self.dims.eps)
-            return jnp.dot(x, self.head.astype(self.dtype),
-                           preferred_element_type=jnp.float32)
-
-    def __call__(self, tokens, train: bool = False):
-        """The training-shape forward (``train`` is accepted for the
-        trainers' calling convention; the model has no dropout)."""
-        x = self._embed(tokens.astype(jnp.int32))
-        for blk in self.blocks:
-            x = blk(x)
-        return self._logits(x)
-
-    # --- what a model states to DecodeEngine -------------------------------
-    def serving_module(self):
-        return self
-
-    def cache_rows(self, cache_len: int) -> tuple:
-        """``(kind, rows)`` per layer: what one slot's cache holds."""
-        c = self.dims
-        return tuple(("window", min(c.window, cache_len)) if kind == WINDOW
-                     else ("full", cache_len) for kind in c.layer_types)
+    def _real(self, toks, lengths):
+        """This family's blocks take the mask [B, P], made once."""
+        return jnp.arange(toks.shape[1])[None] < lengths[:, None]
 
     def prefill_buckets(self, cache_len: int):
         """The lengths a prompt is padded to, one prefill program each,
@@ -345,60 +313,14 @@ class AfmoeLM(nn.Module):
                             if t * tile < cache_len)) + (cache_len,)
 
     def decode_fetch_block(self, rows: int) -> int:
-        """Rows the decode step's attention fetches at a time from a
-        layer that holds ``rows`` a slot; 0 where it reads them all."""
         c = self.dims
         return decode_fetch_block(rows, c.n_kv_heads, c.head_dim)
 
-    def init_cache(self, slots: int, cache_len: int) -> tuple:
-        """``(ck, cv)``, each one ``[slots, rows, Hkv, Dh]`` array a
-        layer."""
-        c = self.dims
-        make = lambda: tuple(
-            jnp.zeros((slots, rows, c.n_kv_heads, c.head_dim), self.dtype)
-            for _, rows in self.cache_rows(cache_len))
-        return make(), make()
-
-    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
-        """toks [B, P] (B prompts padded into one bucket), each written
-        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
-        lengths.  Returns (logits at each prompt's LAST position [B, V]
-        f32 — the head is never taken over the bucket — ck, cv, stats).
-        A window layer whose ring is shorter than the bucket keeps each
-        prompt's last ``rows`` real positions, each at ``position mod
-        rows``."""
-        B, P = toks.shape
-        live = jnp.arange(P)[None] < lengths[:, None]
-        x = self._embed(toks)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, k, v, st = blk.sequence(x, live)
-            stats = stats + st
-            R = ck_l.shape[1]
-            with jax.named_scope("cache_update"):
-                if P > R:
-                    last = lengths[:, None] - 1
-                    at = last - jnp.mod(last - jnp.arange(R)[None], R)
-                    at = jnp.maximum(at, 0)[:, :, None, None]   # [B,R,1,1]
-                    k = jnp.take_along_axis(k, at, axis=1)
-                    v = jnp.take_along_axis(v, at, axis=1)
-                rows = min(P, R)
-                new_k.append(ck_l.at[slots_ix, :rows].set(k))
-                new_v.append(cv_l.at[slots_ix, :rows].set(v))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logits(last[:, 0]), tuple(new_k), tuple(new_v), stats
-
     def verify(self, toks, positions, ck, cv):
         """toks [S, K], positions [S] -> (logits [S, K, V] f32, ck, cv,
-        stats): the K-token step (see AfmoeBlock.step)."""
-        x = self._embed(toks)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, k_l, v_l, st = blk.step(x, ck_l, cv_l, positions)
-            new_k.append(k_l)
-            new_v.append(v_l)
-            stats = stats + st
-        return self._logits(x), tuple(new_k), tuple(new_v), stats
+        stats): the K-token step (see AfmoeBlock.step) — the shell's walk
+        over the blocks, which never looks at a window's length."""
+        return super().decode(toks, positions, ck, cv)
 
     def decode(self, tok, positions, ck, cv):
         """tok [S] -> (logits [S, V], ck, cv, stats): the K == 1 window

@@ -56,22 +56,16 @@ bias anywhere::
               return sum_e w_e ffn_e(m) + ffn_shared(m)
               ffn(m; G, U, D) = (silu(m G) * (m U)) D
 
-**One definition of a block** (:class:`BailingBlock`), as the other
-serving models have: ``sequence`` (the training-shape forward, and
-prefill, which also keeps what the layer remembers) and ``step`` (one
-token a slot against what the slot remembers).  :class:`BailingHybridLM`
-is its own serving module and states each layer's cache itself
-(``cache_rows``): an MLA layer holds ``cache_len`` rows a slot, ONE row
-a position (kind ``latent``: ``[c | k_pe]`` padded with zeros to whole
-lane groups, 576 -> 640 at the published sizes, because the TPU's kernel
-takes a cache whose rows are whole tiles without a relaid copy and no
-other; there is no V array), a KDA layer NO rows but a state of a fixed
-size (kind ``state``): ``S [H, D, D]`` float32 and the convolution's
-last 3 inputs.  Both ride in the ``(ck, cv)`` pair ``DecodeEngine``
-donates: layer ``l``'s entries are its rows and an empty array, or its
-recurrent state and its convolution state.  As in ``qwen3_next.py``,
-prefill OVERWRITES an admitted slot's states with those at the prompt's
-true length and a parked slot (position 0) neither decays nor writes.
+**One definition of a block** (:class:`BailingBlock`) with the two
+methods the shell of ``served_lm.py`` walks, ``sequence`` and ``step``;
+:class:`BailingHybridLM` is that shell and states each layer's cache: an
+MLA layer holds ``cache_len`` rows a slot, ONE row a position (kind
+``latent``: ``[c | k_pe]`` padded with zeros to whole lane groups, 576 ->
+640 at the published sizes, because the TPU's kernel takes a cache whose
+rows are whole tiles without a relaid copy and no other; there is no V
+array), a KDA layer NO rows but a state of a fixed size (kind ``state``):
+``S [H, D, D]`` float32 and the convolution's last 3 inputs.  A parked
+slot (position 0) neither decays nor writes.
 
 **The expert layer holds a share** (``ops/moe.py``): ``experts_held`` of
 ``n_routed`` from ``first_expert`` on.  With ``n_group`` shares a group
@@ -93,11 +87,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.models.served_lm import (
+    CacheLayer, ServedLM, gated_params, rms_norm)
 from distributedtensorflowexample_tpu.ops import linear_attention as la
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
     ATTN_BLOCK, latent_decode_attention, latent_expanded_attention,
-    latent_fetch_block, tile_ladder)
+    latent_fetch_block)
 
 F32 = jnp.float32
 LANES = 128     # a latent row is padded to whole groups of these
@@ -143,12 +139,6 @@ class BailingDims:
         """Features of a latent cache row: [c | k_pe], in whole lane
         groups."""
         return -(-(self.kv_rank + self.rope_dim) // LANES) * LANES
-
-
-def _rms(x, g, eps):
-    xf = x.astype(F32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * g.astype(F32)).astype(x.dtype)
 
 
 def _rope_pairs(x, positions, theta):
@@ -211,18 +201,14 @@ class BailingBlock(nn.Module):
             self.norm_o = P("norm_o", ones, (c.head_dim,), pd)
             self.wo = P("wo", w, (hd, d), pd)
         if not self.experts:
-            self.ffn = tuple(P(f"ffn_{n}", w, s, pd) for n, s in (
-                ("gate", (d, c.d_ff)), ("up", (d, c.d_ff)),
-                ("down", (c.d_ff, d))))
+            self.ffn = gated_params(P, "ffn", w, pd, d, c.d_ff)
             return
         f, E, fs = c.d_expert, c.experts_held, c.d_shared
         self.router = P("router", w, (d, c.n_routed), pd)
         self.router_bias = P("router_bias", nn.initializers.normal(0.01),
                              (c.n_routed,), F32)
-        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
-            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
-        self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
-            ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+        self.shared = gated_params(P, "shared", w, pd, d, fs)
+        self.held = gated_params(P, "experts", w, pd, d, f, E)
 
     # --- latent attention --------------------------------------------------
     def _mla_q(self, a, positions):
@@ -241,7 +227,7 @@ class BailingBlock(nn.Module):
         c = self.dims
         with jax.named_scope("mla.kv"):
             kva = jnp.dot(a, self.w_kva.astype(self.dtype))
-            lat = _rms(kva[..., :c.kv_rank], self.norm_c, c.eps)
+            lat = rms_norm(kva[..., :c.kv_rank], self.norm_c, c.eps)
             k_pe = _rope_pairs(kva[..., None, c.kv_rank:], positions,
                                c.rope_theta)[..., 0, :]
             pad = jnp.zeros((*a.shape[:-1],
@@ -300,7 +286,7 @@ class BailingBlock(nn.Module):
     def _ffn(self, h, live):
         """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
-        m = _rms(h, self.norm_post, c.eps).reshape(-1, c.d_model)
+        m = rms_norm(h, self.norm_post, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
         if not self.experts:
             f = moe.gated_ffn(m, *cast(self.ffn))
@@ -330,7 +316,7 @@ class BailingBlock(nn.Module):
         B, T, _ = x.shape
         live = None if lengths is None else (
             jnp.arange(T)[None] < lengths[:, None])
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         if self.latent:
             pos = jnp.arange(T)[None]
             q_nope, q_pe = self._mla_q(a, pos)
@@ -362,9 +348,6 @@ class BailingBlock(nn.Module):
         x, stats = self._ffn(x, live)
         return x, kept, stats
 
-    def __call__(self, x):
-        return self.sequence(x)[0]
-
     def step(self, x, ck, cv, pos):
         """One token a slot: x [S, d], pos [S] its position, and what
         the layer remembers of each slot — an MLA layer's rows ``[S, R,
@@ -376,7 +359,7 @@ class BailingBlock(nn.Module):
         c = self.dims
         S = x.shape[0]
         live = pos > 0
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         if self.latent:
             q_nope, q_pe = self._mla_q(a[:, None], pos[:, None])
             with jax.named_scope("cache_update"):
@@ -407,139 +390,38 @@ class BailingBlock(nn.Module):
         return x, ck, cv, stats
 
 
-class BailingHybridLM(nn.Module):
-    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
-    programs ``DecodeEngine`` asks a model for."""
+class BailingHybridLM(ServedLM):
+    """The shell (``served_lm.py``) over :class:`BailingBlock`: latent rows
+    in one layer of a group, KDA's states in the others."""
     dims: BailingDims
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-    attn_block: int = ATTN_BLOCK
 
-    # What DecodeEngine reads of any model.
-    vocab_size = property(lambda self: self.dims.vocab_size)
-    max_len = property(lambda self: self.dims.max_len)
-    n_layers = property(lambda self: self.dims.n_layers)
-    #: Positions one prefill program takes at most (DecodeEngine splits a
-    #: larger group): two prompts of 4,096.
+    #: Two prompts of 4,096.
     prefill_positions_max = 8192
-    #: Held experts x expert layers: what one step can touch at most.
     expert_slots = property(lambda self: self.dims.experts_held * (
         self.dims.n_layers - self.dims.n_dense_layers))
 
-    def setup(self):
-        c, pd = self.dims, self.param_dtype
-        w = nn.initializers.normal(c.init_std)
-        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
-        self.blocks = [BailingBlock(
-            c, c.is_latent(i), i >= c.n_dense_layers, self.dtype, pd,
-            self.attn_block, name=f"block{i}") for i in range(c.n_layers)]
-        self.norm_f = self.param("norm_f", nn.initializers.ones,
-                                 (c.d_model,), pd)
-        self.head = self.param("head", w, (c.d_model, c.vocab_size), pd)
-
-    def _embed(self, tokens):
-        return self.embed.astype(self.dtype)[tokens]
-
-    def _logits(self, x):
-        with jax.named_scope("head"):
-            x = _rms(x, self.norm_f, self.dims.eps)
-            return jnp.dot(x, self.head.astype(self.dtype),
-                           preferred_element_type=F32)
-
-    def __call__(self, tokens, train: bool = False):
-        """The training-shape forward (``train`` is accepted for the
-        trainers' calling convention; the model has no dropout)."""
-        x = self._embed(tokens.astype(jnp.int32))
-        for blk in self.blocks:
-            x = blk(x)
-        return self._logits(x)
-
-    # --- what a model states to DecodeEngine -------------------------------
-    def serving_module(self):
-        return self
-
-    def cache_rows(self, cache_len: int) -> tuple:
-        """``(kind, rows)`` per layer: an MLA layer holds ``cache_len``
-        latent rows a slot, a KDA layer no rows but a state."""
+    def make_block(self, i):
         c = self.dims
-        return tuple(("latent", cache_len) if c.is_latent(i)
-                     else ("state", 0) for i in range(c.n_layers))
+        return BailingBlock(
+            c, c.is_latent(i), i >= c.n_dense_layers, self.dtype,
+            self.param_dtype, self.attn_block, name=f"block{i}")
 
-    def cache_slot_bytes(self, cache_len: int) -> tuple:
-        """Bytes one slot holds in each layer (``cache_rows``' order):
-        the latent rows, or the recurrent state (float32) and the
-        convolution's — read off ``init_cache``'s own shapes."""
-        ck, cv = jax.eval_shape(lambda: self.init_cache(1, cache_len))
-        return tuple(sum(x.size * x.dtype.itemsize for x in layer)
-                     for layer in zip(ck, cv))
-
-    def prefill_buckets(self, cache_len: int):
-        """The lengths a prompt is padded to, one prefill program each:
-        powers of two from 256 (below it a program's time is the weights
-        it reads, whatever it pads) up to a tile of attention, then
-        whole tiles (``ops/attention.takes_splash`` asks for that; the
-        chunked scan for whole chunks, which a tile is), ``cache_len``
-        last.  ``None`` (the engine's powers of two) for a cache shorter
-        than that first bucket."""
-        return tile_ladder(cache_len, self.attn_block)
+    def cache_layers(self, cache_len: int) -> tuple:
+        """An MLA layer holds ``cache_len`` latent rows ``[row_dim]`` a
+        slot; a KDA layer no rows but its state ``[H, D, D]`` float32 and
+        the convolution's last inputs ``[K - 1, 3 H D]``."""
+        c = self.dims
+        latent = CacheLayer("latent", cache_len,
+                            ((cache_len, c.row_dim), self.dtype), None)
+        state = CacheLayer(
+            "state", 0, ((c.n_heads, c.head_dim, c.head_dim), F32),
+            ((c.conv_kernel - 1, 3 * c.n_heads * c.head_dim), self.dtype))
+        return tuple(latent if c.is_latent(i) else state
+                     for i in range(c.n_layers))
 
     def decode_fetch_block(self, rows: int) -> int:
-        """Rows the decode step's attention fetches at a time from a
-        layer that holds ``rows`` a slot; 0 where it reads them all (and
-        for a layer that holds no rows)."""
         c = self.dims
         return rows and latent_fetch_block(rows, c.row_dim, c.kv_rank)
-
-    def init_cache(self, slots: int, cache_len: int) -> tuple:
-        """``(ck, cv)``, one array a layer in each: the latent rows
-        ``[slots, cache_len, row_dim]`` and an empty array, or the
-        recurrent state ``[slots, H, D, D]`` float32 and the
-        convolution's ``[slots, K - 1, 3 H D]``."""
-        c = self.dims
-        rows = (slots, cache_len, c.row_dim)
-        conv = (slots, c.conv_kernel - 1, 3 * c.n_heads * c.head_dim)
-        state = (slots, c.n_heads, c.head_dim, c.head_dim)
-        ck = tuple(jnp.zeros(rows, self.dtype) if c.is_latent(i)
-                   else jnp.zeros(state, F32) for i in range(c.n_layers))
-        cv = tuple(jnp.zeros((0,) if c.is_latent(i) else conv, self.dtype)
-                   for i in range(c.n_layers))
-        return ck, cv
-
-    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
-        """toks [B, P] (B prompts padded into one bucket), each written
-        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
-        lengths.  Returns (logits at each prompt's LAST position [B, V]
-        f32, ck, cv, stats).  A slot's latent rows beyond the prompt are
-        stale and masked; its recurrent and convolution states are
-        overwritten with the states at the prompt's length."""
-        x = self._embed(toks)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, (k, v), st = blk.sequence(x, lengths)
-            stats = stats + st
-            with jax.named_scope("cache_update"):
-                if blk.latent:
-                    new_k.append(ck_l.at[slots_ix, :k.shape[1]].set(k))
-                    new_v.append(cv_l)
-                else:
-                    new_k.append(ck_l.at[slots_ix].set(k))
-                    new_v.append(cv_l.at[slots_ix].set(v.astype(cv_l.dtype)))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logits(last[:, 0]), tuple(new_k), tuple(new_v), stats
-
-    def decode(self, tok, positions, ck, cv):
-        """tok [S], positions [S] -> (logits [S, V] f32, ck, cv, stats):
-        the one token step.  There is no K-token ``verify``: a state
-        that has taken K tokens cannot give back the last of them
-        (``serving/engine.py`` refuses what would need it)."""
-        x = self._embed(tok)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, k_l, v_l, st = blk.step(x, ck_l, cv_l, positions)
-            new_k.append(k_l)
-            new_v.append(v_l)
-            stats = stats + st
-        return self._logits(x), tuple(new_k), tuple(new_v), stats
 
 
 #: What of a ``bailing_hybrid`` configuration is built here, and only so.

@@ -38,19 +38,14 @@ bias but the convolution's::
               return sum_e w_e ffn_e(m);  ffn(m; G, U, D) = (silu(m G) *
               (m U)) D;  shared(m) = ffn(m) at its own width, weight 1
 
-**One definition of a block** (:class:`GraniteMoeHybridBlock`), as the
-other blocks have: ``sequence`` (the training-shape forward, and prefill,
-which also keeps what the layer remembers) and ``step`` (one token a slot
-against what the slot remembers).  :class:`GraniteMoeHybridLM` is its own
-serving module and states each layer's cache itself (``cache_rows``): an
-attention layer holds ``cache_len`` K/V rows a slot (kind ``full``), a
+**One definition of a block** (:class:`GraniteMoeHybridBlock`) with the
+two methods the shell of ``served_lm.py`` walks, ``sequence`` and ``step``;
+:class:`GraniteMoeHybridLM` is that shell and states each layer's cache:
+an attention layer holds ``cache_len`` K/V rows a slot (kind ``full``), a
 state-space layer NO rows but a state of a fixed size (kind ``state``):
-``S [H, P, N]`` float32 and the convolution's last 3 inputs.  Both ride
-in the ``(ck, cv)`` pair ``DecodeEngine`` donates: layer ``l``'s entries
-are its K and V rows, or its recurrent state and its convolution state.
-Prefill OVERWRITES an admitted slot's state with the state at the
-prompt's true length (``ops/state_space.py`` leaves padding out), and a
-parked slot (position 0) neither decays nor writes.
+``S [H, P, N]`` float32 and the convolution's last 3 inputs.  Prefill
+writes the state at the prompt's true length (``ops/state_space.py``
+leaves padding out); a parked slot (position 0) neither decays nor writes.
 
 **The expert layer holds a share** (``ops/moe.py``), as the other three
 served blocks': ``experts_held`` of ``n_routed`` from ``first_expert``
@@ -71,12 +66,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.models.served_lm import (
+    CacheLayer, ServedLM, gated_params, log_uniform, rms_norm)
 from distributedtensorflowexample_tpu.ops import linear_attention as la
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops import state_space as ss
 from distributedtensorflowexample_tpu.ops.attention import (
-    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention,
-    tile_ladder)
+    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention)
 
 F32 = jnp.float32
 MAMBA, ATTENTION = "mamba", "attention"
@@ -123,19 +119,6 @@ class GraniteMoeHybridDims:
         return self.d_inner + 2 * self.ssm_state
 
 
-def _rms(x, w, eps):
-    xf = x.astype(F32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(F32)).astype(x.dtype)
-
-
-def _log_uniform(lo: float, hi: float):
-    def init(key, shape, dtype=F32):
-        return jnp.log(jax.random.uniform(key, shape, F32, lo, hi)) \
-            .astype(dtype)
-    return init
-
-
 def _step_bias(lo: float, hi: float):
     """The bias whose softplus is log-uniform in ``[lo, hi]``."""
     def init(key, shape, dtype=F32):
@@ -176,7 +159,7 @@ class GraniteMoeHybridBlock(nn.Module):
             self.conv_bias = P("conv_bias", w, (c.conv_dim,), pd) \
                 if c.conv_bias else None
             # Mamba-2's own: A in [1, 16], steps of 0.001 to 0.1.
-            self.a_log = P("a_log", _log_uniform(1.0, 16.0),
+            self.a_log = P("a_log", log_uniform(1.0, 16.0),
                            (c.ssm_heads,), F32)
             self.dt_bias = P("dt_bias", _step_bias(1e-3, 1e-1),
                              (c.ssm_heads,), F32)
@@ -184,12 +167,10 @@ class GraniteMoeHybridBlock(nn.Module):
             self.norm_y = P("norm_y", ones, (c.d_inner,), pd)
             self.w_out = P("w_out", w, (c.d_inner, d), pd)
         f, E, fs = c.d_expert, c.experts_held, c.d_shared
-        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
-            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
+        self.shared = gated_params(P, "shared", w, pd, d, fs)
         if c.n_routed:
             self.router = P("router", w, (d, c.n_routed), pd)
-            self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
-                ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+            self.held = gated_params(P, "experts", w, pd, d, f, E)
 
     def _residual(self, h, branch):
         """``h + residual_multiplier * branch``, the sum in float32 (the
@@ -243,14 +224,14 @@ class GraniteMoeHybridBlock(nn.Module):
         with jax.named_scope("ssm.out"):
             y = y + self.d_skip[:, None] * x.astype(F32)
             y = y.reshape(*y.shape[:-2], -1) * jax.nn.silu(z.astype(F32))
-            y = _rms(y, self.norm_y, c.eps).astype(self.dtype)
+            y = rms_norm(y, self.norm_y, c.eps).astype(self.dtype)
             return jnp.dot(y, self.w_out.astype(self.dtype))
 
     # --- feed-forward ------------------------------------------------------
     def _ffn(self, h, live):
         """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
-        m = _rms(h, self.norm_post, c.eps).reshape(-1, c.d_model)
+        m = rms_norm(h, self.norm_post, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
         with jax.named_scope("moe.shared"):
             f = moe.gated_ffn(m, *cast(self.shared))
@@ -280,7 +261,7 @@ class GraniteMoeHybridBlock(nn.Module):
         B, T, _ = x.shape
         live = None if lengths is None else (
             jnp.arange(T)[None] < lengths[:, None])
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         if self.attention:
             q, k, v = self._qkv(a)
             with jax.named_scope("attn.nope"):
@@ -304,9 +285,6 @@ class GraniteMoeHybridBlock(nn.Module):
         x, stats = self._ffn(x, live)
         return x, kept, stats
 
-    def __call__(self, x):
-        return self.sequence(x)[0]
-
     def step(self, x, ck, cv, pos):
         """One token a slot: x [S, d], pos [S] its position, and what
         the layer remembers of each slot — an attention layer's K and V
@@ -318,7 +296,7 @@ class GraniteMoeHybridBlock(nn.Module):
         c = self.dims
         S = x.shape[0]
         live = pos > 0
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         if self.attention:
             q, k, v = self._qkv(a)
             q = q.reshape(S, 1, c.n_kv_heads, -1, c.head_dim)
@@ -345,141 +323,53 @@ class GraniteMoeHybridBlock(nn.Module):
         return x, ck, cv, stats
 
 
-class GraniteMoeHybridLM(nn.Module):
-    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
-    programs ``DecodeEngine`` asks a model for."""
+class GraniteMoeHybridLM(ServedLM):
+    """The shell (``served_lm.py``) over :class:`GraniteMoeHybridBlock`:
+    a scaled embedding that is the head too, K/V rows in the attention
+    layers, Mamba-2's states in the others."""
     dims: GraniteMoeHybridDims
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-    attn_block: int = ATTN_BLOCK
 
-    # What DecodeEngine reads of any model.
-    vocab_size = property(lambda self: self.dims.vocab_size)
-    max_len = property(lambda self: self.dims.max_len)
-    n_layers = property(lambda self: len(self.dims.layer_types))
-    #: Positions one prefill program takes at most (DecodeEngine splits a
-    #: larger group): two prompts of 1,024, beside a chip the states of
-    #: many slots have nearly filled.
+    #: Two prompts of 1,024, beside a chip the states of many slots have
+    #: nearly filled.
     prefill_positions_max = 2048
-    #: Held experts x expert layers: what one step can touch at most.
     expert_slots = property(lambda self: self.dims.experts_held
                             * len(self.dims.layer_types))
 
-    def setup(self):
-        c, pd = self.dims, self.param_dtype
-        w = nn.initializers.normal(c.init_std)
-        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
-        self.blocks = [GraniteMoeHybridBlock(
-            c, kind == ATTENTION, self.dtype, pd, self.attn_block,
-            name=f"block{i}") for i, kind in enumerate(c.layer_types)]
-        self.norm_f = self.param("norm_f", nn.initializers.ones,
-                                 (c.d_model,), pd)
+    def make_block(self, i):
+        return GraniteMoeHybridBlock(
+            self.dims, self.dims.layer_types[i] == ATTENTION, self.dtype,
+            self.param_dtype, self.attn_block, name=f"block{i}")
+
+    def cache_layers(self, cache_len: int) -> tuple:
+        """An attention layer holds ``cache_len`` K/V rows ``[Hkv, Dh]`` a
+        slot; a state-space layer no rows but its state ``[H, P, N]``
+        float32 and the convolution's last inputs ``[K - 1, C]``."""
+        c = self.dims
+        kv = ((cache_len, c.n_kv_heads, c.head_dim), self.dtype)
+        full = CacheLayer("full", cache_len, kv, kv)
+        state = CacheLayer(
+            "state", 0, ((c.ssm_heads, c.ssm_head_dim, c.ssm_state), F32),
+            ((c.conv_kernel - 1, c.conv_dim), self.dtype))
+        return tuple(full if kind == ATTENTION else state
+                     for kind in c.layer_types)
 
     def _embed(self, tokens):
         x = self.embed.astype(self.dtype)[tokens]
         return x * jnp.asarray(self.dims.embedding_multiplier, self.dtype)
 
     def _logits(self, x):
-        """The tied head: ``RMS(x) E^T / logits_scaling``."""
+        """The tied head (so no ``head``): ``RMS(x) E^T / logits_scaling``."""
         with jax.named_scope("head"):
-            x = _rms(x, self.norm_f, self.dims.eps)
+            x = rms_norm(x, self.norm_f, self.dims.eps)
             logits = jax.lax.dot_general(
                 x, self.embed.astype(self.dtype),
                 (((x.ndim - 1,), (1,)), ((), ())),
                 preferred_element_type=F32)
             return logits / self.dims.logits_scaling
 
-    def __call__(self, tokens, train: bool = False):
-        """The training-shape forward (``train`` is accepted for the
-        trainers' calling convention; the model has no dropout)."""
-        x = self._embed(tokens.astype(jnp.int32))
-        for blk in self.blocks:
-            x = blk(x)
-        return self._logits(x)
-
-    # --- what a model states to DecodeEngine -------------------------------
-    def serving_module(self):
-        return self
-
-    def cache_rows(self, cache_len: int) -> tuple:
-        """``(kind, rows)`` per layer: an attention layer holds
-        ``cache_len`` K/V rows a slot, a state-space layer no rows but a
-        state."""
-        return tuple(("full", cache_len) if kind == ATTENTION
-                     else ("state", 0) for kind in self.dims.layer_types)
-
-    def cache_slot_bytes(self, cache_len: int) -> tuple:
-        """Bytes one slot holds in each layer (``cache_rows``' order):
-        K and V rows, or the recurrent state (float32) and the
-        convolution's — read off ``init_cache``'s own shapes."""
-        ck, cv = jax.eval_shape(lambda: self.init_cache(1, cache_len))
-        return tuple(sum(x.size * x.dtype.itemsize for x in layer)
-                     for layer in zip(ck, cv))
-
-    def prefill_buckets(self, cache_len: int):
-        """The lengths a prompt is padded to, one prefill program each:
-        ``ops/attention.tile_ladder`` — powers of two from 256 up to a
-        tile of attention, then whole tiles, ``cache_len`` last; every
-        one whole chunks of the scan.  ``None`` (the engine's powers of
-        two) for a cache shorter than the first bucket."""
-        return tile_ladder(cache_len, self.attn_block)
-
     def decode_fetch_block(self, rows: int) -> int:
-        """Rows the decode step's attention fetches at a time from a
-        layer that holds ``rows`` a slot; 0 where it reads them all (and
-        for a layer that holds no rows)."""
         c = self.dims
         return rows and decode_fetch_block(rows, c.n_kv_heads, c.head_dim)
-
-    def init_cache(self, slots: int, cache_len: int) -> tuple:
-        """``(ck, cv)``, one array a layer in each: K and V rows ``[slots,
-        cache_len, Hkv, Dh]``, or the recurrent state ``[slots, H, P, N]``
-        float32 and the convolution's ``[slots, K - 1, C]``."""
-        c = self.dims
-        rows = (slots, cache_len, c.n_kv_heads, c.head_dim)
-        conv = (slots, c.conv_kernel - 1, c.conv_dim)
-        state = (slots, c.ssm_heads, c.ssm_head_dim, c.ssm_state)
-        full = [kind == ATTENTION for kind in c.layer_types]
-        ck = tuple(jnp.zeros(rows, self.dtype) if a
-                   else jnp.zeros(state, F32) for a in full)
-        cv = tuple(jnp.zeros(rows if a else conv, self.dtype) for a in full)
-        return ck, cv
-
-    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
-        """toks [B, P] (B prompts padded into one bucket), each written
-        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
-        lengths.  Returns (logits at each prompt's LAST position [B, V]
-        f32, ck, cv, stats).  A slot's K/V rows beyond the prompt are
-        stale and masked; its recurrent and convolution states are
-        overwritten with the states at the prompt's length."""
-        x = self._embed(toks)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, (k, v), st = blk.sequence(x, lengths)
-            stats = stats + st
-            with jax.named_scope("cache_update"):
-                if blk.attention:
-                    new_k.append(ck_l.at[slots_ix, :k.shape[1]].set(k))
-                    new_v.append(cv_l.at[slots_ix, :v.shape[1]].set(v))
-                else:
-                    new_k.append(ck_l.at[slots_ix].set(k))
-                    new_v.append(cv_l.at[slots_ix].set(v.astype(cv_l.dtype)))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logits(last[:, 0]), tuple(new_k), tuple(new_v), stats
-
-    def decode(self, tok, positions, ck, cv):
-        """tok [S], positions [S] -> (logits [S, V] f32, ck, cv, stats):
-        the one token step.  There is no K-token ``verify``: a state
-        that has taken K tokens cannot give back the last of them
-        (``serving/engine.py`` refuses what would need it)."""
-        x = self._embed(tok)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, k_l, v_l, st = blk.step(x, ck_l, cv_l, positions)
-            new_k.append(k_l)
-            new_v.append(v_l)
-            stats = stats + st
-        return self._logits(x), tuple(new_k), tuple(new_v), stats
 
 
 def dims_from_config(cfg: dict) -> GraniteMoeHybridDims:

@@ -51,17 +51,13 @@ and in this layout neither form of the attention slices or relays a
 matrix inside a program (compiled for a v5e, a ``[rank, H, D]`` matrix
 was copied head-first in every layer of every step).
 
-**One definition of a block** (:class:`KimiBlock`), as the other serving
-models have: ``sequence`` (the training-shape forward, and prefill,
-which also keeps the rows) and ``step`` (one token a slot against the
-slot's rows).  :class:`KimiK2LM` is its own serving module and states
-each layer's cache itself (``cache_rows``): every layer holds
-``cache_len`` rows a slot, ONE row a position (kind ``latent``: ``[c |
-k_pe]`` padded with zeros to whole lane groups, 576 -> 640 at the
+**One definition of a block** (:class:`KimiBlock`) with the two methods
+the shell of ``served_lm.py`` walks, ``sequence`` and ``step``;
+:class:`KimiK2LM` is that shell and states each layer's cache: every layer
+holds ``cache_len`` rows a slot, ONE row a position (kind ``latent``: ``[c
+| k_pe]`` padded with zeros to whole lane groups, 576 -> 640 at the
 published sizes, as ``bailing_hybrid.py`` pads its own; there is no V
-array and no other kind).  The rows ride in the ``(ck, cv)`` pair
-``DecodeEngine`` donates: layer ``l``'s entries are its rows and an empty
-array.  A parked slot (position 0) goes to no expert.
+array and no other kind).  A parked slot (position 0) goes to no expert.
 
 **The expert layer holds a share** (``ops/moe.py``): ``experts_held`` of
 ``n_routed`` from ``first_expert`` on.
@@ -83,6 +79,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.models.served_lm import (
+    CacheLayer, ServedLM, gated_params, rms_norm)
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
@@ -208,12 +206,6 @@ def _rope_pairs(x, rot):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _rms(x, g, eps):
-    xf = x.astype(F32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * g.astype(F32)).astype(x.dtype)
-
-
 class KimiBlock(nn.Module):
     """One layer: latent attention, then a dense or an expert
     feed-forward."""
@@ -244,18 +236,14 @@ class KimiBlock(nn.Module):
         self.w_uv = P("w_uv", w, (H, c.kv_rank, c.v_dim), pd)
         self.wo = P("wo", w, (H * c.v_dim, d), pd)
         if not self.experts:
-            self.ffn = tuple(P(f"ffn_{n}", w, s, pd) for n, s in (
-                ("gate", (d, c.d_ff)), ("up", (d, c.d_ff)),
-                ("down", (c.d_ff, d))))
+            self.ffn = gated_params(P, "ffn", w, pd, d, c.d_ff)
             return
         f, E, fs = c.d_expert, c.experts_held, c.d_shared
         self.router = P("router", w, (d, c.n_routed), pd)
         self.router_bias = P("router_bias", nn.initializers.normal(0.01),
                              (c.n_routed,), F32)
-        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
-            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
-        self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
-            ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+        self.shared = gated_params(P, "shared", w, pd, d, fs)
+        self.held = gated_params(P, "experts", w, pd, d, f, E)
 
     # --- latent attention --------------------------------------------------
     def _mla_q(self, a, rot):
@@ -264,7 +252,7 @@ class KimiBlock(nn.Module):
         c, dt = self.dims, self.dtype
         if c.q_rank:
             with jax.named_scope("mla.q_down"):
-                a = _rms(jnp.dot(a, self.wq_a.astype(dt)), self.norm_q,
+                a = rms_norm(jnp.dot(a, self.wq_a.astype(dt)), self.norm_q,
                          c.eps)
         with jax.named_scope("mla.q_up"):
             q = jnp.dot(a, (self.wq_b if c.q_rank else self.wq).astype(dt))
@@ -277,7 +265,7 @@ class KimiBlock(nn.Module):
         c = self.dims
         with jax.named_scope("mla.kv"):
             kva = jnp.dot(a, self.w_kva.astype(self.dtype))
-            lat = _rms(kva[..., :c.kv_rank], self.norm_c, c.eps)
+            lat = rms_norm(kva[..., :c.kv_rank], self.norm_c, c.eps)
             k_pe = _rope_pairs(kva[..., None, c.kv_rank:], rot)[..., 0, :]
             pad = jnp.zeros((*a.shape[:-1],
                              c.row_dim - c.kv_rank - c.rope_dim), lat.dtype)
@@ -293,7 +281,7 @@ class KimiBlock(nn.Module):
     def _ffn(self, h, live):
         """h [..., d], live [...] or None -> (h', stats int32[4])."""
         c, dt = self.dims, self.dtype
-        m = _rms(h, self.norm_post, c.eps).reshape(-1, c.d_model)
+        m = rms_norm(h, self.norm_post, c.eps).reshape(-1, c.d_model)
         cast = lambda ws: tuple(x.astype(dt) for x in ws)
         if not self.experts:
             f = moe.gated_ffn(m, *cast(self.ffn))
@@ -312,16 +300,16 @@ class KimiBlock(nn.Module):
         return h + f.reshape(h.shape), stats
 
     # --- the two shapes of work --------------------------------------------
-    def sequence(self, x, rot, lengths=None):
-        """A whole sequence from position 0: x [B, T, d], ``rot`` the
-        rotary table of positions 0..T-1, lengths [B] the live length of
-        each row (None: T) -> (x', the positions' rows ``[B, T,
-        row_dim]``, stats)."""
+    def sequence(self, x, lengths, rot):
+        """A whole sequence from position 0: x [B, T, d], lengths [B] the
+        live length of each row (None: T), ``rot`` the rotary table of
+        positions 0..T-1 -> (x', what the layer remembers — the positions'
+        rows ``[B, T, row_dim]`` and nothing beside them: None —, stats)."""
         c = self.dims
         B, T, _ = x.shape
         live = None if lengths is None else (
             jnp.arange(T)[None] < lengths[:, None])
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         q_nope, q_pe = self._mla_q(a, rot)
         rows = self._mla_rows(a, rot)
         with jax.named_scope("mla.attend"):
@@ -337,21 +325,19 @@ class KimiBlock(nn.Module):
                 block=self.attn_block, scale=c.softmax_scale)
         x = x + self._mla_out(o)
         x, stats = self._ffn(x, live)
-        return x, rows, stats
+        return x, (rows, None), stats
 
-    def __call__(self, x, rot):
-        return self.sequence(x, rot)[0]
-
-    def step(self, x, ck, pos, rot):
+    def step(self, x, ck, cv, pos, rot):
         """One token a slot: x [S, d], pos [S] its position (``rot`` the
         rotary table of ``pos[:, None]``) and the slot's rows ``ck [S, R,
-        row_dim]``: the token's row is written at its position, then the
-        query reads rows ``0..pos``, each once, never expanded.  A slot at
-        ``pos == 0`` is parked: its token goes to no expert."""
+        row_dim]`` (``cv`` is the empty array that rides beside them): the
+        token's row is written at its position, then the query reads rows
+        ``0..pos``, each once, never expanded.  A slot at ``pos == 0`` is
+        parked: its token goes to no expert."""
         c = self.dims
         S = x.shape[0]
         live = pos > 0
-        a = _rms(x, self.norm_in, c.eps)
+        a = rms_norm(x, self.norm_in, c.eps)
         q_nope, q_pe = self._mla_q(a[:, None], rot)
         with jax.named_scope("cache_update"):
             ck = ck.at[jnp.arange(S), pos].set(
@@ -370,71 +356,37 @@ class KimiBlock(nn.Module):
             o = jnp.einsum("shc,hcv->shv", o, self.w_uv.astype(self.dtype))
         x = x + self._mla_out(o)
         x, stats = self._ffn(x, live)
-        return x, ck, stats
+        return x, ck, cv, stats
 
 
-class KimiK2LM(nn.Module):
-    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
-    programs ``DecodeEngine`` asks a model for."""
+class KimiK2LM(ServedLM):
+    """The shell (``served_lm.py``) over :class:`KimiBlock`: latent rows in
+    every layer, one rotary table a program, a ladder of whole tiles."""
     dims: KimiDims
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
     attn_block: int = ATTN_TILE
 
-    # What DecodeEngine reads of any model.
-    vocab_size = property(lambda self: self.dims.vocab_size)
-    max_len = property(lambda self: self.dims.max_len)
-    n_layers = property(lambda self: self.dims.n_layers)
-    #: Positions one prefill program takes at most (DecodeEngine splits a
-    #: larger group): two prompts of 5,120.
+    #: Two prompts of 5,120.
     prefill_positions_max = 10240
-    #: Held experts x expert layers: what one step can touch at most.
     expert_slots = property(lambda self: self.dims.experts_held * (
         self.dims.n_layers - self.dims.n_dense_layers))
 
-    def setup(self):
-        c, pd = self.dims, self.param_dtype
-        w = nn.initializers.normal(c.init_std)
-        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
-        self.blocks = [KimiBlock(
-            c, i >= c.n_dense_layers, self.dtype, pd, self.attn_block,
-            name=f"block{i}") for i in range(c.n_layers)]
-        self.norm_f = self.param("norm_f", nn.initializers.ones,
-                                 (c.d_model,), pd)
-        self.head = self.param("head", w, (c.d_model, c.vocab_size), pd)
+    def make_block(self, i):
+        return KimiBlock(
+            self.dims, i >= self.dims.n_dense_layers, self.dtype,
+            self.param_dtype, self.attn_block, name=f"block{i}")
 
-    def _embed(self, tokens):
-        return self.embed.astype(self.dtype)[tokens]
+    def cache_layers(self, cache_len: int) -> tuple:
+        """Every layer holds ``cache_len`` latent rows ``[row_dim]`` a
+        slot, and there is no other kind."""
+        rows = ((cache_len, self.dims.row_dim), self.dtype)
+        return (CacheLayer("latent", cache_len, rows, None),) \
+            * self.dims.n_layers
 
-    def _logits(self, x):
-        with jax.named_scope("head"):
-            x = _rms(x, self.norm_f, self.dims.eps)
-            return jnp.dot(x, self.head.astype(self.dtype),
-                           preferred_element_type=F32)
-
-    def __call__(self, tokens, train: bool = False):
-        """The training-shape forward (``train`` is accepted for the
-        trainers' calling convention; the model has no dropout)."""
-        x = self._embed(tokens.astype(jnp.int32))
-        rot = rotary(self.dims, jnp.arange(tokens.shape[1])[None])
-        for blk in self.blocks:
-            x = blk(x, rot)
-        return self._logits(x)
-
-    # --- what a model states to DecodeEngine -------------------------------
-    def serving_module(self):
-        return self
-
-    def cache_rows(self, cache_len: int) -> tuple:
-        """``(kind, rows)`` per layer: every layer holds ``cache_len``
-        latent rows a slot, and there is no other kind."""
-        return (("latent", cache_len),) * self.dims.n_layers
-
-    def cache_slot_bytes(self, cache_len: int) -> tuple:
-        """Bytes one slot holds in each layer: its latent rows, as
-        ``init_cache`` lays them out."""
-        held = cache_len * self.dims.row_dim * jnp.dtype(self.dtype).itemsize
-        return (held,) * self.dims.n_layers
+    def _shared(self, tokens, positions=None) -> tuple:
+        """The rotary table of the program's positions, which every layer
+        rotates by."""
+        return (rotary(self.dims, jnp.arange(tokens.shape[1])[None]
+                       if positions is None else positions[:, None]),)
 
     def prefill_buckets(self, cache_len: int):
         """The lengths a prompt is padded to, one prefill program each:
@@ -450,49 +402,8 @@ class KimiK2LM(nn.Module):
         return first + tuple(range(tile, cache_len, tile)) + (cache_len,)
 
     def decode_fetch_block(self, rows: int) -> int:
-        """Rows the decode step's attention fetches at a time from a
-        layer that holds ``rows`` a slot; 0 where it reads them all."""
         c = self.dims
         return latent_fetch_block(rows, c.row_dim, c.kv_rank)
-
-    def init_cache(self, slots: int, cache_len: int) -> tuple:
-        """``(ck, cv)``, one array a layer in each: the latent rows
-        ``[slots, cache_len, row_dim]`` and an empty array."""
-        c = self.dims
-        ck = tuple(jnp.zeros((slots, cache_len, c.row_dim), self.dtype)
-                   for _ in range(c.n_layers))
-        cv = tuple(jnp.zeros((0,), self.dtype) for _ in range(c.n_layers))
-        return ck, cv
-
-    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
-        """toks [B, P] (B prompts padded into one bucket), each written
-        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
-        lengths.  Returns (logits at each prompt's LAST position [B, V]
-        f32, ck, cv, stats).  A slot's rows beyond the prompt are stale
-        and masked."""
-        x = self._embed(toks)
-        rot = rotary(self.dims, jnp.arange(toks.shape[1])[None])
-        new_k, stats = [], 0
-        for blk, ck_l in zip(self.blocks, ck):
-            x, rows, st = blk.sequence(x, rot, lengths)
-            stats = stats + st
-            with jax.named_scope("cache_update"):
-                new_k.append(ck_l.at[slots_ix, :rows.shape[1]].set(rows))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logits(last[:, 0]), tuple(new_k), cv, stats
-
-    def decode(self, tok, positions, ck, cv):
-        """tok [S], positions [S] -> (logits [S, V] f32, ck, cv, stats):
-        the one token step.  There is no K-token ``verify`` here
-        (``serving/engine.py`` refuses what would need it)."""
-        x = self._embed(tok)
-        rot = rotary(self.dims, positions[:, None])
-        new_k, stats = [], 0
-        for blk, ck_l in zip(self.blocks, ck):
-            x, k_l, st = blk.step(x, ck_l, positions, rot)
-            new_k.append(k_l)
-            stats = stats + st
-        return self._logits(x), tuple(new_k), cv, stats
 
 
 #: What of a ``kimi_k2`` configuration is built here, and only so.

@@ -43,23 +43,18 @@ g)`` (the family's zero-centred norm), no bias anywhere::
               return sum_e w_e ffn_e(m) + sigmoid(m w_sg) * ffn_shared(m)
               ffn(m; G, U, D) = (silu(m G) * (m U)) D
 
-**One definition of a block** (:class:`Qwen3NextBlock`), as ``afmoe.py``
-has: ``sequence`` (the training-shape forward, and prefill, which also
-keeps what the layer remembers) and ``step`` (one token a slot against
-what the slot remembers).  :class:`Qwen3NextLM` is its own serving
-module and states each layer's cache itself (``cache_rows``): an
+**One definition of a block** (:class:`Qwen3NextBlock`) with the two
+methods the shell of ``served_lm.py`` walks, ``sequence`` and ``step``;
+:class:`Qwen3NextLM` is that shell and states each layer's cache: an
 attention layer holds ``cache_len`` K/V rows a slot (kind ``full``), a
 Gated DeltaNet layer holds NO rows but a state of a fixed size (kind
 ``state``): ``S [Hv, Dk, Dv]`` float32 and the convolution's last 3
-inputs.  Both ride in the ``(ck, cv)`` pair ``DecodeEngine`` donates:
-layer ``l``'s entries are its K and V rows, or its recurrent state and
-its convolution state.  A state cannot be masked as a stale row can:
-prefill OVERWRITES an admitted slot's state with the state at the
-prompt's true length (``ops/linear_attention.py`` leaves padding out),
-and a parked slot (position 0) neither decays nor writes.  The K/V rows
-are kept flat, ``[S, rows * Hkv, Dh]``: two K/V heads do not fill a
-tile's sublanes, and the token step's ragged kernel reads that view
-anyway (``ops/pallas/decode_attention.py``).
+inputs.  A state cannot be masked as a stale row can: prefill writes the
+state at the prompt's true length (``ops/linear_attention.py`` leaves
+padding out), and a parked slot (position 0) neither decays nor writes.
+The K/V rows are kept flat, ``[S, rows * Hkv, Dh]``: two K/V heads do
+not fill a tile's sublanes, and the token step's ragged kernel reads that
+view anyway (``ops/pallas/decode_attention.py``).
 
 **The expert layer holds a share** (``ops/moe.py``), as ``afmoe.py``'s:
 ``experts_held`` of ``n_routed`` from ``first_expert`` on.
@@ -78,11 +73,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributedtensorflowexample_tpu.models.served_lm import (
+    CacheLayer, ServedLM, gated_params, log_uniform)
 from distributedtensorflowexample_tpu.ops import linear_attention as la
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
-    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention,
-    tile_ladder)
+    ATTN_BLOCK, decode_attention, decode_fetch_block, grouped_attention)
 
 F32 = jnp.float32
 
@@ -140,13 +136,6 @@ def _partial_rope(x, positions, theta, rotary_dim):
                            axis=-1).astype(x.dtype)
 
 
-def _log_uniform(lo: float, hi: float):
-    def init(key, shape, dtype=F32):
-        return jnp.log(jax.random.uniform(key, shape, F32, lo, hi)) \
-            .astype(dtype)
-    return init
-
-
 class Qwen3NextBlock(nn.Module):
     """One layer: gated attention or a Gated DeltaNet, then the expert
     feed-forward."""
@@ -180,7 +169,7 @@ class Qwen3NextBlock(nn.Module):
                           (c.conv_kernel, 2 * kd + vd), pd)
             # Decays between ~0.5 and ~0.998 a step: memories of a few
             # tokens and of hundreds side by side.
-            self.a_log = P("a_log", _log_uniform(0.02, 0.5),
+            self.a_log = P("a_log", log_uniform(0.02, 0.5),
                            (c.lin_v_heads,), F32)
             self.dt_bias = P("dt_bias", nn.initializers.uniform(1.0),
                              (c.lin_v_heads,), F32)
@@ -188,11 +177,9 @@ class Qwen3NextBlock(nn.Module):
             self.w_out = P("w_out", w, (vd, d), pd)
         f, E, fs = c.d_expert, c.experts_held, c.d_shared
         self.router = P("router", w, (d, c.n_routed), pd)
-        self.shared = tuple(P(f"shared_{n}", w, s, pd) for n, s in (
-            ("gate", (d, fs)), ("up", (d, fs)), ("down", (fs, d))))
+        self.shared = gated_params(P, "shared", w, pd, d, fs)
         self.shared_gate_w = P("shared_gate_w", w, (d, 1), pd)
-        self.held = tuple(P(f"experts_{n}", w, s, pd) for n, s in (
-            ("gate", (E, d, f)), ("up", (E, d, f)), ("down", (E, f, d))))
+        self.held = gated_params(P, "experts", w, pd, d, f, E)
 
     # --- gated attention ---------------------------------------------------
     def _qkvu(self, a, positions):
@@ -314,9 +301,6 @@ class Qwen3NextBlock(nn.Module):
         x, stats = self._ffn(x, live)
         return x, kept, stats
 
-    def __call__(self, x):
-        return self.sequence(x)[0]
-
     def step(self, x, ck, cv, pos):
         """One token a slot: x [S, d], pos [S] its position, and what
         the layer remembers of each slot — an attention layer's K and V
@@ -355,143 +339,43 @@ class Qwen3NextBlock(nn.Module):
         return x, ck, cv, stats
 
 
-class Qwen3NextLM(nn.Module):
-    """tokens [B, T] -> logits [B, T, vocab] float32, and the serving
-    programs ``DecodeEngine`` asks a model for."""
+class Qwen3NextLM(ServedLM):
+    """The shell (``served_lm.py``) over :class:`Qwen3NextBlock`: the
+    family's zero-centred norm before the head, attention rows kept flat
+    beside the Gated DeltaNet's states."""
     dims: Qwen3NextDims
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-    attn_block: int = ATTN_BLOCK
 
-    # What DecodeEngine reads of any model.
-    vocab_size = property(lambda self: self.dims.vocab_size)
-    max_len = property(lambda self: self.dims.max_len)
-    n_layers = property(lambda self: self.dims.n_layers)
-    #: Positions one prefill program takes at most (DecodeEngine splits a
-    #: larger group): two prompts of the longest bucket.
+    norm = staticmethod(_rms0)
+    norm_init = staticmethod(nn.initializers.zeros)
+    #: Two prompts of the longest bucket.
     prefill_positions_max = 8192
-    #: Held experts x expert layers: what one step can touch at most.
     expert_slots = property(
         lambda self: self.dims.experts_held * self.dims.n_layers)
 
-    def setup(self):
-        c, pd = self.dims, self.param_dtype
-        w = nn.initializers.normal(c.init_std)
-        self.embed = self.param("embed", w, (c.vocab_size, c.d_model), pd)
-        self.blocks = [Qwen3NextBlock(
-            c, c.is_attention(i), self.dtype, pd, self.attn_block,
-            name=f"block{i}") for i in range(c.n_layers)]
-        self.norm_f = self.param("norm_f", nn.initializers.zeros,
-                                 (c.d_model,), pd)
-        self.head = self.param("head", w, (c.d_model, c.vocab_size), pd)
+    def make_block(self, i):
+        return Qwen3NextBlock(
+            self.dims, self.dims.is_attention(i), self.dtype,
+            self.param_dtype, self.attn_block, name=f"block{i}")
 
-    def _embed(self, tokens):
-        return self.embed.astype(self.dtype)[tokens]
-
-    def _logits(self, x):
-        with jax.named_scope("head"):
-            x = _rms0(x, self.norm_f, self.dims.eps)
-            return jnp.dot(x, self.head.astype(self.dtype),
-                           preferred_element_type=F32)
-
-    def __call__(self, tokens, train: bool = False):
-        """The training-shape forward (``train`` is accepted for the
-        trainers' calling convention; the model has no dropout)."""
-        x = self._embed(tokens.astype(jnp.int32))
-        for blk in self.blocks:
-            x = blk(x)
-        return self._logits(x)
-
-    # --- what a model states to DecodeEngine -------------------------------
-    def serving_module(self):
-        return self
-
-    def cache_rows(self, cache_len: int) -> tuple:
-        """``(kind, rows)`` per layer: an attention layer holds
-        ``cache_len`` K/V rows a slot, a Gated DeltaNet no rows but a
-        state."""
+    def cache_layers(self, cache_len: int) -> tuple:
+        """An attention layer holds ``cache_len`` K/V rows a slot, flat as
+        ``[cache_len * Hkv, Dh]``; a Gated DeltaNet layer no rows but its
+        state ``[Hv, Dk, Dv]`` float32 and the convolution's last inputs
+        ``[K - 1, C]``."""
         c = self.dims
-        return tuple(("full", cache_len) if c.is_attention(i)
-                     else ("state", 0) for i in range(c.n_layers))
-
-    def cache_slot_bytes(self, cache_len: int) -> tuple:
-        """Bytes one slot holds in each layer (``cache_rows``' order):
-        K and V rows, or the recurrent state (float32) and the
-        convolution's — read off ``init_cache``'s own shapes."""
-        ck, cv = jax.eval_shape(lambda: self.init_cache(1, cache_len))
-        return tuple(sum(x.size * x.dtype.itemsize for x in layer)
-                     for layer in zip(ck, cv))
-
-    def prefill_buckets(self, cache_len: int):
-        """The lengths a prompt is padded to, one prefill program each:
-        powers of two from 256 (below it a program's time is the 4 GB of
-        weights it reads, whatever it pads) up to a tile of attention,
-        then whole tiles (``ops/attention.takes_splash`` asks for that;
-        the chunked scan for whole chunks, which a tile is), ``cache_len``
-        last.  ``None`` (the engine's powers of two) for a cache shorter
-        than that first bucket."""
-        return tile_ladder(cache_len, self.attn_block)
+        kv = ((cache_len * c.n_kv_heads, c.head_dim), self.dtype)
+        conv = 2 * c.lin_k_heads * c.lin_k_dim + c.lin_v_heads * c.lin_v_dim
+        full = CacheLayer("full", cache_len, kv, kv)
+        state = CacheLayer(
+            "state", 0, ((c.lin_v_heads, c.lin_k_dim, c.lin_v_dim), F32),
+            ((c.conv_kernel - 1, conv), self.dtype))
+        return tuple(full if c.is_attention(i) else state
+                     for i in range(c.n_layers))
 
     def decode_fetch_block(self, rows: int) -> int:
-        """Rows the decode step's attention fetches at a time from a
-        layer that holds ``rows`` a slot; 0 where it reads them all (and
-        for a layer that holds no rows)."""
         c = self.dims
         return rows and decode_fetch_block(rows, c.n_kv_heads, c.head_dim,
                                            flat=True)
-
-    def init_cache(self, slots: int, cache_len: int) -> tuple:
-        """``(ck, cv)``, one array a layer in each: K and V rows ``[slots,
-        cache_len * Hkv, Dh]``, or the recurrent state ``[slots, Hv, Dk,
-        Dv]`` float32 and the convolution's ``[slots, K - 1, C]``."""
-        c = self.dims
-        rows = (slots, cache_len * c.n_kv_heads, c.head_dim)
-        conv = (slots, c.conv_kernel - 1, 2 * c.lin_k_heads * c.lin_k_dim
-                + c.lin_v_heads * c.lin_v_dim)
-        state = (slots, c.lin_v_heads, c.lin_k_dim, c.lin_v_dim)
-        ck = tuple(jnp.zeros(rows, self.dtype) if c.is_attention(i)
-                   else jnp.zeros(state, F32) for i in range(c.n_layers))
-        cv = tuple(jnp.zeros(rows if c.is_attention(i) else conv, self.dtype)
-                   for i in range(c.n_layers))
-        return ck, cv
-
-    def prefill_into(self, toks, slots_ix, lengths, ck, cv):
-        """toks [B, P] (B prompts padded into one bucket), each written
-        into its slot ``slots_ix [B]``; ``lengths [B]`` the real prompt
-        lengths.  Returns (logits at each prompt's LAST position [B, V]
-        f32, ck, cv, stats).  A slot's K/V rows beyond the prompt are
-        stale and masked; its recurrent and convolution states are
-        overwritten with the states at the prompt's length."""
-        B, P = toks.shape
-        x = self._embed(toks)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, (k, v), st = blk.sequence(x, lengths)
-            stats = stats + st
-            with jax.named_scope("cache_update"):
-                if blk.attention:
-                    k, v = (t.reshape(B, -1, t.shape[-1]) for t in (k, v))
-                    new_k.append(ck_l.at[slots_ix, :k.shape[1]].set(k))
-                    new_v.append(cv_l.at[slots_ix, :v.shape[1]].set(v))
-                else:
-                    new_k.append(ck_l.at[slots_ix].set(k))
-                    new_v.append(cv_l.at[slots_ix].set(v.astype(cv_l.dtype)))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logits(last[:, 0]), tuple(new_k), tuple(new_v), stats
-
-    def decode(self, tok, positions, ck, cv):
-        """tok [S], positions [S] -> (logits [S, V] f32, ck, cv, stats):
-        the one token step.  There is no K-token ``verify``: a state
-        that has taken K tokens cannot give back the last of them
-        (``serving/engine.py`` refuses what would need it)."""
-        x = self._embed(tok)
-        new_k, new_v, stats = [], [], 0
-        for blk, ck_l, cv_l in zip(self.blocks, ck, cv):
-            x, k_l, v_l, st = blk.step(x, ck_l, cv_l, positions)
-            new_k.append(k_l)
-            new_v.append(v_l)
-            stats = stats + st
-        return self._logits(x), tuple(new_k), tuple(new_v), stats
 
 
 def dims_from_config(cfg: dict) -> Qwen3NextDims:

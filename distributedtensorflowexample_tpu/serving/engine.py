@@ -10,60 +10,33 @@ already produced.  This module keeps those rows resident and compiles
 ONE decode step whose cache arguments are DONATED.
 
 **The engine asks the model** for what it serves with
-(``model.serving_module()``): a flax module with ``prefill_into``,
-``verify`` and ``decode`` methods, which also states each layer's cache
-(``cache_rows(cache_len)`` -> ``(kind, rows)`` a layer) and allocates it
-(``init_cache(slots, cache_len)`` -> ``(ck, cv)``).  The engine holds
-that pair, donates it to every program and rebinds what comes back; it
-never looks inside.  There are four kinds of layer:
+(``model.serving_module()``), and what it asks is one class:
+``models/served_lm.py: ServedLM``, the shell the five served
+architectures subclass (:data:`SERVING_SEAM` names its members here, and a
+module that lacks one is refused by that name).  :class:`ServingLM`
+below, GPT-2's, states the same members beside its stacked cache.  The
+module states each layer's cache (``cache_rows(cache_len)`` -> ``(kind,
+rows)`` a layer, ``cache_slot_bytes(cache_len)`` -> bytes a slot holds in
+each) and allocates it (``init_cache(slots, cache_len)`` -> ``(ck, cv)``).
+The engine holds that pair, donates it to every program and rebinds what
+comes back; it never looks inside.  The four kinds of layer (``full``,
+``window``, ``latent``, ``state``) are ``served_lm.py``'s to define; what
+the engine knows of them: a ``state`` layer holds NO rows (``rows`` is 0)
+but a state that is read AND written whole every step
+(``serve_state_bytes_total``), and only ``full`` layers' rows can be read,
+written or rolled back by position (:data:`_NO_ROWS_BY_POSITION`).
+``serve_cache_bytes{kind}`` is ``cache_slot_bytes`` summed by kind.
 
-* ``full``: ``cache_len`` K/V rows a slot, a row a position;
-* ``window``: a ring of the last ``rows`` positions;
-* ``latent``: ``cache_len`` rows a slot addressed by position as
-  ``full``'s are, but ONE compressed row a position that every head
-  shares as key and as value (latent attention), and no V array: 576
-  features where 32 heads of keys and values would be 10,240;
-* ``state``: NO rows (``rows`` is 0) but a recurrent state whose size
-  does not depend on ``cache_len``.  It is read AND written whole every
-  step; a stale one cannot be masked as a stale row is, so the module's
-  prefill overwrites an admitted slot's, and its token step leaves a
-  parked slot's (position 0) as it is.  A module with such layers says
-  what a slot holds in each layer (``cache_slot_bytes(cache_len)``):
-  ``serve_cache_bytes{kind}`` and ``serve_state_bytes_total{whose}``
-  come from that; for the other modules every row is as wide in every
-  layer and the engine splits the cache's bytes by rows.
+Two layouts exist today:
 
-A module may also state the ladder of lengths its prompts are padded to
-(``prefill_buckets(cache_len)``); without one the engine pads to the
-next power of two (:func:`_prefill_buckets`).
-Six layouts exist today:
-
-* ``models/afmoe.py``'s model (its own serving module): one ``[S, rows,
-  Hkv, Dh]`` array a layer, a full layer ``cache_len`` rows a slot, a
-  window layer ``min(window, cache_len)`` rows as a ring (row = position
-  mod rows).  One shape for all layers would hold every window layer at
-  ``cache_len`` rows.
-* ``models/qwen3_next.py``'s model (its own serving module too): one
-  array a layer in each of ``ck`` and ``cv``; an attention layer's are
-  its K and V rows, kept flat as ``[S, cache_len * Hkv, Dh]``, a Gated
-  DeltaNet layer's its recurrent state ``[S, Hv, Dk, Dv]`` float32 and
-  its convolution's last inputs.
-* ``models/bailing_hybrid.py``'s model (its own serving module): one
-  array a layer in each of ``ck`` and ``cv``; a latent-attention layer's
-  ``ck`` is its rows ``[S, cache_len, row]`` and its ``cv`` is empty, a
-  Kimi Delta Attention layer's are its recurrent state ``[S, H, D, D]``
-  float32 and its convolution's last inputs.
-* ``models/granitemoehybrid.py``'s model (its own serving module): one
-  array a layer in each of ``ck`` and ``cv``; the attention layer's are
-  its K and V rows ``[S, cache_len, Hkv, Dh]`` (eight K/V heads: whole
-  tiles as they are), a Mamba-2 layer's its recurrent state ``[S, H, P,
-  N]`` float32 and its convolution's last inputs — the ``state`` kind
-  again, under a third recurrence; nothing here changed for it.
-* ``models/kimi_k2.py``'s model (its own serving module): every layer
-  ``latent`` and no other kind — ``ck`` holds the rows ``[S, cache_len,
-  row]`` of each layer, ``cv`` only empty arrays; the bookkeeping is the
-  ``latent`` kind's as it was (``cache_slot_bytes``, rows read and
-  fetched), and what moves the stacked K/V pair refuses it by name.
+* ``ServedLM``'s: one array a layer in each of ``ck`` and ``cv``, of the
+  kind, shape and type the family's ``cache_layers`` states (``models/
+  afmoe.py``: window rings beside full rows, where one shape for all layers
+  would hold every window layer at ``cache_len`` rows; ``qwen3_next.py``,
+  ``bailing_hybrid.py``, ``granitemoehybrid.py``: rows or latent rows in
+  the attention layers, a recurrent state and a convolution's in the
+  others; ``kimi_k2.py``: latent rows and nothing else).  What moves the
+  stacked K/V pair refuses each of them by name.
 * ``TransformerLM``'s (:class:`ServingLM`, every layer full): the two
   stacked ``[L, S, T, H, Dh]`` buffers it always had.  Donation aliases
   them (:data:`DECODE_HLO_CONTRACT`, checked on freshly compiled XLA:CPU
@@ -185,6 +158,14 @@ DECODE_HLO_CONTRACT = {
     "collective_budget": {"all-reduce": 0},
     "dtype_ceiling": "f32",
 }
+
+#: What ``DecodeEngine`` asks of a serving module, by name: the members of
+#: ``models/served_lm.py: ServedLM`` it reads (``verify`` is asked only of
+#: a model whose every layer is ``full``; ``expert_slots`` only of one that
+#: returns counts).
+SERVING_SEAM = ("prefill_into", "decode", "cache_rows", "cache_slot_bytes",
+                "init_cache", "prefill_buckets", "decode_fetch_block",
+                "prefill_positions_max")
 
 #: Default decode-slot count (SERVE_SLOTS overrides): enough concurrency
 #: to show continuous batching on the CPU demo without compiling a wide
@@ -399,9 +380,25 @@ class ServingLM(nn.Module):
         return last, ck, cv
 
     # --- what a serving module states to DecodeEngine ----------------------
+    #: No limit on the positions of one prefill program.
+    prefill_positions_max = None
+
     def cache_rows(self, cache_len: int) -> tuple:
         """``(kind, rows)`` per layer: every layer full."""
         return (("full", cache_len),) * self.n_layers
+
+    def cache_slot_bytes(self, cache_len: int) -> tuple:
+        """Bytes one slot holds in each layer: its K and V rows."""
+        row = 2 * self.d_model * jnp.dtype(self.dtype).itemsize
+        return (cache_len * row,) * self.n_layers
+
+    def prefill_buckets(self, cache_len: int):
+        """None: the engine's powers of two (:func:`_prefill_buckets`)."""
+        return None
+
+    def decode_fetch_block(self, rows: int) -> int:
+        """0: the token step's attention reads every row a layer holds."""
+        return 0
 
     def init_cache(self, slots: int, cache_len: int) -> tuple:
         """The stacked pair ``[L, S, T, H, Dh]`` (the module docstring
@@ -466,9 +463,9 @@ def _prefill_buckets(cache_len: int, smallest: int = 8) -> tuple:
     to ``cache_len`` (inclusive as the final bucket).  Each bucket is
     one compiled prefill program; a prompt pads to the smallest bucket
     that fits, so N distinct prompt lengths cost log(N) compiles, not
-    N.  This is the DEFAULT ladder: a serving module whose positions
-    cost unevenly states its own (``prefill_buckets(cache_len)``, see
-    ``AfmoeLM``), and ``DecodeEngine`` takes that one instead."""
+    N.  This is the ladder of a serving module whose
+    ``prefill_buckets(cache_len)`` answers None; ``DecodeEngine`` takes
+    the module's own where it states one (``ServedLM``, ``AfmoeLM``)."""
     out = []
     b = smallest
     while b < cache_len:
@@ -598,10 +595,15 @@ class DecodeEngine:
         self.slots = int(slots)
         self.cache_len = int(cache_len)
         self.vocab = int(model.vocab_size)
-        # The padding ladder is the model's where it states one (None:
-        # it leaves the choice here), the powers of two otherwise.
-        stated = getattr(self.smodel, "prefill_buckets", lambda n: None)
-        self.buckets = (stated(self.cache_len)
+        missing = [m for m in SERVING_SEAM if not hasattr(self.smodel, m)]
+        if missing:
+            raise TypeError(
+                f"{type(self.smodel).__name__} is no serving module: it "
+                f"lacks {', '.join(missing)} (models/served_lm.py: ServedLM "
+                f"states what DecodeEngine asks of one)")
+        # The padding ladder is the module's (None: it leaves the choice
+        # here, the powers of two).
+        self.buckets = (self.smodel.prefill_buckets(self.cache_len)
                         or _prefill_buckets(self.cache_len, prefill_smallest))
         self._ck, self._cv = self.smodel.init_cache(self.slots,
                                                     self.cache_len)
@@ -615,25 +617,17 @@ class DecodeEngine:
         kinds: dict = {}
         for kind, rows in layers:
             kinds[kind] = (kinds.get(kind, (0, rows))[0] + 1, rows)
-        fetch_block = getattr(self.smodel, "decode_fetch_block",
-                              lambda rows: 0)
         self._kinds = [(_ROWS_READ.labels(kind=kind),
                         _ROWS_FETCHED.labels(kind=kind), n, rows,
-                        fetch_block(rows))
+                        self.smodel.decode_fetch_block(rows))
                        for kind, (n, rows) in kinds.items()
                        if kind != "state"]
-        # The bytes of each kind: the module's own count of what a slot
-        # holds in each layer, or (no module without state layers states
-        # one) the cache's bytes split by rows, a row as wide everywhere.
-        stated = getattr(self.smodel, "cache_slot_bytes", None)
+        # The bytes of each kind: what a slot holds in each layer, by the
+        # module's own count.
         by_kind = dict.fromkeys(kinds, 0)
-        if stated is not None:
-            for (kind, _), held in zip(layers, stated(self.cache_len)):
-                by_kind[kind] += self.slots * held
-        else:
-            for kind, (n, rows) in kinds.items():
-                by_kind[kind] = (self.cache_bytes * n * rows
-                                 // sum(r for _, r in layers))
+        for (kind, _), held in zip(
+                layers, self.smodel.cache_slot_bytes(self.cache_len)):
+            by_kind[kind] += self.slots * held
         for kind, held in by_kind.items():
             _CACHE_BYTES.labels(kind=kind).set(held)
         # What one slot's states cost a decode step: read and written.
@@ -719,7 +713,7 @@ class DecodeEngine:
         # A model may cap the positions one prefill program takes (its
         # activations beside a nearly full chip): a larger group goes as
         # several programs of the same bucket.
-        most = getattr(self.smodel, "prefill_positions_max", None)
+        most = self.smodel.prefill_positions_max
         split = [(bucket, group[i:i + n])
                  for bucket, group in sorted(groups.items())
                  for n in [max(1, most // bucket) if most else len(group)]

@@ -4,6 +4,7 @@ reference (benchmarks/reference/afmoe.py) at tiny widths on the CPU,
 float32 compute so that the comparison is of the mathematics: a window of
 8 positions, so every context here wraps the rings several times."""
 
+import functools
 import json
 import os
 
@@ -12,6 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_families as fam
+
 from benchmarks.reference import afmoe as ref
 from distributedtensorflowexample_tpu.models import build_model_from_config
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
@@ -19,51 +22,31 @@ from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.attention import (
     grouped_attention, splash_grouped_attention, takes_splash)
 from distributedtensorflowexample_tpu.refusal import ModeRefusal
-from distributedtensorflowexample_tpu.serving.engine import (
-    DECODE_HLO_CONTRACT, DecodeEngine)
+from distributedtensorflowexample_tpu.serving.engine import DecodeEngine
 from distributedtensorflowexample_tpu.serving.queue import (
     ContinuousBatcher, RequestQueue)
 
-TINY = dict(
-    model_type="afmoe", vocab_size=97, hidden_size=32,
-    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
-    intermediate_size=64, moe_intermediate_size=16, num_hidden_layers=4,
-    num_dense_layers=1,
-    layer_types=["sliding_attention", "sliding_attention", "full_attention",
-                 "sliding_attention"],
-    num_experts=4, num_experts_per_tok=2, num_shared_experts=1,
-    route_scale=2.448, route_norm=True, sliding_window=8, rope_theta=10000,
-    rms_norm_eps=1e-5, max_position_embeddings=128, mup_enabled=True,
-    published={"num_experts": 16}, deployment={"rank": 1})
 TOL = 2e-5      # float32 against float32 at HIGHEST: summation order only
 
-
-def _model(attn_block=1024, **sizes):
-    return build_model_from_config({**TINY, **sizes}, dtype=jnp.float32,
-                                   param_dtype=jnp.float32,
-                                   attn_block=attn_block)
+FAMILY = "afmoe"
+TINY = fam.TINY[FAMILY]
+_model = functools.partial(fam.model, FAMILY)
+_counter = fam.counter
 
 
 @pytest.fixture(scope="module")
 def params():
-    return _model().init(jax.random.PRNGKey(3),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
+    return fam.params(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def sequences():
-    return np.random.default_rng(5).integers(0, TINY["vocab_size"],
-                                             (4, 60)).astype(np.int32)
+    return fam.sequences(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def ref_logits(params, sequences):
     return np.asarray(ref.forward(params, jnp.asarray(sequences), TINY))
-
-
-def _counter(series: str) -> float:
-    got = obs_metrics.registry().snapshot()["counters"].get(series)
-    return (got["value"] if isinstance(got, dict) else got) or 0
 
 
 # ---- the training-shape forward -------------------------------------------
@@ -566,35 +549,6 @@ def test_the_products_an_engine_traces_are_counted_by_kernel(
 
 # ---- what refuses, and what holds -----------------------------------------
 
-def _engine(params, **kw):
-    return DecodeEngine(_model(), params, slots=2, cache_len=32, **kw)
-
-
-@pytest.mark.parametrize("what", ["PrefixCache", "SpecDecoder",
-                                  "ShardedDecodeEngine", "read_rows",
-                                  "write_rows", "verify_step", "extend"])
-def test_what_assumes_one_row_shape_refuses_window_layers_by_name(params,
-                                                                  what):
-    from distributedtensorflowexample_tpu.serving.prefix import PrefixCache
-    from distributedtensorflowexample_tpu.serving.sharded import (
-        ShardedDecodeEngine)
-    from distributedtensorflowexample_tpu.serving.spec import SpecDecoder
-    engine = _engine(params)
-    calls = {
-        "PrefixCache": lambda: PrefixCache(engine),
-        "SpecDecoder": lambda: SpecDecoder(engine, _engine(params)),
-        "ShardedDecodeEngine": lambda: ShardedDecodeEngine(
-            engine.model, (), None),
-        "read_rows": lambda: engine.read_rows(0, 4),
-        "write_rows": lambda: engine.write_rows(0, None, None),
-        "verify_step": lambda: engine.verify_step(
-            np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)),
-        "extend": lambda: engine.extend(0, [1, 2], 3),
-    }
-    with pytest.raises(ModeRefusal, match="window-attention layers"):
-        calls[what]()
-
-
 def test_a_model_of_full_layers_only_is_not_refused(params):
     """The refusal is of rings, not of the architecture."""
     from distributedtensorflowexample_tpu.serving.engine import (
@@ -602,29 +556,6 @@ def test_a_model_of_full_layers_only_is_not_refused(params):
     refuse(_model(layer_types=["full_attention"] * 4), "x")
     with pytest.raises(ModeRefusal):
         refuse(_model(), "x")
-
-
-def test_a_cache_longer_than_the_models_positions_is_refused(params):
-    with pytest.raises(ModeRefusal, match="exceeds"):
-        DecodeEngine(_model(), params, slots=2, cache_len=129)
-
-
-def test_the_decode_program_honours_the_hlo_contract(params):
-    """Donation aliased for every layer's cache, no copy of a donated
-    buffer, no collective, nothing wider than f32 — and the jitted
-    function's name has ``decode_step`` in it (the benchmark finds the
-    program's device time by that)."""
-    from distributedtensorflowexample_tpu.analysis.hlo_lint import (
-        check_contract)
-    from distributedtensorflowexample_tpu.serving import engine as eng
-    engine = _engine(params)
-    assert check_contract(engine.decode_hlo(), DECODE_HLO_CONTRACT) == []
-    assert "decode_step" in eng._decode_step.__name__
-    text = eng._decode_step.lower(
-        engine.smodel, *engine.decode_args()).as_text(debug_info=True)
-    for scope in ("moe.route", "moe.experts", "moe.shared", "attn.window",
-                  "attn.full", "cache_update", "head"):
-        assert f"/{scope}/" in text, scope
 
 
 def test_gpt2_goes_through_the_same_engine_with_every_layer_full():

@@ -7,6 +7,7 @@ hold K/V rows or a recurrent state — against the plain reference
 float32 compute so that the comparison is of the mathematics: one period
 in small (three Mamba-2 layers, attention, two more)."""
 
+import functools
 import json
 import os
 
@@ -15,83 +16,39 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_families as fam
+
 from benchmarks.reference import granitemoehybrid as ref
 from distributedtensorflowexample_tpu.models import build_model_from_config
 from distributedtensorflowexample_tpu.obs import metrics as obs_metrics
 from distributedtensorflowexample_tpu.ops import moe
-from distributedtensorflowexample_tpu.refusal import ModeRefusal
-from distributedtensorflowexample_tpu.serving.engine import (
-    DECODE_HLO_CONTRACT, DecodeEngine)
+from distributedtensorflowexample_tpu.serving.engine import DecodeEngine
 
-TINY = dict(
-    model_type="granitemoehybrid", vocab_size=97, hidden_size=32,
-    num_hidden_layers=6,
-    layer_types=["mamba", "mamba", "mamba", "attention", "mamba", "mamba"],
-    num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=8,
-    mamba_d_head=8, mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
-    mamba_n_groups=1, mamba_conv_bias=True, mamba_proj_bias=False,
-    intermediate_size=16, shared_intermediate_size=24, num_local_experts=3,
-    num_experts_per_tok=4, embedding_multiplier=12, residual_multiplier=0.22,
-    attention_multiplier=0.0625, logits_scaling=16, rms_norm_eps=1e-5,
-    max_position_embeddings=512, position_embedding_type="nope",
-    tie_word_embeddings=True, published={"num_local_experts": 24},
-    deployment={"rank": 1})
 KINDS = ["state"] * 3 + ["full"] + ["state"] * 2
 #: float32 against float32 at HIGHEST: summation order only.  The logits
 #: are divided by 16 and lie within +-0.1 here (readings: 1e-8).
 TOL = 2e-7
 
 
-def _model(**sizes):
-    return build_model_from_config({**TINY, **sizes}, dtype=jnp.float32,
-                                   param_dtype=jnp.float32)
+FAMILY = "granitemoehybrid"
+TINY = fam.TINY[FAMILY]
+_model = functools.partial(fam.model, FAMILY)
+_counter = fam.counter
 
 
 @pytest.fixture(scope="module")
 def params():
-    """Seeded, with the norms' scales and the skip moved off one (where a
-    norm whose scale is dropped, or a skip left out, would pass), and
-    with steps twenty times Mamba-2's own, a step's projection that
-    matters at 32 features and an input projection ten times as large
-    (B and C of ~0.4, as 4,096 features give them): what is read of the
-    state is then as large as the skip, and a step's decay runs from
-    ~0.98 down to nothing."""
-    p = _model().init(jax.random.PRNGKey(3),
-                      jnp.zeros((1, 8), jnp.int32))["params"]
-    keys = iter(jax.random.split(jax.random.PRNGKey(4), 64))
-
-    def moved(path, x):
-        name = path[-1].key
-        if name.startswith("norm_") or name in ("d_skip", "w_dt"):
-            return x + 0.2 * jax.random.normal(next(keys), x.shape)
-        if name == "w_in":
-            return 10.0 * x
-        return x + 3.0 if name == "dt_bias" else x
-
-    return jax.tree_util.tree_map_with_path(moved, p)
+    return fam.params(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def sequences():
-    return np.random.default_rng(5).integers(0, TINY["vocab_size"],
-                                             (4, 300)).astype(np.int32)
+    return fam.sequences(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def ref_logits(params, sequences):
     return np.asarray(ref.forward(params, jnp.asarray(sequences), TINY))
-
-
-def _counter(series: str) -> float:
-    got = obs_metrics.registry().snapshot()["counters"].get(series)
-    return (got["value"] if isinstance(got, dict) else got) or 0
-
-
-def _state_leaves(engine, slot):
-    """What the state-space layers remember of ``slot``."""
-    rows = engine.smodel.cache_rows(engine.cache_len)
-    return [np.asarray(c[i][slot]) for i, (kind, _) in enumerate(rows)
-            if kind == "state" for c in (engine._ck, engine._cv)]
 
 
 def test_forward_matches_the_reference(params, sequences, ref_logits):
@@ -224,81 +181,6 @@ def test_a_state_kept_in_bfloat16_fails_the_tolerance(params, sequences,
         worst = max(worst, np.abs(engine.decode_logits(busy=[0])[0]
                                   - ref_logits[0, t]).max())
     assert worst > 10 * TOL, worst
-
-
-def test_one_prompt_in_two_buckets_and_in_a_mixed_batch_leaves_one_state(
-        params, sequences):
-    """A 21-token prompt alone in its bucket of 32, in a bucket of 128
-    (an engine whose ladder starts there), and beside a 30-token prompt
-    in one batch: the same last logits, the same recurrent and
-    convolution states (padding neither decays nor writes) and the same
-    21 K/V rows."""
-    prompt = sequences[0, :21]
-    alone = DecodeEngine(_model(), params, slots=2, cache_len=256)
-    (_, want), = alone.prefill_many([(1, prompt, 1)]).values()
-    wide = DecodeEngine(_model(), params, slots=2, cache_len=256,
-                        prefill_smallest=128)
-    assert wide.bucket_for(21, 1) == 128
-    (_, got), = wide.prefill_many([(1, prompt, 1)]).values()
-    assert np.abs(got - want).max() < TOL
-    mixed = DecodeEngine(_model(), params, slots=2, cache_len=256)
-    out = mixed.prefill_many([(0, sequences[3, :30], 1), (1, prompt, 1)])
-    assert (32, 2) in mixed._warm_buckets
-    assert np.abs(out[1][1] - want).max() < TOL
-    for engine in (wide, mixed):
-        for a, b in zip(_state_leaves(engine, 1), _state_leaves(alone, 1)):
-            assert np.abs(a - b).max() < 1e-5
-        assert np.abs(np.asarray(engine._ck[3][1, :21]
-                                 - alone._ck[3][1, :21])).max() < 1e-5
-
-
-def _serve_alone(params, prompt, steps, slot, slots=3):
-    engine = DecodeEngine(_model(), params, slots=slots, cache_len=256)
-    engine.prefill_many([(slot, prompt, 1)])
-    return np.stack([engine.decode_logits(busy=[slot])[slot]
-                     for _ in range(steps)])
-
-
-def test_a_reused_slot_serves_what_a_fresh_engine_serves_bitwise(
-        params, sequences):
-    """Slot 1 serves a 40-token prompt for 25 steps, is parked, and is
-    then given another request: admission overwrites the states the first
-    left and masks its rows, so the second request's logits are, bit for
-    bit, a fresh engine's."""
-    engine = DecodeEngine(_model(), params, slots=3, cache_len=256)
-    engine.prefill_many([(1, sequences[0, :40], 1)])
-    for _ in range(25):
-        engine.decode_logits(busy=[1])
-    engine.set_slot(1, 0, 0)                        # retired: parked
-    engine.decode_logits(busy=[])                   # parked slots compute
-    engine.prefill_many([(1, sequences[1, :13], 1)])
-    got = np.stack([engine.decode_logits(busy=[1])[1] for _ in range(20)])
-    assert np.array_equal(got, _serve_alone(params, sequences[1, :13], 20, 1))
-
-
-def test_a_request_admitted_mid_decode_serves_what_it_serves_alone(
-        params, sequences):
-    engine = DecodeEngine(_model(), params, slots=3, cache_len=256)
-    engine.prefill_many([(0, sequences[0, :17], 1)])
-    first = [engine.decode_logits(busy=[0])[0] for _ in range(9)]
-    engine.prefill_many([(2, sequences[2, :33], 1)])
-    both = [engine.decode_logits(busy=[0, 2]) for _ in range(12)]
-    assert np.array_equal(np.stack([b[2] for b in both]),
-                          _serve_alone(params, sequences[2, :33], 12, 2))
-    assert np.array_equal(np.stack(first + [b[0] for b in both]),
-                          _serve_alone(params, sequences[0, :17], 21, 0))
-
-
-def test_parked_slots_keep_their_state_and_stay_finite(params, sequences):
-    engine = DecodeEngine(_model(), params, slots=2, cache_len=64)
-    engine.prefill_many([(0, sequences[0, :9], 1), (1, sequences[1, :9], 1)])
-    engine.set_slot(1, 0, 0)
-    before = _state_leaves(engine, 1)
-    for _ in range(5):
-        logits = engine.decode_logits(busy=[0])
-        assert np.isfinite(logits).all()
-    for a, b in zip(before, _state_leaves(engine, 1)):
-        assert np.array_equal(a, b)
 
 
 PLAN = [(5, 30), (21, 25), (9, 12), (70, 20), (3, 40), (14, 9), (27, 18)]
@@ -472,66 +354,6 @@ def test_the_engines_counters_follow_a_hand_count(params, sequences):
 
 
 # ---- what refuses, and what holds -----------------------------------------
-
-def _engine(params, **kw):
-    return DecodeEngine(_model(), params, slots=2, cache_len=32, **kw)
-
-
-@pytest.mark.parametrize("what", ["PrefixCache", "SpecDecoder",
-                                  "ShardedDecodeEngine", "read_rows",
-                                  "write_rows", "verify_step", "extend"])
-def test_what_rolls_a_cache_back_refuses_state_layers_by_name(params, what):
-    from distributedtensorflowexample_tpu.serving.prefix import PrefixCache
-    from distributedtensorflowexample_tpu.serving.sharded import (
-        ShardedDecodeEngine)
-    from distributedtensorflowexample_tpu.serving.spec import SpecDecoder
-    engine = _engine(params)
-    calls = {
-        "PrefixCache": lambda: PrefixCache(engine),
-        "SpecDecoder": lambda: SpecDecoder(engine, _engine(params)),
-        "ShardedDecodeEngine": lambda: ShardedDecodeEngine(
-            engine.model, (), None),
-        "read_rows": lambda: engine.read_rows(0, 4),
-        "write_rows": lambda: engine.write_rows(0, None, None),
-        "verify_step": lambda: engine.verify_step(
-            np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)),
-        "extend": lambda: engine.extend(0, [1, 2], 3),
-    }
-    with pytest.raises(ModeRefusal, match="recurrent-state layers"):
-        calls[what]()
-
-
-def test_the_decode_program_honours_the_hlo_contract(params):
-    """Donation aliased for the K/V rows and every convolution state, no
-    collective, nothing wider than f32; the scopes the traced metrics
-    read are there.  XLA:CPU, whose text this is, copies the five
-    recurrent states before it updates them (one finding each, and no
-    other): the TPU's compiler updates them in place, which
-    tests/test_tpu_compile.py holds it to at the cell's own size."""
-    from distributedtensorflowexample_tpu.analysis.hlo_lint import (
-        check_contract)
-    from distributedtensorflowexample_tpu.serving import engine as eng
-    engine = _engine(params)
-    found = check_contract(engine.decode_hlo(), DECODE_HLO_CONTRACT)
-    assert sorted(f.key for f in found) == [
-        f"hlo-donation:serve_decode:copy:ck_{i}_.1" for i in (0, 1, 2, 4, 5)]
-    lower = lambda f, *a: f.lower(engine.smodel, engine.params, engine._ck,
-                                  engine._cv, *a).as_text(debug_info=True)
-    text = lower(eng._decode_step, *engine.decode_args()[3:])
-    for scope in ("ssm.proj", "ssm.conv", "ssm.step", "ssm.out", "attn.nope",
-                  "moe.route", "moe.experts", "moe.shared", "cache_update",
-                  "head"):
-        assert f"/{scope}/" in text, scope
-    i32 = lambda *s: np.zeros(s, np.int32)
-    text = lower(eng._prefill_bucketed, i32(1, 32), i32(1), i32(1) + 5)
-    assert "/ssm.scan/" in text and "/ssm.step/" not in text
-    assert "/attn.nope/" in text
-
-
-def test_a_cache_longer_than_the_models_positions_is_refused(params):
-    with pytest.raises(ModeRefusal, match="exceeds"):
-        DecodeEngine(_model(), params, slots=2, cache_len=513)
-
 
 @pytest.mark.parametrize("cache_len, ladder", [
     (2048, (256, 512, 1024, 2048)),     # the benchmark's cell

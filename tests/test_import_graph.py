@@ -133,15 +133,18 @@ def test_an_architectures_module_loads_when_a_configuration_asks(module,
     """``models`` and the serving path load no architecture's module (its
     imports, counters and kernels' wrappers would be set-up seconds of
     every cell that does not run it); ``build_model_from_config`` loads
-    the one a configuration names, and no other."""
-    others = sorted({"afmoe", "bailing_hybrid", "granitemoehybrid",
-                     "kimi_k2", "qwen3_next"} - {module})
+    the one a configuration names, and no other; the shell they share
+    (``models/served_lm.py``) comes with the first of them, not with the
+    engine."""
+    from distributedtensorflowexample_tpu.config import CONFIG_MODEL_TYPES
+    others = sorted(set(CONFIG_MODEL_TYPES) - {module})
     fresh_python(SERVE_IMPORTS + f"""
 import {PKG}.models as models
 blocks = tuple("{PKG}.models." + m for m in {[module] + others!r})
-assert not loaded(*blocks), loaded(*blocks)
+shell = "{PKG}.models.served_lm"
+assert not loaded(shell, *blocks), loaded(shell, *blocks)
 model = models.build_model_from_config("benchmarks/configs/{config}.json")
-assert blocks[0] in sys.modules
+assert blocks[0] in sys.modules and shell in sys.modules
 assert not loaded(*blocks[1:]), loaded(*blocks[1:])
 assert model.serving_module() is model
 """)

@@ -7,6 +7,7 @@ kimi_k2.py) at tiny widths on the CPU, float32 compute so that the
 comparison is of the mathematics: a dense layer, then four expert
 layers."""
 
+import functools
 import json
 import math
 import os
@@ -17,6 +18,8 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+import served_families as fam
+
 from benchmarks.reference import kimi_k2 as ref
 from distributedtensorflowexample_tpu.models import build_model_from_config
 from distributedtensorflowexample_tpu.models import kimi_k2
@@ -25,64 +28,31 @@ from distributedtensorflowexample_tpu.ops import attention as attention_op
 from distributedtensorflowexample_tpu.ops import moe
 from distributedtensorflowexample_tpu.ops.pallas import (
     decode_attention as latent_kernel)
-from distributedtensorflowexample_tpu.refusal import ModeRefusal
-from distributedtensorflowexample_tpu.serving.engine import (
-    DECODE_HLO_CONTRACT, DecodeEngine)
-from distributedtensorflowexample_tpu.serving.queue import (
-    ContinuousBatcher, RequestQueue)
+from distributedtensorflowexample_tpu.serving.engine import DecodeEngine
 
-YARN = dict(type="yarn", factor=8, original_max_position_embeddings=64,
-            beta_fast=4, beta_slow=1, mscale=1, mscale_all_dim=1)
-TINY = dict(
-    model_type="kimi_k2", vocab_size=97, hidden_size=32, num_hidden_layers=5,
-    first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=12,
-    kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=16, v_head_dim=8,
-    intermediate_size=48, moe_intermediate_size=16, n_shared_experts=1,
-    n_routed_experts=4, num_experts_per_tok=3, n_group=1, topk_group=1,
-    norm_topk_prob=True, routed_scaling_factor=2.827, rope_theta=10000,
-    rope_scaling=YARN, rms_norm_eps=1e-5, max_position_embeddings=512,
-    scoring_func="sigmoid", topk_method="noaux_tc", hidden_act="silu",
-    tie_word_embeddings=False, attention_bias=False,
-    published={"n_routed_experts": 32}, deployment={"rank": 1})
 TOL = 2e-5      # float32 against float32 at HIGHEST: summation order only
 
-
-def _model(attn_block=64, **sizes):
-    return build_model_from_config({**TINY, **sizes}, dtype=jnp.float32,
-                                   param_dtype=jnp.float32,
-                                   attn_block=attn_block)
-
-
-def _seeded(model):
-    """Seeded, with the norms' scales moved off one (where a norm whose
-    scale is dropped would pass)."""
-    p = model.init(jax.random.PRNGKey(3),
-                   jnp.zeros((1, 8), jnp.int32))["params"]
-    keys = iter(jax.random.split(jax.random.PRNGKey(4), 64))
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.2 * jax.random.normal(next(keys), x.shape)
-        if path[-1].key.startswith("norm_") else x, p)
+FAMILY = "kimi_k2"
+TINY = fam.TINY[FAMILY]
+_model = functools.partial(fam.model, FAMILY)
+_counter = fam.counter
+_seeded = functools.partial(fam.seeded, FAMILY)
+YARN = fam.YARN
 
 
 @pytest.fixture(scope="module")
 def params():
-    return _seeded(_model())
+    return fam.params(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def sequences():
-    return np.random.default_rng(5).integers(0, TINY["vocab_size"],
-                                             (4, 260)).astype(np.int32)
+    return fam.sequences(FAMILY)
 
 
 @pytest.fixture(scope="module")
 def ref_logits(params, sequences):
     return np.asarray(ref.forward(params, jnp.asarray(sequences), TINY))
-
-
-def _counter(series: str) -> float:
-    got = obs_metrics.registry().snapshot()["counters"].get(series)
-    return (got["value"] if isinstance(got, dict) else got) or 0
 
 
 # ---- positions --------------------------------------------------------------
@@ -245,15 +215,17 @@ def test_the_absorbed_form_is_the_expanded_form(params):
     x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 90, 32)),
                     jnp.float32)
     rot = kimi_k2.rotary(dims, jnp.arange(90)[None])
-    want, rows, _ = blk.apply(block, x, rot, method="sequence")
+    want, (rows, none), _ = blk.apply(block, x, None, rot,
+                                      method="sequence")
+    assert none is None
     assert rows.shape == (2, 90, 128)                  # 16 + 16 -> 128
     assert np.array_equal(np.asarray(rows[..., 32:]), np.zeros((2, 90, 96)))
     ck = jnp.zeros((2, 128, 128))
     for t in range(90):
         pos = jnp.full((2,), t, jnp.int32)
-        got, ck, _ = blk.apply(block, x[:, t], ck, pos,
-                               kimi_k2.rotary(dims, pos[:, None]),
-                               method="step")
+        got, ck, _, _ = blk.apply(block, x[:, t], ck, jnp.zeros((0,)), pos,
+                                  kimi_k2.rotary(dims, pos[:, None]),
+                                  method="step")
         if t:       # position 0 is a parked slot's: it goes to no expert
             assert np.abs(np.asarray(got - want[:, t])).max() < TOL, t
     assert np.abs(np.asarray(ck[:, :90] - rows)).max() < 1e-6
@@ -492,43 +464,6 @@ def test_latent_rows_kept_in_fp8_fail_the_tolerance(params, sequences,
     assert np.abs(got - ref_logits[0, 40]).max() > 20 * TOL
 
 
-def _serve_alone(params, prompt, steps, slot, slots=3):
-    engine = DecodeEngine(_model(), params, slots=slots, cache_len=128)
-    engine.prefill_many([(slot, prompt, 1)])
-    return np.stack([engine.decode_logits(busy=[slot])[slot]
-                     for _ in range(steps)])
-
-
-def test_a_reused_slot_serves_what_a_fresh_engine_serves_bitwise(
-        params, sequences):
-    """Slot 1 serves a 40-token prompt for 25 steps, is parked, and is
-    then given a shorter request: the first one's rows past the second's
-    frontier are stale and masked, so the second request's logits are,
-    bit for bit, a fresh engine's."""
-    engine = DecodeEngine(_model(), params, slots=3, cache_len=128)
-    engine.prefill_many([(1, sequences[0, :40], 1)])
-    for _ in range(25):
-        engine.decode_logits(busy=[1])
-    engine.set_slot(1, 0, 0)                        # retired: parked
-    assert np.isfinite(engine.decode_logits(busy=[])).all()
-    engine.prefill_many([(1, sequences[1, :13], 1)])
-    got = np.stack([engine.decode_logits(busy=[1])[1] for _ in range(20)])
-    assert np.array_equal(got, _serve_alone(params, sequences[1, :13], 20, 1))
-
-
-def test_a_request_admitted_mid_decode_serves_what_it_serves_alone(
-        params, sequences):
-    engine = DecodeEngine(_model(), params, slots=3, cache_len=128)
-    engine.prefill_many([(0, sequences[0, :17], 1)])
-    first = [engine.decode_logits(busy=[0])[0] for _ in range(9)]
-    engine.prefill_many([(2, sequences[2, :33], 1)])
-    both = [engine.decode_logits(busy=[0, 2]) for _ in range(12)]
-    assert np.array_equal(np.stack([b[2] for b in both]),
-                          _serve_alone(params, sequences[2, :33], 12, 2))
-    assert np.array_equal(np.stack(first + [b[0] for b in both]),
-                          _serve_alone(params, sequences[0, :17], 21, 0))
-
-
 # ---- the expert layer ------------------------------------------------------
 
 def _layer_inputs(n=50, seed=2, E=64):
@@ -590,7 +525,7 @@ def test_the_dense_layer_is_counted_once_too(params):
         rot = kimi_k2.rotary(model.dims, jnp.arange(12)[None])
         blk = model.bind({"params": params}).blocks[0]
         assert not blk.experts
-        outs.append(np.asarray(blk(x, rot)))
+        outs.append(np.asarray(blk.sequence(x, None, rot)[0]))
     assert np.array_equal(outs[0], outs[1]) and np.array_equal(outs[0],
                                                                outs[2])
 
@@ -662,62 +597,6 @@ def test_the_engines_counters_follow_a_hand_count(params, sequences):
 
 
 # ---- what refuses, and what holds -----------------------------------------
-
-def _engine(params, **kw):
-    return DecodeEngine(_model(), params, slots=2, cache_len=32, **kw)
-
-
-@pytest.mark.parametrize("what", ["PrefixCache", "SpecDecoder",
-                                  "ShardedDecodeEngine", "read_rows",
-                                  "write_rows", "verify_step", "extend"])
-def test_what_moves_the_stacked_pair_refuses_latent_layers_by_name(params,
-                                                                   what):
-    from distributedtensorflowexample_tpu.serving.prefix import PrefixCache
-    from distributedtensorflowexample_tpu.serving.sharded import (
-        ShardedDecodeEngine)
-    from distributedtensorflowexample_tpu.serving.spec import SpecDecoder
-    engine = _engine(params)
-    calls = {
-        "PrefixCache": lambda: PrefixCache(engine),
-        "SpecDecoder": lambda: SpecDecoder(engine, _engine(params)),
-        "ShardedDecodeEngine": lambda: ShardedDecodeEngine(
-            engine.model, (), None),
-        "read_rows": lambda: engine.read_rows(0, 4),
-        "write_rows": lambda: engine.write_rows(0, None, None),
-        "verify_step": lambda: engine.verify_step(
-            np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)),
-        "extend": lambda: engine.extend(0, [1, 2], 3),
-    }
-    with pytest.raises(ModeRefusal,
-                       match="latent-attention layers .5 of this model's 5"):
-        calls[what]()
-
-
-def test_the_decode_program_honours_the_hlo_contract(params):
-    """Donation aliased for every layer's rows, no collective, nothing
-    wider than f32; the scopes the traced metrics read are there."""
-    from distributedtensorflowexample_tpu.analysis.hlo_lint import (
-        check_contract)
-    from distributedtensorflowexample_tpu.serving import engine as eng
-    engine = _engine(params)
-    assert check_contract(engine.decode_hlo(), DECODE_HLO_CONTRACT) == []
-    lower = lambda f, *a: f.lower(engine.smodel, engine.params, engine._ck,
-                                  engine._cv, *a).as_text(debug_info=True)
-    text = lower(eng._decode_step, *engine.decode_args()[3:])
-    for scope in ("rope.yarn", "mla.q_down", "mla.q_up", "mla.kv",
-                  "mla.absorb", "mla.attend", "mla.out", "moe.route",
-                  "moe.experts", "moe.shared", "cache_update", "head"):
-        assert f"/{scope}/" in text, scope
-    i32 = lambda *s: np.zeros(s, np.int32)
-    text = lower(eng._prefill_bucketed, i32(1, 32), i32(1), i32(1) + 5)
-    assert "/mla.attend/" in text and "/mla.absorb/" not in text
-    assert "/rope.yarn/" in text and "/mla.q_down/" in text
-
-
-def test_a_cache_longer_than_the_models_positions_is_refused(params):
-    with pytest.raises(ModeRefusal, match="exceeds"):
-        DecodeEngine(_model(), params, slots=2, cache_len=513)
-
 
 def test_the_cells_configuration_builds_the_cells_model():
     """benchmarks/configs/kimi_k2_5_ep32.json through the one

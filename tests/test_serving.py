@@ -173,13 +173,14 @@ def test_prefill_bucket_table_and_refusals(engine):
     (64, 16, (16, 32, 64)), (8, 8, (8,))])
 def test_a_serving_lm_states_no_ladder_and_keeps_the_powers_of_two(
         lm_state, cache_len, smallest, ladder):
-    """``ServingLM`` has no ``prefill_buckets``: GPT-2's engines pad to
-    the next power of two, the cache's length last, as they always did
-    (a model that states a ladder: tests/test_afmoe.py)."""
+    """``ServingLM``'s ``prefill_buckets`` leaves the choice to the
+    engine (None): GPT-2's engines pad to the next power of two, the
+    cache's length last, as they always did (a model that states a
+    ladder: tests/test_afmoe.py)."""
     model, state = lm_state
     engine = DecodeEngine(model, state.params, slots=1, cache_len=cache_len,
                           prefill_smallest=smallest)
-    assert not hasattr(engine.smodel, "prefill_buckets")
+    assert engine.smodel.prefill_buckets(cache_len) is None
     assert engine.buckets == ladder
 
 

@@ -52,6 +52,8 @@ RC_PREEMPTED = 143
 
 
 def main(argv: list[str] | None = None) -> int:
+    from distributedtensorflowexample_tpu.config import (
+        CONFIG_MODEL_TYPES)   # stdlib only: jax is imported below
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--snapshot", default="",
                    help="SnapshotStore directory to promote (default "
@@ -61,9 +63,9 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--model_config", default="",
                    help="serve a model built from a published "
                         "configuration's JSON file (its model_type names "
-                        "the architecture: afmoe) instead of a --size of "
-                        "the ladder; snapshots are stamped with the "
-                        "file's name")
+                        "the architecture: " + ", ".join(CONFIG_MODEL_TYPES)
+                        + ") instead of a --size of the ladder; snapshots "
+                        "are stamped with the file's name")
     p.add_argument("--slots", type=int, default=0,
                    help="concurrent decode slots (default $SERVE_SLOTS "
                         "or 4)")
